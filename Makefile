@@ -1,7 +1,7 @@
 # Convenience targets (the package is pure Python + an optional on-demand
 # C++ component; there is no build step — ref parity: Makefile builds bin/simon).
 
-.PHONY: test test-fast test-tpu bench bench-scale bench-scale-smoke resume-smoke profile-smoke serve-smoke sweep-smoke svc-smoke serve-latency-smoke tune-smoke policy-smoke pallas-hbm-smoke chaos-smoke mesh-chaos-smoke fleet-chaos-smoke fleet-wan-smoke fleet-ha-smoke fleet-trace-smoke slo-smoke bench-gate sweep native clean
+.PHONY: test test-fast test-tpu chip-smoke bench bench-scale bench-scale-smoke resume-smoke profile-smoke serve-smoke sweep-smoke svc-smoke serve-latency-smoke tune-smoke policy-smoke pallas-hbm-smoke chaos-smoke mesh-chaos-smoke fleet-chaos-smoke fleet-wan-smoke fleet-ha-smoke fleet-trace-smoke slo-smoke bench-gate sweep native clean
 
 # full suite, INCLUDING @pytest.mark.slow tests (pallas interpreter
 # sweeps, openb kill/resume, the full Bellman replay)
@@ -13,8 +13,16 @@ test-fast:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow'
 
 # on-accelerator lane: golden frag values + engine equivalence on the chip
+# (VMEM tier on full openb, HBM tier at N=8192). FAILS without a TPU.
 test-tpu:
 	TPUSIM_TPU_TESTS=1 python -m pytest tests/ -m tpu -q
+
+# the quickest proof the system still starts on the chip: one process,
+# the main path once through every user entry point, pallas == table bit
+# for bit; the last stdout line is {"ok": true, "device": {...}}. Exits
+# non-zero without a TPU.
+chip-smoke:
+	python chip_smoke.py
 
 bench:
 	python bench.py
@@ -49,11 +57,11 @@ resume-smoke:
 # vmapped-sweep suite (cross-engine bit-identity under traced weights,
 # the B=16 openb acceptance incl. the one-compile and marginal-cost
 # bounds), then a small end-to-end `bench_scale --sweep` row through the
-# persistent compilation cache. Runs the slow-marked cases tier-1 skips.
+# persistent compilation cache ($$JAX_COMPILATION_CACHE_DIR, else
+# .jax_cache/). Runs the slow-marked cases tier-1 skips.
 sweep-smoke:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_sweep.py -q
-	JAX_PLATFORMS=cpu TPUSIM_COMPILE_CACHE_DIR=.tpusim_obs/compile_cache \
-		python bench_scale.py --nodes 1500 --pods 2000 --sweep 4
+	JAX_PLATFORMS=cpu python bench_scale.py --nodes 1500 --pods 2000 --sweep 4
 
 # observability smoke (ENGINES.md "Round 8"/"Round 10"): a small
 # profiled scale run emitting the full artifact set — JSONL run record

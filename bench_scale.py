@@ -279,13 +279,6 @@ def main():
         "(a `sweep` block; `make bench-gate` reads the newest committed "
         "one for its advisory sweep comparison)",
     )
-    ap.add_argument(
-        "--compile-cache-dir", default="", metavar="DIR",
-        help="JAX persistent compilation cache "
-        "(SimulatorConfig.compile_cache_dir / $TPUSIM_COMPILE_CACHE_DIR): "
-        "re-runs of the same job family load the compiled scan from disk "
-        "instead of re-compiling",
-    )
     args = ap.parse_args()
     if args.chunk <= 0:
         ap.error("--chunk must be positive")
@@ -294,20 +287,14 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from tpusim.compile_cache import enable_compile_cache
     from tpusim.constants import MILLI
     from tpusim.io.trace import build_events, pods_to_specs
-    from tpusim.sim.driver import (
-        Simulator,
-        SimulatorConfig,
-        enable_compile_cache,
-    )
+    from tpusim.sim.driver import Simulator, SimulatorConfig
     from tpusim.sim.typical import TypicalPodsConfig
 
-    # persistent compilation cache (ISSUE 6 satellite): wired BEFORE the
-    # first jitted dispatch so the scan compile lands in / loads from it
-    cache_dir = enable_compile_cache(args.compile_cache_dir)
-    if cache_dir:
-        print(f"[obs] compile cache at {cache_dir}", file=sys.stderr)
+    cache_dir = enable_compile_cache()
+    print(f"[obs] compile cache at {cache_dir}", file=sys.stderr)
 
     if args.unswitched and args.block_size >= 0:
         # unswitched_select only alters the FLAT scan body; under the
@@ -453,9 +440,7 @@ def main():
     if profiling or monitor is not None:
         from tpusim.obs import emitters, note_compile_cache
 
-        note_compile_cache(
-            sim.obs, enabled=bool(cache_dir), cache_dir=cache_dir or ""
-        )
+        note_compile_cache(sim.obs, enabled=True, cache_dir=cache_dir)
         telemetry = sim.run_telemetry()
         record = emitters.build_record(
             telemetry,

@@ -55,7 +55,13 @@ def _write_tiny_trace(dirpath: Path):
 
 
 def test_run_analysis_merge_plot(tmp_path):
+    import jax
+
+    cache_before = jax.config.jax_compilation_cache_dir
     run = _load("exp_run", EXP / "run.py")
+    # loading the runner as a library re-points nothing process-wide: the
+    # compile cache is placed by entry points (tpusim.compile_cache)
+    assert jax.config.jax_compilation_cache_dir == cache_before
     node_csv, pod_csv = _write_tiny_trace(tmp_path)
     outdir = tmp_path / "data" / "tiny_trace" / "06-FGD" / "1.0" / "42"
     args = run.get_args(

@@ -301,6 +301,43 @@ def test_pallas_vmem_degrades_to_table(monkeypatch):
     )
 
 
+def test_pallas_failure_raises_instead_of_rerunning():
+    """Only the size-based routing above reroutes a fused-kernel replay. A
+    kernel that fails (a Mosaic compile error, a death mid-scan) or
+    returns out-of-range telemetry raises: no `except` turns it into a
+    quiet run on the table engine with exit 0."""
+    from tpusim.io.trace import pods_to_specs
+
+    nodes = _two_nodes()
+    pods = _share_pods(4)
+    sim = Simulator(nodes, SimulatorConfig(
+        policies=(("FGDScore", 1000),), gpu_sel_method="FGDScore",
+        report_per_event=False, engine="pallas",
+    ))
+    sim.set_workload_pods(pods)
+    sim.set_typical_pods()
+    args = (sim.init_state, pods_to_specs(pods), jnp.zeros(4, jnp.int32),
+            jnp.arange(4, dtype=jnp.int32), jax.random.PRNGKey(0))
+    good = sim._pallas_fn
+
+    def dies(*a, **k):
+        raise jax.errors.JaxRuntimeError("INTERNAL: Mosaic failed to compile")
+
+    sim._pallas_fn = dies
+    with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic"):
+        sim.run_events(*args)
+
+    def corrupt(*a, **k):
+        out = good(*a, **k)
+        return out._replace(event_node=out.event_node + 10_000)
+
+    sim._pallas_fn = corrupt
+    with pytest.raises(RuntimeError, match="corrupt telemetry"):
+        sim.run_events(*args)
+    assert not any(k.startswith("degrade_") for k in sim.obs.counts)
+    assert not any("[Degrade]" in l for l in sim.log.lines)
+
+
 @pytest.mark.slow  # compiles its own chunked segment lengths
 def test_fault_replay_composes_with_checkpointing(tmp_path):
     """The create/delete/fault-mix half of the resume acceptance: fault

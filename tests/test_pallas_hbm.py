@@ -1,5 +1,5 @@
 """HBM-residency fused Pallas engine (ENGINES.md Round 19): the
-[K, N] score/sdev/feas tables live in HBM (`TPUMemorySpace.ANY`) with
+[K, N] score/sdev/feas tables live in HBM (`pl.ANY`) with
 per-event double-buffered DMA, selectHost runs over VMEM-resident block
 summaries — and placements/devices/failure flags/final state must stay
 bit-identical to the (blocked) table engine.
@@ -35,14 +35,15 @@ _MIX = [(make_policy("PWRScore"), 500), (make_policy("FGDScore"), 500)]
 
 
 def _run_both(policies, gpu_sel, state, tp, pods, ev_kind, ev_pod, rank,
-              block_size=128):
-    """(blocked table engine, hbm pallas) results + the DMA stats row."""
+              block_size=128, interpret=True):
+    """(blocked table engine, hbm pallas) results + the DMA stats row.
+    interpret=False is the on-chip lane's (tests/test_tpu.py)."""
     key = jax.random.PRNGKey(3)
     types = build_pod_types(pods)
     tab = make_table_replay(policies, gpu_sel=gpu_sel,
                             block_size=block_size)
     r0 = tab(state, pods, types, ev_kind, ev_pod, tp, key, rank)
-    hbm = make_pallas_replay(policies, gpu_sel=gpu_sel, interpret=True,
+    hbm = make_pallas_replay(policies, gpu_sel=gpu_sel, interpret=interpret,
                              residency="hbm")
     r1, dma = hbm(state, pods, types, ev_kind, ev_pod, tp, key, rank)
     return r0, r1, np.asarray(dma)

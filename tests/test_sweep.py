@@ -48,7 +48,6 @@ from tpusim.sim.driver import (
     Simulator,
     SimulatorConfig,
     SweepLane,
-    enable_compile_cache,
     format_sweep_table,
     schedule_pods_sweep,
     tiebreak_rank,
@@ -185,34 +184,6 @@ def test_format_sweep_table():
                                        ("BestFitScore", 500)])
     assert "weights(FGDScore,BestFitScore)" in text
     assert "1000,500" in text and "12.50" in text and "321" in text
-
-
-def test_enable_compile_cache(tmp_path, monkeypatch):
-    """Resolution order: explicit dir > $TPUSIM_COMPILE_CACHE_DIR >
-    disabled; the chosen dir is created and wired into jax.config."""
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        monkeypatch.delenv("TPUSIM_COMPILE_CACHE_DIR", raising=False)
-        assert enable_compile_cache("") is None
-
-        d1 = str(tmp_path / "explicit")
-        assert enable_compile_cache(d1) == d1
-        assert os.path.isdir(d1)
-        assert jax.config.jax_compilation_cache_dir == d1
-
-        d2 = str(tmp_path / "from_env")
-        monkeypatch.setenv("TPUSIM_COMPILE_CACHE_DIR", d2)
-        assert enable_compile_cache("") == d2
-        assert enable_compile_cache(d1) == d1  # explicit wins over env
-
-        # the cache actually takes: jax latches cache-used once per
-        # process at the FIRST compile (which import-time jits always
-        # win), so enable_compile_cache must clear the latch — a fresh
-        # compile after wiring must land an entry on disk
-        jax.jit(lambda x: x * 3 + 1)(jnp.arange(7))
-        assert os.listdir(d1), "no persistent-cache entry written"
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def test_note_compile_cache_heuristic():
@@ -353,12 +324,22 @@ def test_apply_sweep_weights_cli(tmp_path):
     # a full standalone run)
     from tpusim.cli import main
 
-    rc = main([
-        "apply", "-f", os.path.join(REPO, "example/test-cluster-config.yaml"),
-        "-s", os.path.join(REPO, "example/test-scheduler-config.yaml"),
-        "--base-dir", REPO,
-        "--sweep-weights", str(wfile),
-    ])
+    # `apply` is an entry point and places the process-wide compile cache
+    # (tpusim.compile_cache); in-process, put back what the test found
+    cache_knobs = ("jax_compilation_cache_dir",
+                   "jax_persistent_cache_min_compile_time_secs")
+    before = {k: getattr(jax.config, k) for k in cache_knobs}
+    try:
+        rc = main([
+            "apply",
+            "-f", os.path.join(REPO, "example/test-cluster-config.yaml"),
+            "-s", os.path.join(REPO, "example/test-scheduler-config.yaml"),
+            "--base-dir", REPO,
+            "--sweep-weights", str(wfile),
+        ])
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
     assert rc == 0
 
     # a bare list-of-rows payload parses too, and an empty one is loud

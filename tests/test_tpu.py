@@ -1,10 +1,14 @@
-"""On-TPU test lane: `TPUSIM_TPU_TESTS=1 pytest -m tpu`.
+"""On-TPU test lane: `TPUSIM_TPU_TESTS=1 pytest -m tpu` (`make test-tpu`).
 
 Asserts that the accelerator backend reproduces the CPU/Go-oracle
 numerics: the golden frag values from the reference's frag_test.go, and
 sequential-engine vs incremental-table-engine placement equality — the
-same invariants the CPU suite pins, re-checked on real TPU hardware
-(VERDICT round 1: "No test runs on the TPU backend").
+same invariants the CPU suite pins, re-checked on real TPU hardware —
+plus the two fused-kernel pins only Mosaic can give: the VMEM tier on the
+full openb trace and the HBM tier at N = 8,192.
+
+With no chip the lane FAILS: every test takes the `accel` fixture, which
+errors when JAX did not come up on a TPU.
 """
 
 import jax
@@ -18,13 +22,16 @@ pytestmark = pytest.mark.tpu
 @pytest.fixture(scope="module")
 def accel():
     dev = jax.devices()[0]
-    if dev.platform == "cpu":
-        pytest.skip("no accelerator backend available")
+    if dev.platform != "tpu":
+        pytest.fail(
+            f"the tpu lane needs a TPU; JAX came up on {dev.platform!r} "
+            f"({dev.device_kind})", pytrace=False,
+        )
     return dev
 
 
 def test_backend_is_accelerator(accel):
-    assert accel.platform != "cpu"
+    assert accel.platform == "tpu"
 
 
 def test_golden_frag_values_on_tpu(accel):
@@ -137,6 +144,34 @@ def test_pallas_engine_full_openb_on_tpu(accel, policy, gpu_sel):
     assert np.array_equal(np.asarray(tab.event_node), np.asarray(pal.event_node))
     for a, b in zip(jax.tree.leaves(tab.state), jax.tree.leaves(pal.state)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("mix", [False, True], ids=["fgd", "pwr+fgd"])
+def test_pallas_hbm_tier_n8192_on_tpu(accel, mix):
+    """The HBM-resident kernel compiled by Mosaic (real DMAs, real
+    semaphores) against the blocked table engine, bit for bit, at the
+    acceptance shape of tests/test_pallas_hbm.py: N = 8,192, K = 151
+    distinct pod types, creates and deletes. The PWR+FGD mix adds the
+    normalizer path (block extrema, drift rebuilds) FGD alone never
+    enters. select_residency must route this shape to `hbm`."""
+    from tests.fixtures import random_cluster
+    from tests.test_pallas_hbm import _FGD, _MIX, _check, _pods_k_types, _run_both
+    from tests.test_table_engine import _events_with_deletes
+    from tpusim.sim import pallas_engine
+
+    policies, gpu_sel = (_MIX, "FGDScore") if mix else (_FGD, "FGDScore")
+    rng = np.random.default_rng(29)
+    state, tp = random_cluster(rng, num_nodes=8192)
+    pods = _pods_k_types(151, rng)
+    ev_kind, ev_pod = _events_with_deletes(151, rng)
+    rank = jnp.asarray(rng.permutation(8192).astype(np.int32))
+    assert pallas_engine.select_residency(
+        8192, 151, len(policies), 151, int(ev_kind.shape[0]),
+        pallas_engine.num_normalized(policies),
+    ) == "hbm"
+    r0, r1, dma = _run_both(policies, gpu_sel, state, tp, pods, ev_kind,
+                            ev_pod, rank, interpret=False)
+    _check(r0, r1, dma)
 
 
 def test_driver_small_run_on_tpu(accel):

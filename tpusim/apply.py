@@ -99,11 +99,6 @@ class ApplyOptions:
     # The file is a bare [{...FaultConfig fields...}, ...] list or
     # {"faults": [...], "weights": [[...]], "seeds": [...]}.
     sweep_faults: str = ""
-    # JAX persistent compilation cache dir (ISSUE 6 satellite;
-    # SimulatorConfig.compile_cache_dir / $TPUSIM_COMPILE_CACHE_DIR):
-    # wired before the first dispatch so re-runs skip the scan compile;
-    # the obs record notes the probable hit/miss.
-    compile_cache_dir: str = ""
     # score-plugin override (ISSUE 14): 'LearnedScore:FILE.json' replays
     # a signed learned-policy artifact as the (only) scoring family,
     # 'learned'/'learned-bucketed' the default-parameter families, or a
@@ -179,7 +174,6 @@ class Applier:
             heartbeat_every=self.options.heartbeat_every,
             record_decisions=bool(self.options.decisions_out),
             series_every=self.options.series_every,
-            compile_cache_dir=self.options.compile_cache_dir,
         )
 
     def _fault_config(self):
@@ -226,20 +220,6 @@ class Applier:
         return apps
 
     def run(self, out=sys.stdout) -> SimulateResult:
-        # persistent compilation cache (ISSUE 6 satellite): wired BEFORE
-        # any jitted dispatch so the scan compile itself lands in / loads
-        # from the cache; the post-run telemetry notes the probable
-        # hit/miss via the dispatch-wall heuristic
-        from tpusim.sim.driver import enable_compile_cache
-
-        self._compile_cache_dir = enable_compile_cache(
-            self.options.compile_cache_dir
-        )
-        if self._compile_cache_dir:
-            print(
-                f"[obs] compile cache at {self._compile_cache_dir}",
-                file=out,
-            )
         if self.cr.kube_config:
             from tpusim.io.k8s_yaml import load_cluster_from_dump
             from tpusim.io.kube_client import (
@@ -384,12 +364,16 @@ class Applier:
     def _note_compile_cache(self, sim: Simulator):
         """Record the persistent-compilation-cache outcome on the run's
         telemetry (the `timing.compile_cache` block of the JSONL record;
-        dispatch-wall heuristic, obs.spans.note_compile_cache)."""
+        dispatch-wall heuristic, obs.spans.note_compile_cache). The
+        directory is whatever the process runs under — the entry point
+        placed it (tpusim.compile_cache), not this run."""
+        import jax
+
         from tpusim.obs import note_compile_cache
 
+        cache_dir = jax.config.jax_compilation_cache_dir or ""
         note_compile_cache(
-            sim.obs, enabled=bool(self._compile_cache_dir),
-            cache_dir=self._compile_cache_dir or "",
+            sim.obs, enabled=bool(cache_dir), cache_dir=cache_dir
         )
 
     def _run_sweep(self, sim: Simulator, out):

@@ -188,13 +188,6 @@ def _build_parser() -> argparse.ArgumentParser:
         "[...]}) and print the per-lane disruption frontier — B fault "
         "what-ifs, one compiled scan",
     )
-    p_apply.add_argument(
-        "--compile-cache-dir", default="", metavar="DIR",
-        help="JAX persistent compilation cache (default "
-        "$TPUSIM_COMPILE_CACHE_DIR): re-runs of the same job family "
-        "load the compiled scan from disk instead of re-compiling; the "
-        "obs record notes the probable hit/miss",
-    )
     # the learned policy as a drop-in scorer (ISSUE 14)
     p_apply.add_argument(
         "--policy", default="", metavar="SPEC",
@@ -386,12 +379,6 @@ def _build_parser() -> argparse.ArgumentParser:
         help="content-keyed init-table cache shared by the fleet "
         "(default $TPUSIM_TABLE_CACHE_DIR)",
     )
-    p_serve.add_argument(
-        "--compile-cache-dir", default="", metavar="DIR",
-        help="JAX persistent compile cache shared by the fleet — a "
-        "fresh joiner's first batch skips the ~5 s compile (default "
-        "$TPUSIM_COMPILE_CACHE_DIR)",
-    )
 
     # the fleet worker process (ISSUE 12): joins a `serve --jobs`
     # coordinator, pulls leased batches, writes signed results into the
@@ -425,10 +412,6 @@ def _build_parser() -> argparse.ArgumentParser:
     p_worker.add_argument(
         "--table-cache-dir", default="", metavar="DIR",
         help="shared content-keyed table cache",
-    )
-    p_worker.add_argument(
-        "--compile-cache-dir", default="", metavar="DIR",
-        help="shared JAX persistent compile cache",
     )
     # the no-shared-fs transport (ISSUE 13)
     p_worker.add_argument(
@@ -780,7 +763,9 @@ def _build_parser() -> argparse.ArgumentParser:
 
 def cmd_apply(args) -> int:
     from tpusim.apply import Applier, ApplyOptions
+    from tpusim.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     opts = ApplyOptions(
         simon_config=args.simon_config,
         default_scheduler_config=args.default_scheduler_config,
@@ -808,7 +793,6 @@ def cmd_apply(args) -> int:
         listen=args.listen,
         sweep_weights=args.sweep_weights,
         sweep_faults=args.sweep_faults,
-        compile_cache_dir=args.compile_cache_dir,
         policy=args.policy,
     )
     Applier(opts).run()
@@ -955,6 +939,7 @@ def _serve_jobs(args) -> int:
     import time
     import urllib.request
 
+    from tpusim.compile_cache import enable_compile_cache
     from tpusim.obs.server import watch_dir
     from tpusim.svc import load_trace, start_job_server
     from tpusim.svc.api import recover_pending_jobs
@@ -962,6 +947,7 @@ def _serve_jobs(args) -> int:
     from tpusim.svc.auth import load_token
     from tpusim.svc.coord import CoordinatorState, CoordKeeper
 
+    enable_compile_cache()
     traces = {}
     if args.nodes or args.pods:
         if not (args.nodes and args.pods):
@@ -1034,7 +1020,6 @@ def _serve_jobs(args) -> int:
         args.dir, traces, listen=args.listen,
         lane_width=args.lane_width, queue_size=args.queue_size,
         table_cache_dir=args.table_cache_dir,
-        compile_cache_dir=args.compile_cache_dir,
         fleet=fleet_mode, lease_s=args.lease_s,
         family_quota=args.family_quota,
         policy_presets=presets,
@@ -1054,7 +1039,6 @@ def _serve_jobs(args) -> int:
 
         cmd = worker_command(
             srv.url, table_cache_dir=args.table_cache_dir,
-            compile_cache_dir=args.compile_cache_dir,
             token_file=getattr(args, "token_file", ""),
         )
         sup = Supervisor(
@@ -1201,10 +1185,12 @@ def cmd_worker(args) -> int:
     import signal
     import threading
 
+    from tpusim.compile_cache import enable_compile_cache
     from tpusim.svc.auth import load_token
     from tpusim.svc.client import ServiceError
     from tpusim.svc.fleet import run_worker
 
+    enable_compile_cache()
     stop_event = threading.Event()
 
     def _graceful(_signum, _frame):
@@ -1220,7 +1206,6 @@ def cmd_worker(args) -> int:
             args.join, worker_id=args.id, poll_s=args.poll,
             max_batches=args.max_batches,
             table_cache_dir=args.table_cache_dir,
-            compile_cache_dir=args.compile_cache_dir,
             out=sys.stderr, stop_event=stop_event,
             mode=args.mode, cache_dir=args.cache_dir,
             token=load_token(getattr(args, "token_file", "")),

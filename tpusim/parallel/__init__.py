@@ -18,7 +18,6 @@ __all__ = [
     "state_sharding",
 ]
 
-# Virtual-mesh bootstrap (force_virtual_cpu_devices) deliberately does NOT
-# live or re-export here: importing this package — even for a submodule —
-# initializes the JAX backend through its module graph, after which the
-# platform switch is a no-op. Import it from tpusim.virtual_mesh instead.
+# Virtual-mesh bootstrap (virtual_cpu_devices) does not live or re-export
+# here: a caller needs it before it imports anything heavy. Import it from
+# tpusim.virtual_mesh instead.

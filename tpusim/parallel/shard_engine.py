@@ -61,6 +61,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from tpusim.constants import MAX_GPUS_PER_NODE, MAX_NODE_SCORE
@@ -97,7 +98,7 @@ from tpusim.types import NodeState, PodSpec
 
 from tpusim.parallel.sharding import NODE_AXIS
 
-_INT_MAX = jnp.int32(jnp.iinfo(jnp.int32).max)
+_INT_MAX = np.int32(np.iinfo(np.int32).max)
 
 
 class ShardTableCarry(NamedTuple):
@@ -864,17 +865,9 @@ def make_shardmap_table_replay(policies, mesh, gpu_sel: str = "best",
     )
 
     def _wrap(fn, in_specs, out_specs):
-        if hasattr(jax, "shard_map"):
-            return jax.shard_map(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        # pre-0.5 jax spells it jax.experimental.shard_map.shard_map
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        return _shard_map(
+        return jax.shard_map(
             fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
+            check_vma=False,
         )
 
     # decision records and series samples are replicated outputs

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tpusim.constants import (
     MAX_GPUS_PER_NODE,
@@ -35,7 +36,7 @@ from tpusim.constants import (
 from tpusim.policies.base import PolicyResult, ScoreContext
 from tpusim.types import NodeState, PodSpec
 
-_NEG = jnp.float32(-jnp.inf)
+_NEG = np.float32(-np.inf)
 
 
 def _safe_div(v, n):

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tpusim.constants import MILLI
 from tpusim.ops.energy import cpu_power_watts, gpu_busy_delta_watts, gpu_power_watts
@@ -18,7 +19,7 @@ from tpusim.ops.resource import sub_pod
 from tpusim.policies.base import PolicyResult, ScoreContext
 from tpusim.types import NodeState, PodSpec
 
-_NEG_INF = jnp.int32(-(2**31) + 1)  # stands in for Go's math.MinInt64 init
+_NEG_INF = np.int32(-(2**31) + 1)  # stands in for Go's math.MinInt64 init
 
 
 def _pwr_node(row: NodeState, pod: PodSpec):

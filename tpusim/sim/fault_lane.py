@@ -58,7 +58,7 @@ from tpusim.sim.engine import (
     EV_SKIP,
 )
 
-_INT_MAX = jnp.int32(jnp.iinfo(jnp.int32).max)
+_INT_MAX = np.int32(np.iinfo(np.int32).max)
 _VICTIM_MIX = 2654435761  # pick_eviction_victim's Knuth multiplier
 
 # dctr layout (i32[7] disruption counters carried in-scan)

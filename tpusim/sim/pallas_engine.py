@@ -79,12 +79,17 @@ _INT_MAX = np.int32(np.iinfo(np.int32).max)
 
 _EV_FIELDS = 12  # packed per-event row size (see _pack_events)
 
-# Per-core VMEM budget the fused kernel's resident set must fit in. Real
-# TPU cores carry ~16 MiB; the default leaves headroom for Mosaic's own
-# scratch. Exceeding it used to surface as an opaque Mosaic allocation
-# failure mid-compile (or a wedged device) — driver.run_events now probes
-# fits_vmem() first and degrades to the blocked table engine instead
-# (ISSUE 2 graceful degradation). Override with TPUSIM_PALLAS_VMEM_BYTES.
+# VMEM budget the fused kernel's resident set must fit in: the compiler's
+# default scoped-VMEM allowance of 16 MiB less headroom for Mosaic's own
+# scratch — not the core's physical VMEM. On the 'TPU v5 lite' of PR 22's
+# chip run (pltpu.get_tpu_info(): 128 MiB of VMEM per core) a 12.7 MiB
+# estimate (N = 8,192, K = 128) compiled and ran, and 18.9 MiB (N = 12,288)
+# failed in XLA with "RESOURCE_EXHAUSTED: Ran out of memory in memory
+# space vmem while allocating on stack". driver.run_events probes
+# fits_vmem()/fits_hbm() first and routes a shape neither tier fits to the
+# blocked table engine. Using more of the core needs
+# CompilerParams(vmem_limit_bytes=...) as well as a larger budget (ROADMAP
+# S5). Override with TPUSIM_PALLAS_VMEM_BYTES.
 DEFAULT_VMEM_BUDGET = 14 * 2**20
 
 
@@ -102,21 +107,6 @@ def vmem_resident_bytes(
     events = _EV_FIELDS * num_events * 4
     pods = 12 * num_pods * 4
     return tables + state + events + pods
-
-
-def _compiler_params_cls():
-    """pltpu compiler-params class across the 0.5.x rename; a clear error
-    beats `None(...)` when a future jax drops both spellings."""
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is None:
-        raise RuntimeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams; this jax version is unsupported by "
-            "the fused pallas engine (use engine: table)"
-        )
-    return cls
 
 
 def vmem_budget() -> int:
@@ -152,7 +142,7 @@ def vmem_resident_bytes_hbm(
 ) -> int:
     """Estimated VMEM-resident footprint of the HBM-residency kernel
     (ENGINES.md Round 19). The [K, N] score/sdev/feas tables and the
-    mutable node state live in HBM (`TPUMemorySpace.ANY`); what stays
+    mutable node state live in HBM (`pl.ANY`); what stays
     VMEM-resident is
 
       blocked summaries   bt/br/bn [N/B, K] + brmin/brmax
@@ -1132,7 +1122,7 @@ def make_pallas_replay(
     residency='vmem' is the original layout: every table VMEM-resident
     across grid steps (N ≤ 4096 at K = 151). residency='hbm' is the
     Round-19 layout (ENGINES.md): the [K, N] score/sdev/feas tables and
-    the mutable node state live in HBM (`TPUMemorySpace.ANY`) and only
+    the mutable node state live in HBM (`pl.ANY`) and only
     the event's active working set crosses into VMEM by per-event
     double-buffered async DMA; its replay returns
     `(ReplayResult, dma_stats i32[3])` where dma_stats counts the
@@ -1264,9 +1254,7 @@ def make_pallas_replay(
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 25,
             out_specs=tuple([pl.BlockSpec(memory_space=pltpu.VMEM)] * 12),
             scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
-            # jax renamed TPUCompilerParams -> CompilerParams in 0.5.x;
-            # accept either so the engine survives both sides of the rename
-            compiler_params=_compiler_params_cls()(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
             ),
             interpret=interpret,
@@ -1307,9 +1295,9 @@ def make_pallas_replay(
 
 # ---------------------------------------------------------------------------
 # HBM residency (ENGINES.md Round 19): the [K, N] score/sdev/feas tables and
-# the mutable node state live in HBM (`pl.BlockSpec(memory_space=
-# pltpu.TPUMemorySpace.ANY)`); only the event's ACTIVE working set crosses
-# into VMEM, by per-event async DMA (`pltpu.make_async_copy` + DMA
+# the mutable node state live in HBM (`pl.BlockSpec(memory_space=pl.ANY)`);
+# only the event's ACTIVE working set crosses into VMEM, by per-event async
+# DMA (`pltpu.make_async_copy` + DMA
 # semaphores — the SNIPPETS.md [2] primitive):
 #
 #   row slice    the event type's score rows + feas row, double-buffered:
@@ -2089,7 +2077,7 @@ def _make_hbm_replay(policies, gpu_sel: str, interpret: bool):
         tids = types.type_id[ev_pod].astype(jnp.int32)
 
         kernel = _make_hbm_kernel(columns, ks, gpu_sel)
-        any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+        any_spec = pl.BlockSpec(memory_space=pl.ANY)
         vmem_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
         out_shape = (
             jax.ShapeDtypeStruct((n_pol * kdim, nc, _CH), jnp.int32),
@@ -2161,7 +2149,7 @@ def _make_hbm_replay(policies, gpu_sel: str, interpret: bool):
             kernel,
             grid_spec=grid_spec,
             out_shape=out_shape,
-            compiler_params=_compiler_params_cls()(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
             ),
             interpret=interpret,

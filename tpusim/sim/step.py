@@ -13,6 +13,7 @@ from typing import NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from tpusim.constants import MAX_GPUS_PER_NODE, MILLI
 from tpusim.ops.resource import (
@@ -27,7 +28,7 @@ from tpusim.policies import ScoreContext, minmax_normalize_i32, pwr_normalize_i3
 from tpusim.policies.clustering import pod_affinity_class
 from tpusim.types import NodeState, PodSpec
 
-_INT_MAX = jnp.int32(jnp.iinfo(jnp.int32).max)
+_INT_MAX = np.int32(np.iinfo(np.int32).max)
 
 
 def resolve_weights(policies, weights=None) -> jnp.ndarray:

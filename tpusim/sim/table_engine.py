@@ -64,7 +64,7 @@ from tpusim.sim.step import (
 )
 from tpusim.types import NodeState, PodSpec
 
-_INT_MAX = jnp.int32(jnp.iinfo(jnp.int32).max)
+_INT_MAX = np.int32(np.iinfo(np.int32).max)
 
 # Below this node count the flat O(N) select wins: the blocked path's extra
 # per-event fixed costs (dirty-block refresh + two-level combine) outweigh

@@ -491,7 +491,7 @@ def recover_pending_jobs(service: JobService, out=None) -> int:
 def start_job_server(
     artifact_dir: str, traces: Dict[str, TraceRef], listen: str = "",
     lane_width: int = 8, queue_size: int = 64, bucket: int = 512,
-    table_cache_dir: str = "", compile_cache_dir: str = "",
+    table_cache_dir: str = "",
     start_worker: bool = True, recover: bool = True, out=None,
     fleet: bool = False, lease_s: float = 0.0, family_quota: int = 0,
     policy_presets: Optional[dict] = None, token: str = "",
@@ -529,7 +529,6 @@ def start_job_server(
         worker = Worker(
             queue, traces, artifact_dir, bucket=bucket, monitor=srv,
             table_cache_dir=table_cache_dir,
-            compile_cache_dir=compile_cache_dir,
         )
     service = JobService(queue, worker, traces, artifact_dir, monitor=srv,
                          policy_presets=policy_presets)

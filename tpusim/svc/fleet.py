@@ -1439,7 +1439,7 @@ def resolve_worker_mode(mode: str, reg: dict) -> str:
 
 def run_worker(url: str, worker_id: str = "", poll_s: float = 0.2,
                max_batches: int = 0, table_cache_dir: str = "",
-               compile_cache_dir: str = "", out=None,
+               out=None,
                stop_event=None, mode: str = "auto",
                cache_dir: str = "", token: str = "",
                caps: Optional[dict] = None) -> int:
@@ -1586,7 +1586,6 @@ def run_worker(url: str, worker_id: str = "", poll_s: float = 0.2,
     worker = Worker(
         queue, traces, artifact_dir, bucket=int(reg.get("bucket") or 512),
         table_cache_dir=table_cache_dir,
-        compile_cache_dir=compile_cache_dir,
         worker_id=wid, lease_files=True,
     )
 
@@ -1640,9 +1639,6 @@ def run_worker(url: str, worker_id: str = "", poll_s: float = 0.2,
         worker.lease_stake_cb = _stake
         worker.lease_release_cb = _release
 
-    from tpusim.sim.driver import enable_compile_cache
-
-    enable_compile_cache(compile_cache_dir)
     if out is not None:
         print(
             f"[worker {wid}] joined {ring.url} ({mode}, pid "
@@ -1873,8 +1869,7 @@ def run_worker(url: str, worker_id: str = "", poll_s: float = 0.2,
 # ---------------------------------------------------------------------------
 
 
-def worker_command(url: str, table_cache_dir: str = "",
-                   compile_cache_dir: str = "", mode: str = "",
+def worker_command(url: str, table_cache_dir: str = "", mode: str = "",
                    cache_dir: str = "", token_file: str = "") -> List[str]:
     """The `tpusim worker --join` argv for one spawned child — shared
     by spawn_local_workers and the supervisor's spawn_fn (ISSUE 13).
@@ -1884,8 +1879,6 @@ def worker_command(url: str, table_cache_dir: str = "",
     cmd = [sys.executable, "-m", "tpusim", "worker", "--join", url]
     if table_cache_dir:
         cmd += ["--table-cache-dir", table_cache_dir]
-    if compile_cache_dir:
-        cmd += ["--compile-cache-dir", compile_cache_dir]
     if mode:
         cmd += ["--mode", mode]
     if cache_dir:
@@ -1898,17 +1891,16 @@ def worker_command(url: str, table_cache_dir: str = "",
 
 
 def spawn_local_workers(url: str, n: int, table_cache_dir: str = "",
-                        compile_cache_dir: str = "",
                         out=None, token_file: str = "") -> List[subprocess.Popen]:
     """Spawn N `tpusim worker --join` processes against this
-    coordinator. They inherit the environment (JAX_PLATFORMS etc.) and
-    share the persistent compile cache + table cache dirs — the warm
+    coordinator. They inherit the environment (JAX_PLATFORMS,
+    JAX_COMPILATION_CACHE_DIR etc.) and share the table cache dir and
+    the one persistent compile cache (tpusim.compile_cache) — the warm
     state that makes a joiner's first batch skip the compile."""
     procs = []
     for _ in range(int(n)):
         cmd = worker_command(
-            url, table_cache_dir=table_cache_dir,
-            compile_cache_dir=compile_cache_dir, token_file=token_file,
+            url, table_cache_dir=table_cache_dir, token_file=token_file,
         )
         procs.append(subprocess.Popen(cmd))
         if out is not None:

@@ -221,21 +221,17 @@ def load_trace(name: str, nodes_csv: str, pods_csv: str,
 
 def local_caps() -> dict:
     """The capability tags this process declares in the fleet register
-    handshake (ISSUE 17): accelerator backend + local device count
-    (from jax when importable; cpu/1 otherwise — a handshake must never
-    crash on a worker without the toolchain warm), approximate host
-    memory, fault-lane support (every engine in this tree carries the
-    chaos dispatch, so True unless an operator override says otherwise),
-    and max_nodes (0 = no cluster-size ceiling). The coordinator routes
+    handshake (ISSUE 17): accelerator backend + local device count as
+    JAX reports them (a JAX that cannot start its backend raises here —
+    a worker that cannot reach its device must not register as a
+    one-device CPU worker), approximate host memory, fault-lane support
+    (every engine in this tree carries the chaos dispatch, so True unless
+    an operator override says otherwise), and max_nodes (0 = no cluster-size ceiling). The coordinator routes
     claims against these tags (JobQueue.eligible)."""
-    backend, devices = "cpu", 1
-    try:
-        import jax
+    import jax
 
-        backend = str(jax.default_backend())
-        devices = int(jax.local_device_count())
-    except Exception:
-        pass  # capability probing is best-effort, never fatal
+    backend = str(jax.default_backend())
+    devices = int(jax.local_device_count())
     mem = 0
     try:
         mem = int(os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES"))
@@ -288,7 +284,7 @@ class Worker:
 
     def __init__(self, queue: JobQueue, traces: Dict[str, TraceRef],
                  artifact_dir: str, bucket: int = 512, monitor=None,
-                 table_cache_dir: str = "", compile_cache_dir: str = "",
+                 table_cache_dir: str = "",
                  linger_s: float = 0.05, worker_id: str = "",
                  lease_files: bool = True):
         self.queue = queue
@@ -297,7 +293,6 @@ class Worker:
         self.bucket = int(bucket)
         self.monitor = monitor  # MonitorServer (per-job /progress) or None
         self.table_cache_dir = table_cache_dir
-        self.compile_cache_dir = compile_cache_dir
         self.linger_s = float(linger_s)  # batching window (JobQueue.next_batch)
         # fleet identity (ISSUE 12): the id the lease files and the
         # /queue per-worker rows carry; in-process workers default to a
@@ -328,9 +323,6 @@ class Worker:
     # ---- lifecycle ----
 
     def start(self) -> "Worker":
-        from tpusim.sim.driver import enable_compile_cache
-
-        enable_compile_cache(self.compile_cache_dir)
         self._thread = threading.Thread(
             target=self._loop, name="tpusim-svc-worker", daemon=True
         )
