@@ -186,33 +186,6 @@ def test_format_sweep_table():
     assert "1000,500" in text and "12.50" in text and "321" in text
 
 
-def test_note_compile_cache_heuristic():
-    """The obs run record notes the probable persistent-cache outcome via
-    the dispatch-wall heuristic: enabled + sub-threshold first scan
-    dispatch = probable hit."""
-    from tpusim.obs import Recorder, note_compile_cache
-
-    rec = Recorder()
-    with rec.span("scan") as h:
-        h.dispatched()
-    rec.spans[0].dispatch_s = 0.12
-    info = note_compile_cache(rec, enabled=True, cache_dir="/tmp/cc")
-    assert info["probable_hit"] is True
-    record = rec.snapshot().to_record()
-    assert record["timing"]["compile_cache"]["dir"] == "/tmp/cc"
-
-    rec = Recorder()
-    with rec.span("scan") as h:
-        h.dispatched()
-    rec.spans[0].dispatch_s = 6.5
-    assert note_compile_cache(rec, enabled=True)["probable_hit"] is False
-    # cache off + fast dispatch is still not a hit
-    assert note_compile_cache(rec, enabled=False)["probable_hit"] is False
-    # never assessed -> no block in the record
-    rec2 = Recorder()
-    assert "compile_cache" not in rec2.snapshot().to_record()["timing"]
-
-
 # ---------------------------------------------------------------------------
 # sweep lanes == standalone baked-weight runs (tier-1: one table family)
 # ---------------------------------------------------------------------------

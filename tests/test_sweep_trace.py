@@ -1,0 +1,261 @@
+"""The sweep's own tracing: one SweepRecord per schedule_pods_sweep call
+made of eight flat spans, the exact compile counter at the same boundary,
+the profiler annotations of the spans, and the named scopes of the step
+body (tpusim/obs/spans.py, tpusim/sim/driver.py, tpusim/sim/table_engine.py).
+"""
+
+import jax
+import numpy as np
+import pytest
+
+from tests.test_sweep import _cfg, _mk_cluster, _mk_pods
+from tpusim.obs import Recorder, compile_counts, note_compile_cache, sweep_log
+from tpusim.obs.spans import CACHE_HIT_EVENT, COMPILE_EVENT
+from tpusim.sim import driver
+from tpusim.sim.driver import Simulator, schedule_pods_sweep
+
+SPAN_NAMES = ["specs", "lane_keys", "lane_ranks", "init_tables", "scan",
+              "frag_postpass", "fetch", "slice_lanes"]
+BODY_SCOPES = {
+    # block_size: the scopes its step body carries
+    -1: {"tpusim.commit", "tpusim.refresh", "tpusim.select"},
+    8: {"tpusim.commit", "tpusim.refresh", "tpusim.summary", "tpusim.select"},
+}
+WEIGHTS = [[1000], [1000], [700]]
+SEEDS = [11, 12, 13]
+
+
+def _sim(block_size, profile=False, engine="table"):
+    rng = np.random.default_rng(5)
+    sim = Simulator(_mk_cluster(rng), _cfg(
+        42, engine=engine, block_size=block_size, profile=profile))
+    sim.set_workload_pods(_mk_pods(rng))
+    sim.set_typical_pods()
+    return sim, sim.prepare_pods()
+
+
+class _Listener:
+    """An independent count of what jax.monitoring reports."""
+
+    def __init__(self):
+        self.requests = self.cache_loads = 0
+
+    def on_duration(self, event, _secs, **_kw):
+        self.requests += event == COMPILE_EVENT
+
+    def on_event(self, event, **_kw):
+        self.cache_loads += event == CACHE_HIT_EVENT
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self.on_duration)
+        jax.monitoring.register_event_listener(self.on_event)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self.on_duration)
+        jax.monitoring.unregister_event_listener(self.on_event)
+
+
+@pytest.fixture(scope="module")
+def two_sweeps():
+    """Two sweeps of one tiny Simulator (flat step, profile off), each
+    beside an independent listener's counts over the same call."""
+    sim, trace = _sim(block_size=-1)
+    calls = []
+    for _ in range(2):
+        with _Listener() as seen:
+            lanes = schedule_pods_sweep(sim, trace, WEIGHTS, SEEDS)
+        calls.append((sweep_log()[-1], seen, lanes))
+    return sim, trace, calls
+
+
+def test_a_sweep_records_eight_flat_spans_under_one_id(two_sweeps):
+    sim, trace, calls = two_sweeps
+    rec = calls[0][0]
+    assert [s.name for s in rec.spans] == SPAN_NAMES
+    assert {s.sweep for s in rec.spans} == {rec.id}
+    assert (rec.engine, rec.lanes, rec.events, rec.blocked) == (
+        "table (3-config vmap sweep)", 3, len(trace), False)
+    # flat: back to back on the recorder's clock, none inside another,
+    # and the recorder's own list holds the same objects and no root span
+    at = rec.start_s - sim.obs.epoch
+    for s in rec.spans:
+        assert s.start_s >= at - 1e-9, (s.name, s.start_s, at)
+        at = s.start_s + s.total_s
+    assert at <= rec.start_s - sim.obs.epoch + rec.wall_s + 1e-9
+    assert sum(s.total_s for s in rec.spans) >= 0.95 * rec.wall_s
+    assert [s for s in sim.obs.spans if s.sweep == rec.id] == rec.spans
+
+
+def test_a_second_sweep_appends_the_next_record(two_sweeps):
+    sim, _, calls = two_sweeps
+    first, second = calls[0][0], calls[1][0]
+    assert second.id == first.id + 1
+    assert second.start_s >= first.start_s + first.wall_s
+    assert [s.name for s in second.spans] == SPAN_NAMES
+    assert sim.obs.sweeps == [first, second]
+    timing = sim.run_telemetry().to_record()["timing"]
+    assert [r["id"] for r in timing["sweeps"]] == [first.id, second.id]
+    assert timing["sweeps"][0]["spans"][0]["sweep"] == first.id
+    assert timing["spans"][-1]["sweep"] == second.id
+
+
+def test_compile_counts_equal_an_independent_listeners(two_sweeps):
+    _, _, calls = two_sweeps
+    for rec, seen, _ in calls:
+        assert rec.programs_requested == seen.requests
+        assert rec.cache_loads == seen.cache_loads
+        assert rec.compiled == seen.requests - seen.cache_loads
+    # the first call builds every program; the second still requests the
+    # frag post-pass, whose jit wraps a new function object each call
+    assert calls[0][0].programs_requested > calls[1][0].programs_requested
+    assert calls[1][0].programs_requested >= 1
+
+
+def test_spans_outside_a_sweep_carry_no_sweep_id():
+    rec = Recorder()
+    with rec.span("report"):
+        pass
+    with rec.sweep(lanes=1) as sw:
+        with rec.span("scan"):
+            pass
+    with rec.span("report"):
+        pass
+    assert [s.sweep for s in rec.spans] == [None, sw.id, None]
+    assert "sweep" not in rec.spans[0].to_dict()
+    assert rec.spans[1].to_dict()["sweep"] == sw.id
+    # a call that raises leaves no record
+    with pytest.raises(RuntimeError):
+        with rec.sweep(lanes=1):
+            raise RuntimeError("boom")
+    assert rec.sweeps == [sw] and sweep_log()[-1] is sw
+    with rec.span("report"):
+        pass
+    assert rec.spans[-1].sweep is None
+
+
+@pytest.mark.parametrize("profile", [False, True])
+def test_only_profile_blocks_on_the_phases(profile, monkeypatch):
+    sim, trace = _sim(block_size=-1, profile=profile)
+    blocked = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready", lambda x: blocked.append(1) or real(x))
+    schedule_pods_sweep(sim, trace, WEIGHTS, SEEDS)
+    rec = sweep_log()[-1]
+    assert rec.blocked is profile
+    # specs, lane_keys, lane_ranks, init_tables, scan, frag_postpass
+    assert len(blocked) == (6 if profile else 0)
+
+
+def test_note_compile_cache_exact_fields():
+    """The run record's compile-cache note is counted, not guessed:
+    programs requested since the recorder's epoch, those the persistent
+    cache served, and the rest."""
+    rec = Recorder()
+    with rec.span("scan") as h:
+        h.dispatched()
+    rec.spans[0].dispatch_s = 6.5
+    before = compile_counts()
+    with _Listener() as seen:
+        jax.jit(lambda x: x * 3 + 1)(np.arange(7)).block_until_ready()
+    assert seen.requests >= 1
+    assert compile_counts()[0] - before[0] == seen.requests
+    info = note_compile_cache(rec, enabled=True, cache_dir="/tmp/cc")
+    assert info == {
+        "enabled": True, "dir": "/tmp/cc", "first_scan_dispatch_s": 6.5,
+        "requests": seen.requests, "cache_loads": seen.cache_loads,
+        "compiled": seen.requests - seen.cache_loads,
+    }
+    record = rec.snapshot().to_record()
+    assert record["timing"]["compile_cache"] == info
+    # nothing requested since a fresh epoch, cache off, no scan yet
+    assert note_compile_cache(Recorder(), enabled=False) == {
+        "enabled": False, "dir": "", "first_scan_dispatch_s": None,
+        "requests": 0, "cache_loads": 0, "compiled": 0,
+    }
+    # never assessed -> no block in the record
+    assert "compile_cache" not in Recorder().snapshot().to_record()["timing"]
+
+
+def test_spans_are_annotations_on_the_profilers_host_plane(
+        two_sweeps, tmp_path):
+    sim, trace, _ = two_sweeps
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        schedule_pods_sweep(sim, trace, WEIGHTS, SEEDS)
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    host = next(p for p in data.planes if p.name == "/host:CPU")
+    names = {ev.name for line in host.lines for ev in line.events}
+    assert {f"tpusim/{n}" for n in SPAN_NAMES} <= names
+
+
+@pytest.fixture(scope="module", params=sorted(BODY_SCOPES))
+def scoped(request):
+    """One sweep on the flat (-1) or blocked (8) step body, with the
+    shapes its vmapped engine was called on."""
+    block_size = request.param
+    sim, trace = _sim(block_size=block_size)
+    called = {}
+    real = driver._sweep_engine
+
+    def spy(engine, table):
+        fn = real(engine, table)
+
+        def call(*args):
+            called["fn"] = fn
+            called["shapes"] = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+            return fn(*args)
+
+        return call
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(driver, "_sweep_engine", spy)
+        lanes = schedule_pods_sweep(sim, trace, WEIGHTS, SEEDS)
+    return block_size, sim, lanes, called
+
+
+def test_every_stage_of_the_step_body_has_its_scope(scoped):
+    block_size, sim, lanes, called = scoped
+    text = called["fn"].lower(*called["shapes"]).as_text(debug_info=True)
+    for scope in BODY_SCOPES[block_size]:
+        assert scope in text, scope
+    assert ("tpusim.summary" in text) == (block_size > 0)
+    state, _, types, _, _, tp, keys = called["shapes"][:7]
+    build = sim._table_fn.build_tables.lower(
+        state, types, tp, jax.ShapeDtypeStruct(keys.shape[1:], keys.dtype))
+    assert "tpusim.table_build" in build.as_text(debug_info=True)
+    states = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), lanes[0].state)
+    post = jax.jit(jax.vmap(driver._lane_frag_amounts, in_axes=(0, None)))
+    stacked = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((3,) + a.shape, a.dtype), states)
+    assert "tpusim.frag_postpass" in post.lower(stacked, tp).as_text(
+        debug_info=True)
+
+
+def test_a_scoped_sweep_equals_the_sequential_oracle(scoped):
+    """Names are metadata: placements, masks, counters and final state of
+    every lane equal a standalone replay on the sequential engine."""
+    from tpusim.obs.counters import COUNTER_FIELDS, INVARIANT_FIELDS
+
+    _, sim, lanes, _ = scoped
+    for lane, wrow, seed in zip(lanes, WEIGHTS, SEEDS):
+        rng = np.random.default_rng(5)
+        oracle = Simulator(_mk_cluster(rng), _cfg(
+            seed, (("FGDScore", wrow[0]),), engine="sequential"))
+        oracle.set_workload_pods(_mk_pods(rng))
+        res = oracle.run()
+        assert "sequential" in oracle._last_engine
+        np.testing.assert_array_equal(lane.placed_node, res.placed_node)
+        np.testing.assert_array_equal(lane.dev_mask, res.dev_mask)
+        for a, b in zip(jax.tree.leaves(lane.state),
+                        jax.tree.leaves(res.state)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        got = dict(zip(COUNTER_FIELDS, (int(c) for c in lane.counters)))
+        want = res.telemetry.counters
+        assert all(got[f] == want[f] for f in INVARIANT_FIELDS), (got, want)

@@ -364,7 +364,7 @@ class Applier:
     def _note_compile_cache(self, sim: Simulator):
         """Record the persistent-compilation-cache outcome on the run's
         telemetry (the `timing.compile_cache` block of the JSONL record;
-        dispatch-wall heuristic, obs.spans.note_compile_cache). The
+        counted through jax.monitoring, obs.spans.note_compile_cache). The
         directory is whatever the process runs under — the entry point
         placed it (tpusim.compile_cache), not this run."""
         import jax

@@ -57,5 +57,8 @@ from tpusim.obs.spans import (  # noqa: F401
     Recorder,
     RunTelemetry,
     Span,
+    SweepRecord,
+    compile_counts,
     note_compile_cache,
+    sweep_log,
 )

@@ -27,14 +27,22 @@ the driver attaches to SimulateResult; its to_record() splits the JSONL
 payload into a `deterministic` block (bit-identical across same-seed
 runs and across kill/resume — the acceptance contract tests pin) and a
 `timing` block (machine-dependent walls).
+
+A sweep call wraps its body in Recorder.sweep: its spans carry the
+sweep's id and stay flat, and one SweepRecord (wall, spans, the compile
+counts over the call) goes to a bounded process-wide log that outlives
+the Simulator (sweep_log()).
 """
 
 from __future__ import annotations
 
+import itertools
+import threading
 import time
+from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -53,6 +61,7 @@ class Span:
     dispatch_s: float  # host wall until dispatch returned (compile on cold)
     block_s: float  # wall waiting on the device result (execute); 0 = unknown
     meta: Dict[str, object] = field(default_factory=dict)
+    sweep: Optional[int] = None  # id of the enclosing Recorder.sweep, if any
 
     @property
     def total_s(self) -> float:
@@ -68,7 +77,105 @@ class Span:
         }
         if self.meta:
             d["meta"] = self.meta
+        if self.sweep is not None:
+            d["sweep"] = self.sweep
         return d
+
+
+# ---------------------------------------------------------------------------
+# The compile counter: programs that reached the backend, and those among
+# them the persistent compilation cache served, counted where jax reports
+# them. One listener pair a process, installed on first use.
+# ---------------------------------------------------------------------------
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+_compile_totals = [0, 0]  # [programs requested, cache loads], process-wide
+_compile_lock = threading.Lock()  # any thread may compile
+_compile_listening = False
+
+
+def _on_compile_duration(event, _secs, **_kw):
+    if event == COMPILE_EVENT:
+        with _compile_lock:
+            _compile_totals[0] += 1
+
+
+def _on_compile_event(event, **_kw):
+    if event == CACHE_HIT_EVENT:
+        with _compile_lock:
+            _compile_totals[1] += 1
+
+
+def compile_counts() -> Tuple[int, int]:
+    """(programs requested, cache loads) of this process since the first
+    call, which installs the listeners. Every program that reaches the
+    backend cost a trace and a lowering; a cache load among them was
+    served by the persistent compilation cache, the rest were compiled.
+    Callers take differences."""
+    global _compile_listening
+    with _compile_lock:
+        if not _compile_listening:
+            import jax.monitoring as mon
+
+            mon.register_event_duration_secs_listener(_on_compile_duration)
+            mon.register_event_listener(_on_compile_event)
+            _compile_listening = True
+        return _compile_totals[0], _compile_totals[1]
+
+
+# ---------------------------------------------------------------------------
+# Sweep records: one per Recorder.sweep, kept process-wide so that a reader
+# that no longer holds the Simulator (the benchmark's layer metrics) finds
+# the phases of the last waves.
+# ---------------------------------------------------------------------------
+
+SWEEP_LOG_SIZE = 1024
+_sweep_log: deque = deque(maxlen=SWEEP_LOG_SIZE)
+_sweep_ids = itertools.count()
+
+
+@dataclass
+class SweepRecord:
+    """One schedule_pods_sweep call: its spans are flat and back to back
+    (Recorder.spans holds the same objects, no root span among them), so
+    the call's own start and wall live here."""
+
+    id: int
+    start_s: float  # absolute time.perf_counter() at entry
+    blocked: bool  # spans blocked on their results (Recorder.enabled)
+    lanes: int = 0
+    events: int = 0
+    engine: str = ""
+    wall_s: float = 0.0
+    spans: List[Span] = field(default_factory=list)
+    programs_requested: int = 0  # reached the backend: traced and lowered
+    cache_loads: int = 0  # of those, served by the persistent cache
+
+    @property
+    def compiled(self) -> int:
+        return self.programs_requested - self.cache_loads
+
+    def to_dict(self) -> dict:
+        return {
+            "id": self.id,
+            "start_s": round(self.start_s, 6),
+            "wall_s": round(self.wall_s, 6),
+            "engine": self.engine,
+            "lanes": self.lanes,
+            "events": self.events,
+            "blocked": self.blocked,
+            "programs_requested": self.programs_requested,
+            "cache_loads": self.cache_loads,
+            "compiled": self.compiled,
+            "spans": [s.to_dict() for s in self.spans],
+        }
+
+
+def sweep_log() -> List[SweepRecord]:
+    """The process's last SWEEP_LOG_SIZE sweep records, oldest first."""
+    return list(_sweep_log)
 
 
 class _SpanHandle:
@@ -99,6 +206,9 @@ class Recorder:
     def reset(self):
         self.epoch = time.perf_counter()
         self.spans: List[Span] = []
+        self.sweeps: List[SweepRecord] = []
+        self._sweep: Optional[int] = None  # id of the sweep in progress
+        self._compile_base = compile_counts()
         self.counts: Dict[str, int] = {}
         self.scan_counters = np.zeros(NUM_COUNTERS, np.int64)
         self._pending_scans: List[tuple] = []  # (device ctr array, pad_skips)
@@ -111,26 +221,71 @@ class Recorder:
         # driver's residency select; lands in the run record's
         # deterministic block beside table_cache
         self.pallas_residency = "off"
-        # persistent-compilation-cache note (ISSUE 6 satellite): set by
-        # note_compile_cache after the run; None = never assessed
+        # persistent-compilation-cache note: set by note_compile_cache
+        # after the run; None = never assessed
         self.compile_cache: Optional[dict] = None
 
     @contextmanager
     def span(self, name: str, **meta):
-        t0 = time.perf_counter()
-        h = _SpanHandle(t0)
+        """One phase. It is also a `tpusim/<name>` annotation in the host
+        plane of a jax.profiler trace, on the clock the device plane
+        uses; with no profiler session that is a flag check."""
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation(f"tpusim/{name}"):
+            t0 = time.perf_counter()
+            h = _SpanHandle(t0)
+            try:
+                yield h
+            finally:
+                t1 = time.perf_counter()
+                td = h._t_dispatch if h._t_dispatch is not None else t1
+                self.spans.append(Span(
+                    name=name,
+                    start_s=t0 - self.epoch,
+                    dispatch_s=td - t0,
+                    block_s=t1 - td,
+                    meta=meta,
+                    sweep=self._sweep,
+                ))
+
+    def settle(self, handle: _SpanHandle, *results):
+        """Close a span's dispatch half; in profiling mode wait for the
+        device results, so that the rest of the span is their time."""
+        handle.dispatched()
+        if self.enabled:
+            import jax
+
+            jax.block_until_ready(results)
+
+    @contextmanager
+    def sweep(self, lanes: int):
+        """Wrap one whole sweep call. Every span opened inside carries the
+        sweep's id; the spans stay flat in `spans` (no root span: a reader
+        that walks them as back-to-back phases would count an enclosing
+        one twice). Yields the SweepRecord so the body can name its engine
+        and events; at exit the record gets the wall, the spans and the
+        compile counts over the call, and goes to `sweeps` and to the
+        process-wide log (sweep_log())."""
+        rec = SweepRecord(
+            id=next(_sweep_ids), start_s=time.perf_counter(),
+            blocked=self.enabled, lanes=int(lanes),
+        )
+        first_span = len(self.spans)
+        requested0, loads0 = compile_counts()
+        self._sweep = rec.id
         try:
-            yield h
+            yield rec
         finally:
-            t1 = time.perf_counter()
-            td = h._t_dispatch if h._t_dispatch is not None else t1
-            self.spans.append(Span(
-                name=name,
-                start_s=t0 - self.epoch,
-                dispatch_s=td - t0,
-                block_s=t1 - td,
-                meta=meta,
-            ))
+            self._sweep = None
+        # a call that raised leaves its spans and no record
+        rec.wall_s = time.perf_counter() - rec.start_s
+        rec.spans = self.spans[first_span:]
+        requested1, loads1 = compile_counts()
+        rec.programs_requested = requested1 - requested0
+        rec.cache_loads = loads1 - loads0
+        self.sweeps.append(rec)
+        _sweep_log.append(rec)
 
     def count(self, name: str, n: int = 1):
         self.counts[name] = self.counts.get(name, 0) + n
@@ -180,32 +335,32 @@ class Recorder:
             compile_cache=(
                 dict(self.compile_cache) if self.compile_cache else None
             ),
+            sweeps=list(self.sweeps),
         )
 
 
-def note_compile_cache(recorder: Recorder, enabled: bool, cache_dir: str = "",
-                       hit_threshold_s: float = 2.0) -> dict:
+def note_compile_cache(recorder: Recorder, enabled: bool,
+                       cache_dir: str = "") -> dict:
     """Stamp the run's persistent-compilation-cache outcome onto the
-    recorder (ISSUE 6 satellite). The verdict is a DISPATCH-WALL
-    HEURISTIC, not ground truth: jax exposes no portable per-executable
-    hit signal, but a cold scan compile costs several seconds of
-    dispatch wall while a persistent-cache load costs well under the
-    threshold — so `probable_hit` = (cache enabled AND the first scan
-    span's dispatch wall stayed under hit_threshold_s). Lands in the
-    run record's `timing` block (machine-dependent walls, never the
-    deterministic block)."""
-    scans = [s for s in recorder.spans if s.name == "scan"]
-    first = scans[0] if scans else None
+    recorder: the programs that reached the backend since the recorder's
+    epoch (`requests`: each cost a trace and a lowering), those among
+    them the persistent cache served (`cache_loads`) and the rest
+    (`compiled`), as compile_counts() saw them, beside the first scan
+    span's dispatch wall. Lands in the run record's `timing` block
+    (machine-dependent, never the deterministic block)."""
+    first = next((s for s in recorder.spans if s.name == "scan"), None)
+    requests_now, loads_now = compile_counts()
+    requests = requests_now - recorder._compile_base[0]
+    loads = loads_now - recorder._compile_base[1]
     info = {
         "enabled": bool(enabled),
         "dir": cache_dir,
         "first_scan_dispatch_s": (
             round(first.dispatch_s, 6) if first is not None else None
         ),
-        "probable_hit": bool(
-            enabled and first is not None
-            and first.dispatch_s < hit_threshold_s
-        ),
+        "requests": requests,
+        "cache_loads": loads,
+        "compiled": requests - loads,
     }
     recorder.compile_cache = info
     return info
@@ -228,9 +383,12 @@ class RunTelemetry:
     # (off | vmem | hbm) — deterministic, like table_cache
     pallas_residency: str = "off"
     # persistent-compilation-cache note (note_compile_cache): enabled /
-    # dir / first-scan dispatch wall / probable_hit heuristic. None when
-    # never assessed; machine-dependent, so it reports under `timing`.
+    # dir / first-scan dispatch wall / requests, cache_loads, compiled.
+    # None when never assessed; machine-dependent, so it reports under
+    # `timing`.
     compile_cache: Optional[dict] = None
+    # the sweep records of the run (Recorder.sweep), under `timing` too
+    sweeps: List[SweepRecord] = field(default_factory=list)
 
     def to_record(self) -> dict:
         """The JSONL run record. `deterministic` is bit-identical across
@@ -265,6 +423,10 @@ class RunTelemetry:
                 **(
                     {"compile_cache": self.compile_cache}
                     if self.compile_cache is not None else {}
+                ),
+                **(
+                    {"sweeps": [r.to_dict() for r in self.sweeps]}
+                    if self.sweeps else {}
                 ),
             },
         }

@@ -3423,6 +3423,16 @@ def _sweep_engine(engine, table: bool, donate: bool = True):
     return _SWEEP_WRAP_CACHE[ck]
 
 
+def _lane_frag_amounts(state, tp):
+    """One lane's frag amounts by category, summed over its nodes (the
+    reduction cluster_analysis reports): the sweeps vmap it over their
+    lanes' final states before the single fetch."""
+    from tpusim.ops.frag import cluster_frag_amounts
+
+    with jax.named_scope("tpusim.frag_postpass"):
+        return cluster_frag_amounts(state, tp).sum(0)
+
+
 def _sweep_metrics_fn():
     """compute_event_metrics vmapped over the config axis: ONE cluster,
     ONE workload, per-lane telemetry."""
@@ -3814,7 +3824,6 @@ def schedule_pods_sweep(
     engine unless forced sequential or the workload is too small to
     amortize the table init; pallas has no batched form; extenders /
     mesh / decision-recording / series configs are rejected."""
-    from tpusim.ops.frag import cluster_frag_amounts
     from tpusim.sim.table_engine import (
         build_pod_types,
         num_pod_types,
@@ -3823,125 +3832,143 @@ def schedule_pods_sweep(
     from tpusim.types import PodSpec
 
     cfg = sim.cfg
+    obs = sim.obs
     _reject_unsweepable(cfg)
     w, b, seeds = _check_sweep_grid(cfg, weights, seeds)
     if sim.typical is None:
-        sim.set_typical_pods()
+        sim.set_typical_pods()  # a span of its own, before the sweep's
 
-    specs = pods_to_specs(pods, sim.node_index, device=False)
-    ev_kind_l, ev_pod_l = build_events(pods, cfg.use_timestamps)
-    validate_events(ev_kind_l, ev_pod_l, int(specs.cpu.shape[0]))
-    p, e = int(specs.cpu.shape[0]), len(ev_kind_l)
-    p2, e2 = _bucket_sizes(p, e, bucket)
+    # eight flat, back-to-back spans under one sweep record: specs,
+    # lane_keys, lane_ranks, init_tables, scan, frag_postpass, fetch,
+    # slice_lanes
+    with obs.sweep(lanes=b) as sweep:
+        with obs.span("specs") as h:
+            specs = pods_to_specs(pods, sim.node_index, device=False)
+            ev_kind_l, ev_pod_l = build_events(pods, cfg.use_timestamps)
+            validate_events(ev_kind_l, ev_pod_l, int(specs.cpu.shape[0]))
+            p, e = int(specs.cpu.shape[0]), len(ev_kind_l)
+            p2, e2 = _bucket_sizes(p, e, bucket)
 
-    types = build_pod_types(specs)
-    k = int(types.share.cpu.shape[0]) + int(types.whole.cpu.shape[0])
-    use_table = (
-        cfg.engine != "sequential"
-        and k > 0
-        and (cfg.engine == "table" or e >= 2 * num_pod_types(specs))
-    )
-
-    specs_h, tid = _pad_specs(
-        specs, p2, types.type_id if use_table else None, xp=np
-    )
-    ev_kind_h, ev_pod_h = _pad_events(
-        np.asarray(ev_kind_l, np.int32), np.asarray(ev_pod_l, np.int32),
-        e2, xp=np,
-    )
-    specs_d = PodSpec(
-        *(jnp.asarray(np.asarray(getattr(specs_h, f)))
-          for f in PodSpec._fields)
-    )
-    ev_kind_d, ev_pod_d = jnp.asarray(ev_kind_h), jnp.asarray(ev_pod_h)
-    keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    ranks = jnp.stack(
-        [jnp.asarray(tiebreak_rank(len(sim.nodes), s)) for s in seeds]
-    )
-    weights_d = jnp.asarray(w)
-    state = sim.init_state
-
-    if use_table:
-        types = types._replace(type_id=jnp.asarray(tid))
-        if p2 != p or e2 != e:  # bucketed run: stabilize K too
-            types = pad_pod_types(types)
-        # ONE table build for the whole sweep: the tables hold raw
-        # per-policy scores (weight-independent), so every lane shares
-        # them bit-identically — through the content-keyed disk cache
-        # when configured, else built here once instead of B times
-        # under the vmap
-        key0 = jax.random.PRNGKey(seeds[0])
-        table_fn = sim._table_fn
-        if cfg.heartbeat_every:
-            # the in-scan heartbeat cond doesn't survive vmap (a batched
-            # predicate executes both branches, firing the host tick
-            # callback every event per lane) — the sweep replays on the
-            # heartbeat-free build of the same family instead
-            from tpusim.sim.table_engine import make_table_replay
-
-            sim.log.info(
-                "[Sweep] in-scan heartbeat has no batched form; "
-                "disabled for the sweep replay"
+            types = build_pod_types(specs)
+            k = int(types.share.cpu.shape[0]) + int(types.whole.cpu.shape[0])
+            use_table = (
+                cfg.engine != "sequential"
+                and k > 0
+                and (cfg.engine == "table" or e >= 2 * num_pod_types(specs))
             )
-            table_fn = make_table_replay(
-                sim._policy_fns, gpu_sel=cfg.gpu_sel_method, report=False,
-                block_size=cfg.block_size,
+
+            specs_h, tid = _pad_specs(
+                specs, p2, types.type_id if use_table else None, xp=np
             )
-        tables = sim._cached_tables(state, types, key0)
-        if tables is None:
-            with sim.obs.span("init_tables", cache="sweep-shared") as h:
-                tables = table_fn.build_tables(
-                    state, types, sim.typical, key0
+            ev_kind_h, ev_pod_h = _pad_events(
+                np.asarray(ev_kind_l, np.int32),
+                np.asarray(ev_pod_l, np.int32), e2, xp=np,
+            )
+            specs_d = PodSpec(
+                *(jnp.asarray(np.asarray(getattr(specs_h, f)))
+                  for f in PodSpec._fields)
+            )
+            ev_kind_d, ev_pod_d = jnp.asarray(ev_kind_h), jnp.asarray(ev_pod_h)
+            if use_table:
+                types = types._replace(type_id=jnp.asarray(tid))
+                if p2 != p or e2 != e:  # bucketed run: stabilize K too
+                    types = pad_pod_types(types)
+            obs.settle(h, specs_d, ev_kind_d, ev_pod_d, types)
+        sweep.events = e
+        with obs.span("lane_keys") as h:
+            keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
+            obs.settle(h, keys)
+        with obs.span("lane_ranks") as h:
+            ranks = jnp.stack(
+                [jnp.asarray(tiebreak_rank(len(sim.nodes), s)) for s in seeds]
+            )
+            weights_d = jnp.asarray(w)
+            obs.settle(h, ranks, weights_d)
+        state = sim.init_state
+
+        if use_table:
+            # ONE table build for the whole sweep: the tables hold raw
+            # per-policy scores (weight-independent), so every lane shares
+            # them bit-identically — through the content-keyed disk cache
+            # when configured, else built here once instead of B times
+            # under the vmap
+            key0 = jax.random.PRNGKey(seeds[0])
+            table_fn = sim._table_fn
+            if cfg.heartbeat_every:
+                # the in-scan heartbeat cond doesn't survive vmap (a batched
+                # predicate executes both branches, firing the host tick
+                # callback every event per lane) — the sweep replays on the
+                # heartbeat-free build of the same family instead
+                from tpusim.sim.table_engine import make_table_replay
+
+                sim.log.info(
+                    "[Sweep] in-scan heartbeat has no batched form; "
+                    "disabled for the sweep replay"
                 )
-                h.dispatched()
-        fn = _sweep_engine(table_fn.engine.replay, table=True)
-        sim._last_engine = f"table ({b}-config vmap sweep)"
-        out = sim._dispatch_span(
-            lambda: fn(
-                state, specs_d, types, ev_kind_d, ev_pod_d, sim.typical,
-                keys, weights_d, ranks, tables,
-            ),
-            engine=sim._last_engine, events=e,
-        )
-    else:
-        fn = _sweep_engine(sim.replay_fn.engine, table=False)
-        sim._last_engine = f"sequential ({b}-config vmap sweep)"
-        out = sim._dispatch_span(
-            lambda: fn(
-                state, specs_d, ev_kind_d, ev_pod_d, sim.typical, keys,
-                weights_d, ranks,
-            ),
-            engine=sim._last_engine, events=e,
-        )
-    sim.obs.note_scan(sim._last_engine, counters=None, events=e * b)
-    sim.log.info(
-        f"[Engine] sweep of {b} configs x {e} events ran on: "
-        f"{sim._last_engine}"
-    )
-    if cfg.report_per_event:
-        out = out._replace(
-            metrics=_sweep_metrics_fn()(
-                state, specs_d, ev_kind_d, ev_pod_d,
-                out.event_node, out.event_dev, sim.typical,
+                table_fn = make_table_replay(
+                    sim._policy_fns, gpu_sel=cfg.gpu_sel_method, report=False,
+                    block_size=cfg.block_size,
+                )
+            tables = sim._cached_tables(state, types, key0)
+            if tables is None:
+                with obs.span("init_tables", cache="sweep-shared") as h:
+                    tables = table_fn.build_tables(
+                        state, types, sim.typical, key0
+                    )
+                    obs.settle(h, tables)
+            fn = _sweep_engine(table_fn.engine.replay, table=True)
+            sim._last_engine = f"table ({b}-config vmap sweep)"
+            out = sim._dispatch_span(
+                lambda: fn(
+                    state, specs_d, types, ev_kind_d, ev_pod_d, sim.typical,
+                    keys, weights_d, ranks, tables,
+                ),
+                engine=sim._last_engine, events=e,
             )
+        else:
+            fn = _sweep_engine(sim.replay_fn.engine, table=False)
+            sim._last_engine = f"sequential ({b}-config vmap sweep)"
+            out = sim._dispatch_span(
+                lambda: fn(
+                    state, specs_d, ev_kind_d, ev_pod_d, sim.typical, keys,
+                    weights_d, ranks,
+                ),
+                engine=sim._last_engine, events=e,
+            )
+        sweep.engine = sim._last_engine
+        obs.note_scan(sim._last_engine, counters=None, events=e * b)
+        sim.log.info(
+            f"[Engine] sweep of {b} configs x {e} events ran on: "
+            f"{sim._last_engine}"
         )
-    # per-lane frag of the final states in one vmapped call (the same
-    # reduction cluster_analysis reports), before the single fetch
-    amounts = jax.jit(
-        jax.vmap(
-            lambda s, tp: cluster_frag_amounts(s, tp).sum(0),
-            in_axes=(0, None),
-        )
-    )(out.state, sim.typical)
-    with sim.obs.span("fetch", events=e * b):
-        out = device_fetch(out)
-        amounts = np.asarray(amounts)
+        with obs.span("frag_postpass") as h:
+            if cfg.report_per_event:
+                out = out._replace(
+                    metrics=_sweep_metrics_fn()(
+                        state, specs_d, ev_kind_d, ev_pod_d,
+                        out.event_node, out.event_dev, sim.typical,
+                    )
+                )
+            # per-lane frag of the final states in one vmapped call (the same
+            # reduction cluster_analysis reports), before the single fetch.
+            # The jit wraps a new function object in every call, so dispatch
+            # here is a trace, a lowering and a compile or a cache load.
+            amounts = jax.jit(
+                jax.vmap(_lane_frag_amounts, in_axes=(0, None))
+            )(out.state, sim.typical)
+            obs.settle(h, amounts, out.metrics)
+        with obs.span("fetch", events=e * b):
+            out = device_fetch(out)
+            amounts = np.asarray(amounts)
 
-    pad_skips = e2 - e
-    return [
-        _slice_sweep_lane(out, amounts, i, w[i], seeds[i], p, e, pad_skips)
-        for i in range(b)
-    ]
+        with obs.span("slice_lanes"):
+            pad_skips = e2 - e
+            return [
+                _slice_sweep_lane(
+                    out, amounts, i, w[i], seeds[i], p, e, pad_skips
+                )
+                for i in range(b)
+            ]
 
 
 # ---------------------------------------------------------------------------
@@ -4095,7 +4122,6 @@ def schedule_pods_sweep_multi(
     stays bit-identical to the standalone run_with_faults run over that
     tuned trace (given the sweep's unified retry-queue capacity —
     explicit queue_capacity pins it, the chaos-sweep contract)."""
-    from tpusim.ops.frag import cluster_frag_amounts
     from tpusim.sim.table_engine import (
         build_pod_types,
         num_pod_types,
@@ -4287,10 +4313,7 @@ def schedule_pods_sweep_multi(
             )
         )
     amounts = jax.jit(
-        jax.vmap(
-            lambda s, tp: cluster_frag_amounts(s, tp).sum(0),
-            in_axes=(0, None),
-        )
+        jax.vmap(_lane_frag_amounts, in_axes=(0, None))
     )(out.state, sim.typical)
     with sim.obs.span("fetch", events=true_events):
         out = device_fetch(out)
@@ -4317,7 +4340,6 @@ def _dispatch_sweep_multi_faults(
     length, draw rows, queue capacity, frag flag) are shared with
     schedule_pods_sweep_faults, so a service family's consecutive mixed
     fault/tune waves hold one compiled executable."""
-    from tpusim.ops.frag import cluster_frag_amounts
     from tpusim.sim import fault_lane
     from tpusim.sim.engine import make_replay
     from tpusim.sim.faults import FaultConfig
@@ -4420,10 +4442,7 @@ def _dispatch_sweep_multi_faults(
         f"{e_m}) ran on: {sim._last_engine}"
     )
     amounts = jax.jit(
-        jax.vmap(
-            lambda s, tp: cluster_frag_amounts(s, tp).sum(0),
-            in_axes=(0, None),
-        )
+        jax.vmap(_lane_frag_amounts, in_axes=(0, None))
     )(out.state, sim.typical)
     with sim.obs.span("fetch", events=true_events):
         out = device_fetch(out)
@@ -4530,7 +4549,6 @@ def schedule_pods_sweep_faults(
     chaos-smoke zero-recompile pin). Each SweepLane carries its
     DisruptionMetrics, bit-identical to the standalone run_with_faults
     run with that schedule (tests/test_fault_lane.py)."""
-    from tpusim.ops.frag import cluster_frag_amounts
     from tpusim.sim import fault_lane
     from tpusim.sim.engine import make_replay
     from tpusim.sim.table_engine import (
@@ -4674,10 +4692,7 @@ def schedule_pods_sweep_faults(
         f"(merged stream {e_m}) ran on: {sim._last_engine}"
     )
     amounts = jax.jit(
-        jax.vmap(
-            lambda s, tp: cluster_frag_amounts(s, tp).sum(0),
-            in_axes=(0, None),
-        )
+        jax.vmap(_lane_frag_amounts, in_axes=(0, None))
     )(out.state, sim.typical)
     with sim.obs.span("fetch", events=e * b):
         out = device_fetch(out)

@@ -355,6 +355,7 @@ def make_table_builders(policies, sel_idx: int):
         feas = jnp.concatenate([o[2][:, 0] for o in outs], 0)  # [K]
         return scores.T, sdev, feas
 
+    @jax.named_scope("tpusim.table_build")
     def init_tables(state: NodeState, types: PodTypes, tp, key):
         outs = []
         for which, specs in (("share", types.share), ("whole", types.whole)):
@@ -734,24 +735,26 @@ def _make_table_engine(
             # apply the PREVIOUS event's deferred scatters first — every
             # carried buffer is written before anything reads it, so all
             # updates alias in place (PendingCommit)
-            state, placed, masks, failed = apply_commit(
-                state, placed, masks, failed, pend
-            )
+            with jax.named_scope("tpusim.commit"):
+                state, placed, masks, failed = apply_commit(
+                    state, placed, masks, failed, pend
+                )
 
             # dirty-column refresh — same kernels, same order as the flat
             # path; dirty < n always, so sentinel columns are never written
-            col_scores, col_sdev, col_feas = _columns(
-                _row_state(state, dirty), types, tp, k_rand
-            )
-            score_tbl = jax.lax.dynamic_update_slice(
-                score_tbl, col_scores[:, :, None], (0, 0, dirty)
-            )
-            sdev_tbl = jax.lax.dynamic_update_slice(
-                sdev_tbl, col_sdev[:, None], (0, dirty)
-            )
-            feas_tbl = jax.lax.dynamic_update_slice(
-                feas_tbl, col_feas[:, None], (0, dirty)
-            )
+            with jax.named_scope("tpusim.refresh"):
+                col_scores, col_sdev, col_feas = _columns(
+                    _row_state(state, dirty), types, tp, k_rand
+                )
+                score_tbl = jax.lax.dynamic_update_slice(
+                    score_tbl, col_scores[:, :, None], (0, 0, dirty)
+                )
+                sdev_tbl = jax.lax.dynamic_update_slice(
+                    sdev_tbl, col_sdev[:, None], (0, dirty)
+                )
+                feas_tbl = jax.lax.dynamic_update_slice(
+                    feas_tbl, col_feas[:, None], (0, dirty)
+                )
 
             # in-scan series sample (ISSUE 5): committed state + current
             # tables, on the processed-event stride
@@ -762,103 +765,105 @@ def _make_table_engine(
             )
 
             # dirty-block aggregate refresh for ALL K types: O(K*B)
-            blk = dirty // bsz
-            j0 = blk * bsz
-            raw_blk = jax.lax.dynamic_slice(
-                score_tbl, (0, 0, j0), (num_pol, k_types, bsz)
-            )
-            feas_blk = jax.lax.dynamic_slice(
-                feas_tbl, (0, j0), (k_types, bsz)
-            )
-            rank_blk = jax.lax.dynamic_slice(rank_p, (j0,), (bsz,))
-            if n_norm:
-                selb = jnp.stack([raw_blk[i] for i in norm_idx])
-                mn = jnp.where(feas_blk, selb, _INT_MAX).min(-1)
-                mx = jnp.where(feas_blk, selb, -_INT_MAX).max(-1)
-                brmin = jax.lax.dynamic_update_slice(
-                    brmin, mn[:, :, None], (0, 0, blk)
+            with jax.named_scope("tpusim.summary"):
+                blk = dirty // bsz
+                j0 = blk * bsz
+                raw_blk = jax.lax.dynamic_slice(
+                    score_tbl, (0, 0, j0), (num_pol, k_types, bsz)
                 )
-                brmax = jax.lax.dynamic_update_slice(
-                    brmax, mx[:, :, None], (0, 0, blk)
+                feas_blk = jax.lax.dynamic_slice(
+                    feas_tbl, (0, j0), (k_types, bsz)
                 )
-            # block totals use the STORED extrema — consistent with every
-            # other block of each type's summary row by construction
-            tot_blk = _totals(raw_blk, feas_blk, slo, shi, wts)
-            bm, brk, bar = block_reduce(tot_blk, rank_blk)
-            bt = jax.lax.dynamic_update_slice(bt, bm[:, None], (0, blk))
-            br = jax.lax.dynamic_update_slice(br, brk[:, None], (0, blk))
-            bn = jax.lax.dynamic_update_slice(
-                bn, (j0 + bar)[:, None], (0, blk)
-            )
-
-            # extrema drift check + conditional summary-row rebuild for
-            # this event's type — outside the event switch, so only [N/B]
-            # rows (never whole tables) cross a cond/switch boundary
-            rebuilt = None  # obs: did this event pay the O(N) rebuild?
-            if n_norm:
-                brmin_row = jax.lax.dynamic_index_in_dim(
-                    brmin, t_id, 1, False
-                )
-                brmax_row = jax.lax.dynamic_index_in_dim(
-                    brmax, t_id, 1, False
-                )
-                lo_cur = brmin_row.min(-1)
-                hi_cur = brmax_row.max(-1)
-                slo_col = jax.lax.dynamic_index_in_dim(slo, t_id, 1, False)
-                shi_col = jax.lax.dynamic_index_in_dim(shi, t_id, 1, False)
-                changed = jnp.any(
-                    (lo_cur != slo_col) | (hi_cur != shi_col)
-                )
-                rebuilt = changed
-
-                def rebuild():
-                    raws = jax.lax.dynamic_index_in_dim(
-                        score_tbl, t_id, 1, False
-                    )  # [num_pol, n_pad]
-                    fr = jax.lax.dynamic_index_in_dim(
-                        feas_tbl, t_id, 0, False
+                rank_blk = jax.lax.dynamic_slice(rank_p, (j0,), (bsz,))
+                if n_norm:
+                    selb = jnp.stack([raw_blk[i] for i in norm_idx])
+                    mn = jnp.where(feas_blk, selb, _INT_MAX).min(-1)
+                    mx = jnp.where(feas_blk, selb, -_INT_MAX).max(-1)
+                    brmin = jax.lax.dynamic_update_slice(
+                        brmin, mn[:, :, None], (0, 0, blk)
                     )
-                    tot = _totals(
-                        raws[:, None, :], fr[None, :],
-                        lo_cur[:, None], hi_cur[:, None], wts,
-                    )[0]
-                    m2, r2, a2 = block_reduce(
-                        tot.reshape(nblk, bsz), rank_p.reshape(nblk, bsz)
+                    brmax = jax.lax.dynamic_update_slice(
+                        brmax, mx[:, :, None], (0, 0, blk)
                     )
-                    return m2, r2, offs + a2, lo_cur, hi_cur
-
-                def keep():
-                    return (
-                        jax.lax.dynamic_index_in_dim(bt, t_id, 0, False),
-                        jax.lax.dynamic_index_in_dim(br, t_id, 0, False),
-                        jax.lax.dynamic_index_in_dim(bn, t_id, 0, False),
-                        slo_col,
-                        shi_col,
-                    )
-
-                bt_row, br_row, bn_row, lo_new, hi_new = jax.lax.cond(
-                    changed, rebuild, keep
-                )
-                bt = jax.lax.dynamic_update_slice(
-                    bt, bt_row[None], (t_id, 0)
-                )
-                br = jax.lax.dynamic_update_slice(
-                    br, br_row[None], (t_id, 0)
-                )
+                # block totals use the STORED extrema — consistent with every
+                # other block of each type's summary row by construction
+                tot_blk = _totals(raw_blk, feas_blk, slo, shi, wts)
+                bm, brk, bar = block_reduce(tot_blk, rank_blk)
+                bt = jax.lax.dynamic_update_slice(bt, bm[:, None], (0, blk))
+                br = jax.lax.dynamic_update_slice(br, brk[:, None], (0, blk))
                 bn = jax.lax.dynamic_update_slice(
-                    bn, bn_row[None], (t_id, 0)
+                    bn, (j0 + bar)[:, None], (0, blk)
                 )
-                slo = jax.lax.dynamic_update_slice(
-                    slo, lo_new[:, None], (0, t_id)
-                )
-                shi = jax.lax.dynamic_update_slice(
-                    shi, hi_new[:, None], (0, t_id)
-                )
-            else:
-                bt_row = jax.lax.dynamic_index_in_dim(bt, t_id, 0, False)
-                br_row = jax.lax.dynamic_index_in_dim(br, t_id, 0, False)
-                bn_row = jax.lax.dynamic_index_in_dim(bn, t_id, 0, False)
 
+                # extrema drift check + conditional summary-row rebuild for
+                # this event's type — outside the event switch, so only [N/B]
+                # rows (never whole tables) cross a cond/switch boundary
+                rebuilt = None  # obs: did this event pay the O(N) rebuild?
+                if n_norm:
+                    brmin_row = jax.lax.dynamic_index_in_dim(
+                        brmin, t_id, 1, False
+                    )
+                    brmax_row = jax.lax.dynamic_index_in_dim(
+                        brmax, t_id, 1, False
+                    )
+                    lo_cur = brmin_row.min(-1)
+                    hi_cur = brmax_row.max(-1)
+                    slo_col = jax.lax.dynamic_index_in_dim(slo, t_id, 1, False)
+                    shi_col = jax.lax.dynamic_index_in_dim(shi, t_id, 1, False)
+                    changed = jnp.any(
+                        (lo_cur != slo_col) | (hi_cur != shi_col)
+                    )
+                    rebuilt = changed
+
+                    def rebuild():
+                        raws = jax.lax.dynamic_index_in_dim(
+                            score_tbl, t_id, 1, False
+                        )  # [num_pol, n_pad]
+                        fr = jax.lax.dynamic_index_in_dim(
+                            feas_tbl, t_id, 0, False
+                        )
+                        tot = _totals(
+                            raws[:, None, :], fr[None, :],
+                            lo_cur[:, None], hi_cur[:, None], wts,
+                        )[0]
+                        m2, r2, a2 = block_reduce(
+                            tot.reshape(nblk, bsz), rank_p.reshape(nblk, bsz)
+                        )
+                        return m2, r2, offs + a2, lo_cur, hi_cur
+
+                    def keep():
+                        return (
+                            jax.lax.dynamic_index_in_dim(bt, t_id, 0, False),
+                            jax.lax.dynamic_index_in_dim(br, t_id, 0, False),
+                            jax.lax.dynamic_index_in_dim(bn, t_id, 0, False),
+                            slo_col,
+                            shi_col,
+                        )
+
+                    bt_row, br_row, bn_row, lo_new, hi_new = jax.lax.cond(
+                        changed, rebuild, keep
+                    )
+                    bt = jax.lax.dynamic_update_slice(
+                        bt, bt_row[None], (t_id, 0)
+                    )
+                    br = jax.lax.dynamic_update_slice(
+                        br, br_row[None], (t_id, 0)
+                    )
+                    bn = jax.lax.dynamic_update_slice(
+                        bn, bn_row[None], (t_id, 0)
+                    )
+                    slo = jax.lax.dynamic_update_slice(
+                        slo, lo_new[:, None], (0, t_id)
+                    )
+                    shi = jax.lax.dynamic_update_slice(
+                        shi, hi_new[:, None], (0, t_id)
+                    )
+                else:
+                    bt_row = jax.lax.dynamic_index_in_dim(bt, t_id, 0, False)
+                    br_row = jax.lax.dynamic_index_in_dim(br, t_id, 0, False)
+                    bn_row = jax.lax.dynamic_index_in_dim(bn, t_id, 0, False)
+
+            @jax.named_scope("tpusim.select")
             def do_create():
                 # selectHost over N/B block summaries — the same
                 # packed_argmax combine the oracle runs over N nodes
@@ -1051,24 +1056,26 @@ def _make_table_engine(
             # apply the PREVIOUS event's deferred scatters first: every
             # carried buffer is written before anything reads it this
             # iteration, so all updates alias in place (PendingCommit)
-            state, placed, masks, failed = apply_commit(
-                state, placed, masks, failed, pend
-            )
+            with jax.named_scope("tpusim.commit"):
+                state, placed, masks, failed = apply_commit(
+                    state, placed, masks, failed, pend
+                )
 
             # refresh the one column whose node changed last event (from
             # the just-committed state)
-            col_scores, col_sdev, col_feas = _columns(
-                _row_state(state, dirty), types, tp, k_rand
-            )
-            score_tbl = jax.lax.dynamic_update_slice(
-                score_tbl, col_scores[:, :, None], (0, 0, dirty)
-            )
-            sdev_tbl = jax.lax.dynamic_update_slice(
-                sdev_tbl, col_sdev[:, None], (0, dirty)
-            )
-            feas_tbl = jax.lax.dynamic_update_slice(
-                feas_tbl, col_feas[:, None], (0, dirty)
-            )
+            with jax.named_scope("tpusim.refresh"):
+                col_scores, col_sdev, col_feas = _columns(
+                    _row_state(state, dirty), types, tp, k_rand
+                )
+                score_tbl = jax.lax.dynamic_update_slice(
+                    score_tbl, col_scores[:, :, None], (0, 0, dirty)
+                )
+                sdev_tbl = jax.lax.dynamic_update_slice(
+                    sdev_tbl, col_sdev[:, None], (0, dirty)
+                )
+                feas_tbl = jax.lax.dynamic_update_slice(
+                    feas_tbl, col_feas[:, None], (0, dirty)
+                )
 
             # in-scan series sample (ISSUE 5): committed state + current
             # tables, on the processed-event stride
@@ -1078,6 +1085,7 @@ def _make_table_engine(
                 if series_every else ()
             )
 
+            @jax.named_scope("tpusim.select")
             def create_result():
                 """The full create computation — ONE definition serving
                 both select layouts below (Round 18)."""
