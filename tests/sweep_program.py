@@ -1,0 +1,136 @@
+"""Helpers for tests that look at the sweep's program without running it:
+capture the jitted vmapped replay with the shapes a cell calls it on, and
+list the large `copy` operations of a compiled module's scan."""
+
+import json
+import math
+import os
+import re
+
+import jax
+import numpy as np
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CELL_CONFIG = os.path.join(REPO, "benchmark", "configs", "synth100k.json")
+
+
+class _Captured(Exception):
+    pass
+
+
+def capture_sweep(sim, trace, weights, seeds, run: bool = False):
+    """(fn, shapes, lanes): the wrapper schedule_pods_sweep dispatches, the
+    ShapeDtypeStructs of its operands, and the sweep's lanes, or None
+    when the sweep is stopped before it runs (`run=False`)."""
+    from tpusim.sim import driver
+
+    called = {}
+    real = driver._sweep_engine
+
+    def spy(engine, table):
+        fn = real(engine, table)
+
+        def call(*args):
+            called["fn"] = fn
+            called["shapes"] = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+            if not run:
+                raise _Captured()
+            return fn(*args)
+
+        return call
+
+    driver._sweep_engine = spy
+    lanes = None
+    try:
+        lanes = driver.schedule_pods_sweep(sim, trace, weights, seeds)
+    except _Captured:
+        pass
+    finally:
+        driver._sweep_engine = real
+    return called["fn"], called["shapes"], lanes
+
+
+def cell_simulator(nodes: int, depth: int, seed: int = 7, **over):
+    """The benchmark's synth100k configuration at `nodes` nodes: the
+    Simulator and its trace of `depth` creates."""
+    from benchmark.drivers import wave
+    from benchmark.lib import inputs
+
+    with open(CELL_CONFIG) as f:
+        config = json.load(f)
+    config["cluster"]["nodes"] = nodes
+    node_list, pods = inputs.build(config, seed, depth)
+    cfg = wave.simulator_config(config["simulator"], seed, profile=False,
+                                **over)
+    sim = wave.build_simulator(node_list, pods, cfg)
+    return sim, sim.prepare_pods()[:depth], cfg
+
+
+def cell_weights(cfg, lanes: int):
+    return np.tile(np.asarray([w for _, w in cfg.policies], np.int32),
+                   (lanes, 1))
+
+
+# ------------------------------------------------------------ HLO text
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(?[^=]*?\)?) ([\w\-]+)\((.*)$")
+_SHAPE = re.compile(r"\w+\[([\d,]*)\]")
+_CALLED = re.compile(
+    r"(?:calls|body|condition|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+
+
+def _computations(text: str) -> dict:
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _called(lines) -> set:
+    out = set()
+    for line in lines:
+        for one, many in _CALLED.findall(line):
+            if one:
+                out.add(one)
+            out.update(n.strip().lstrip("%") for n in many.split(",") if n)
+    return out
+
+
+def big_copies_in_scan(text: str, min_elems: int) -> list:
+    """`copy` / `copy-start` instructions whose result holds at least
+    `min_elems` elements, in the largest while body of a compiled module
+    and every computation it calls (inner loops, branches, fusions). Each
+    as (computation, name, result shape with layout, source line)."""
+    comps = _computations(text)
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    if not bodies:
+        raise ValueError("the module holds no while loop")
+    scan = max(bodies, key=lambda b: len(comps.get(b, ())))
+    reach, todo = set(), [scan]
+    while todo:
+        name = todo.pop()
+        if name in reach or name not in comps:
+            continue
+        reach.add(name)
+        todo.extend(_called(comps[name]))
+    found = []
+    for name in sorted(reach):
+        for line in comps[name]:
+            m = _INSTRUCTION.match(line)
+            if not m or m.group(3) not in ("copy", "copy-start"):
+                continue
+            sizes = [math.prod(int(d) for d in dims.split(",") if d)
+                     for dims in _SHAPE.findall(m.group(2))]
+            if sizes and max(sizes) >= min_elems:
+                found.append((name, m.group(1), m.group(2).strip(),
+                              line.strip()[:300]))
+    return found

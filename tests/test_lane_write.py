@@ -1,0 +1,296 @@
+"""sim/lane_write.py: under jax.vmap every access goes through the module's
+own batching rule and must equal, bit for bit, vmap of the plain expression
+the step bodies used to hold; without vmap it IS that expression."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from tpusim.sim import lane_write as lw
+
+L, N, K, POL, P, BSZ = 5, 37, 6, 2, 11, 8
+N_PAD = 40  # the blocked tables are padded to whole blocks
+
+
+def _rng():
+    return np.random.default_rng(7)
+
+
+def _ints(shape, lo=-50, hi=50):
+    return jnp.asarray(_rng().integers(lo, hi, shape), jnp.int32)
+
+
+def _bools(shape):
+    return jnp.asarray(_rng().integers(0, 2, shape).astype(bool))
+
+
+# indices a sweep produces: the same node in several lanes, node 0, the
+# last real node / column, and the dummy bookkeeping row [P]
+NODE_IDX = jnp.asarray([3, 0, N - 1, 3, 3], jnp.int32)
+POD_IDX = jnp.asarray([P, 0, P - 1, 4, 4], jnp.int32)
+
+# which operands carry the lane axis: everything (a steady scan step), the
+# leaf alone shared (first pass of a vmapped scan: tables and state come in
+# unbatched), the index shared by the lanes (one event stream), and only
+# the leaf batched
+PATTERNS = {
+    "all": (True, True, True),
+    "leaf_shared": (False, True, True),
+    "index_shared": (True, True, False),
+    "leaf_only": (True, False, False),
+}
+
+
+def _axes(args, batched):
+    """Operands with the lane axis dropped where `batched` says shared, and
+    the matching in_axes."""
+    out = tuple(a if b else a[0] for a, b in zip(args, batched))
+    return out, tuple(0 if b else None for b in batched)
+
+
+def _one_lane(args, axes):
+    """Lane 0 of the operands: not vmapped, the plain expression itself."""
+    return tuple(a[0] if ax == 0 else a for a, ax in zip(args, axes))
+
+
+def _same(got, want):
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, (g, w)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# ---------------------------------------------------------------- columns
+def _plain_column(tbl, col, idx):
+    return lax.dynamic_update_slice(
+        tbl, col[..., None], (0,) * (tbl.ndim - 1) + (idx,))
+
+
+def _plain_column_block(tbl, col, idx):
+    out = _plain_column(tbl, col, idx)
+    start = (idx // BSZ) * BSZ
+    return out, lax.dynamic_slice(
+        out, (0,) * (tbl.ndim - 1) + (start,), tbl.shape[:-1] + (BSZ,))
+
+
+COLUMN_LEAVES = {
+    "score": lambda: (_ints((L, POL, K, N_PAD)), _ints((L, POL, K))),
+    "sdev": lambda: (_ints((L, K, N_PAD), -1, 8), _ints((L, K), -1, 8)),
+    "feas": lambda: (_bools((L, K, N_PAD)), _bools((L, K))),
+}
+
+
+@pytest.mark.parametrize("pattern", sorted(PATTERNS))
+@pytest.mark.parametrize("block", [False, True])
+@pytest.mark.parametrize("leaf", sorted(COLUMN_LEAVES))
+def test_write_column_equals_vmap_of_the_plain_write(leaf, block, pattern):
+    tbl, col = COLUMN_LEAVES[leaf]()
+    args, axes = _axes((tbl, col, NODE_IDX), PATTERNS[pattern])
+    if block:
+        mine = lambda t, c, i: lw.write_column(  # noqa: E731
+            t, c, i, block=((i // BSZ) * BSZ, BSZ))
+        plain = _plain_column_block
+    else:
+        mine, plain = lw.write_column, _plain_column
+    _same(jax.jit(jax.vmap(mine, in_axes=axes))(*args),
+          jax.vmap(plain, in_axes=axes)(*args))
+    _same(mine(*_one_lane(args, axes)), plain(*_one_lane(args, axes)))
+
+
+# ------------------------------------------------------------------- rows
+def _aff(leaf, idx, val):
+    return lw.add_row(leaf, (idx, jnp.int32(2)), val)
+
+
+ROW_WRITES = {
+    # name: (leaf, value a lane, index, mine, plain)
+    "cpu_left": lambda: (_ints((L, N)), _ints((L,)), NODE_IDX, lw.add_row,
+                         lambda a, i, v: a.at[i].add(v)),
+    "mem_left": lambda: (_ints((L, N)), _ints((L,)), NODE_IDX, lw.add_row,
+                         lambda a, i, v: a.at[i].add(v)),
+    "gpu_left": lambda: (_ints((L, N, 8)), _ints((L, 8)), NODE_IDX,
+                         lw.add_row, lambda a, i, v: a.at[i].add(v)),
+    # node == -1 commits add a zero at the clipped row
+    "gpu_left_zero_delta": lambda: (
+        _ints((L, N, 8)), jnp.zeros((L, 8), jnp.int32),
+        jnp.zeros(L, jnp.int32), lw.add_row, lambda a, i, v: a.at[i].add(v)),
+    "aff_cnt": lambda: (_ints((L, N, 9)), _ints((L,)), NODE_IDX, _aff,
+                        lambda a, i, v: a.at[i, jnp.int32(2)].add(v)),
+    "placed": lambda: (_ints((L, P + 1)), _ints((L,)), POD_IDX, lw.set_row,
+                       lambda a, i, v: a.at[i].set(v)),
+    "masks": lambda: (_bools((L, P + 1, 8)), _bools((L, 8)), POD_IDX,
+                      lw.set_row, lambda a, i, v: a.at[i].set(v)),
+    "failed": lambda: (_bools((L, P + 1)), _bools((L,)), POD_IDX, lw.set_row,
+                       lambda a, i, v: a.at[i].set(v)),
+}
+
+
+@pytest.mark.parametrize("pattern", sorted(PATTERNS))
+@pytest.mark.parametrize("leaf", sorted(ROW_WRITES))
+def test_row_writes_equal_vmap_of_the_plain_update(leaf, pattern):
+    arr, val, idx, mine, plain = ROW_WRITES[leaf]()
+    leaf_b, val_b, idx_b = PATTERNS[pattern]
+    args, axes = _axes((arr, idx, val), (leaf_b, idx_b, val_b))
+    _same(jax.jit(jax.vmap(mine, in_axes=axes))(*args),
+          jax.vmap(plain, in_axes=axes)(*args))
+    _same(mine(*_one_lane(args, axes)), plain(*_one_lane(args, axes)))
+
+
+ROW_READS = {
+    "cpu_left": lambda: _ints((L, N)),
+    "gpu_left": lambda: _ints((L, N, 8)),
+    "aff_cnt": lambda: _ints((L, N, 9)),
+}
+READ_PATTERNS = {"all": (True, True), "leaf_shared": (False, True),
+                 "index_shared": (True, False)}
+
+
+@pytest.mark.parametrize("pattern", sorted(READ_PATTERNS))
+@pytest.mark.parametrize("keepdims", [True, False])
+@pytest.mark.parametrize("leaf", sorted(ROW_READS))
+def test_read_row_equals_vmap_of_the_plain_slice(leaf, keepdims, pattern):
+    args, axes = _axes((ROW_READS[leaf](), NODE_IDX), READ_PATTERNS[pattern])
+    mine = lambda a, i: lw.read_row(a, i, keepdims=keepdims)  # noqa: E731
+    if keepdims:
+        plain = lambda a, i: lax.dynamic_slice_in_dim(a, i, 1, 0)  # noqa: E731
+    else:
+        plain = lambda a, i: a[i]  # noqa: E731
+    _same(jax.jit(jax.vmap(mine, in_axes=axes))(*args),
+          jax.vmap(plain, in_axes=axes)(*args))
+    _same(mine(*_one_lane(args, axes)), plain(*_one_lane(args, axes)))
+
+
+@pytest.mark.parametrize("pattern", sorted(PATTERNS))
+@pytest.mark.parametrize("leaf", ["sdev", "feas"])
+def test_read_entry_equals_vmap_of_the_plain_slice(leaf, pattern):
+    tbl, _ = COLUMN_LEAVES[leaf]()
+    rows = jnp.asarray([0, K - 1, 2, 2, 5], jnp.int32)
+    args, axes = _axes((tbl, rows, NODE_IDX), PATTERNS[pattern])
+    plain = lambda t, r, c: lax.dynamic_slice(  # noqa: E731
+        t, (r, c), (1, 1))[0, 0]
+    _same(jax.jit(jax.vmap(lw.read_entry, in_axes=axes))(*args),
+          jax.vmap(plain, in_axes=axes)(*args))
+    one = _one_lane(args, axes)
+    _same(lw.read_entry(*one), plain(*one))
+
+
+# ------------------------------------------------------- the rule's shape
+def _step(tbl, left, col, idx, delta):
+    tbl, blk = lw.write_column(tbl, col, idx, block=((idx // BSZ) * BSZ, BSZ))
+    left = lw.add_row(left, idx, delta)
+    return tbl, left, blk, lw.read_row(left, idx)
+
+
+def _plain_step(tbl, left, col, idx, delta):
+    tbl, blk = _plain_column_block(tbl, col, idx)
+    left = left.at[idx].add(delta)
+    return tbl, left, blk, lax.dynamic_slice_in_dim(left, idx, 1, 0)
+
+
+def _step_args():
+    return (_ints((L, K, N_PAD)), _ints((L, N, 8)), _ints((L, K)), NODE_IDX,
+            _ints((L, 8)))
+
+
+def test_reads_are_windows_batched_over_the_lanes():
+    """What the rule is for: a vmapped step writes through the scatters
+    vmap derives and reads through gathers of (rows, nodes) windows batched
+    over the lane axis, none holding every row of its leaf, so the layout
+    that serves the scatters serves the reads too (on the TPU;
+    tests/test_sweep_compile.py)."""
+    n, n_pad = 300, 384  # more than one 128-node tile
+    args = (_ints((L, K, n_pad)), _ints((L, n, 8)), _ints((L, K)),
+            jnp.asarray([3, 0, n - 1, 3, 200], jnp.int32), _ints((L, 8)))
+    text = jax.jit(jax.vmap(_step)).lower(*args).as_text()
+    _same(jax.jit(jax.vmap(_step))(*args), jax.vmap(_plain_step)(*args))
+    assert text.count('"stablehlo.scatter"') == 2
+    # gathers that read a carried leaf (the rest pick from a gathered tile)
+    gathers = [ln for ln in text.splitlines() if '"stablehlo.gather"' in ln
+               and (f": (tensor<{L}x{K}x{n_pad}xi32>, " in ln
+                    or f": (tensor<{L}x8x{n}xi32>, " in ln)]
+    sizes = [ln.split("slice_sizes = array<i64: ")[1].split(">")[0]
+             for ln in gathers]
+    # the block of the [K, N] table; the tile that holds gpu_left's row
+    assert sorted(sizes) == [f"1, {K // 2}, {BSZ}", "1, 4, 128"], sizes
+    for ln in gathers:
+        assert "operand_batching_dims = [0]" in ln, ln
+
+
+@pytest.mark.parametrize("k,dtype", [
+    (71, jnp.int32), (71, jnp.bool_), (16, jnp.int32), (9, jnp.int32),
+    (2, jnp.int32), (1, jnp.int32)])
+def test_a_block_is_read_as_two_half_windows(k, dtype):
+    """The dirty block of a [K, N] table comes in two windows of
+    ceil(K / 2) rows (overlapping by a row when K is odd; one window when
+    K is 1); equal to the plain slice for every K."""
+    tbl = (_bools((L, k, N_PAD)) if dtype == jnp.bool_
+           else _ints((L, k, N_PAD)))
+    col = tbl[:, :, 0]
+    mine = lambda t, c, i: lw.write_column(  # noqa: E731
+        t, c, i, block=((i // BSZ) * BSZ, BSZ))
+    fn = jax.jit(jax.vmap(mine))
+    _same(fn(tbl, col, NODE_IDX),
+          jax.vmap(_plain_column_block)(tbl, col, NODE_IDX))
+    text = fn.lower(tbl, col, NODE_IDX).as_text()
+    (gather,) = [ln for ln in text.splitlines() if '"stablehlo.gather"' in ln
+                 and f"x{N_PAD}x" in ln.split("->")[0]]
+    h = -(-k // 2)
+    assert f"slice_sizes = array<i64: 1, {h}, {BSZ}>" in gather
+    assert f"-> tensor<{L}x{min(k, 2)}x{h}x{BSZ}x" in gather, gather
+
+
+def test_the_unbatched_program_is_the_plain_one():
+    plain = jax.jit(lambda t, a, c, i, d: (
+        *_plain_column_block(t, c, i), a.at[i].add(d))).lower(
+            *(x[0] for x in _step_args())).as_text()
+    mine = jax.jit(lambda t, a, c, i, d: (
+        *lw.write_column(t, c, i, block=((i // BSZ) * BSZ, BSZ)),
+        lw.add_row(a, i, d))).lower(*(x[0] for x in _step_args())).as_text()
+    for op in ("dynamic_update_slice", "dynamic_slice", "scatter", "gather",
+               "custom_call"):
+        assert mine.count(f"stablehlo.{op}") == plain.count(
+            f"stablehlo.{op}"), op
+    assert "stablehlo.gather" not in mine and "custom_call" not in mine
+    assert mine.count("stablehlo.dynamic_update_slice") == 1
+
+
+def test_counting_sees_write_sites_once_and_only_under_vmap():
+    with lw.counting() as sites:
+        jax.jit(_step).lower(*(x[0] for x in _step_args()))
+    assert len(sites) == 0
+    with lw.counting() as sites:
+        jax.jit(jax.vmap(_step)).lower(*_step_args())
+    assert len(sites) == 2  # write_column and add_row; read_row is no write
+
+    def scanned(tbl, left, col, idx, delta):
+        def body(carry, _):
+            tbl, left = carry
+            tbl, left, _, _ = _step(tbl, left, col, idx, delta)
+            return (tbl, left), None
+        return lax.scan(body, (tbl, left), None, length=3, unroll=2)[0]
+
+    # tables and state come in shared: the scan's batching runs to a
+    # fixpoint and visits each site more than once
+    axes = (None, None, 0, 0, 0)
+    args = tuple(a if ax == 0 else a[0]
+                 for a, ax in zip(_step_args(), axes))
+    with lw.counting() as sites:
+        got = jax.jit(jax.vmap(scanned, in_axes=axes))(*args)
+    assert len(sites) == 2
+    want = jax.vmap(scanned, in_axes=axes)(*args)
+    _same(got, want)
+
+
+def test_the_rule_can_be_vmapped_again():
+    """A config axis over a seed axis: the rule's output is plain lax, so
+    an outer vmap batches it like any other program."""
+    args = jax.tree.map(lambda a: jnp.stack([a, a + 1 if a.dtype != bool
+                                             else ~a]), _step_args())
+    args = (args[0], args[1], args[2], jnp.stack([NODE_IDX, NODE_IDX[::-1]]),
+            args[4])
+    got = jax.jit(jax.vmap(jax.vmap(_step)))(*args)
+    want = [jax.vmap(_step)(*(a[i] for a in args)) for i in range(2)]
+    _same(got, jax.tree.map(lambda *x: jnp.stack(x), *want))

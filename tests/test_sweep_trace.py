@@ -8,6 +8,7 @@ import jax
 import numpy as np
 import pytest
 
+from tests.sweep_program import capture_sweep
 from tests.test_sweep import _cfg, _mk_cluster, _mk_pods
 from tpusim.obs import Recorder, compile_counts, note_compile_cache, sweep_log
 from tpusim.obs.spans import CACHE_HIT_EVENT, COMPILE_EVENT
@@ -199,24 +200,8 @@ def scoped(request):
     shapes its vmapped engine was called on."""
     block_size = request.param
     sim, trace = _sim(block_size=block_size)
-    called = {}
-    real = driver._sweep_engine
-
-    def spy(engine, table):
-        fn = real(engine, table)
-
-        def call(*args):
-            called["fn"] = fn
-            called["shapes"] = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
-            return fn(*args)
-
-        return call
-
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(driver, "_sweep_engine", spy)
-        lanes = schedule_pods_sweep(sim, trace, WEIGHTS, SEEDS)
-    return block_size, sim, lanes, called
+    fn, shapes, lanes = capture_sweep(sim, trace, WEIGHTS, SEEDS, run=True)
+    return block_size, sim, lanes, {"fn": fn, "shapes": shapes}
 
 
 def test_every_stage_of_the_step_body_has_its_scope(scoped):
@@ -259,3 +244,43 @@ def test_a_scoped_sweep_equals_the_sequential_oracle(scoped):
         got = dict(zip(COUNTER_FIELDS, (int(c) for c in lane.counters)))
         want = res.telemetry.counters
         assert all(got[f] == want[f] for f in INVARIANT_FIELDS), (got, want)
+
+
+# the step body: write_column x3 (score, device, feasibility) and the
+# commit's add_row x4 (cpu_left, mem_left, gpu_left, aff_cnt) and set_row x3
+# (placed, masks, failed); the commit once more in the replay's epilogue
+WRITE_SITES = 3 + 7 + 7
+
+
+def test_lane_writes_counts_the_sites_the_batching_rule_lowered(
+        scoped, two_sweeps):
+    _, sim, _, _ = scoped
+    assert sim.obs.sweeps[-1].lane_writes == WRITE_SITES
+    assert sim.run_telemetry().to_record()["timing"]["sweeps"][-1][
+        "lane_writes"] == WRITE_SITES
+    # a warm sweep traces nothing and reports what its program's trace saw
+    _, _, calls = two_sweeps
+    assert [rec.lane_writes for rec, _, _ in calls] == [WRITE_SITES] * 2
+
+
+def test_the_standalone_replay_lowers_as_it_always_did(scoped):
+    """Not vmapped, nothing goes through the rule: no site is counted, the
+    column writes are dynamic_update_slices and there is no custom call
+    (no kernel); the vmapped program holds the rule's scatters and gathers
+    on top."""
+    from tpusim.sim import lane_write
+
+    _, sim, _, called = scoped
+    shapes = list(called["shapes"])
+    for i in (6, 7, 8):  # key, weights, tie-break rank: one lane's
+        shapes[i] = jax.ShapeDtypeStruct(shapes[i].shape[1:], shapes[i].dtype)
+    with lane_write.counting() as sites:
+        text = sim._table_fn.engine.replay.lower(*shapes).as_text()
+    assert not sites
+    assert "custom_call" not in text
+    assert text.count("stablehlo.dynamic_update_slice") >= 3
+    swept = called["fn"].lower(*called["shapes"]).as_text()
+    assert swept.count('"stablehlo.gather"') > text.count('"stablehlo.gather"')
+    assert swept.count('"stablehlo.scatter"') > text.count(
+        '"stablehlo.scatter"')
+    assert "custom_call" not in swept

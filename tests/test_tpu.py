@@ -269,3 +269,66 @@ def test_seed_batched_replay_on_tpu(accel):
         assert np.array_equal(
             np.asarray(single.placed_node), np.asarray(batched.placed_node[s])
         ), f"seed {s}"
+
+
+def test_cell_sized_sweep_holds_no_whole_carry_copy_on_tpu(accel):
+    """ISSUE 27: compile (do not run) the benchmark cell's sweep, 100,000
+    nodes x 40 lanes x K=71, on the chip itself, and list every `copy` in
+    its scan whose result is as large as lanes x nodes. The parent's
+    program held twenty (five whole carried arrays, each in all four
+    steps of the unrolled body)."""
+    from tests import sweep_program
+
+    nodes, lanes = 100_000, 40
+    sim, trace, cfg = sweep_program.cell_simulator(nodes, 512)
+    fn, shapes, _ = sweep_program.capture_sweep(
+        sim, trace, sweep_program.cell_weights(cfg, lanes),
+        list(range(lanes)))
+    assert shapes[9][0].shape[1] == 71
+    text = fn.lower(*shapes).compile().as_text()
+    found = sweep_program.big_copies_in_scan(text, lanes * nodes)
+    assert not found, "\n".join(f"{c}: {n} = copy -> {s}"
+                                for c, n, s, _ in found)
+
+
+def test_wide_flat_sweep_equals_the_oracle_in_every_leaf_on_tpu(accel):
+    """1,213 openb nodes x 256 lanes on the flat step body: placements,
+    masks and EVERY NodeState leaf of the checked lanes, aff_cnt among
+    them (ROADMAP S0: the leaf that came back wrong from sweeps of 1,152
+    lanes or more; its write now goes through lane_write.add_row),
+    against a standalone replay on the sequential oracle."""
+    from benchmark.lib import inputs
+    from tests.test_sweep import _cfg
+    from tpusim.io.trace import load_node_csv, load_pod_csv
+    from tpusim.sim.driver import Simulator, schedule_pods_sweep
+
+    lanes, events = 256, 256
+    nodes = load_node_csv(inputs.NODE_CSV)
+    assert len(nodes) == 1213
+    pods = load_pod_csv(inputs.POD_CSV)[:events]
+    policies = (("FGDScore", 1000),)
+    sim = Simulator(nodes, _cfg(42, policies, engine="table", block_size=-1))
+    sim.set_workload_pods(pods)
+    sim.set_typical_pods()
+    trace = sim.prepare_pods()
+    seeds = [1000 + i for i in range(lanes)]
+    out = schedule_pods_sweep(
+        sim, trace, np.full((lanes, 1), 1000, np.int32), seeds)
+    assert "table" in sim._last_engine and len(out) == lanes
+    differing = []
+    for i in (0, 1, 127, 255):
+        oracle = Simulator(nodes, _cfg(seeds[i], policies,
+                                       engine="sequential"))
+        oracle.set_workload_pods(pods)
+        want = oracle.run()
+        assert "sequential" in oracle._last_engine
+        lane = out[i]
+        pairs = [("placed_node", lane.placed_node, want.placed_node),
+                 ("dev_mask", lane.dev_mask, want.dev_mask)]
+        pairs += [(f"state.{f}", getattr(lane.state, f),
+                   getattr(want.state, f)) for f in lane.state._fields]
+        for name, got, exp in pairs:
+            bad = int((np.asarray(got) != np.asarray(exp)).sum())
+            if bad:
+                differing.append((i, name, bad))
+    assert not differing, differing

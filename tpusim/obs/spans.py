@@ -152,6 +152,9 @@ class SweepRecord:
     spans: List[Span] = field(default_factory=list)
     programs_requested: int = 0  # reached the backend: traced and lowered
     cache_loads: int = 0  # of those, served by the persistent cache
+    # write sites of the sweep's program lowered through
+    # sim/lane_write.py's batching rule (0: the program was not vmapped)
+    lane_writes: int = 0
 
     @property
     def compiled(self) -> int:
@@ -169,6 +172,7 @@ class SweepRecord:
             "programs_requested": self.programs_requested,
             "cache_loads": self.cache_loads,
             "compiled": self.compiled,
+            "lane_writes": self.lane_writes,
             "spans": [s.to_dict() for s in self.spans],
         }
 

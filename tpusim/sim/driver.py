@@ -3423,6 +3423,24 @@ def _sweep_engine(engine, table: bool, donate: bool = True):
     return _SWEEP_WRAP_CACHE[ck]
 
 
+_SWEEP_LANE_WRITES = {}  # engine -> write sites its vmapped program batched
+
+
+def _dispatch_counting_lane_writes(engine, fn, *args):
+    """fn(*args) for fn = _sweep_engine(engine, ...), and the number of
+    write sites of that program that went through sim/lane_write.py's
+    batching rule. The rule runs while the program is traced, so a call
+    served from the jit cache reports what the engine's last trace
+    counted."""
+    from tpusim.sim import lane_write
+
+    with lane_write.counting() as sites:
+        out = fn(*args)
+    if sites:
+        _SWEEP_LANE_WRITES[engine] = len(sites)
+    return out, _SWEEP_LANE_WRITES.get(engine, 0)
+
+
 def _lane_frag_amounts(state, tp):
     """One lane's frag amounts by category, summed over its nodes (the
     reduction cluster_analysis reports): the sweeps vmap it over their
@@ -3918,10 +3936,11 @@ def schedule_pods_sweep(
                     obs.settle(h, tables)
             fn = _sweep_engine(table_fn.engine.replay, table=True)
             sim._last_engine = f"table ({b}-config vmap sweep)"
-            out = sim._dispatch_span(
-                lambda: fn(
-                    state, specs_d, types, ev_kind_d, ev_pod_d, sim.typical,
-                    keys, weights_d, ranks, tables,
+            out, sweep.lane_writes = sim._dispatch_span(
+                lambda: _dispatch_counting_lane_writes(
+                    table_fn.engine.replay, fn, state, specs_d, types,
+                    ev_kind_d, ev_pod_d, sim.typical, keys, weights_d,
+                    ranks, tables,
                 ),
                 engine=sim._last_engine, events=e,
             )

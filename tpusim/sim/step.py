@@ -26,6 +26,7 @@ from tpusim.ops.resource import (
 )
 from tpusim.policies import ScoreContext, minmax_normalize_i32, pwr_normalize_i32
 from tpusim.policies.clustering import pod_affinity_class
+from tpusim.sim.lane_write import add_row, set_row
 from tpusim.types import NodeState, PodSpec
 
 _INT_MAX = np.int32(np.iinfo(np.int32).max)
@@ -278,6 +279,16 @@ class PendingCommit(NamedTuple):
     scatters first, then read state/tables freely. Bit-identical by
     construction — the same scatters land before anything reads them.
 
+    The writes are sim/lane_write.py's add_row / set_row. Standalone they
+    lower to the `.at[]` updates they always were. Under a sweep's vmap the
+    module's batching rule keeps them the batched scatters vmap derives
+    (in place on the carry's layout) and changes the READS of the same
+    leaves (the dirty row, the selected row, the dirty block) into gathers
+    windowed along the node axis only: it was a reader wanting another
+    layout, not a write, that cost a whole-buffer copy an event there
+    (ENGINES.md, PR 27; per-lane update loops and a layout constraint
+    were tried and deleted).
+
     node == -1 encodes a no-op state commit (failed create / skip / the
     pre-first-event initial value). pod_write is the bookkeeping row index
     (the P-th dummy row for skip events); failed_write is the row for the
@@ -369,19 +380,25 @@ def apply_commit_sharded(state: NodeState, placed, masks, failed,
     owns = (p.node >= 0) & (li >= 0) & (li < nloc)
     sel = jnp.clip(li, 0, nloc - 1)
     state = state._replace(
-        cpu_left=state.cpu_left.at[sel].add(jnp.where(owns, p.rs * p.cpu, 0)),
-        mem_left=state.mem_left.at[sel].add(jnp.where(owns, p.rs * p.mem, 0)),
-        gpu_left=state.gpu_left.at[sel].add(
-            jnp.where(owns, p.rs, 0) * p.dev_mask.astype(jnp.int32)
-            * p.gpu_milli
+        cpu_left=add_row(
+            state.cpu_left, sel, jnp.where(owns, p.rs * p.cpu, 0)
         ),
-        aff_cnt=state.aff_cnt.at[sel, jnp.maximum(p.cls, 0)].add(
-            jnp.where(owns & (p.cls >= 0), -p.rs, 0)
+        mem_left=add_row(
+            state.mem_left, sel, jnp.where(owns, p.rs * p.mem, 0)
+        ),
+        gpu_left=add_row(
+            state.gpu_left, sel,
+            jnp.where(owns, p.rs, 0) * p.dev_mask.astype(jnp.int32)
+            * p.gpu_milli,
+        ),
+        aff_cnt=add_row(
+            state.aff_cnt, (sel, jnp.maximum(p.cls, 0)),
+            jnp.where(owns & (p.cls >= 0), -p.rs, 0),
         ),
     )
-    placed = placed.at[p.pod_write].set(p.placed_val)
-    masks = masks.at[p.pod_write].set(p.mask_val)
-    failed = failed.at[p.failed_write].set(p.failed_val)
+    placed = set_row(placed, p.pod_write, p.placed_val)
+    masks = set_row(masks, p.pod_write, p.mask_val)
+    failed = set_row(failed, p.failed_write, p.failed_val)
     return state, placed, masks, failed
 
 

@@ -49,6 +49,7 @@ from tpusim.policies import (
     minmax_scale_i32,
     pwr_normalize_i32,
 )
+from tpusim.sim import lane_write
 from tpusim.sim.engine import EV_RETRY, ReplayResult
 from tpusim.sim.step import (
     SELF_SELECT_POLICIES,
@@ -196,9 +197,7 @@ def pad_pod_types(types: PodTypes, multiple: int = 16) -> PodTypes:
 
 def _row_state(state: NodeState, node) -> NodeState:
     """1-node slice of the cluster state at a dynamic index."""
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, node, 1, axis=0), state
-    )
+    return jax.tree.map(lambda a: lane_write.read_row(a, node), state)
 
 
 def _pad_rank(rank: jnp.ndarray, n_pad: int) -> jnp.ndarray:
@@ -746,14 +745,14 @@ def _make_table_engine(
                 col_scores, col_sdev, col_feas = _columns(
                     _row_state(state, dirty), types, tp, k_rand
                 )
-                score_tbl = jax.lax.dynamic_update_slice(
-                    score_tbl, col_scores[:, :, None], (0, 0, dirty)
+                blk = dirty // bsz
+                j0 = blk * bsz
+                score_tbl, raw_blk = lane_write.write_column(
+                    score_tbl, col_scores, dirty, block=(j0, bsz)
                 )
-                sdev_tbl = jax.lax.dynamic_update_slice(
-                    sdev_tbl, col_sdev[:, None], (0, dirty)
-                )
-                feas_tbl = jax.lax.dynamic_update_slice(
-                    feas_tbl, col_feas[:, None], (0, dirty)
+                sdev_tbl = lane_write.write_column(sdev_tbl, col_sdev, dirty)
+                feas_tbl, feas_blk = lane_write.write_column(
+                    feas_tbl, col_feas, dirty, block=(j0, bsz)
                 )
 
             # in-scan series sample (ISSUE 5): committed state + current
@@ -766,14 +765,6 @@ def _make_table_engine(
 
             # dirty-block aggregate refresh for ALL K types: O(K*B)
             with jax.named_scope("tpusim.summary"):
-                blk = dirty // bsz
-                j0 = blk * bsz
-                raw_blk = jax.lax.dynamic_slice(
-                    score_tbl, (0, 0, j0), (num_pol, k_types, bsz)
-                )
-                feas_blk = jax.lax.dynamic_slice(
-                    feas_tbl, (0, j0), (k_types, bsz)
-                )
                 rank_blk = jax.lax.dynamic_slice(rank_p, (j0,), (bsz,))
                 if n_norm:
                     selb = jnp.stack([raw_blk[i] for i in norm_idx])
@@ -879,7 +870,7 @@ def _make_table_engine(
                 # encodes it as index n) can never be feasible.
                 pin = jnp.clip(pod.pinned, 0, n - 1)
                 pin_feas = (
-                    jax.lax.dynamic_slice(feas_tbl, (t_id, pin), (1, 1))[0, 0]
+                    lane_write.read_entry(feas_tbl, t_id, pin)
                     & (pod.pinned < n)
                 )
                 node = jnp.where(
@@ -889,11 +880,10 @@ def _make_table_engine(
                 ).astype(jnp.int32)
                 ok = node >= 0
                 sel = jnp.maximum(node, 0)
-                dev_scalar = jax.lax.dynamic_slice(
-                    sdev_tbl, (t_id, sel), (1, 1)
-                )[0, 0]
+                dev_scalar = lane_write.read_entry(sdev_tbl, t_id, sel)
                 dmask = choose_devices(
-                    state.gpu_left[sel], pod, dev_scalar, gpu_sel, k_sel
+                    lane_write.read_row(state.gpu_left, sel, keepdims=False),
+                    pod, dev_scalar, gpu_sel, k_sel,
                 ) & ok
                 node_f = jnp.where(ok, sel, -1).astype(jnp.int32)
                 if not decisions:
@@ -1067,15 +1057,11 @@ def _make_table_engine(
                 col_scores, col_sdev, col_feas = _columns(
                     _row_state(state, dirty), types, tp, k_rand
                 )
-                score_tbl = jax.lax.dynamic_update_slice(
-                    score_tbl, col_scores[:, :, None], (0, 0, dirty)
+                score_tbl = lane_write.write_column(
+                    score_tbl, col_scores, dirty
                 )
-                sdev_tbl = jax.lax.dynamic_update_slice(
-                    sdev_tbl, col_sdev[:, None], (0, dirty)
-                )
-                feas_tbl = jax.lax.dynamic_update_slice(
-                    feas_tbl, col_feas[:, None], (0, dirty)
-                )
+                sdev_tbl = lane_write.write_column(sdev_tbl, col_sdev, dirty)
+                feas_tbl = lane_write.write_column(feas_tbl, col_feas, dirty)
 
             # in-scan series sample (ISSUE 5): committed state + current
             # tables, on the processed-event stride
@@ -1121,8 +1107,8 @@ def _make_table_engine(
                 # scatter is deferred via PendingCommit
                 sel, _, ok = packed_argmax(total, feasible, tiebreak_rank)
                 dmask = choose_devices(
-                    state.gpu_left[sel], pod, sdev_tbl[t_id, sel],
-                    gpu_sel, k_sel,
+                    lane_write.read_row(state.gpu_left, sel, keepdims=False),
+                    pod, sdev_tbl[t_id, sel], gpu_sel, k_sel,
                 ) & ok
                 node_f = jnp.where(ok, sel, -1).astype(jnp.int32)
                 if not decisions:
