@@ -11,7 +11,7 @@ import jax
 import numpy as np
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CELL_CONFIG = os.path.join(REPO, "benchmark", "configs", "synth100k.json")
+CONFIGS = os.path.join(REPO, "benchmark", "configs")
 
 
 class _Captured(Exception):
@@ -51,15 +51,17 @@ def capture_sweep(sim, trace, weights, seeds, run: bool = False):
     return called["fn"], called["shapes"], lanes
 
 
-def cell_simulator(nodes: int, depth: int, seed: int = 7, **over):
-    """The benchmark's synth100k configuration at `nodes` nodes: the
-    Simulator and its trace of `depth` creates."""
+def cell_simulator(nodes, depth: int, seed: int = 7,
+                   config: str = "synth100k", **over):
+    """One of the benchmark's configurations at `nodes` nodes (None: as
+    the file has it): the Simulator and its trace of `depth` creates."""
     from benchmark.drivers import wave
     from benchmark.lib import inputs
 
-    with open(CELL_CONFIG) as f:
+    with open(os.path.join(CONFIGS, f"{config}.json")) as f:
         config = json.load(f)
-    config["cluster"]["nodes"] = nodes
+    if nodes is not None:
+        config["cluster"]["nodes"] = nodes
     node_list, pods = inputs.build(config, seed, depth)
     cfg = wave.simulator_config(config["simulator"], seed, profile=False,
                                 **over)
@@ -103,6 +105,17 @@ def _called(lines) -> set:
                 out.add(one)
             out.update(n.strip().lstrip("%") for n in many.split(",") if n)
     return out
+
+
+_WHILE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) while\(")
+
+
+def while_loops(text: str) -> list:
+    """(computation, instruction, result shape) of every `while` of a
+    compiled module."""
+    return [(name, m.group(1), m.group(2))
+            for name, lines in _computations(text).items()
+            for m in map(_WHILE.match, lines) if m]
 
 
 def big_copies_in_scan(text: str, min_elems: int) -> list:

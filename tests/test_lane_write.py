@@ -1,6 +1,9 @@
 """sim/lane_write.py: under jax.vmap every access goes through the module's
 own batching rule and must equal, bit for bit, vmap of the plain expression
-the step bodies used to hold; without vmap it IS that expression."""
+the step bodies used to hold; without vmap it IS that expression. Every
+access is held at two sizes: a SHORT node axis takes the dense form (masks
+over the whole leaf, no scatter, no gather), a LONG one (BLOCKED_MIN_NODES
+or more) the scatters and window gathers."""
 
 import jax
 import jax.numpy as jnp
@@ -10,8 +13,15 @@ from jax import lax
 
 from tpusim.sim import lane_write as lw
 
-L, N, K, POL, P, BSZ = 5, 37, 6, 2, 11, 8
-N_PAD = 40  # the blocked tables are padded to whole blocks
+from tpusim.sim.table_engine import BLOCKED_MIN_NODES
+
+L, K, POL, P, BSZ = 5, 6, 2, 11, 8
+# nodes, and nodes padded to whole blocks as the blocked tables are
+SIZES = {"short": (37, 40), "long": (BLOCKED_MIN_NODES + 37,
+                                     BLOCKED_MIN_NODES + 40)}
+LONG_N, LONG_PAD = SIZES["long"]
+# the bookkeeping leaves (placed, masks, failed) have a pod axis
+POD_ROWS = {"short": P, "long": BLOCKED_MIN_NODES + P}
 
 
 def _rng():
@@ -28,8 +38,13 @@ def _bools(shape):
 
 # indices a sweep produces: the same node in several lanes, node 0, the
 # last real node / column, and the dummy bookkeeping row [P]
-NODE_IDX = jnp.asarray([3, 0, N - 1, 3, 3], jnp.int32)
-POD_IDX = jnp.asarray([P, 0, P - 1, 4, 4], jnp.int32)
+def _node_idx(n):
+    return jnp.asarray([3, 0, n - 1, 3, 3], jnp.int32)
+
+
+def _pod_idx(p):
+    return jnp.asarray([p, 0, p - 1, 4, 4], jnp.int32)
+
 
 # which operands carry the lane axis: everything (a steady scan step), the
 # leaf alone shared (first pass of a vmapped scan: tables and state come in
@@ -77,18 +92,21 @@ def _plain_column_block(tbl, col, idx):
 
 
 COLUMN_LEAVES = {
-    "score": lambda: (_ints((L, POL, K, N_PAD)), _ints((L, POL, K))),
-    "sdev": lambda: (_ints((L, K, N_PAD), -1, 8), _ints((L, K), -1, 8)),
-    "feas": lambda: (_bools((L, K, N_PAD)), _bools((L, K))),
+    "score": lambda n: (_ints((L, POL, K, n)), _ints((L, POL, K))),
+    "sdev": lambda n: (_ints((L, K, n), -1, 8), _ints((L, K), -1, 8)),
+    "feas": lambda n: (_bools((L, K, n)), _bools((L, K))),
 }
 
 
+@pytest.mark.parametrize("size", sorted(SIZES))
 @pytest.mark.parametrize("pattern", sorted(PATTERNS))
 @pytest.mark.parametrize("block", [False, True])
 @pytest.mark.parametrize("leaf", sorted(COLUMN_LEAVES))
-def test_write_column_equals_vmap_of_the_plain_write(leaf, block, pattern):
-    tbl, col = COLUMN_LEAVES[leaf]()
-    args, axes = _axes((tbl, col, NODE_IDX), PATTERNS[pattern])
+def test_write_column_equals_vmap_of_the_plain_write(leaf, block, pattern,
+                                                     size):
+    n, n_pad = SIZES[size]
+    tbl, col = COLUMN_LEAVES[leaf](n_pad)
+    args, axes = _axes((tbl, col, _node_idx(n)), PATTERNS[pattern])
     if block:
         mine = lambda t, c, i: lw.write_column(  # noqa: E731
             t, c, i, block=((i // BSZ) * BSZ, BSZ))
@@ -106,32 +124,41 @@ def _aff(leaf, idx, val):
 
 
 ROW_WRITES = {
-    # name: (leaf, value a lane, index, mine, plain)
-    "cpu_left": lambda: (_ints((L, N)), _ints((L,)), NODE_IDX, lw.add_row,
-                         lambda a, i, v: a.at[i].add(v)),
-    "mem_left": lambda: (_ints((L, N)), _ints((L,)), NODE_IDX, lw.add_row,
-                         lambda a, i, v: a.at[i].add(v)),
-    "gpu_left": lambda: (_ints((L, N, 8)), _ints((L, 8)), NODE_IDX,
-                         lw.add_row, lambda a, i, v: a.at[i].add(v)),
+    # name: n nodes, p pods -> (leaf, value a lane, index, mine, plain)
+    "cpu_left": lambda n, p: (
+        _ints((L, n)), _ints((L,)), _node_idx(n), lw.add_row,
+        lambda a, i, v: a.at[i].add(v)),
+    "mem_left": lambda n, p: (
+        _ints((L, n)), _ints((L,)), _node_idx(n), lw.add_row,
+        lambda a, i, v: a.at[i].add(v)),
+    "gpu_left": lambda n, p: (
+        _ints((L, n, 8)), _ints((L, 8)), _node_idx(n), lw.add_row,
+        lambda a, i, v: a.at[i].add(v)),
     # node == -1 commits add a zero at the clipped row
-    "gpu_left_zero_delta": lambda: (
-        _ints((L, N, 8)), jnp.zeros((L, 8), jnp.int32),
+    "gpu_left_zero_delta": lambda n, p: (
+        _ints((L, n, 8)), jnp.zeros((L, 8), jnp.int32),
         jnp.zeros(L, jnp.int32), lw.add_row, lambda a, i, v: a.at[i].add(v)),
-    "aff_cnt": lambda: (_ints((L, N, 9)), _ints((L,)), NODE_IDX, _aff,
-                        lambda a, i, v: a.at[i, jnp.int32(2)].add(v)),
-    "placed": lambda: (_ints((L, P + 1)), _ints((L,)), POD_IDX, lw.set_row,
-                       lambda a, i, v: a.at[i].set(v)),
-    "masks": lambda: (_bools((L, P + 1, 8)), _bools((L, 8)), POD_IDX,
-                      lw.set_row, lambda a, i, v: a.at[i].set(v)),
-    "failed": lambda: (_bools((L, P + 1)), _bools((L,)), POD_IDX, lw.set_row,
-                       lambda a, i, v: a.at[i].set(v)),
+    "aff_cnt": lambda n, p: (
+        _ints((L, n, 9)), _ints((L,)), _node_idx(n), _aff,
+        lambda a, i, v: a.at[i, jnp.int32(2)].add(v)),
+    "placed": lambda n, p: (
+        _ints((L, p + 1)), _ints((L,)), _pod_idx(p), lw.set_row,
+        lambda a, i, v: a.at[i].set(v)),
+    "masks": lambda n, p: (
+        _bools((L, p + 1, 8)), _bools((L, 8)), _pod_idx(p), lw.set_row,
+        lambda a, i, v: a.at[i].set(v)),
+    "failed": lambda n, p: (
+        _bools((L, p + 1)), _bools((L,)), _pod_idx(p), lw.set_row,
+        lambda a, i, v: a.at[i].set(v)),
 }
 
 
+@pytest.mark.parametrize("size", sorted(SIZES))
 @pytest.mark.parametrize("pattern", sorted(PATTERNS))
 @pytest.mark.parametrize("leaf", sorted(ROW_WRITES))
-def test_row_writes_equal_vmap_of_the_plain_update(leaf, pattern):
-    arr, val, idx, mine, plain = ROW_WRITES[leaf]()
+def test_row_writes_equal_vmap_of_the_plain_update(leaf, pattern, size):
+    arr, val, idx, mine, plain = ROW_WRITES[leaf](
+        SIZES[size][0], POD_ROWS[size])
     leaf_b, val_b, idx_b = PATTERNS[pattern]
     args, axes = _axes((arr, idx, val), (leaf_b, idx_b, val_b))
     _same(jax.jit(jax.vmap(mine, in_axes=axes))(*args),
@@ -140,19 +167,26 @@ def test_row_writes_equal_vmap_of_the_plain_update(leaf, pattern):
 
 
 ROW_READS = {
-    "cpu_left": lambda: _ints((L, N)),
-    "gpu_left": lambda: _ints((L, N, 8)),
-    "aff_cnt": lambda: _ints((L, N, 9)),
+    "cpu_left": lambda n: _ints((L, n)),
+    "gpu_left": lambda n: _ints((L, n, 8)),
+    "aff_cnt": lambda n: _ints((L, n, 9)),
+    "masks": lambda n: _bools((L, n, 8)),
 }
 READ_PATTERNS = {"all": (True, True), "leaf_shared": (False, True),
                  "index_shared": (True, False)}
 
 
+@pytest.mark.parametrize("size", sorted(SIZES))
 @pytest.mark.parametrize("pattern", sorted(READ_PATTERNS))
 @pytest.mark.parametrize("keepdims", [True, False])
 @pytest.mark.parametrize("leaf", sorted(ROW_READS))
-def test_read_row_equals_vmap_of_the_plain_slice(leaf, keepdims, pattern):
-    args, axes = _axes((ROW_READS[leaf](), NODE_IDX), READ_PATTERNS[pattern])
+def test_read_row_equals_vmap_of_the_plain_slice(leaf, keepdims, pattern,
+                                                 size):
+    n = SIZES[size][0]
+    idx = _node_idx(n)
+    if not keepdims:  # leaf[idx] wraps a negative index once
+        idx = idx.at[3].set(-2)
+    args, axes = _axes((ROW_READS[leaf](n), idx), READ_PATTERNS[pattern])
     mine = lambda a, i: lw.read_row(a, i, keepdims=keepdims)  # noqa: E731
     if keepdims:
         plain = lambda a, i: lax.dynamic_slice_in_dim(a, i, 1, 0)  # noqa: E731
@@ -163,12 +197,14 @@ def test_read_row_equals_vmap_of_the_plain_slice(leaf, keepdims, pattern):
     _same(mine(*_one_lane(args, axes)), plain(*_one_lane(args, axes)))
 
 
+@pytest.mark.parametrize("size", sorted(SIZES))
 @pytest.mark.parametrize("pattern", sorted(PATTERNS))
 @pytest.mark.parametrize("leaf", ["sdev", "feas"])
-def test_read_entry_equals_vmap_of_the_plain_slice(leaf, pattern):
-    tbl, _ = COLUMN_LEAVES[leaf]()
+def test_read_entry_equals_vmap_of_the_plain_slice(leaf, pattern, size):
+    n, n_pad = SIZES[size]
+    tbl, _ = COLUMN_LEAVES[leaf](n_pad)
     rows = jnp.asarray([0, K - 1, 2, 2, 5], jnp.int32)
-    args, axes = _axes((tbl, rows, NODE_IDX), PATTERNS[pattern])
+    args, axes = _axes((tbl, rows, _node_idx(n)), PATTERNS[pattern])
     plain = lambda t, r, c: lax.dynamic_slice(  # noqa: E731
         t, (r, c), (1, 1))[0, 0]
     _same(jax.jit(jax.vmap(lw.read_entry, in_axes=axes))(*args),
@@ -190,18 +226,19 @@ def _plain_step(tbl, left, col, idx, delta):
     return tbl, left, blk, lax.dynamic_slice_in_dim(left, idx, 1, 0)
 
 
-def _step_args():
-    return (_ints((L, K, N_PAD)), _ints((L, N, 8)), _ints((L, K)), NODE_IDX,
-            _ints((L, 8)))
+def _step_args(size="long"):
+    n, n_pad = SIZES[size]
+    return (_ints((L, K, n_pad)), _ints((L, n, 8)), _ints((L, K)),
+            _node_idx(n), _ints((L, 8)))
 
 
 def test_reads_are_windows_batched_over_the_lanes():
-    """What the rule is for: a vmapped step writes through the scatters
-    vmap derives and reads through gathers of (rows, nodes) windows batched
-    over the lane axis, none holding every row of its leaf, so the layout
-    that serves the scatters serves the reads too (on the TPU;
-    tests/test_sweep_compile.py)."""
-    n, n_pad = 300, 384  # more than one 128-node tile
+    """What the rule is for on a long node axis: a vmapped step writes
+    through the scatters vmap derives and reads through gathers of (rows,
+    nodes) windows batched over the lane axis, none holding every row of
+    its leaf, so the layout that serves the scatters serves the reads too
+    (on the TPU; tests/test_sweep_compile.py)."""
+    n, n_pad = LONG_N + 263, LONG_PAD + 344  # not a whole 128-node tile
     args = (_ints((L, K, n_pad)), _ints((L, n, 8)), _ints((L, K)),
             jnp.asarray([3, 0, n - 1, 3, 200], jnp.int32), _ints((L, 8)))
     text = jax.jit(jax.vmap(_step)).lower(*args).as_text()
@@ -219,6 +256,67 @@ def test_reads_are_windows_batched_over_the_lanes():
         assert "operand_batching_dims = [0]" in ln, ln
 
 
+def _every_access(tbl, sdev, left, aff, placed, col, idx, delta, pod):
+    """Each access of the module once, as the step bodies call them."""
+    tbl, blk = lw.write_column(tbl, col, idx, block=((idx // BSZ) * BSZ, BSZ))
+    sdev = lw.write_column(sdev, col, idx)
+    left = lw.add_row(left, idx, delta)
+    aff = lw.add_row(aff, (idx, jnp.int32(2)), delta[0])
+    placed = lw.set_row(placed, pod, idx)
+    return (tbl, sdev, left, aff, placed, blk, lw.read_row(left, idx),
+            lw.read_row(left, idx, keepdims=False),
+            lw.read_entry(sdev, jnp.int32(2), idx))
+
+
+def _every_access_args(size):
+    n, n_pad = SIZES[size]
+    return (_ints((L, K, n_pad)), _ints((L, K, n_pad)), _ints((L, n, 8)),
+            _ints((L, n, 9)), _ints((L, POD_ROWS[size] + 1)), _ints((L, K)),
+            _node_idx(n), _ints((L, 8)), _pod_idx(POD_ROWS[size]))
+
+
+@pytest.mark.parametrize("size", sorted(SIZES))
+def test_a_short_node_axis_is_served_without_scatter_or_gather(size):
+    """The dense forms: on a short node axis the vmapped program of every
+    access holds no scatter and no gather (what XLA runs as a loop over the
+    lanes on the TPU), only selects and reductions over the whole leaf; a
+    long axis keeps both. The choice follows from the leaf's shape alone."""
+    args = _every_access_args(size)
+    with lw.counting() as sites:
+        text = jax.jit(jax.vmap(_every_access)).lower(*args).as_text()
+    scatters = text.count('"stablehlo.scatter"')
+    # gathers with one index row a lane; the slice of a table's row that
+    # the lanes share is a gather with ONE index, a dynamic slice to XLA
+    gathers = sum(1 for ln in text.splitlines() if '"stablehlo.gather"' in ln
+                  and f", tensor<{L}x" in ln.split(") -> ")[0])
+    assert len(sites) == 5
+    if size == "short":
+        assert (scatters, gathers) == (0, 0)
+        assert "stablehlo.while" not in text
+        assert len(sites.dense) == 8  # every site, the three reads too
+    else:
+        assert scatters == 5 and gathers >= 4
+        assert len(sites.dense) == 0
+
+
+def test_a_table_not_of_whole_blocks_keeps_the_window_read():
+    """The dense block read views the table as whole blocks; a short table
+    that is not (no caller makes one) writes densely and reads its block
+    through the windows, from any start."""
+    tbl, col = _ints((L, K, 37)), _ints((L, K))
+    idx = _node_idx(37)
+    mine = lambda t, c, i: lw.write_column(  # noqa: E731
+        t, c, i, block=(jnp.minimum(i, 37 - BSZ), BSZ))
+
+    def plain(t, c, i):
+        out = _plain_column(t, c, i)
+        return out, lax.dynamic_slice(
+            out, (0, jnp.minimum(i, 37 - BSZ)), (K, BSZ))
+
+    _same(jax.jit(jax.vmap(mine))(tbl, col, idx),
+          jax.vmap(plain)(tbl, col, idx))
+
+
 @pytest.mark.parametrize("k,dtype", [
     (71, jnp.int32), (71, jnp.bool_), (16, jnp.int32), (9, jnp.int32),
     (2, jnp.int32), (1, jnp.int32)])
@@ -226,29 +324,30 @@ def test_a_block_is_read_as_two_half_windows(k, dtype):
     """The dirty block of a [K, N] table comes in two windows of
     ceil(K / 2) rows (overlapping by a row when K is odd; one window when
     K is 1); equal to the plain slice for every K."""
-    tbl = (_bools((L, k, N_PAD)) if dtype == jnp.bool_
-           else _ints((L, k, N_PAD)))
+    tbl = (_bools((L, k, LONG_PAD)) if dtype == jnp.bool_
+           else _ints((L, k, LONG_PAD)))
     col = tbl[:, :, 0]
+    idx = _node_idx(LONG_N)
     mine = lambda t, c, i: lw.write_column(  # noqa: E731
         t, c, i, block=((i // BSZ) * BSZ, BSZ))
     fn = jax.jit(jax.vmap(mine))
-    _same(fn(tbl, col, NODE_IDX),
-          jax.vmap(_plain_column_block)(tbl, col, NODE_IDX))
-    text = fn.lower(tbl, col, NODE_IDX).as_text()
+    _same(fn(tbl, col, idx), jax.vmap(_plain_column_block)(tbl, col, idx))
+    text = fn.lower(tbl, col, idx).as_text()
     (gather,) = [ln for ln in text.splitlines() if '"stablehlo.gather"' in ln
-                 and f"x{N_PAD}x" in ln.split("->")[0]]
+                 and f"x{LONG_PAD}x" in ln.split("->")[0]]
     h = -(-k // 2)
     assert f"slice_sizes = array<i64: 1, {h}, {BSZ}>" in gather
     assert f"-> tensor<{L}x{min(k, 2)}x{h}x{BSZ}x" in gather, gather
 
 
-def test_the_unbatched_program_is_the_plain_one():
+@pytest.mark.parametrize("size", sorted(SIZES))
+def test_the_unbatched_program_is_the_plain_one(size):
+    one = tuple(x[0] for x in _step_args(size))
     plain = jax.jit(lambda t, a, c, i, d: (
-        *_plain_column_block(t, c, i), a.at[i].add(d))).lower(
-            *(x[0] for x in _step_args())).as_text()
+        *_plain_column_block(t, c, i), a.at[i].add(d))).lower(*one).as_text()
     mine = jax.jit(lambda t, a, c, i, d: (
         *lw.write_column(t, c, i, block=((i // BSZ) * BSZ, BSZ)),
-        lw.add_row(a, i, d))).lower(*(x[0] for x in _step_args())).as_text()
+        lw.add_row(a, i, d))).lower(*one).as_text()
     for op in ("dynamic_update_slice", "dynamic_slice", "scatter", "gather",
                "custom_call"):
         assert mine.count(f"stablehlo.{op}") == plain.count(
@@ -257,13 +356,16 @@ def test_the_unbatched_program_is_the_plain_one():
     assert mine.count("stablehlo.dynamic_update_slice") == 1
 
 
-def test_counting_sees_write_sites_once_and_only_under_vmap():
+@pytest.mark.parametrize("size", sorted(SIZES))
+def test_counting_sees_write_sites_once_and_only_under_vmap(size):
+    dense = 3 if size == "short" else 0  # the read is a dense site too
     with lw.counting() as sites:
-        jax.jit(_step).lower(*(x[0] for x in _step_args()))
-    assert len(sites) == 0
+        jax.jit(_step).lower(*(x[0] for x in _step_args(size)))
+    assert len(sites) == 0 and len(sites.dense) == 0
     with lw.counting() as sites:
-        jax.jit(jax.vmap(_step)).lower(*_step_args())
+        jax.jit(jax.vmap(_step)).lower(*_step_args(size))
     assert len(sites) == 2  # write_column and add_row; read_row is no write
+    assert len(sites.dense) == dense
 
     def scanned(tbl, left, col, idx, delta):
         def body(carry, _):
@@ -276,21 +378,22 @@ def test_counting_sees_write_sites_once_and_only_under_vmap():
     # fixpoint and visits each site more than once
     axes = (None, None, 0, 0, 0)
     args = tuple(a if ax == 0 else a[0]
-                 for a, ax in zip(_step_args(), axes))
+                 for a, ax in zip(_step_args(size), axes))
     with lw.counting() as sites:
         got = jax.jit(jax.vmap(scanned, in_axes=axes))(*args)
-    assert len(sites) == 2
+    assert len(sites) == 2 and len(sites.dense) == dense
     want = jax.vmap(scanned, in_axes=axes)(*args)
     _same(got, want)
 
 
-def test_the_rule_can_be_vmapped_again():
+@pytest.mark.parametrize("size", sorted(SIZES))
+def test_the_rule_can_be_vmapped_again(size):
     """A config axis over a seed axis: the rule's output is plain lax, so
     an outer vmap batches it like any other program."""
     args = jax.tree.map(lambda a: jnp.stack([a, a + 1 if a.dtype != bool
-                                             else ~a]), _step_args())
-    args = (args[0], args[1], args[2], jnp.stack([NODE_IDX, NODE_IDX[::-1]]),
-            args[4])
+                                             else ~a]), _step_args(size))
+    idx = _node_idx(SIZES[size][0])
+    args = (args[0], args[1], args[2], jnp.stack([idx, idx[::-1]]), args[4])
     got = jax.jit(jax.vmap(jax.vmap(_step)))(*args)
     want = [jax.vmap(_step)(*(a[i] for a in args)) for i in range(2)]
     _same(got, jax.tree.map(lambda *x: jnp.stack(x), *want))

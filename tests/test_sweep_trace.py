@@ -4,6 +4,8 @@ the profiler annotations of the spans, and the named scopes of the step
 body (tpusim/obs/spans.py, tpusim/sim/driver.py, tpusim/sim/table_engine.py).
 """
 
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -210,6 +212,13 @@ def test_every_stage_of_the_step_body_has_its_scope(scoped):
     for scope in BODY_SCOPES[block_size]:
         assert scope in text, scope
     assert ("tpusim.summary" in text) == (block_size > 0)
+    # the dense forms of sim/lane_write.py sit inside the stage that calls
+    # them: the commit's masked adds, the refresh's column writes and row
+    # reads, the select's row and entry reads
+    for scope, op in (("commit", "select_n"), ("refresh", "select_n"),
+                      ("refresh", "reduce_sum"), ("select", "reduce_sum")):
+        assert re.search(rf'tpusim\.{scope}/vmap[^"/]*/{op}', text), (
+            scope, op)
     state, _, types, _, _, tp, keys = called["shapes"][:7]
     build = sim._table_fn.build_tables.lower(
         state, types, tp, jax.ShapeDtypeStruct(keys.shape[1:], keys.dtype))
@@ -265,9 +274,13 @@ def test_lane_writes_counts_the_sites_the_batching_rule_lowered(
 
 def test_the_standalone_replay_lowers_as_it_always_did(scoped):
     """Not vmapped, nothing goes through the rule: no site is counted, the
-    column writes are dynamic_update_slices and there is no custom call
-    (no kernel); the vmapped program holds the rule's scatters and gathers
-    on top."""
+    column writes are dynamic_update_slices, the commit's `.at[]` updates
+    are scatters and there is no custom call (no kernel). The vmapped
+    program of these short clusters takes the rule's dense forms: the
+    scatters of the node state's write sites are gone (the bookkeeping
+    rows' stay: the lanes share their index; the blocked body's summary
+    rows, not this module's, become three), and so are the column writes'
+    dynamic_update_slices."""
     from tpusim.sim import lane_write
 
     _, sim, _, called = scoped
@@ -276,11 +289,55 @@ def test_the_standalone_replay_lowers_as_it_always_did(scoped):
         shapes[i] = jax.ShapeDtypeStruct(shapes[i].shape[1:], shapes[i].dtype)
     with lane_write.counting() as sites:
         text = sim._table_fn.engine.replay.lower(*shapes).as_text()
-    assert not sites
+    assert not sites and not sites.dense
     assert "custom_call" not in text
     assert text.count("stablehlo.dynamic_update_slice") >= 3
+    assert text.count('"stablehlo.scatter"') >= 14  # the commit, twice
     swept = called["fn"].lower(*called["shapes"]).as_text()
-    assert swept.count('"stablehlo.gather"') > text.count('"stablehlo.gather"')
-    assert swept.count('"stablehlo.scatter"') > text.count(
-        '"stablehlo.scatter"')
+    assert swept.count('"stablehlo.scatter"') <= text.count(
+        '"stablehlo.scatter"') - 5
+    assert swept.count("stablehlo.dynamic_update_slice") <= text.count(
+        "stablehlo.dynamic_update_slice") - 3
     assert "custom_call" not in swept
+
+
+# every write site but the set_rows (the lanes of one event stream share
+# the bookkeeping row's index: vmap's one update serves them all), and the
+# reads: the dirty row of the nine NodeState leaves, the selected gpu_left
+# row and the device table's entry
+DENSE_SITES = {-1: WRITE_SITES - 2 * 3 + 9 + 2}
+
+
+def test_dense_accesses_counts_the_sites_lowered_in_the_dense_form(scoped):
+    """A short cluster's sweep takes the dense form at every site; a
+    program that is not vmapped (the test above), and the blocked body
+    from 8,192 nodes (tests/test_sweep_compile.py), at none."""
+    block_size, sim, _, _ = scoped
+    rec = sim.obs.sweeps[-1]
+    assert rec.lane_writes == WRITE_SITES and rec.dense_accesses > 0
+    if block_size in DENSE_SITES:
+        assert rec.dense_accesses == DENSE_SITES[block_size]
+    assert sim.run_telemetry().to_record()["timing"]["sweeps"][-1][
+        "dense_accesses"] == rec.dense_accesses
+
+
+@pytest.mark.parametrize("seeds", [
+    [0, 1, 42, 2**31 - 1],  # int32: one transfer, one vmapped program
+    [-1, -2**31, 7],
+    [5, 2**31, 3000000019],  # beyond int32: PRNGKey's own wrap, a lane each
+])
+def test_lane_keys_equal_one_prngkey_a_lane(seeds):
+    got = np.asarray(driver._lane_keys(seeds))
+    want = np.stack([np.asarray(jax.random.PRNGKey(s)) for s in seeds])
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)
+
+
+def test_lane_ranks_equal_one_permutation_a_lane():
+    from tpusim.io.trace import tiebreak_rank
+
+    seeds = [0, 11, 2**31 - 2]
+    got = driver._lane_ranks(97, seeds)
+    assert isinstance(got, jax.Array) and got.dtype == np.int32
+    np.testing.assert_array_equal(
+        np.asarray(got), np.stack([tiebreak_rank(97, s) for s in seeds]))

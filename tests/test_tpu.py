@@ -291,18 +291,15 @@ def test_cell_sized_sweep_holds_no_whole_carry_copy_on_tpu(accel):
                                 for c, n, s, _ in found)
 
 
-def test_wide_flat_sweep_equals_the_oracle_in_every_leaf_on_tpu(accel):
-    """1,213 openb nodes x 256 lanes on the flat step body: placements,
-    masks and EVERY NodeState leaf of the checked lanes, aff_cnt among
-    them (ROADMAP S0: the leaf that came back wrong from sweeps of 1,152
-    lanes or more; its write now goes through lane_write.add_row),
-    against a standalone replay on the sequential oracle."""
+def _openb_flat_sweep(lanes, events):
+    """(sim, trace, seeds, lanes' results) of an openb FGD sweep on the
+    flat step body, and a function that replays one lane on the
+    sequential oracle."""
     from benchmark.lib import inputs
     from tests.test_sweep import _cfg
     from tpusim.io.trace import load_node_csv, load_pod_csv
     from tpusim.sim.driver import Simulator, schedule_pods_sweep
 
-    lanes, events = 256, 256
     nodes = load_node_csv(inputs.NODE_CSV)
     assert len(nodes) == 1213
     pods = load_pod_csv(inputs.POD_CSV)[:events]
@@ -315,14 +312,36 @@ def test_wide_flat_sweep_equals_the_oracle_in_every_leaf_on_tpu(accel):
     out = schedule_pods_sweep(
         sim, trace, np.full((lanes, 1), 1000, np.int32), seeds)
     assert "table" in sim._last_engine and len(out) == lanes
+
+    def oracle(i):
+        one = Simulator(nodes, _cfg(seeds[i], policies, engine="sequential"))
+        one.set_workload_pods(pods)
+        want = one.run()
+        assert "sequential" in one._last_engine
+        return want
+
+    return sim, trace, seeds, out, oracle
+
+
+# the first from PR 27; S0_LANES x 64 is the smallest width at which the
+# parent's sweep came back with NodeState.aff_cnt off the oracle (my chip
+# run, PR 28: PERF.md section 6); the last is the benchmark cell's shape
+S0_LANES = 1144
+
+
+@pytest.mark.parametrize("lanes,events", [
+    (256, 256), (S0_LANES, 64), (2560, 512)])
+def test_wide_flat_sweep_equals_the_oracle_in_every_leaf_on_tpu(
+        accel, lanes, events):
+    """1,213 openb nodes on the flat step body: placements, masks and
+    EVERY NodeState leaf of lanes 0, 1, the middle and the last, aff_cnt
+    among them (ROADMAP S0: the leaf that came back wrong from wide sweeps
+    while its write was a scatter with one index row a lane), against a
+    standalone replay on the sequential oracle."""
+    _, _, _, out, oracle = _openb_flat_sweep(lanes, events)
     differing = []
-    for i in (0, 1, 127, 255):
-        oracle = Simulator(nodes, _cfg(seeds[i], policies,
-                                       engine="sequential"))
-        oracle.set_workload_pods(pods)
-        want = oracle.run()
-        assert "sequential" in oracle._last_engine
-        lane = out[i]
+    for i in (0, 1, lanes // 2 - 1, lanes - 1):
+        want, lane = oracle(i), out[i]
         pairs = [("placed_node", lane.placed_node, want.placed_node),
                  ("dev_mask", lane.dev_mask, want.dev_mask)]
         pairs += [(f"state.{f}", getattr(lane.state, f),
@@ -332,3 +351,29 @@ def test_wide_flat_sweep_equals_the_oracle_in_every_leaf_on_tpu(accel):
             if bad:
                 differing.append((i, name, bad))
     assert not differing, differing
+
+
+def test_lanes_of_the_widest_sweep_equal_the_numpy_reference_on_tpu(accel):
+    """Two lanes of a 2,560-lane openb sweep, all 512 events, against
+    tpusim/ref/fgd_numpy.py (numpy only, none of the program's kernels).
+    The tolerance and its reason are tests/test_reference_fgd.py's: every
+    integer exact; a score may differ by 1 only within NEAR of an integer,
+    and a lane is held up to the first event such an entry could decide.
+    Under this trace's typical pods lane 2,000 meets 11 such entries and
+    none can decide (held in full, final state too); lane 1,777 meets one
+    that can, at event 228 (held for 228 placements)."""
+    from tests.test_reference_fgd import (
+        held_to_the_reference,
+        reference_inputs,
+    )
+    from tpusim.ref import fgd_numpy
+
+    sim, trace, seeds, out, _ = _openb_flat_sweep(2560, 512)
+    for lane, undecided in ((2000, -1), (1777, 228)):
+        ref = fgd_numpy.replay(*reference_inputs(sim, trace, seeds[lane]))
+        print(f"lane {lane}: {ref['near_entries']} score entries within "
+              f"{fgd_numpy.NEAR} of an integer; first event one could "
+              f"decide: {ref['first_undecided']}")
+        assert ref["first_undecided"] == undecided
+        assert held_to_the_reference(out[lane], ref, f"lane {lane}") == (
+            len(trace) if undecided < 0 else undecided)

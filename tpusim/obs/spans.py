@@ -155,6 +155,9 @@ class SweepRecord:
     # write sites of the sweep's program lowered through
     # sim/lane_write.py's batching rule (0: the program was not vmapped)
     lane_writes: int = 0
+    # sites of that program, reads too, that the rule lowered in the dense
+    # form (a short node axis: the flat step body); 0 on the blocked body
+    dense_accesses: int = 0
 
     @property
     def compiled(self) -> int:
@@ -173,6 +176,7 @@ class SweepRecord:
             "cache_loads": self.cache_loads,
             "compiled": self.compiled,
             "lane_writes": self.lane_writes,
+            "dense_accesses": self.dense_accesses,
             "spans": [s.to_dict() for s in self.spans],
         }
 

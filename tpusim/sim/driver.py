@@ -3423,13 +3423,14 @@ def _sweep_engine(engine, table: bool, donate: bool = True):
     return _SWEEP_WRAP_CACHE[ck]
 
 
-_SWEEP_LANE_WRITES = {}  # engine -> write sites its vmapped program batched
+_SWEEP_LANE_SITES = {}  # engine -> (write sites, dense sites) of its program
 
 
-def _dispatch_counting_lane_writes(engine, fn, *args):
-    """fn(*args) for fn = _sweep_engine(engine, ...), and the number of
-    write sites of that program that went through sim/lane_write.py's
-    batching rule. The rule runs while the program is traced, so a call
+def _dispatch_counting_lane_sites(engine, fn, *args):
+    """fn(*args) for fn = _sweep_engine(engine, ...), the number of write
+    sites of that program that went through sim/lane_write.py's batching
+    rule, and the number of its sites (reads too) that the rule lowered in
+    the dense form. The rule runs while the program is traced, so a call
     served from the jit cache reports what the engine's last trace
     counted."""
     from tpusim.sim import lane_write
@@ -3437,8 +3438,8 @@ def _dispatch_counting_lane_writes(engine, fn, *args):
     with lane_write.counting() as sites:
         out = fn(*args)
     if sites:
-        _SWEEP_LANE_WRITES[engine] = len(sites)
-    return out, _SWEEP_LANE_WRITES.get(engine, 0)
+        _SWEEP_LANE_SITES[engine] = len(sites), len(sites.dense)
+    return (out,) + _SWEEP_LANE_SITES.get(engine, (0, 0))
 
 
 def _lane_frag_amounts(state, tp):
@@ -3513,6 +3514,28 @@ def _check_sweep_grid(cfg, weights, seeds):
             f"seeds has {len(seeds)} entries for {b} weight rows"
         )
     return w, b, seeds
+
+
+_INT32 = np.iinfo(np.int32)
+_LANE_KEYS_FN = jax.jit(jax.vmap(jax.random.PRNGKey))
+
+
+def _lane_keys(seeds):
+    """[B, ...] PRNG keys, one a lane, equal to stacking
+    jax.random.PRNGKey(s): ONE transfer and ONE vmapped program, where the
+    per-lane form dispatched two small programs a lane (2.2 s of a 9.6 s
+    wave at 2,560 lanes; PERF.md section 6, PR 28). Seeds outside int32
+    keep the per-lane form, whose wrap-around rule is PRNGKey's own."""
+    if all(_INT32.min <= s <= _INT32.max for s in seeds):
+        return _LANE_KEYS_FN(np.asarray(seeds, np.int32))
+    return jnp.stack([jax.random.PRNGKey(s) for s in seeds])
+
+
+def _lane_ranks(num_nodes: int, seeds):
+    """[B, N] tie-break ranks, one permutation a lane, stacked on the host
+    and moved in one transfer (fresh every call: the sweep engines donate
+    it)."""
+    return jnp.asarray(np.stack([tiebreak_rank(num_nodes, s) for s in seeds]))
 
 
 def _slice_sweep_lane(out, amounts, i, wrow, seed, p, e, pad_skips):
@@ -3894,12 +3917,10 @@ def schedule_pods_sweep(
             obs.settle(h, specs_d, ev_kind_d, ev_pod_d, types)
         sweep.events = e
         with obs.span("lane_keys") as h:
-            keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
+            keys = _lane_keys(seeds)
             obs.settle(h, keys)
         with obs.span("lane_ranks") as h:
-            ranks = jnp.stack(
-                [jnp.asarray(tiebreak_rank(len(sim.nodes), s)) for s in seeds]
-            )
+            ranks = _lane_ranks(len(sim.nodes), seeds)
             weights_d = jnp.asarray(w)
             obs.settle(h, ranks, weights_d)
         state = sim.init_state
@@ -3936,8 +3957,8 @@ def schedule_pods_sweep(
                     obs.settle(h, tables)
             fn = _sweep_engine(table_fn.engine.replay, table=True)
             sim._last_engine = f"table ({b}-config vmap sweep)"
-            out, sweep.lane_writes = sim._dispatch_span(
-                lambda: _dispatch_counting_lane_writes(
+            out, sweep.lane_writes, sweep.dense_accesses = sim._dispatch_span(
+                lambda: _dispatch_counting_lane_sites(
                     table_fn.engine.replay, fn, state, specs_d, types,
                     ev_kind_d, ev_pod_d, sim.typical, keys, weights_d,
                     ranks, tables,
@@ -4230,10 +4251,8 @@ def schedule_pods_sweep_multi(
             for f in PodSpec._fields
         )
     )
-    keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    ranks = jnp.stack(
-        [jnp.asarray(tiebreak_rank(len(sim.nodes), s)) for s in seeds]
-    )
+    keys = _lane_keys(seeds)
+    ranks = _lane_ranks(len(sim.nodes), seeds)
     weights_d = jnp.asarray(w)
     state = sim.init_state
     true_events = sum(len(kk) for kk, _ in ev_list)
@@ -4645,10 +4664,8 @@ def schedule_pods_sweep_faults(
         *(jnp.asarray(np.asarray(getattr(specs, f)))
           for f in PodSpec._fields)
     )
-    keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    ranks = jnp.stack(
-        [jnp.asarray(tiebreak_rank(len(sim.nodes), s)) for s in seeds]
-    )
+    keys = _lane_keys(seeds)
+    ranks = _lane_ranks(len(sim.nodes), seeds)
     weights_d = jnp.asarray(w)
     state = sim.init_state
     ops = fault_lane.FaultOps(

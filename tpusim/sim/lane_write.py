@@ -31,6 +31,26 @@ rule below gives every access ONE shape the carry's layout serves as it is:
   back from `write_column` itself, so the step body never gathers a block
   from the table.
 
+Those forms are for leaves of `table_engine.BLOCKED_MIN_NODES` nodes or
+more. On a SHORT node axis (the flat step body's clusters, openb's 1,213
+nodes) at sweep width they are the wrong expression: XLA runs a scatter or a
+gather with one index row a lane as a `while` over the lanes, about 0.9 us a
+lane an access (2,560 lanes x 24 accesses an event; PERF.md section 6, PR
+28), and the profiler's buffer overflows on them. There every access takes
+its DENSE form, one pass over the whole lane-batched leaf with a one-hot
+mask on the node axis and no `scatter` or `gather` at all:
+
+- a write is `where(node_iota == idx, new, leaf)`, an add
+  `leaf + where(node_iota == idx, delta, 0)` (where the lanes bring their
+  own index; one they share, as the bookkeeping rows' is in a sweep of one
+  event stream, stays the single update across the lanes vmap derives);
+- a read is the masked reduction over the node axis (a sum; an `any` for a
+  `bool` leaf), the block `write_column` returns the masked reduction over
+  the block axis of the table viewed as whole blocks.
+
+Which form an access takes follows from the leaf's static shape alone
+(`_short`): no option selects it.
+
 What XLA accepts was found by compiling the cell's program for a described
 v5e (tests/test_sweep_compile.py keeps that compile): one window of all K
 rows, or of all 8 devices and one node, brings the copies back; one window
@@ -43,7 +63,8 @@ every call site (the callers clip them), which is where `.at[]`,
 `dynamic_slice` and a gather agree.
 
 `counting()` observes, at trace time, which write sites were lowered through
-the rule: `SweepRecord.lane_writes`.
+the rule and which sites, reads too, took the dense form:
+`SweepRecord.lane_writes` and `.dense_accesses`.
 """
 
 from __future__ import annotations
@@ -57,49 +78,99 @@ from jax.custom_batching import custom_vmap
 
 TILE_NODES = 128  # nodes in one tile of a nodes-minor leaf
 
-_counting: list = []  # open counting() sets; the rule adds its site to each
+
+class Sites(set):
+    """What counting() yields: the write sites lowered through the rule;
+    `.dense` holds the sites, reads among them, that took the dense form."""
+
+    def __init__(self):
+        super().__init__()
+        self.dense: set = set()
+
+
+_counting: list = []  # open counting() Sites; the rule adds its site to each
 
 
 @contextlib.contextmanager
 def counting():
-    """Yields a set that gains one entry for every write SITE (one call of
+    """Yields a Sites that gains one entry for every write SITE (one call of
     write_column / add_row / set_row in a traced program) lowered through
-    the batching rule while the block runs. A site batched again (the
-    fixpoint of a vmapped scan) counts once; a program served from a jit
-    cache traces nothing and adds nothing."""
-    sites: set = set()
+    the batching rule while the block runs, and in `.dense` every site,
+    read or write, whose batched form was the dense one. A site batched
+    again (the fixpoint of a vmapped scan) counts once; a program served
+    from a jit cache traces nothing and adds nothing."""
+    sites = Sites()
     _counting.append(sites)
     try:
         yield sites
-    finally:
-        _counting.remove(sites)
+    finally:  # by identity: nested blocks may hold equal sets
+        _counting[:] = [s for s in _counting if s is not sites]
 
 
-def _lane_batched(expr, lanes, write: bool, per_lane=()):
+def _short(nodes: int) -> bool:
+    """A node axis this short is served by the dense forms: the clusters
+    the flat step body runs (table_engine.resolve_block_size)."""
+    from tpusim.sim.table_engine import BLOCKED_MIN_NODES  # imports this file
+
+    return nodes < BLOCKED_MIN_NODES
+
+
+def _lane_batched(expr, lanes, write: bool, per_lane=(), dense=None):
     """custom_vmap of `expr` for one call site. Under vmap it runs `lanes`
     (an expression equal to `expr` lane by lane) vmapped over whatever
     operands are batched; the operands at `per_lane` are stacked first, so
-    an index the lanes share still reads as one index a lane."""
+    an index the lanes share still reads as one index a lane. A site whose
+    leaf has a short node axis gives `dense`: `dense(in_batched)` is the
+    site's dense form for those operands, run in place of `lanes` with
+    nothing stacked (it is elementwise in the lanes), or None to decline."""
     fn = custom_vmap(expr)
     site = object()
 
     @fn.def_vmap
     def rule(size, in_batched, *args):
-        if write:
-            for sites in _counting:
+        per = dense(in_batched) if dense is not None else None
+        for sites in _counting:
+            if write:
                 sites.add(site)
-        stacked = [b or i in per_lane for i, b in enumerate(in_batched)]
+            if per is not None:
+                sites.dense.add(site)
+        if per is not None:
+            stacked = list(in_batched)
+        else:
+            stacked = [b or i in per_lane for i, b in enumerate(in_batched)]
+            per = lanes
         args = [
             jnp.broadcast_to(a[None], (size,) + a.shape) if s and not b else a
             for a, s, b in zip(args, stacked, in_batched)
         ]
         out = jax.vmap(
-            lanes, in_axes=[0 if s else None for s in stacked],
+            per, in_axes=[0 if s else None for s in stacked],
             axis_size=size,
         )(*args)
         return out, jax.tree.map(lambda _: True, out)
 
     return fn
+
+
+def _dense_write(form):
+    """The `dense` of a write site (leaf, value, *index): `form` when the
+    lanes bring their own index. An index they share needs no form of its
+    own: the update vmap derives is ONE slice written across all the lanes."""
+    return lambda in_batched: form if any(in_batched[2:]) else None
+
+
+def _one_hot(n: int, idx, trailing: int = 0):
+    """bool[n, 1 x trailing]: True at idx."""
+    mask = lax.iota(jnp.int32, n) == idx
+    return mask.reshape((n,) + (1,) * trailing)
+
+
+def _picked(leaf, mask, axis):
+    """The entries of `leaf` where the one-hot `mask` holds along `axis`,
+    as a masked reduction: no gather."""
+    if leaf.dtype == jnp.bool_:
+        return jnp.any(leaf & mask, axis=axis)
+    return jnp.sum(jnp.where(mask, leaf, 0), axis=axis, dtype=leaf.dtype)
 
 
 def _windows(arr, start, width: int):
@@ -133,14 +204,21 @@ def write_column(tbl, col, idx, block=None):
     """tbl[..., idx] = col for a table with nodes on its last axis.
     With block=(start, width) also returns the written table's
     [..., start:start+width] block (the dirty block of the blocked select,
-    which holds column idx)."""
+    which holds column idx; `start` is a multiple of `width`)."""
     lead = (0,) * (tbl.ndim - 1)
+    n = tbl.shape[-1]
 
     def written(tbl, col, idx):
         return lax.dynamic_update_slice(tbl, col[..., None], lead + (idx,))
 
+    def dense_written(tbl, col, idx):
+        return jnp.where(_one_hot(n, idx), col[..., None], tbl)
+
     if block is None:
-        return _lane_batched(written, written, write=True)(tbl, col, idx)
+        return _lane_batched(
+            written, written, write=True,
+            dense=_dense_write(dense_written) if _short(n) else None,
+        )(tbl, col, idx)
     start, width = block
 
     def expr(tbl, col, idx, start):
@@ -152,17 +230,41 @@ def write_column(tbl, col, idx, block=None):
         out = written(tbl, col, idx)
         return out, _windows(out, start, width)
 
-    return _lane_batched(expr, lanes, write=True)(tbl, col, idx, start)
+    def dense(tbl, col, idx, start):
+        out = dense_written(tbl, col, idx)
+        blocks = out.reshape(tbl.shape[:-1] + (n // width, width))
+        return out, _picked(
+            blocks, _one_hot(n // width, start // width, 1), axis=-2)
+
+    # a table of whole blocks (the blocked layout pads to them); any other
+    # keeps the windows, which read from any start
+    return _lane_batched(
+        expr, lanes, write=True,
+        dense=_dense_write(dense) if _short(n) and n % width == 0 else None,
+    )(tbl, col, idx, start)
 
 
 def read_entry(tbl, row, col):
     """tbl[row, col] of a [K, N] table, as the step bodies slice it."""
+    k, n = tbl.shape
 
     def expr(tbl, row, col):
         return lax.dynamic_slice(tbl, (row, col), (1, 1))[0, 0]
 
-    return _lane_batched(expr, expr, write=False, per_lane=(1, 2))(
-        tbl, row, col)
+    def dense(in_batched):
+        if not in_batched[1]:
+            # the lanes share the row (one event stream): a slice of the
+            # row axis, then one pass over [lanes, N], not [lanes, K, N]
+            return lambda tbl, row, col: _picked(
+                lax.dynamic_index_in_dim(tbl, row, 0, keepdims=False),
+                _one_hot(n, col), axis=0)
+        return lambda tbl, row, col: _picked(
+            tbl, _one_hot(k, row, 1) & _one_hot(n, col), axis=(0, 1))
+
+    return _lane_batched(
+        expr, expr, write=False, per_lane=(1, 2),
+        dense=dense if _short(n) else None,
+    )(tbl, row, col)
 
 
 # ------------------------------------------------------------------ rows
@@ -171,8 +273,19 @@ def _row_write(leaf, idx, val, add: bool):
         ref = leaf.at[idx if len(idx) > 1 else idx[0]]
         return ref.add(val) if add else ref.set(val)
 
+    def dense(leaf, val, *idx):
+        mask = _one_hot(leaf.shape[0], idx[0], leaf.ndim - 1)
+        for axis, i in enumerate(idx[1:], 1):
+            mask = mask & _one_hot(leaf.shape[axis], i, leaf.ndim - 1 - axis)
+        val = jnp.asarray(val, leaf.dtype)  # one row: leaf.shape[len(idx):]
+        return leaf + jnp.where(mask, val, 0) if add else jnp.where(
+            mask, val, leaf)
+
     idx = idx if isinstance(idx, tuple) else (idx,)
-    return _lane_batched(expr, expr, write=True)(leaf, val, *idx)
+    return _lane_batched(
+        expr, expr, write=True,
+        dense=_dense_write(dense) if _short(leaf.shape[0]) else None,
+    )(leaf, val, *idx)
 
 
 def add_row(leaf, idx, val):
@@ -188,24 +301,33 @@ def set_row(leaf, idx, val):
 def read_row(leaf, idx, keepdims: bool = True):
     """Row idx of a leaf with nodes on its first axis: the [1, ...] slice
     `dynamic_slice_in_dim` gives (keepdims) or `leaf[idx]`."""
+    n = leaf.shape[0]
 
     def expr(leaf, idx):
         if keepdims:
             return lax.dynamic_slice_in_dim(leaf, idx, 1, axis=0)
         return leaf[idx]
 
+    def wrapped(idx):  # leaf[idx] wraps a negative index once
+        return idx if keepdims else jnp.where(idx < 0, idx + n, idx)
+
     def lanes(leaf, idx):
         if leaf.ndim == 1:
             return expr(leaf, idx)
-        if not keepdims:  # leaf[idx] wraps a negative index once
-            idx = jnp.where(idx < 0, idx + leaf.shape[0], idx)
+        idx = wrapped(idx)
         if leaf.ndim != 2:
             raise NotImplementedError(leaf.shape)
-        n = leaf.shape[0]
         width = min(TILE_NODES, n)
         start = jnp.minimum((idx // width) * width, n - width)
         tile = _windows(leaf.T, start, width)  # [C, width]
         row = lax.dynamic_slice_in_dim(tile, idx - start, 1, axis=1).T
         return row if keepdims else row[0]
 
-    return _lane_batched(expr, lanes, write=False, per_lane=(1,))(leaf, idx)
+    def dense(leaf, idx):
+        row = _picked(leaf, _one_hot(n, wrapped(idx), leaf.ndim - 1), axis=0)
+        return row[None] if keepdims else row
+
+    return _lane_batched(
+        expr, lanes, write=False, per_lane=(1,),
+        dense=(lambda _: dense) if _short(n) else None,
+    )(leaf, idx)

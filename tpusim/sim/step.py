@@ -254,13 +254,14 @@ def bind_selected(
     # Bind: scatter-commit the placement.
     cls = pod_affinity_class(pod)
     new_state = state._replace(
-        cpu_left=state.cpu_left.at[node].add(jnp.where(ok, -pod.cpu, 0)),
-        mem_left=state.mem_left.at[node].add(jnp.where(ok, -pod.mem, 0)),
-        gpu_left=state.gpu_left.at[node].add(
-            -dev_mask.astype(jnp.int32) * pod.gpu_milli
+        cpu_left=add_row(state.cpu_left, node, jnp.where(ok, -pod.cpu, 0)),
+        mem_left=add_row(state.mem_left, node, jnp.where(ok, -pod.mem, 0)),
+        gpu_left=add_row(
+            state.gpu_left, node, -dev_mask.astype(jnp.int32) * pod.gpu_milli
         ),
-        aff_cnt=state.aff_cnt.at[node, jnp.maximum(cls, 0)].add(
-            jnp.where(ok & (cls >= 0), 1, 0)
+        aff_cnt=add_row(
+            state.aff_cnt, (node, jnp.maximum(cls, 0)),
+            jnp.where(ok & (cls >= 0), 1, 0),
         ),
     )
     return new_state, Placement(jnp.where(ok, node, -1).astype(jnp.int32), dev_mask)
@@ -565,12 +566,14 @@ def unschedule(state: NodeState, pod: PodSpec, placement: Placement) -> NodeStat
     placed = placement.node >= 0
     cls = pod_affinity_class(pod)
     return state._replace(
-        cpu_left=state.cpu_left.at[node].add(jnp.where(placed, pod.cpu, 0)),
-        mem_left=state.mem_left.at[node].add(jnp.where(placed, pod.mem, 0)),
-        gpu_left=state.gpu_left.at[node].add(
-            jnp.where(placed, placement.dev_mask.astype(jnp.int32) * pod.gpu_milli, 0)
+        cpu_left=add_row(state.cpu_left, node, jnp.where(placed, pod.cpu, 0)),
+        mem_left=add_row(state.mem_left, node, jnp.where(placed, pod.mem, 0)),
+        gpu_left=add_row(
+            state.gpu_left, node,
+            jnp.where(placed, placement.dev_mask.astype(jnp.int32) * pod.gpu_milli, 0),
         ),
-        aff_cnt=state.aff_cnt.at[node, jnp.maximum(cls, 0)].add(
-            jnp.where(placed & (cls >= 0), -1, 0)
+        aff_cnt=add_row(
+            state.aff_cnt, (node, jnp.maximum(cls, 0)),
+            jnp.where(placed & (cls >= 0), -1, 0),
         ),
     )

@@ -1108,7 +1108,8 @@ def _make_table_engine(
                 sel, _, ok = packed_argmax(total, feasible, tiebreak_rank)
                 dmask = choose_devices(
                     lane_write.read_row(state.gpu_left, sel, keepdims=False),
-                    pod, sdev_tbl[t_id, sel], gpu_sel, k_sel,
+                    pod, lane_write.read_entry(sdev_tbl, t_id, sel),
+                    gpu_sel, k_sel,
                 ) & ok
                 node_f = jnp.where(ok, sel, -1).astype(jnp.int32)
                 if not decisions:
