@@ -1,6 +1,7 @@
 """Helpers for tests that look at the sweep's program without running it:
-capture the jitted vmapped replay with the shapes a cell calls it on, and
-list the large `copy` operations of a compiled module's scan."""
+capture the jitted vmapped replay with the shapes a cell calls it on, list
+the large `copy` operations of a compiled module's scan, its loops and how
+they nest, and the operations of a loop that produce a given shape."""
 
 import json
 import math
@@ -118,6 +119,41 @@ def while_loops(text: str) -> list:
             for m in map(_WHILE.match, lines) if m]
 
 
+def loop_bodies(text: str) -> dict:
+    """{body computation: computation that holds its `while`} for every
+    loop of a compiled module: a body whose holder is itself a body is an
+    inner loop."""
+    return {re.search(r"body=%?([\w.\-]+)", line).group(1): name
+            for name, lines in _computations(text).items()
+            for line in lines if _WHILE.match(line)}
+
+
+def _reachable(comps: dict, root: str) -> set:
+    reach, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in reach or name not in comps:
+            continue
+        reach.add(name)
+        todo.extend(_called(comps[name]))
+    return reach
+
+
+def producers_in(text: str, root: str, shape: str) -> list:
+    """(computation, name, opcode) of every instruction in `root` and the
+    computations it calls (inner loops, branches, fusions) whose result
+    matches the regular expression `shape`, less those that only hand a
+    buffer on (parameter, get-tuple-element, tuple, bitcast)."""
+    comps, found = _computations(text), []
+    for name in sorted(_reachable(comps, root)):
+        for line in comps[name]:
+            m = _INSTRUCTION.match(line)
+            if m and re.search(shape, m.group(2)) and m.group(3) not in (
+                    "parameter", "get-tuple-element", "tuple", "bitcast"):
+                found.append((name, m.group(1), m.group(3)))
+    return found
+
+
 def big_copies_in_scan(text: str, min_elems: int) -> list:
     """`copy` / `copy-start` instructions whose result holds at least
     `min_elems` elements, in the largest while body of a compiled module
@@ -128,15 +164,8 @@ def big_copies_in_scan(text: str, min_elems: int) -> list:
     if not bodies:
         raise ValueError("the module holds no while loop")
     scan = max(bodies, key=lambda b: len(comps.get(b, ())))
-    reach, todo = set(), [scan]
-    while todo:
-        name = todo.pop()
-        if name in reach or name not in comps:
-            continue
-        reach.add(name)
-        todo.extend(_called(comps[name]))
     found = []
-    for name in sorted(reach):
+    for name in sorted(_reachable(comps, scan)):
         for line in comps[name]:
             m = _INSTRUCTION.match(line)
             if not m or m.group(3) not in ("copy", "copy-start"):

@@ -397,3 +397,113 @@ def test_the_rule_can_be_vmapped_again(size):
     got = jax.jit(jax.vmap(jax.vmap(_step)))(*args)
     want = [jax.vmap(_step)(*(a[i] for a in args)) for i in range(2)]
     _same(got, jax.tree.map(lambda *x: jnp.stack(x), *want))
+
+
+# ------------------------------------------------- a group of columns
+SLOTS = 4
+
+
+def _group_idx(n):
+    """One index row a lane: a node named twice (the later slot wins), an
+    unused slot (-1) in the middle and at the end, a lane with nothing
+    pending, two nodes written alternately, the last node."""
+    return jnp.asarray([[3, 3, -1, 0], [0, n - 1, n - 1, -1],
+                        [-1, -1, -1, -1], [5, 4, 5, 4], [n - 1, 0, 3, 3]],
+                       jnp.int32)
+
+
+def _group_cols(leaf, n_pad):
+    tbl, col = COLUMN_LEAVES[leaf](n_pad)
+    cols = jnp.stack([jnp.roll(col, s, axis=-1) if s % 2 else ~col
+                      if col.dtype == jnp.bool_ else col + s
+                      for s in range(SLOTS)], axis=1)  # [L, SLOTS, ...]
+    return tbl, cols
+
+
+def _plain_columns(tbl, cols, idxs):
+    """SLOTS sequential write_columns, a slot at -1 left out."""
+    for s in range(SLOTS):
+        tbl = jnp.where(idxs[s] >= 0,
+                        _plain_column(tbl, cols[s], jnp.maximum(idxs[s], 0)),
+                        tbl)
+    return tbl
+
+
+@pytest.mark.parametrize("size", sorted(SIZES))
+@pytest.mark.parametrize("pattern", sorted(PATTERNS))
+@pytest.mark.parametrize("leaf", sorted(COLUMN_LEAVES))
+def test_write_columns_equals_the_sequential_column_writes(leaf, pattern,
+                                                           size):
+    n, n_pad = SIZES[size]
+    tbl, cols = _group_cols(leaf, n_pad)
+    args, axes = _axes((tbl, cols, _group_idx(n)), PATTERNS[pattern])
+    _same(jax.jit(jax.vmap(lw.write_columns, in_axes=axes))(*args),
+          jax.vmap(_plain_columns, in_axes=axes)(*args))
+    for lane in range(L if pattern == "all" else 1):
+        one = tuple(a[lane] if ax == 0 else a for a, ax in zip(args, axes))
+        _same(lw.write_columns(*one), _plain_columns(*one))
+        # through write_column itself, the flat step's former form
+        want = one[0]
+        for s in range(SLOTS):
+            if int(one[2][s]) >= 0:
+                want = lw.write_column(want, one[1][s], one[2][s])
+        _same(lw.write_columns(*one), want)
+
+
+@pytest.mark.parametrize("size", sorted(SIZES))
+def test_write_columns_is_one_pass_on_a_short_axis_and_counts_its_slots(size):
+    """Dense (short axis, one index row a lane): selects over the leaf, no
+    scatter, gather or loop, and counting() reports the group's depth as
+    the events one table pass writes; a long axis keeps the scatters vmap
+    derives and reports no dense pass; unbatched, it is SLOTS in-place
+    column updates and nothing is counted."""
+    n, n_pad = SIZES[size]
+    tbl, cols = _group_cols("score", n_pad)
+    args = (tbl, cols, _group_idx(n))
+    with lw.counting() as sites:
+        text = jax.jit(jax.vmap(lw.write_columns)).lower(*args).as_text()
+    assert len(sites) == 1
+    if size == "short":
+        assert len(sites.dense) == 1 and sites.table_pass_events == SLOTS
+        for op in ("scatter", "gather", "while", "dynamic_update_slice"):
+            assert f"stablehlo.{op}" not in text, op
+    else:
+        assert len(sites.dense) == 0 and sites.table_pass_events == 0
+        assert text.count('"stablehlo.scatter"') == SLOTS
+    with lw.counting() as sites:
+        jax.jit(jax.vmap(lw.write_column)).lower(tbl, cols[:, 0], args[2][:, 0])
+    assert sites.table_pass_events == (1 if size == "short" else 0)
+    with lw.counting() as sites:
+        one = jax.jit(lw.write_columns).lower(
+            *(a[0] for a in args)).as_text()
+    assert not sites and sites.table_pass_events == 0
+    assert one.count("stablehlo.dynamic_update_slice") == SLOTS
+    assert "stablehlo.scatter" not in one and "stablehlo.gather" not in one
+
+
+@pytest.mark.parametrize("size", sorted(SIZES))
+@pytest.mark.parametrize("leaf", sorted(COLUMN_LEAVES))
+def test_a_patched_read_equals_the_read_after_the_write(leaf, size):
+    """patch_row / patch_entry give the row and the entry of the table as
+    write_columns would leave it, without writing it."""
+    n, n_pad = SIZES[size]
+    tbl, cols = _group_cols(leaf, n_pad)
+    idxs = _group_idx(n)
+    rows = jnp.asarray([0, K - 1, 2, 2, 5], jnp.int32)
+    at = _node_idx(n)
+
+    def patched(tbl, cols, idxs, row, col):
+        t2, c2 = (tbl[0], cols[:, 0]) if tbl.ndim == 3 else (tbl, cols)
+        vals = c2[:, row]
+        return (lw.patch_row(t2[row], idxs, vals),
+                lw.patch_entry(lw.read_entry(t2, row, col), col, idxs, vals))
+
+    def written(tbl, cols, idxs, row, col):
+        out = _plain_columns(tbl, cols, idxs)
+        out = out[0] if out.ndim == 3 else out
+        return out[row], out[row, col]
+
+    args = (tbl, cols, idxs, rows, at)
+    _same(jax.jit(jax.vmap(patched))(*args), jax.vmap(written)(*args))
+    one = tuple(a[0] for a in args)
+    _same(patched(*one), written(*one))

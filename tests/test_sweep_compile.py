@@ -1,8 +1,11 @@
 """The benchmark cells' sweeps, compiled for a described TPU v5e (no chip:
 the TPU's compiler is installed; nothing runs): the blocked scan of
 synth100k may hold no copy of a whole carried array (ISSUE 27), the flat
-scan of openb no loop over the lanes (ISSUE 28). tests/test_tpu.py holds the
+scan of openb no loop over the lanes (ISSUE 28) and no whole-table
+operation inside its per-event step (ISSUE 29). tests/test_tpu.py holds the
 same checks on the chip itself."""
+
+import re
 
 import jax
 import pytest
@@ -12,7 +15,9 @@ from tests import sweep_program
 from tpusim.sim import lane_write
 
 NODES, LANES, DEPTH = 100_000, 40, 512
-OPENB_LANES, OPENB_DEPTH = 128, 64  # compiles in 6 s; the cell's in 36 s
+# compiles in 9 s, the cell's 2,560 x 512 in 30 s; from 256 lanes the
+# compiler no longer keeps a whole table in fast memory between steps
+OPENB_LANES, OPENB_DEPTH = 256, 64
 
 
 @pytest.fixture(scope="module")
@@ -73,6 +78,7 @@ def test_cell_sized_sweep_compiles_without_whole_carry_copies(one_chip):
     # epilogue, none in the dense form: 100,000 nodes are a long axis, and
     # the lanes share the index of the short bookkeeping rows
     assert (len(sites), len(sites.dense)) == (17, 0)
+    assert sites.table_pass_events == 0  # no table is written densely
     compiled = lowered.compile()
     found = sweep_program.big_copies_in_scan(
         compiled.as_text(), LANES * NODES)
@@ -84,10 +90,16 @@ def test_cell_sized_sweep_compiles_without_whole_carry_copies(one_chip):
 
 def test_the_openb_flat_sweep_loops_over_events_only(one_chip):
     """1,213 nodes on the flat step body: XLA runs a scatter or a gather
-    with one index row a lane as a `while` over the lanes (the parent's
-    program held 21 at this size and 32 at the cell's 2,560 lanes x 512
-    events, eleven-odd inside every scan step). In the dense form the only
-    loop of the module is the event scan."""
+    with one index row a lane as a `while` over the lanes (PR 27's program
+    held 21 at 128 lanes and 32 at the cell's 2,560 lanes x 512 events,
+    eleven-odd inside every scan step). In the dense form the loops of the
+    module are the event loops and nothing else: the scan over groups of
+    FLAT_GROUP_EVENTS events and, inside it, the scan over a group's
+    events. The step inside the inner loop produces no array of a whole
+    table's shape (its column goes into the pending block); the flush in
+    the outer loop is one fusion a table, written in place."""
+    from tpusim.sim.table_engine import FLAT_GROUP_EVENTS
+
     sim, trace, cfg = sweep_program.cell_simulator(
         None, OPENB_DEPTH, config="openb")
     assert len(sim.nodes) == 1213
@@ -100,6 +112,33 @@ def test_the_openb_flat_sweep_loops_over_events_only(one_chip):
                                            sharding=one_chip), shapes)
         lowered = fn.lower(*shapes)
     assert len(sites) == 17 and len(sites.dense) == 22
-    loops = sweep_program.while_loops(lowered.compile().as_text())
-    assert len(loops) == 1, loops
-    assert f"s32[{OPENB_LANES},1213,9]" in loops[0][2]  # the scan's carry
+    assert sites.table_pass_events == FLAT_GROUP_EVENTS
+    assert OPENB_DEPTH % FLAT_GROUP_EVENTS == 0  # no tail group to trace
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    k = shapes[9][0].shape[1]  # the trace's pod types at this depth
+    table = rf"\[{OPENB_LANES},(1,)?{k},1213\]"
+
+    # the loops: one over the groups (it carries the node state and the
+    # tables), one over a group's events inside it; none over the lanes
+    loops = sweep_program.while_loops(text)
+    bodies = sweep_program.loop_bodies(text)
+    (outer,) = [b for b, holder in bodies.items() if holder not in bodies]
+    (inner,) = [b for b, holder in bodies.items() if holder == outer]
+    assert len(loops) == 2, loops
+    for _, _, carried in loops:
+        assert f"s32[{OPENB_LANES},1213,9]" in carried  # the scan's carry
+    held = {holder: carried for holder, _, carried in loops}
+    assert re.search(rf"s32{table}", held[bodies[outer]])
+    assert f"s32[{OPENB_LANES},{FLAT_GROUP_EVENTS},{k}]" in held[outer]
+
+    # no whole-table operation inside the per-event step
+    assert not sweep_program.producers_in(text, inner, table)
+    # the flush: one select-chain fusion a table, in the outer loop only
+    flush = [(c, n, op) for c, n, op in sweep_program.producers_in(
+        text, outer, table) if op == "fusion"]
+    assert len(flush) == 3 and {c for c, _, _ in flush} == {outer}, flush
+    # written in place: the temporaries hold the tables (4 + 4 + 1 bytes an
+    # entry) once, not twice
+    tables = OPENB_LANES * k * 1213 * 9
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * tables

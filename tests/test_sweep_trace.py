@@ -255,8 +255,9 @@ def test_a_scoped_sweep_equals_the_sequential_oracle(scoped):
         assert all(got[f] == want[f] for f in INVARIANT_FIELDS), (got, want)
 
 
-# the step body: write_column x3 (score, device, feasibility) and the
-# commit's add_row x4 (cpu_left, mem_left, gpu_left, aff_cnt) and set_row x3
+# the step body: three column writes (score, device, feasibility: the
+# blocked body's write_column, the flat body's write_columns in its flush)
+# and the commit's add_row x4 (cpu_left, mem_left, gpu_left, aff_cnt) and set_row x3
 # (placed, masks, failed); the commit once more in the replay's epilogue
 WRITE_SITES = 3 + 7 + 7
 
@@ -319,6 +320,47 @@ def test_dense_accesses_counts_the_sites_lowered_in_the_dense_form(scoped):
         assert rec.dense_accesses == DENSE_SITES[block_size]
     assert sim.run_telemetry().to_record()["timing"]["sweeps"][-1][
         "dense_accesses"] == rec.dense_accesses
+
+
+def test_table_pass_events_is_the_flat_bodys_group(scoped):
+    """Events whose columns one dense pass over a table writes. A sweep
+    this narrow (3 lanes) writes a column every event on either body: 1.
+    From FLAT_GROUP_MIN_LANES lanes the flat body runs in groups and the
+    record reads the group (the same Simulator, the wider program); the
+    blocked body forced onto the short cluster stays at 1; 0 where no
+    table is written densely (100,000 nodes: tests/test_sweep_compile.py)
+    and in a record no sweep filled."""
+    from tpusim.obs.spans import SweepRecord
+    from tpusim.sim.table_engine import (
+        FLAT_GROUP_EVENTS, FLAT_GROUP_MIN_LANES)
+
+    block_size, sim, lanes, _ = scoped
+    rec = sim.obs.sweeps[-1]
+    assert rec.table_pass_events == 1
+    assert sim.run_telemetry().to_record()["timing"]["sweeps"][-1][
+        "table_pass_events"] == 1
+    assert SweepRecord(id=0, start_s=0.0, blocked=False).to_dict()[
+        "table_pass_events"] == 0
+    wide = FLAT_GROUP_MIN_LANES
+    got = schedule_pods_sweep(
+        sim, sim.prepare_pods(), [WEIGHTS[i % 3] for i in range(wide)],
+        [SEEDS[i % 3] for i in range(wide)])
+    rec = sim.obs.sweeps[-1]
+    assert rec.lanes == wide and rec.lane_writes == WRITE_SITES
+    assert rec.table_pass_events == (
+        FLAT_GROUP_EVENTS if block_size < 0 else 1)
+    assert rec.to_dict()["table_pass_events"] == rec.table_pass_events
+    for i in range(3):  # the wide lanes are the narrow sweep's, repeated
+        for g in (got[i], got[i + 3]):
+            np.testing.assert_array_equal(
+                g.placed_node, lanes[i].placed_node)
+            np.testing.assert_array_equal(g.dev_mask, lanes[i].dev_mask)
+            for a, b in zip(jax.tree.leaves(g.state),
+                            jax.tree.leaves(lanes[i].state)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the narrow program's record is still its own
+    schedule_pods_sweep(sim, sim.prepare_pods(), WEIGHTS, SEEDS)
+    assert sim.obs.sweeps[-1].table_pass_events == 1
 
 
 @pytest.mark.parametrize("seeds", [

@@ -322,3 +322,197 @@ def test_unswitched_fault_lane_bit_identity():
     np.testing.assert_array_equal(
         np.asarray(r0.dev_mask), np.asarray(r1.dev_mask)
     )
+
+
+# ------------------------------------------------ deferred column writes
+# (the grouped flat body: what a wide sweep runs, table_engine
+# .flat_group_events; the engine's own entries take it as static `group=`)
+def _grouped(tab, policies, group):
+    """(replay, run_chunk) of `tab`'s engine on the grouped flat body, with
+    the weight operand filled in as make_table_replay's wrappers do."""
+    from tpusim.sim.step import resolve_weights
+
+    wts = resolve_weights(policies, None)
+
+    def replay(state, pods, types, ev_kind, ev_pod, tp, key, rank):
+        return tab.engine.replay(state, pods, types, ev_kind, ev_pod, tp,
+                                 key, wts, rank, group=group)
+
+    def run_chunk(carry, pods, types, ev_kind, ev_pod, tp, rank):
+        return tab.engine.run_chunk(carry, pods, types, ev_kind, ev_pod, tp,
+                                    wts, rank, group=group)
+
+    return replay, run_chunk
+
+
+def _mixed_events(depth, num_pods, rng):
+    """`depth` events: creates, deletes of pods created earlier and skips
+    (EV_SKIP: a padded or unscheduled-annotated pod), in one stream."""
+    from tpusim.sim.engine import EV_SKIP
+
+    kinds, idxs, live, nxt = [], [], [], 0
+    while len(kinds) < depth:
+        u = rng.random()
+        if u < 0.2 and live:
+            kinds.append(EV_DELETE)
+            idxs.append(live.pop(int(rng.integers(len(live)))))
+        elif u < 0.3 or nxt == num_pods:
+            kinds.append(EV_SKIP)
+            idxs.append(int(rng.integers(num_pods)))
+        else:
+            kinds.append(EV_CREATE)
+            idxs.append(nxt)
+            live.append(nxt)
+            nxt += 1
+    return jnp.asarray(kinds, jnp.int32), jnp.asarray(idxs, jnp.int32)
+
+
+def _flat_case(depth, nodes=10, seed=41):
+    rng = np.random.default_rng(seed)
+    state, tp = random_cluster(rng, num_nodes=nodes)
+    pods = random_pods(rng, num_pods=depth)
+    ev_kind, ev_pod = _mixed_events(depth, depth, rng)
+    rank = jnp.asarray(rng.permutation(nodes).astype(np.int32))
+    return state, tp, pods, ev_kind, ev_pod, rank
+
+
+def _assert_tables_current(replay, carry, types, tp, key):
+    """Nothing pending: the carried tables are a rebuild on the carried
+    state (the last event's commit is still in the pipeline register, and
+    its column is the next event's refresh)."""
+    want = replay.build_tables(carry.state, types, tp, key)
+    for got, w in zip((carry.score_tbl, carry.sdev_tbl, carry.feas_tbl),
+                      want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(w))
+
+
+@pytest.mark.parametrize("over", [0, 1, -1], ids=["0", "1", "B-1"])
+def test_flat_replay_with_late_columns_matches_sequential(over):
+    """The flat step writes its dirty columns a group of FLAT_GROUP_EVENTS
+    at a time and patches the rows it reads in between: every leaf equals
+    the sequential oracle's for depths that are 0, 1 and B - 1 modulo the
+    group, with deletes and skips in the stream and nodes dirtied more
+    than once inside one group (the later column must win)."""
+    from tpusim.sim.table_engine import FLAT_GROUP_EVENTS as group
+
+    depth = 3 * group + over
+    state, tp, pods, ev_kind, ev_pod, rank = _flat_case(depth)
+    assert {0, 1, 2} <= set(np.asarray(ev_kind).tolist())
+    policies = [(make_policy("FGDScore"), 1000)]
+    key = jax.random.PRNGKey(3)
+    types = build_pod_types(pods)
+    r0 = make_replay(policies, gpu_sel="FGDScore", report=False)(
+        state, pods, ev_kind, ev_pod, tp, key, rank)
+    tab = make_table_replay(policies, gpu_sel="FGDScore", block_size=-1)
+    replay, run_chunk = _grouped(tab, policies, group)
+    r1 = replay(state, pods, types, ev_kind, ev_pod, tp, key, rank)
+    _assert_equal(r0, r1)
+    np.testing.assert_array_equal(
+        np.asarray(r0.event_node), np.asarray(r1.event_node))
+    np.testing.assert_array_equal(
+        np.asarray(r0.event_dev), np.asarray(r1.event_dev))
+    touched = np.asarray(r1.event_node)[:group]
+    touched = touched[touched >= 0]
+    assert len(set(touched.tolist())) < len(touched)  # a node dirtied twice
+    carry = tab.init_carry(state, pods, types, tp, key, rank)
+    carry, _ = run_chunk(carry, pods, types, ev_kind, ev_pod, tp, rank)
+    _assert_tables_current(tab, carry, types, tp, key)
+    # and the plain body (a group of 1: what a replay runs unless told)
+    _assert_equal(r0, tab(state, pods, types, ev_kind, ev_pod, tp, key, rank))
+
+
+def test_flat_chunks_cut_inside_a_group_equal_one_replay():
+    """run_chunk over cut points that are no multiples of the group: each
+    chunk ends in its own flush, so the carry between chunks has nothing
+    pending (its tables are a rebuild on its state) and the chain is
+    bit-identical to one replay() (grouped or not: the carry between
+    chunks is the same carry)."""
+    from tpusim.sim.table_engine import FLAT_GROUP_EVENTS as group
+
+    depth = 3 * group + 5
+    state, tp, pods, ev_kind, ev_pod, rank = _flat_case(depth, seed=43)
+    policies = [(make_policy("FGDScore"), 1000)]
+    key = jax.random.PRNGKey(5)
+    types = build_pod_types(pods)
+    tab = make_table_replay(policies, gpu_sel="FGDScore", block_size=-1)
+    whole = tab(state, pods, types, ev_kind, ev_pod, tp, key, rank)
+    replay, run_chunk = _grouped(tab, policies, group)
+    np.testing.assert_array_equal(
+        np.asarray(whole.event_node), np.asarray(replay(
+            state, pods, types, ev_kind, ev_pod, tp, key, rank).event_node))
+    cuts = [0, 1, group - 1, group + 2, 2 * group + 2, depth]
+    carry = tab.init_carry(state, pods, types, tp, key, rank)
+    nodes, devs = [], []
+    for lo, hi in zip(cuts, cuts[1:]):
+        carry, (nd, dv) = run_chunk(
+            carry, pods, types, ev_kind[lo:hi], ev_pod[lo:hi], tp, rank)
+        assert type(carry).__name__ == "FlatTableCarry"
+        _assert_tables_current(tab, carry, types, tp, key)
+        nodes.append(nd)
+        devs.append(dv)
+    st, placed, masks, failed = tab.finish(carry)
+    np.testing.assert_array_equal(
+        np.asarray(placed), np.asarray(whole.placed_node))
+    np.testing.assert_array_equal(np.asarray(masks), np.asarray(whole.dev_mask))
+    np.testing.assert_array_equal(
+        np.asarray(failed), np.asarray(whole.ever_failed))
+    np.testing.assert_array_equal(
+        np.concatenate([np.asarray(x) for x in nodes]),
+        np.asarray(whole.event_node))
+    np.testing.assert_array_equal(
+        np.concatenate([np.asarray(x) for x in devs]),
+        np.asarray(whole.event_dev))
+    for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(whole.state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_vmapped_flat_sweep_equals_its_standalone_lanes():
+    """Lanes with their own key and tie-break rank dirty their own nodes:
+    each slot of the pending block holds one index a lane. Every lane of
+    the vmapped engine equals its standalone replay in every leaf."""
+    import functools
+
+    from tpusim.sim.step import resolve_weights
+    from tpusim.sim.table_engine import FLAT_GROUP_EVENTS as group
+
+    depth, nodes, lanes = 2 * group + 3, 10, 3
+    state, tp, pods, ev_kind, ev_pod, _ = _flat_case(depth, nodes, seed=47)
+    policies = [(make_policy("FGDScore"), 1000)]
+    tab = make_table_replay(policies, gpu_sel="random", block_size=-1)
+    types = build_pod_types(pods)
+    keys = jnp.stack([jax.random.PRNGKey(s) for s in range(lanes)])
+    ranks = jnp.stack([
+        jnp.asarray(np.random.default_rng(s).permutation(nodes), jnp.int32)
+        for s in range(lanes)])
+    wts = resolve_weights(policies, None)
+    swept = jax.jit(jax.vmap(
+        functools.partial(tab.engine.replay, group=group),
+        in_axes=(None, None, None, None, None, None, 0, None, 0)))(
+        state, pods, types, ev_kind, ev_pod, tp, keys, wts, ranks)
+    differ = False
+    for i in range(lanes):
+        one = tab(state, pods, types, ev_kind, ev_pod, tp, keys[i], ranks[i])
+        lane = jax.tree.map(lambda a: a[i], swept)
+        _assert_equal(one, lane)
+        np.testing.assert_array_equal(
+            np.asarray(one.event_node), np.asarray(lane.event_node))
+        np.testing.assert_array_equal(
+            np.asarray(one.counters), np.asarray(lane.counters))
+        differ |= not np.array_equal(
+            np.asarray(swept.event_node[0]), np.asarray(lane.event_node))
+    assert differ  # the lanes did not all take the same nodes
+
+
+def test_the_flat_group_follows_from_the_sweeps_shapes():
+    """Wide on a short node axis: groups; narrow, or on the blocked
+    body's clusters, every event. No option selects it."""
+    from tpusim.sim.table_engine import (
+        BLOCKED_MIN_NODES, FLAT_GROUP_EVENTS, FLAT_GROUP_MIN_LANES,
+        flat_group_events)
+
+    assert flat_group_events(2560, 1213) == FLAT_GROUP_EVENTS > 1
+    assert flat_group_events(FLAT_GROUP_MIN_LANES, 16) == FLAT_GROUP_EVENTS
+    assert flat_group_events(FLAT_GROUP_MIN_LANES - 1, 1213) == 1
+    assert flat_group_events(1, 1213) == 1
+    assert flat_group_events(2560, BLOCKED_MIN_NODES) == 1
+    assert flat_group_events(40, 100_000) == 1

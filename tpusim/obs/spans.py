@@ -158,6 +158,10 @@ class SweepRecord:
     # sites of that program, reads too, that the rule lowered in the dense
     # form (a short node axis: the flat step body); 0 on the blocked body
     dense_accesses: int = 0
+    # events whose dirty columns one dense pass over a lane-batched table
+    # writes: the flat body's group (table_engine.FLAT_GROUP_EVENTS), 1
+    # where a column is written every event, 0 with no dense table write
+    table_pass_events: int = 0
 
     @property
     def compiled(self) -> int:
@@ -177,6 +181,7 @@ class SweepRecord:
             "compiled": self.compiled,
             "lane_writes": self.lane_writes,
             "dense_accesses": self.dense_accesses,
+            "table_pass_events": self.table_pass_events,
             "spans": [s.to_dict() for s in self.spans],
         }
 

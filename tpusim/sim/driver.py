@@ -9,6 +9,7 @@ device via tpusim.sim.engine.make_replay.
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 import sys
@@ -3412,34 +3413,53 @@ def _sweep_engine(engine, table: bool, donate: bool = True):
             #  tables)
             in_axes = (None, None, None, None, None, None, 0, 0, 0, None)
             dn = (8,)
+
+            def swept(*args):
+                # the stacked ranks are [lanes, nodes]: a wide sweep of a
+                # short cluster runs the flat step in groups
+                from tpusim.sim.table_engine import flat_group_events
+
+                return jax.vmap(
+                    functools.partial(
+                        engine, group=flat_group_events(*args[8].shape)),
+                    in_axes=in_axes,
+                )(*args)
+
+            # the program keeps the engine's name (traces, compile cache)
+            swept.__name__ = getattr(engine, "__name__", swept.__name__)
         else:
             # (state, pods, ev_kind, ev_pod, tp, key, wts, rank)
             in_axes = (None, None, None, None, None, 0, 0, 0)
             dn = (7,)
+            swept = jax.vmap(engine, in_axes=in_axes)
         _SWEEP_WRAP_CACHE[ck] = jax.jit(
-            jax.vmap(engine, in_axes=in_axes),
-            donate_argnums=dn if donate else (),
+            swept, donate_argnums=dn if donate else (),
         )
     return _SWEEP_WRAP_CACHE[ck]
 
 
-_SWEEP_LANE_SITES = {}  # engine -> (write sites, dense sites) of its program
+# (engine, lanes) -> (write sites, dense sites, events a table pass) of the
+# program
+_SWEEP_LANE_SITES = {}
 
 
 def _dispatch_counting_lane_sites(engine, fn, *args):
     """fn(*args) for fn = _sweep_engine(engine, ...), the number of write
     sites of that program that went through sim/lane_write.py's batching
-    rule, and the number of its sites (reads too) that the rule lowered in
-    the dense form. The rule runs while the program is traced, so a call
-    served from the jit cache reports what the engine's last trace
-    counted."""
+    rule, the number of its sites (reads too) that the rule lowered in
+    the dense form, and the events whose columns one dense pass over a
+    table writes (0: the program has no such pass). The rule runs while
+    the program is traced, so a call served from the jit cache reports
+    what the last trace of the engine at that width counted."""
     from tpusim.sim import lane_write
 
     with lane_write.counting() as sites:
         out = fn(*args)
+    program = engine, args[6].shape[0]  # the lanes decide the flat group
     if sites:
-        _SWEEP_LANE_SITES[engine] = len(sites), len(sites.dense)
-    return (out,) + _SWEEP_LANE_SITES.get(engine, (0, 0))
+        _SWEEP_LANE_SITES[program] = (
+            len(sites), len(sites.dense), sites.table_pass_events)
+    return (out,) + _SWEEP_LANE_SITES.get(program, (0, 0, 0))
 
 
 def _lane_frag_amounts(state, tp):
@@ -3957,7 +3977,8 @@ def schedule_pods_sweep(
                     obs.settle(h, tables)
             fn = _sweep_engine(table_fn.engine.replay, table=True)
             sim._last_engine = f"table ({b}-config vmap sweep)"
-            out, sweep.lane_writes, sweep.dense_accesses = sim._dispatch_span(
+            (out, sweep.lane_writes, sweep.dense_accesses,
+             sweep.table_pass_events) = sim._dispatch_span(
                 lambda: _dispatch_counting_lane_sites(
                     table_fn.engine.replay, fn, state, specs_d, types,
                     ev_kind_d, ev_pod_d, sim.typical, keys, weights_d,

@@ -48,6 +48,18 @@ mask on the node axis and no `scatter` or `gather` at all:
   `bool` leaf), the block `write_column` returns the masked reduction over
   the block axis of the table viewed as whole blocks.
 
+A dense write is a pass over the whole leaf whatever it writes, so at sweep
+width (`table_engine.flat_group_events`) the flat step body does not write
+its tables every event: it holds the dirty columns of a group of events
+(`table_engine.LateColumns`) and puts them down together. `write_columns(tbl, cols, idxs)` is that access: unbatched, the
+group's `dynamic_update_slice`s in slot order (a slot at -1 left out);
+batched on a long node axis that expression vmapped; dense, ONE pass with
+the group's select chain, `where(node_iota == idxs[s], cols[s], leaf)` for
+s = 0..B-1, the node compare made once per (lane, node). Until the group is
+written, `patch_row` and `patch_entry` give a row or an entry of the table
+as it will read afterwards (the same chain over `[lanes, N]`, which fuses
+into the pass that reads the row); they are elementwise and need no rule.
+
 Which form an access takes follows from the leaf's static shape alone
 (`_short`): no option selects it.
 
@@ -63,8 +75,9 @@ every call site (the callers clip them), which is where `.at[]`,
 `dynamic_slice` and a gather agree.
 
 `counting()` observes, at trace time, which write sites were lowered through
-the rule and which sites, reads too, took the dense form:
-`SweepRecord.lane_writes` and `.dense_accesses`.
+the rule, which sites, reads too, took the dense form, and how many events'
+columns a dense table write puts down in its pass:
+`SweepRecord.lane_writes`, `.dense_accesses` and `.table_pass_events`.
 """
 
 from __future__ import annotations
@@ -81,11 +94,15 @@ TILE_NODES = 128  # nodes in one tile of a nodes-minor leaf
 
 class Sites(set):
     """What counting() yields: the write sites lowered through the rule;
-    `.dense` holds the sites, reads among them, that took the dense form."""
+    `.dense` holds the sites, reads among them, that took the dense form;
+    `.table_pass_events` is the number of events whose columns the deepest
+    dense TABLE write of the program puts down in its one pass over the
+    leaf (write_column: 1, write_columns: its slots; 0 with no such site)."""
 
     def __init__(self):
         super().__init__()
         self.dense: set = set()
+        self.table_pass_events = 0
 
 
 _counting: list = []  # open counting() Sites; the rule adds its site to each
@@ -115,14 +132,17 @@ def _short(nodes: int) -> bool:
     return nodes < BLOCKED_MIN_NODES
 
 
-def _lane_batched(expr, lanes, write: bool, per_lane=(), dense=None):
+def _lane_batched(expr, lanes, write: bool, per_lane=(), dense=None,
+                  table_pass: int = 0):
     """custom_vmap of `expr` for one call site. Under vmap it runs `lanes`
     (an expression equal to `expr` lane by lane) vmapped over whatever
     operands are batched; the operands at `per_lane` are stacked first, so
     an index the lanes share still reads as one index a lane. A site whose
     leaf has a short node axis gives `dense`: `dense(in_batched)` is the
     site's dense form for those operands, run in place of `lanes` with
-    nothing stacked (it is elementwise in the lanes), or None to decline."""
+    nothing stacked (it is elementwise in the lanes), or None to decline.
+    `table_pass` is, for a table's column write, the events one dense pass
+    of the site writes (Sites.table_pass_events)."""
     fn = custom_vmap(expr)
     site = object()
 
@@ -134,6 +154,8 @@ def _lane_batched(expr, lanes, write: bool, per_lane=(), dense=None):
                 sites.add(site)
             if per is not None:
                 sites.dense.add(site)
+                sites.table_pass_events = max(
+                    sites.table_pass_events, table_pass)
         if per is not None:
             stacked = list(in_batched)
         else:
@@ -218,6 +240,7 @@ def write_column(tbl, col, idx, block=None):
         return _lane_batched(
             written, written, write=True,
             dense=_dense_write(dense_written) if _short(n) else None,
+            table_pass=1,
         )(tbl, col, idx)
     start, width = block
 
@@ -241,7 +264,58 @@ def write_column(tbl, col, idx, block=None):
     return _lane_batched(
         expr, lanes, write=True,
         dense=_dense_write(dense) if _short(n) and n % width == 0 else None,
+        table_pass=1,
     )(tbl, col, idx, start)
+
+
+def write_columns(tbl, cols, idxs):
+    """tbl[..., idxs[s]] = cols[s] for s = 0..B-1 in slot order, a later
+    slot winning where two name one node and a slot with index -1 left
+    out: the columns of B events in ONE access of the table (the flat step
+    body's flush). cols is [B, *tbl.shape[:-1]], idxs i32[B]."""
+    lead = (0,) * (tbl.ndim - 1)
+    n = tbl.shape[-1]
+    slots = cols.shape[0]
+
+    def written(tbl, cols, idxs):
+        for s in range(slots):
+            at = lead + (jnp.maximum(idxs[s], 0),)
+            old = lax.dynamic_slice(tbl, at, tbl.shape[:-1] + (1,))
+            tbl = lax.dynamic_update_slice(
+                tbl, jnp.where(idxs[s] >= 0, cols[s][..., None], old), at)
+        return tbl
+
+    def dense_written(tbl, cols, idxs):
+        return patch_row(tbl, idxs, cols)
+
+    return _lane_batched(
+        written, written, write=True,
+        dense=_dense_write(dense_written) if _short(n) else None,
+        table_pass=slots,
+    )(tbl, cols, idxs)
+
+
+def patch_row(leaf, idxs, vals):
+    """leaf [..., N] with leaf[..., idxs[s]] = vals[s] applied in slot
+    order (a later slot wins, -1 matches no node; vals is
+    [B, *leaf.shape[:-1]]): a row of a table as it will read once
+    write_columns has put the pending columns down, and, over a whole
+    table, write_columns' dense form itself. One select chain over the
+    leaf, the node compare made per node and broadcast over the rows;
+    elementwise, so it has no batching rule of its own and fuses into the
+    pass that reads the row."""
+    node = lax.iota(jnp.int32, leaf.shape[-1])
+    for s in range(idxs.shape[0]):
+        leaf = jnp.where(node == idxs[s], vals[s][..., None], leaf)
+    return leaf
+
+
+def patch_entry(val, col, idxs, vals):
+    """patch_row for the one entry read_entry returns: `val` read at node
+    `col`, with the pending slots that name that node applied in order."""
+    for s in range(idxs.shape[0]):
+        val = jnp.where(idxs[s] == col, vals[s], val)
+    return val
 
 
 def read_entry(tbl, row, col):

@@ -31,6 +31,7 @@ engine still rejects per-event randomness (reject_randomized).
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Sequence, Tuple
 
 import jax
@@ -71,6 +72,30 @@ _INT_MAX = np.int32(np.iinfo(np.int32).max)
 # per-event fixed costs (dirty-block refresh + two-level combine) outweigh
 # the reduction savings, and openb-scale traces (N=1523) must not regress.
 BLOCKED_MIN_NODES = 8192
+
+# At sweep width the flat step writes its dirty columns a group of this many
+# events at a time (LateColumns): one access of each table every
+# FLAT_GROUP_EVENTS events, the rows read in between patched from the
+# pending block. Vmapped over many lanes on a short node axis that access is
+# a pass over the whole lane-batched table, so its cost falls with the
+# group; the patch costs one compare and select a slot on every row read,
+# so it rises with it (PERF.md section 6, PR 29: 16 beat 8 and 32 on the
+# chip). A replay that is not vmapped, or over few lanes, is a chain of
+# small operations and the group only adds to it (the standalone openb
+# replay: 49 us an event as it is, 110 grouped by 16), so it keeps writing
+# every event: flat_group_events() decides from the sweep's own shapes.
+FLAT_GROUP_EVENTS = 16
+FLAT_GROUP_MIN_LANES = 64
+
+
+def flat_group_events(lanes: int, nodes: int) -> int:
+    """Events the flat step of a `lanes`-wide vmapped replay on `nodes`
+    nodes writes its dirty columns at a time; 1: every event, the plain
+    body. Static: the sweep's wrapper reads both off its operands' shapes
+    (driver._sweep_engine), no option selects it."""
+    if nodes < BLOCKED_MIN_NODES and lanes >= FLAT_GROUP_MIN_LANES:
+        return FLAT_GROUP_EVENTS
+    return 1
 
 
 def resolve_block_size(block_size: int, num_nodes: int, num_types: int) -> int:
@@ -235,6 +260,20 @@ class FlatTableCarry(NamedTuple):
     arr_gpu: jnp.ndarray  # i32 arrived milli-GPU so far
     key: jnp.ndarray  # PRNG key after the events consumed so far
     ctr: jnp.ndarray  # i32[obs.NUM_COUNTERS] exact in-scan counters
+
+
+class LateColumns(NamedTuple):
+    """The flat step's pending block: the dirty columns of one group of G
+    events, computed but not yet written into the tables. It lives inside
+    _run_chunk_impl only: every group ends in a flush
+    (lane_write.write_columns), so no FlatTableCarry a caller sees has
+    columns pending. (`late`, not `pend`: that is the carry's
+    PendingCommit.)"""
+
+    idx: jnp.ndarray  # i32[G] each slot's dirty node; -1 until its event
+    score: jnp.ndarray  # i32[G, num_pol, K]
+    sdev: jnp.ndarray  # i32[G, K]
+    feas: jnp.ndarray  # bool[G, K]
 
 
 class BlockedTableCarry(NamedTuple):
@@ -639,7 +678,21 @@ def _make_table_engine(
         NORMALIZE_DEGENERATE[policies[i][0].normalize] for i in norm_idx
     ]
 
-    def _sample_from_tables(state, score_tbl, feas_tbl, t_id, tp, ctr):
+    def _late_row(row, late, cols_of, t_id):
+        """`row` (row t_id of a table, [N]) as it reads once the pending
+        block's columns `cols_of(late)` ([G, K]) are written; the row
+        itself with nothing pending (`late` None: the plain body). Every
+        read of a table row in the flat body goes through here, the one
+        entry read through lane_write.patch_entry."""
+        if late is None:
+            return row
+        return lane_write.patch_row(
+            row, late.idx,
+            jax.lax.dynamic_index_in_dim(cols_of(late), t_id, 1, False),
+        )
+
+    def _sample_from_tables(state, score_tbl, feas_tbl, t_id, tp, ctr,
+                            late=None):
         """One in-scan SeriesSample off the just-refreshed tables — the
         flat and blocked bodies share it. The dirty refresh has already
         made score_tbl/feas_tbl equal to a full rebuild on the committed
@@ -647,12 +700,19 @@ def _make_table_engine(
         sequential engine recomputing it; blocked pad columns are
         infeasible, so the normalized extrema cannot see them. The
         RandomScore slot is a zero table row and score_stats zeroes it
-        anyway — the sample never consumes PRNG."""
+        anyway — the sample never consumes PRNG. The flat body passes its
+        pending block (`late`): the rows are read with it applied."""
         processed = ctr[0] + ctr[3] + ctr[4]
 
         def build():
             raws = jax.lax.dynamic_index_in_dim(score_tbl, t_id, 1, False)
             feas = jax.lax.dynamic_index_in_dim(feas_tbl, t_id, 0, False)
+            if late is not None:
+                raws = jnp.stack([
+                    _late_row(raws[i], late, lambda l: l.score[:, i], t_id)
+                    for i in range(num_pol)
+                ])
+                feas = _late_row(feas, late, lambda l: l.feas, t_id)
             return obs_series.build_sample(
                 state, tp, raws, feas, policies, processed
             )
@@ -992,8 +1052,20 @@ def _make_table_engine(
         return body
 
     def make_flat_body(pods, type_id, types, tp, tiebreak_rank, n, num_pods,
-                       wts, fault_ops=None):
+                       wts, fault_ops=None, grouped: bool = False):
         """Scan body of the flat O(N) select path.
+
+        `grouped` (a wide sweep: flat_group_events) makes it one event of
+        a group (_run_flat_group). Its carry is then (table carry,
+        LateColumns) and its xs lead with the event's slot in the group:
+        the step stores its dirty column in that slot and leaves the tables
+        as they are, every read of a table row applies the pending block
+        (_late_row, lane_write.patch_entry), and the group's flush writes
+        the block down. Same integers in the same order as a write every
+        event: event e reads rows that hold every column computed up to e,
+        its own included (the slot is stored before the select reads), and
+        of two slots naming one node the later wins, in the patch and in
+        the flush.
 
         Round 18 ports the shard engine's Round-15 unconditional-select
         restructure back here as an A/B layout knob (`unswitched`, the
@@ -1015,6 +1087,10 @@ def _make_table_engine(
         motivation) and for A/B measurement."""
 
         def body(carry, ev):
+            late = None
+            if grouped:
+                carry, late = carry
+                slot, *ev = ev
             if faults:
                 carry, fc = carry
                 kind, idx, fpos, farg, faux = ev
@@ -1052,22 +1128,35 @@ def _make_table_engine(
                 )
 
             # refresh the one column whose node changed last event (from
-            # the just-committed state)
+            # the just-committed state). Grouped, the tables are not
+            # written here: the column goes into this event's slot of the
+            # pending block (an index the lanes of a sweep share), every
+            # read below applies the block, and the group's flush writes it
             with jax.named_scope("tpusim.refresh"):
                 col_scores, col_sdev, col_feas = _columns(
                     _row_state(state, dirty), types, tp, k_rand
                 )
-                score_tbl = lane_write.write_column(
-                    score_tbl, col_scores, dirty
-                )
-                sdev_tbl = lane_write.write_column(sdev_tbl, col_sdev, dirty)
-                feas_tbl = lane_write.write_column(feas_tbl, col_feas, dirty)
+                if grouped:
+                    late = LateColumns(*(
+                        jax.lax.dynamic_update_index_in_dim(
+                            arr, val, slot, 0)
+                        for arr, val in zip(
+                            late, (dirty, col_scores, col_sdev, col_feas))
+                    ))
+                else:
+                    score_tbl = lane_write.write_column(
+                        score_tbl, col_scores, dirty
+                    )
+                    sdev_tbl = lane_write.write_column(
+                        sdev_tbl, col_sdev, dirty)
+                    feas_tbl = lane_write.write_column(
+                        feas_tbl, col_feas, dirty)
 
             # in-scan series sample (ISSUE 5): committed state + current
             # tables, on the processed-event stride
             ser = (
                 _sample_from_tables(state, score_tbl, feas_tbl, t_id, tp,
-                                    ctr)
+                                    ctr, late=late)
                 if series_every else ()
             )
 
@@ -1075,7 +1164,9 @@ def _make_table_engine(
             def create_result():
                 """The full create computation — ONE definition serving
                 both select layouts below (Round 18)."""
-                feasible = feas_tbl[t_id] & (
+                feasible = _late_row(
+                    feas_tbl[t_id], late, lambda l: l.feas, t_id
+                ) & (
                     (pod.pinned < 0)
                     | (jnp.arange(n, dtype=jnp.int32) == pod.pinned)
                 )
@@ -1092,7 +1183,10 @@ def _make_table_engine(
                         )
                         raw = fn(state, pod, ctx).raw_scores
                     else:
-                        raw = score_tbl[i, t_id]
+                        raw = _late_row(
+                            score_tbl[i, t_id], late,
+                            lambda l: l.score[:, i], t_id,
+                        )
                     if fn.normalize == "minmax":
                         nrm = minmax_normalize_i32(raw, feasible)
                     elif fn.normalize == "pwr":
@@ -1106,10 +1200,17 @@ def _make_table_engine(
                 # the oracle's selectHost + Reserve halves; the Bind
                 # scatter is deferred via PendingCommit
                 sel, _, ok = packed_argmax(total, feasible, tiebreak_rank)
+                left = lane_write.read_row(
+                    state.gpu_left, sel, keepdims=False)
+                dev_scalar = lane_write.read_entry(sdev_tbl, t_id, sel)
+                if grouped:
+                    dev_scalar = lane_write.patch_entry(
+                        dev_scalar, sel, late.idx,
+                        jax.lax.dynamic_index_in_dim(
+                            late.sdev, t_id, 1, False),
+                    )
                 dmask = choose_devices(
-                    lane_write.read_row(state.gpu_left, sel, keepdims=False),
-                    pod, lane_write.read_entry(sdev_tbl, t_id, sel),
-                    gpu_sel, k_sel,
+                    left, pod, dev_scalar, gpu_sel, k_sel,
                 ) & ok
                 node_f = jnp.where(ok, sel, -1).astype(jnp.int32)
                 if not decisions:
@@ -1214,10 +1315,43 @@ def _make_table_engine(
                 + ((ser,) if series_every else ())
             )
             if faults:
-                return (new_carry, fc), ys + (fy,)
-            return new_carry, ys
+                new_carry, ys = (new_carry, fc), ys + (fy,)
+            return ((new_carry, late) if grouped else new_carry), ys
 
         return body
+
+    def _run_flat_group(body, carry, xs, slots: int):
+        """One group of the flat replay: `slots` events (xs leaves
+        [slots, ...]) through `body` with their dirty columns held in a
+        fresh pending block, then the flush: each table takes the group's
+        columns in one access. The carry that comes back has nothing
+        pending."""
+        base = carry[0] if faults else carry
+        n_pol, k_types = base.score_tbl.shape[:2]
+        held = max(slots, 1)  # an empty segment still traces the body
+        late = LateColumns(
+            jnp.full(held, -1, jnp.int32),
+            jnp.zeros((held, n_pol, k_types), jnp.int32),
+            jnp.zeros((held, k_types), jnp.int32),
+            jnp.zeros((held, k_types), jnp.bool_),
+        )
+        # unroll amortizes per-iteration fixed costs (~20% wall on the openb
+        # replay); higher factors showed no further gain
+        (carry, late), ys = jax.lax.scan(
+            body, (carry, late),
+            (jnp.arange(slots, dtype=jnp.int32),) + tuple(xs), unroll=4,
+        )
+        base = carry[0] if faults else carry
+        with jax.named_scope("tpusim.refresh"):
+            base = base._replace(
+                score_tbl=lane_write.write_columns(
+                    base.score_tbl, late.score, late.idx),
+                sdev_tbl=lane_write.write_columns(
+                    base.sdev_tbl, late.sdev, late.idx),
+                feas_tbl=lane_write.write_columns(
+                    base.feas_tbl, late.feas, late.idx),
+            )
+        return ((base, carry[1]) if faults else base), ys
 
     # FaultCarry pod-axis pad/trim to the carry's P+1 bookkeeping rows —
     # shared with the shard engine (fault_lane.pad/trim_fault_carry)
@@ -1315,7 +1449,7 @@ def _make_table_engine(
         return (blocked, _pad_fc(fault_carry0)) if faults else blocked
 
     def _run_chunk_impl(carry, pods, types, ev_kind, ev_pod, tp, wts,
-                        tiebreak_rank=None, fault_ops=None):
+                        tiebreak_rank=None, fault_ops=None, group: int = 1):
         """Advance `carry` over a segment of the event stream; returns
         (carry', (event_node, event_dev)) for the segment — extended with
         a per-event DecisionRecord element when the engine was built with
@@ -1326,14 +1460,22 @@ def _make_table_engine(
         function of (carry, event), and every carry leaf is an exact dtype
         (i32/bool/u32), so even a host/disk round-trip between chunks
         cannot perturb the trajectory. `wts` must be the weight vector
-        the carry was initialized under (the blocked summaries embed it)."""
+        the carry was initialized under (the blocked summaries embed it).
+        `group` (static; flat_group_events) makes the flat step write its
+        columns that many events at a time; the carry that comes back is
+        the same either way."""
         base = carry[0] if faults else carry
         n = base.state.num_nodes
         num_pods = pods.cpu.shape[0]
         if tiebreak_rank is None:
             tiebreak_rank = jnp.arange(n, dtype=jnp.int32)
         type_id = types.type_id
+        xs = (
+            (ev_kind, ev_pod, fault_ops.pos, fault_ops.arg, fault_ops.aux)
+            if faults else (ev_kind, ev_pod)
+        )
         if isinstance(base, BlockedTableCarry):
+            group = 1  # its column writes hand back the block it reduces
             k_types, nblk = base.bt.shape
             bsz = base.score_tbl.shape[2] // nblk
             rank_p = _pad_rank(tiebreak_rank, nblk * bsz)
@@ -1345,17 +1487,37 @@ def _make_table_engine(
         else:
             body = make_flat_body(
                 pods, type_id, types, tp, tiebreak_rank, n, num_pods, wts,
-                fault_ops,
+                fault_ops, grouped=group > 1,
             )
-        xs = (
-            (ev_kind, ev_pod, fault_ops.pos, fault_ops.arg, fault_ops.aux)
-            if faults else (ev_kind, ev_pod)
-        )
-        # unroll amortizes per-iteration fixed costs (~20% wall on the openb
-        # replay); higher factors showed no further gain
-        return jax.lax.scan(body, carry, xs, unroll=4)
+        if group <= 1:
+            # unroll amortizes per-iteration fixed costs (~20% wall on the
+            # openb replay); higher factors showed no further gain
+            return jax.lax.scan(body, carry, xs, unroll=4)
+        # the grouped flat replay: E = q * G + r events are q groups of G
+        # and one of r, each ending in its flush. No skip events are padded
+        # in: every event splits the key, and the chain must stay the
+        # oracle's
+        q, r = divmod(ev_kind.shape[0], group)
+        parts = []
+        if q:
+            carry, ys = jax.lax.scan(
+                lambda c, x: _run_flat_group(body, c, x, group), carry,
+                jax.tree.map(
+                    lambda a: a[:q * group].reshape(
+                        (q, group) + a.shape[1:]),
+                    xs),
+            )
+            parts.append(jax.tree.map(
+                lambda a: a.reshape((q * group,) + a.shape[2:]), ys))
+        if r or not q:
+            carry, ys = _run_flat_group(
+                body, carry, jax.tree.map(lambda a: a[q * group:], xs), r)
+            parts.append(ys)
+        if len(parts) == 1:
+            return carry, parts[0]
+        return carry, jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
 
-    run_chunk = jax.jit(_run_chunk_impl)
+    run_chunk = jax.jit(_run_chunk_impl, static_argnames="group")
     # the donating twin (ISSUE 11): identical jaxpr, but the input carry's
     # buffers are donated to the outputs, so a long chunked replay stops
     # reallocating its O(N*K) score tables every segment. The caller must
@@ -1363,7 +1525,8 @@ def _make_table_engine(
     # its host checkpoint copy before the next chunk dispatch); callers
     # that reuse a carry (tests probing arbitrary cut points) stay on the
     # non-donating entry.
-    run_chunk_donate = jax.jit(_run_chunk_impl, donate_argnums=0)
+    run_chunk_donate = jax.jit(
+        _run_chunk_impl, donate_argnums=0, static_argnames="group")
 
     @jax.jit
     def finish(carry):
@@ -1378,7 +1541,7 @@ def _make_table_engine(
         )
         return state, placed[:-1], masks[:-1], failed[:-1]
 
-    @jax.jit
+    @functools.partial(jax.jit, static_argnames="group")
     def _replay_impl(
         state: NodeState,
         pods: PodSpec,  # [P]
@@ -1392,6 +1555,7 @@ def _make_table_engine(
         tables=None,
         fault_ops=None,
         fault_carry0=None,
+        group: int = 1,  # static: flat_group_events
     ) -> ReplayResult:
         carry = init_carry(
             state, pods, types, tp, key, wts, tiebreak_rank, tables,
@@ -1399,7 +1563,7 @@ def _make_table_engine(
         )
         carry, ys = run_chunk(
             carry, pods, types, ev_kind, ev_pod, tp, wts, tiebreak_rank,
-            fault_ops,
+            fault_ops, group=group,
         )
         state, placed, masks, failed = finish(carry)
         nodes, devs = ys[0], ys[1]
