@@ -19,36 +19,33 @@ class _Captured(Exception):
     pass
 
 
-def capture_sweep(sim, trace, weights, seeds, run: bool = False):
-    """(fn, shapes, lanes): the wrapper schedule_pods_sweep dispatches, the
-    ShapeDtypeStructs of its operands, and the sweep's lanes, or None
-    when the sweep is stopped before it runs (`run=False`)."""
+def capture_sweep(sim, trace, weights, seeds, run: bool = False, **kw):
+    """(fn, shapes, lanes): the wrapper schedule_pods_sweep dispatches
+    (driver._sweep_engine's, read off the operands), the ShapeDtypeStructs
+    of its operands, and the sweep's lanes, or None when the sweep is
+    stopped before it runs (`run=False`). `kw` goes to the sweep
+    (lane_pods, fault_specs)."""
     from tpusim.sim import driver
 
     called = {}
-    real = driver._sweep_engine
+    real = driver._dispatch_counting_lane_sites
 
-    def spy(engine, table):
-        fn = real(engine, table)
+    def spy(fn, lanes, *args):
+        called["fn"] = fn
+        called["shapes"] = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+        if not run:
+            raise _Captured()
+        return real(fn, lanes, *args)
 
-        def call(*args):
-            called["fn"] = fn
-            called["shapes"] = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
-            if not run:
-                raise _Captured()
-            return fn(*args)
-
-        return call
-
-    driver._sweep_engine = spy
+    driver._dispatch_counting_lane_sites = spy
     lanes = None
     try:
-        lanes = driver.schedule_pods_sweep(sim, trace, weights, seeds)
+        lanes = driver.schedule_pods_sweep(sim, trace, weights, seeds, **kw)
     except _Captured:
         pass
     finally:
-        driver._sweep_engine = real
+        driver._dispatch_counting_lane_sites = real
     return called["fn"], called["shapes"], lanes
 
 

@@ -357,8 +357,6 @@ def test_post_path_lane_vs_standalone(trace, tmp_path):
     seed, AND tune-factor variants batched onto one sweep — duplicates
     come from the digest cache, and a second batch differing only in
     weights+tune adds no compiled executable."""
-    from tpusim.sim.driver import _sweep_engine_multi
-
     queue, worker, service = _service(trace, tmp_path)
     # two tune-1.3 jobs deliberately share their tuned trace shape (and
     # the tune-0 job the base shape): the tier-1 slice pays one
@@ -400,17 +398,15 @@ def test_post_path_lane_vs_standalone(trace, tmp_path):
     # must not grow the jitted sweep wrapper's executable cache (counts
     # are read RELATIVE to the first batch — the wrapper is process-
     # global, so sibling tests may have compiled other shapes into it)
-    # the service lane runs report_per_event=False, so the dispatch
-    # resolves the STREAM-DONATING twin (ISSUE 15) — ask for that one
-    fn = _sweep_engine_multi(
-        worker._sims[list(worker._sims)[0]]._table_fn.engine.replay,
-        table=True, donate_streams=True,
-    )
+    # the wrapper the first batch dispatched (the service lane runs
+    # report_per_event=False: the STREAM-DONATING one, ISSUE 15)
+    sim = worker._sims[list(worker._sims)[0]]
+    fn = sim._last_sweep_fn
     before = fn._cache_size()
     _post(service, {"policies": FAM, "weights": [555, 111], "tune": 1.1,
                     "seed": 7})
     assert _drain(queue, worker) == 1
-    assert fn._cache_size() == before
+    assert sim._last_sweep_fn is fn and fn._cache_size() == before
     assert worker.sweep_executables() == fn._cache_size()
 
     # GET surfaces: status doc, /queue stats, unknown id
@@ -610,7 +606,7 @@ def test_openb_service_acceptance(tmp_path):
         # against a warm single-lane replay at the same padded shapes
         # (the worker's sticky floors; this B=1 call compiles its own
         # vmap shape, which is why it comes after the stability checks)
-        from tpusim.sim.driver import schedule_pods_sweep_multi
+        from tpusim.sim.driver import schedule_pods_sweep
         from tpusim.svc.client import submit_jobs, wait_jobs
 
         sim = worker._sims[list(worker._sims)[0]]
@@ -619,9 +615,10 @@ def test_openb_service_acceptance(tmp_path):
 
         def standalone_warm():
             t0 = time.perf_counter()
-            schedule_pods_sweep_multi(
-                sim, [trace_pods], np.asarray([[1000, 500]], np.int32),
-                seeds=[42], min_pods=hw_p, min_events=hw_e,
+            schedule_pods_sweep(
+                sim, None, np.asarray([[1000, 500]], np.int32),
+                seeds=[42], lane_pods=[trace_pods], min_pods=hw_p,
+                min_events=hw_e,
             )
             return time.perf_counter() - t0
 

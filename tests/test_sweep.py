@@ -464,8 +464,6 @@ def test_openb_sweep_acceptance():
         load_pod_csv,
         pods_to_specs,
     )
-    from tpusim.sim.driver import _sweep_engine
-
     nodes = load_node_csv(
         os.path.join(REPO, "data/csv/openb_node_list_gpu_node.csv")
     )
@@ -491,14 +489,14 @@ def test_openb_sweep_acceptance():
     assert len(scans) == 1, [s.name for s in sim.obs.spans]
 
     # a different weight grid must NOT add a compiled executable
-    fn = _sweep_engine(sim._table_fn.engine.replay, table=True)
+    fn = sim._last_sweep_fn
     before = fn._cache_size()
     grid2 = np.stack(
         [np.asarray([500 + 11 * i, 900 - 23 * i], np.int32)
          for i in range(b)]
     )
     sim.run_sweep(grid2)
-    assert fn._cache_size() == before
+    assert sim._last_sweep_fn is fn and fn._cache_size() == before
 
     # sampled lanes are bit-identical to standalone baked-weight runs
     for i in (0, 7, 15):
@@ -561,10 +559,11 @@ def test_sweep_multi_stream_donation(monkeypatch):
     carries the ev_pod argnum; (2) two waves of different tuned traces
     produce bit-identical lanes to fresh standalone runs AND add zero
     executables (the zero-recompile bookkeeping is donation-invariant —
-    the (engine, donate, donate_streams) cache key keeps one wrapper per
-    family); (3) a report-ON config keeps the non-donating wrapper (the
-    metrics postpass re-reads the streams)."""
-    from tpusim.sim.driver import _sweep_engine_multi
+    the (engine, in_axes, donated operands) cache key keeps one wrapper
+    per family); (3) a report-ON config keeps the non-donating wrapper
+    (the metrics postpass re-reads the streams)."""
+    from tests.sweep_program import capture_sweep
+    from tpusim.sim.driver import _sweep_engine
 
     rng = np.random.default_rng(29)
     nodes, pods = _mk_cluster(rng), _mk_pods(rng, 24)
@@ -574,13 +573,17 @@ def test_sweep_multi_stream_donation(monkeypatch):
     sim.set_workload_pods(pods)
     grid = np.asarray([[1000], [1000]], np.int32)
 
-    fn_don = _sweep_engine_multi(
-        sim._table_fn.engine.replay, table=True, donate_streams=True
-    )
-    fn_plain = _sweep_engine_multi(
-        sim._table_fn.engine.replay, table=True, donate_streams=False
-    )
+    # the one factory reads the wrapper off the operands: a per-lane
+    # sweep's, stopped before it runs
+    sim.set_typical_pods()
+    lane_pods = [sim.prepare_pods(tuning_ratio=t) for t in (0.0, 0.3)]
+    resolved, shapes, _ = capture_sweep(
+        sim, None, grid, None, lane_pods=lane_pods)
+    engine = sim._table_fn.engine.replay
+    fn_don = _sweep_engine(engine, shapes)
+    fn_plain = _sweep_engine(engine, shapes, keep_streams=True)
     assert fn_don is not fn_plain  # distinct wrappers, one cache each
+    assert resolved is fn_don  # report off: the sweep asks for the donor
     # counts are read RELATIVE to this point — the wrappers are
     # process-global, so sibling tests may have compiled other shapes
     # into either one (the test_svc.py discipline)

@@ -142,3 +142,44 @@ def test_the_openb_flat_sweep_loops_over_events_only(one_chip):
     # entry) once, not twice
     tables = OPENB_LANES * k * 1213 * 9
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * tables
+
+
+def _fault_specs(lanes):
+    from tpusim.sim.faults import FaultConfig
+
+    return [FaultConfig(
+        mtbf_events=20 + i % 5, mttr_events=8, evict_every_events=7,
+        seed=5 + i, backoff_base=2, backoff_cap=8, max_retries=2,
+        queue_capacity=8) for i in range(lanes)]
+
+
+@pytest.mark.parametrize("operands", ["a trace a lane", "a fault plan a lane"])
+def test_the_plain_flat_sweeps_loop_over_events_only(one_chip, operands):
+    """The sweeps that keep the plain flat body (driver._sweep_engine: a
+    trace a lane, or fault plans) hold ONE loop, the scan over the events,
+    and none over the lanes, although pods, type ids and event streams
+    carry the lane axis: `feas_tbl[t_id]` and `score_tbl[i, t_id]` become
+    a gather a lane, and the bookkeeping rows' writes, whose index the
+    lanes no longer share, take the dense form (28 dense sites where the
+    shared trace has 22). One dense column write an event, no group."""
+    sim, trace, cfg = sweep_program.cell_simulator(
+        None, OPENB_DEPTH, config="openb")
+    own = operands == "a trace a lane"
+    kw = ({"lane_pods": [trace] * OPENB_LANES} if own
+          else {"fault_specs": _fault_specs(OPENB_LANES)})
+    with lane_write.counting() as sites:
+        fn, shapes, _ = sweep_program.capture_sweep(
+            sim, None if own else trace,
+            sweep_program.cell_weights(cfg, OPENB_LANES),
+            list(range(OPENB_LANES)), **kw)
+        shapes = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), shapes)
+        lowered = fn.lower(*shapes)
+    assert len(sites) == 17 and len(sites.dense) == 28
+    assert sites.table_pass_events == 1
+    assert shapes[1].cpu.shape == (
+        (OPENB_LANES, OPENB_DEPTH) if own else (OPENB_DEPTH,))
+    assert shapes[3].shape[0] == OPENB_LANES  # a stream a lane, both
+    (loop,) = sweep_program.while_loops(lowered.compile().as_text())
+    assert f"s32[{OPENB_LANES},1213,9]" in loop[2]  # the scan's carry

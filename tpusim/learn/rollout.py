@@ -125,8 +125,6 @@ class LocalRollout:
         self._fns: set = set()  # jitted sweep wrappers dispatched
 
     def rollout(self, rows: Sequence[tuple], seed: int) -> List[dict]:
-        from tpusim.sim.driver import _sweep_engine, _sweep_fault_engine
-
         if not rows:
             return []
         if len(rows) > self.width:
@@ -146,16 +144,7 @@ class LocalRollout:
         )[: len(rows)]
         # track the dispatched wrapper so executables() can assert the
         # zero-recompile contract (the svc worker's /queue metric)
-        used_table = self.sim._last_engine.startswith("table")
-        if self.fault:
-            # the chaos-sweep dispatch stashes its jitted wrapper
-            self._fns.add(self.sim._last_sweep_fn)
-        else:
-            self._fns.add(_sweep_engine(
-                self.sim._table_fn.engine.replay if used_table
-                else self.sim.replay_fn.engine,
-                table=used_table,
-            ))
+        self._fns.add(self.sim._last_sweep_fn)
         return [lane_terms(lane) for lane in lanes]
 
     def executables(self) -> int:

@@ -150,7 +150,6 @@ def sweep_advisory(nodes, pods, base: Optional[dict],
     from tpusim.sim.driver import (
         Simulator,
         SimulatorConfig,
-        _sweep_engine,
         schedule_pods_sweep,
     )
 
@@ -180,14 +179,9 @@ def sweep_advisory(nodes, pods, base: Optional[dict],
         _, warm1 = run(grid1)
         # one jaxpr per job family: a different weight grid must reuse
         # the compiled sweep executable, not add one — inspect the
-        # engine the sweep ACTUALLY dispatched (the small smoke workload
+        # wrapper the sweep ACTUALLY dispatched (the small smoke workload
         # may select the sequential path)
-        used_table = sim._last_engine.startswith("table")
-        fn = _sweep_engine(
-            sim._table_fn.engine.replay if used_table
-            else sim.replay_fn.engine,
-            table=used_table,
-        )
+        fn = sim._last_sweep_fn
         before = fn._cache_size()
         if before < 1:
             return False, [
@@ -198,7 +192,7 @@ def sweep_advisory(nodes, pods, base: Optional[dict],
         run(np.stack(
             [np.asarray([500 + i], np.int32) for i in range(b)]
         ))
-        if fn._cache_size() != before:
+        if sim._last_sweep_fn is not fn or fn._cache_size() != before:
             return False, [
                 "[gate] sweep: weight change RECOMPILED the sweep "
                 f"engine ({before} -> {fn._cache_size()} executables) "

@@ -15,7 +15,7 @@ import os
 import sys
 import time
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -440,6 +440,9 @@ class Simulator:
         # which engine the last run_events call dispatched to
         # (pallas | table | sequential) — bench/log labeling
         self._last_engine = None
+        # the jitted vmapped wrapper the last sweep dispatched: its
+        # _cache_size() is the executables census (svc worker, tuner, gate)
+        self._last_sweep_fn = None
         # run-level event offset the next heartbeat arm reports from
         # (the fault loop sets it per segment; plain runs leave it 0)
         self._hb_base = 0
@@ -1800,56 +1803,27 @@ class Simulator:
         list of per-lane tuning ratios. When given, each lane's workload
         is prepared exactly like a standalone run with that
         tuning_ratio (same tuning_seed → same shuffle + clone draws) and
-        the batch dispatches through schedule_pods_sweep_multi — the
-        tuned traces ride the sweep as DATA (specs/events/type_id
+        the tuned traces ride the sweep as DATA (specs/events/type_id
         operands, padded to common buckets), so jobs differing only in
         tune factor pack onto the same compiled scan instead of forcing
-        a new jaxpr."""
+        a new jaxpr.
+
+        `faults` (ISSUE 10, the chaos sweep; with `tunes`, ISSUE 12): B
+        fault schedules as per-lane operands, each compiled against its
+        lane's own trace."""
         self._reset_run_state()
         self.set_typical_pods()
         self.log.info(
             f"Number of original workload pods: {len(self.workload_pods)}"
         )
-        if faults is not None:
-            # the chaos sweep (ISSUE 10): one trace, B fault schedules as
-            # per-lane operands — ONE compiled vmapped scan
-            if tunes is not None:
-                # the chaos x tune lift (ISSUE 12): per-lane TUNED traces
-                # each with their OWN fault schedule (compiled against
-                # that lane's base stream) — mixed fault/tune/weight
-                # what-ifs still share one compiled scan
-                w = np.asarray(weights, np.int32)
-                if w.ndim != 2 or len(tunes) != int(w.shape[0]):
-                    raise ValueError(
-                        f"tunes has {len(tunes)} entries for weight grid "
-                        f"of shape {w.shape} (want one tuning ratio per "
-                        "weight row)"
-                    )
-                pods_list = [
-                    self.prepare_pods(tuning_ratio=t) for t in tunes
-                ]
-                return schedule_pods_sweep_multi(
-                    self, pods_list, w, seeds=seeds, bucket=bucket,
-                    fault_specs=faults,
-                )
-            pods = self.prepare_pods()
-            return schedule_pods_sweep_faults(
-                self, pods, weights, faults, seeds=seeds, bucket=bucket
-            )
         if tunes is None:
-            pods = self.prepare_pods()
             return schedule_pods_sweep(
-                self, pods, weights, seeds=seeds, bucket=bucket
+                self, self.prepare_pods(), weights, seeds, bucket,
+                fault_specs=faults,
             )
-        w = np.asarray(weights, np.int32)
-        if w.ndim != 2 or len(tunes) != int(w.shape[0]):
-            raise ValueError(
-                f"tunes has {len(tunes)} entries for weight grid of shape "
-                f"{w.shape} (want one tuning ratio per weight row)"
-            )
-        pods_list = [self.prepare_pods(tuning_ratio=t) for t in tunes]
-        return schedule_pods_sweep_multi(
-            self, pods_list, w, seeds=seeds, bucket=bucket
+        return schedule_pods_sweep(
+            self, None, weights, seeds, bucket, fault_specs=faults,
+            lane_pods=[self.prepare_pods(tuning_ratio=t) for t in tunes],
         )
 
     def run_with_faults(self, fault_cfg=None, faults=None) -> SimulateResult:
@@ -3349,9 +3323,16 @@ def finish_run_batch(handle: dict) -> List[SimulateResult]:
 # data. The weight-independent score tables are built once and shared
 # across every lane (in_axes None), so the marginal what-if costs only
 # its share of the vmapped scan, never a table build or a compile.
+#
+# Two more scalars ride the same sweep as operands (schedule_pods_sweep):
+# the TUNE FACTOR (ISSUE 7: a lane's own tuned trace, per-lane specs,
+# type_id and event streams) and a FAULT SCHEDULE (ISSUE 10, 12: with the
+# fault plane inside the scan, tpusim.sim.fault_lane, a schedule is five
+# i32 streams, a draw table and a param vector). There is one path for
+# all of it: one wrapper factory that reads the vmap axes off its
+# operands, one host prep, one dispatch, one tail, one sweep record.
 
 _SWEEP_WRAP_CACHE = {}
-_SWEEP_METRICS_FN = None
 
 
 @dataclass
@@ -3387,79 +3368,114 @@ class SweepLane:
     disruption: object = None
 
 
-def _sweep_engine(engine, table: bool, donate: bool = True):
-    """jit(vmap(engine)) over (key, weights, tiebreak_rank); everything
-    else — cluster state, pod specs, types, events, typical pods, and
-    the shared score tables — broadcasts (in_axes None). Cached per
-    underlying weight-operand engine, which is itself shared across
-    weight configs (one jaxpr per job family).
+def _lane_axis(operand, rank: int):
+    """The vmap axis of a sweep operand, read off the operand itself: 0
+    when it was stacked a lane (one axis more than the engine takes), None
+    when the lanes share it."""
+    return 0 if len(operand.shape) > rank else None
 
-    donate=True (the dispatched form, ISSUE 14 satellite — the PR 11
-    run_chunk_donated pattern applied to the batched surfaces): the
-    per-lane stacked tiebreak_rank operand — the [B, N] buffer, the one
-    whose shape/dtype matches output state leaves — is donated, so a
-    repeated-wave caller (the svc worker's batch loop, a tuning run's
-    generations) reuses it for a [B, N] output leaf instead of
-    reallocating per wave (keys/weights are byte-tiny and alias
-    nothing). Safe by construction at every dispatch site: the ranks
-    are built fresh inside the schedule_pods_sweep* call and never
-    read after dispatch. The
-    non-donating twin (donate=False) serves callers that drive the
-    wrapper directly with reusable buffers."""
-    ck = (engine, bool(donate))
+
+def _sweep_engine(engine, args, keep_streams: bool = False):
+    """The jitted vmapped replay of `engine` for the operands `args` (the
+    engine's own, in its order; arrays or ShapeDtypeStructs), one lane a
+    what-if: THE `jit(vmap(...))` of every sweep, cached by (engine,
+    in_axes, donated operands). The underlying weight-operand engine is
+    itself shared across weight configs (one jaxpr per job family), and
+    consecutive waves of one family resolve to one wrapper, so its
+    `_cache_size()` is the family's executable count.
+
+    in_axes are read off the operands. Key, weights and tie-break rank
+    always carry the lane axis; cluster state, the distinct type set,
+    typical pods and the shared score tables always broadcast. Pod specs
+    and `types.type_id` follow the traces: stacked when every lane replays
+    its own workload (tuned traces are data, not jaxpr structure: nothing
+    in an engine reads type_id except as a per-pod gather key). The event
+    streams are stacked with per-lane traces and with fault plans (a plan
+    merges its steps into its lane's stream). Fault plans add two
+    operands: the per-lane FaultOps (whose gpu-count row is the
+    cluster's) and the initial fault carry, which broadcasts.
+
+    Donation, by one rule: the stacked tie-break rank always (the [B, N]
+    buffer matches output state leaves, so a repeated-wave caller — the
+    svc worker's batch loop, a tuning run's generations — reuses it
+    instead of reallocating per wave; keys and weights are byte-tiny and
+    alias nothing), and a per-lane event stream (its [B, E] i32 buffer
+    matches the event_node output leaf) unless `keep_streams`: the
+    report_per_event post-pass reads the streams after dispatch. Safe at
+    the one dispatch site: schedule_pods_sweep builds both fresh and
+    reads neither afterwards. Donation is part of the executable's
+    aliasing contract, not of its jaxpr."""
+    from tpusim.sim.fault_lane import FaultOps
+    from tpusim.sim.table_engine import PodTypes, flat_group_events
+
+    table = isinstance(args[2], PodTypes)
+    trace_ax = _lane_axis(args[1].cpu, 1)
+    if table:
+        # (state, pods, types, ev_kind, ev_pod, tp, key, wts, rank,
+        #  tables[, fault_ops, fault_carry0])
+        ev_pod_i, rank_i, plain = 4, 8, 10
+        ev_ax = _lane_axis(args[3], 1)
+        tid_ax = _lane_axis(args[2].type_id, 1)
+        in_axes = (None, trace_ax, PodTypes(None, None, tid_ax), ev_ax,
+                   ev_ax, None, 0, 0, 0, None)
+    else:
+        # (state, pods, ev_kind, ev_pod, tp, key, wts, rank
+        #  [, fault_ops, fault_carry0])
+        ev_pod_i, rank_i, plain = 3, 7, 8
+        ev_ax = _lane_axis(args[2], 1)
+        tid_ax = None
+        in_axes = (None, trace_ax, ev_ax, ev_ax, None, 0, 0, 0)
+    faulted = len(args) > plain
+    if faulted:
+        in_axes += (FaultOps(0, 0, 0, 0, 0, None), None)
+    donate = (rank_i,) + (
+        (ev_pod_i,) if ev_ax == 0 and not keep_streams else ())
+    # A wide sweep of a short cluster runs the flat step in groups
+    # (flat_group_events of the stacked ranks' [lanes, nodes]) exactly
+    # where the chip judged it (PERF.md section 6, PR 29): the table
+    # engine, one shared trace (type ids not batched), no fault operands.
+    # Per-lane type ids turn the group's patched reads into a gather a
+    # lane and no run has grouped a fault plan's steps: both keep the
+    # plain body until a cell or a chip run says otherwise (section 7)
+    grouped = table and tid_ax is None and not faulted
+
+    ck = (engine, in_axes, donate)
     if ck not in _SWEEP_WRAP_CACHE:
-        if table:
-            # (state, pods, types, ev_kind, ev_pod, tp, key, wts, rank,
-            #  tables)
-            in_axes = (None, None, None, None, None, None, 0, 0, 0, None)
-            dn = (8,)
+        def swept(*operands):
+            group = ({"group": flat_group_events(*operands[rank_i].shape)}
+                     if grouped else {})
+            return jax.vmap(
+                functools.partial(engine, **group), in_axes=in_axes,
+            )(*operands)
 
-            def swept(*args):
-                # the stacked ranks are [lanes, nodes]: a wide sweep of a
-                # short cluster runs the flat step in groups
-                from tpusim.sim.table_engine import flat_group_events
-
-                return jax.vmap(
-                    functools.partial(
-                        engine, group=flat_group_events(*args[8].shape)),
-                    in_axes=in_axes,
-                )(*args)
-
-            # the program keeps the engine's name (traces, compile cache)
-            swept.__name__ = getattr(engine, "__name__", swept.__name__)
-        else:
-            # (state, pods, ev_kind, ev_pod, tp, key, wts, rank)
-            in_axes = (None, None, None, None, None, 0, 0, 0)
-            dn = (7,)
-            swept = jax.vmap(engine, in_axes=in_axes)
-        _SWEEP_WRAP_CACHE[ck] = jax.jit(
-            swept, donate_argnums=dn if donate else (),
-        )
+        # the program keeps the engine's name (traces, compile cache)
+        swept.__name__ = getattr(engine, "__name__", swept.__name__)
+        _SWEEP_WRAP_CACHE[ck] = jax.jit(swept, donate_argnums=donate)
     return _SWEEP_WRAP_CACHE[ck]
 
 
-# (engine, lanes) -> (write sites, dense sites, events a table pass) of the
-# program
+# (wrapper, lanes) -> (write sites, dense sites, events a table pass) of
+# the program
 _SWEEP_LANE_SITES = {}
 
 
-def _dispatch_counting_lane_sites(engine, fn, *args):
-    """fn(*args) for fn = _sweep_engine(engine, ...), the number of write
+def _dispatch_counting_lane_sites(fn, lanes: int, *args):
+    """fn(*args) for fn = _sweep_engine(engine, args), the number of write
     sites of that program that went through sim/lane_write.py's batching
     rule, the number of its sites (reads too) that the rule lowered in
     the dense form, and the events whose columns one dense pass over a
     table writes (0: the program has no such pass). The rule runs while
     the program is traced, so a call served from the jit cache reports
-    what the last trace of the engine at that width counted."""
+    what the last trace of the wrapper at that width counted (the lanes
+    decide the flat group)."""
     from tpusim.sim import lane_write
 
     with lane_write.counting() as sites:
         out = fn(*args)
-    program = engine, args[6].shape[0]  # the lanes decide the flat group
     if sites:
-        _SWEEP_LANE_SITES[program] = (
+        _SWEEP_LANE_SITES[fn, lanes] = (
             len(sites), len(sites.dense), sites.table_pass_events)
-    return (out,) + _SWEEP_LANE_SITES.get(program, (0, 0, 0))
+    return (out,) + _SWEEP_LANE_SITES.get((fn, lanes), (0, 0, 0))
 
 
 def _lane_frag_amounts(state, tp):
@@ -3472,20 +3488,19 @@ def _lane_frag_amounts(state, tp):
         return cluster_frag_amounts(state, tp).sum(0)
 
 
-def _sweep_metrics_fn():
-    """compute_event_metrics vmapped over the config axis: ONE cluster,
-    ONE workload, per-lane telemetry."""
-    global _SWEEP_METRICS_FN
-    if _SWEEP_METRICS_FN is None:
-        from tpusim.sim.metrics import compute_event_metrics
+@functools.lru_cache(maxsize=None)
+def _sweep_metrics_fn(trace_axis):
+    """compute_event_metrics vmapped over the lanes: ONE cluster, per-lane
+    telemetry, of ONE workload (`trace_axis` None) or of the lanes' own
+    specs and event streams (0)."""
+    from tpusim.sim.metrics import compute_event_metrics
 
-        _SWEEP_METRICS_FN = jax.jit(
-            jax.vmap(
-                compute_event_metrics,
-                in_axes=(None, None, None, None, 0, 0, None),
-            )
+    return jax.jit(
+        jax.vmap(
+            compute_event_metrics,
+            in_axes=(None, trace_axis, trace_axis, trace_axis, 0, 0, None),
         )
-    return _SWEEP_METRICS_FN
+    )
 
 
 def _reject_unsweepable(cfg) -> None:
@@ -3869,713 +3884,6 @@ class ChunkWave:
         )
 
 
-def schedule_pods_sweep(
-    sim: "Simulator", pods, weights, seeds=None, bucket: int = 512,
-) -> List[SweepLane]:
-    """Evaluate B what-if configurations of one workload in ONE vmapped
-    replay: `weights` is a [B, num_pol] i32 matrix (one row per config,
-    columns in cfg.policies order), `seeds` an optional length-B list of
-    per-config seeds (default: cfg.seed for every lane; a lane's seed
-    drives its PRNG key AND its tie-break permutation, exactly like a
-    standalone run's cfg.seed). Each lane's placements/counters/metrics
-    are bit-identical to a standalone run with that weight vector in the
-    config — same kernels, same key splits, vmapped — and the whole
-    batch shares one compiled scan and one (weight-independent) table
-    build. Engine selection mirrors schedule_pods_batch: the table
-    engine unless forced sequential or the workload is too small to
-    amortize the table init; pallas has no batched form; extenders /
-    mesh / decision-recording / series configs are rejected."""
-    from tpusim.sim.table_engine import (
-        build_pod_types,
-        num_pod_types,
-        pad_pod_types,
-    )
-    from tpusim.types import PodSpec
-
-    cfg = sim.cfg
-    obs = sim.obs
-    _reject_unsweepable(cfg)
-    w, b, seeds = _check_sweep_grid(cfg, weights, seeds)
-    if sim.typical is None:
-        sim.set_typical_pods()  # a span of its own, before the sweep's
-
-    # eight flat, back-to-back spans under one sweep record: specs,
-    # lane_keys, lane_ranks, init_tables, scan, frag_postpass, fetch,
-    # slice_lanes
-    with obs.sweep(lanes=b) as sweep:
-        with obs.span("specs") as h:
-            specs = pods_to_specs(pods, sim.node_index, device=False)
-            ev_kind_l, ev_pod_l = build_events(pods, cfg.use_timestamps)
-            validate_events(ev_kind_l, ev_pod_l, int(specs.cpu.shape[0]))
-            p, e = int(specs.cpu.shape[0]), len(ev_kind_l)
-            p2, e2 = _bucket_sizes(p, e, bucket)
-
-            types = build_pod_types(specs)
-            k = int(types.share.cpu.shape[0]) + int(types.whole.cpu.shape[0])
-            use_table = (
-                cfg.engine != "sequential"
-                and k > 0
-                and (cfg.engine == "table" or e >= 2 * num_pod_types(specs))
-            )
-
-            specs_h, tid = _pad_specs(
-                specs, p2, types.type_id if use_table else None, xp=np
-            )
-            ev_kind_h, ev_pod_h = _pad_events(
-                np.asarray(ev_kind_l, np.int32),
-                np.asarray(ev_pod_l, np.int32), e2, xp=np,
-            )
-            specs_d = PodSpec(
-                *(jnp.asarray(np.asarray(getattr(specs_h, f)))
-                  for f in PodSpec._fields)
-            )
-            ev_kind_d, ev_pod_d = jnp.asarray(ev_kind_h), jnp.asarray(ev_pod_h)
-            if use_table:
-                types = types._replace(type_id=jnp.asarray(tid))
-                if p2 != p or e2 != e:  # bucketed run: stabilize K too
-                    types = pad_pod_types(types)
-            obs.settle(h, specs_d, ev_kind_d, ev_pod_d, types)
-        sweep.events = e
-        with obs.span("lane_keys") as h:
-            keys = _lane_keys(seeds)
-            obs.settle(h, keys)
-        with obs.span("lane_ranks") as h:
-            ranks = _lane_ranks(len(sim.nodes), seeds)
-            weights_d = jnp.asarray(w)
-            obs.settle(h, ranks, weights_d)
-        state = sim.init_state
-
-        if use_table:
-            # ONE table build for the whole sweep: the tables hold raw
-            # per-policy scores (weight-independent), so every lane shares
-            # them bit-identically — through the content-keyed disk cache
-            # when configured, else built here once instead of B times
-            # under the vmap
-            key0 = jax.random.PRNGKey(seeds[0])
-            table_fn = sim._table_fn
-            if cfg.heartbeat_every:
-                # the in-scan heartbeat cond doesn't survive vmap (a batched
-                # predicate executes both branches, firing the host tick
-                # callback every event per lane) — the sweep replays on the
-                # heartbeat-free build of the same family instead
-                from tpusim.sim.table_engine import make_table_replay
-
-                sim.log.info(
-                    "[Sweep] in-scan heartbeat has no batched form; "
-                    "disabled for the sweep replay"
-                )
-                table_fn = make_table_replay(
-                    sim._policy_fns, gpu_sel=cfg.gpu_sel_method, report=False,
-                    block_size=cfg.block_size,
-                )
-            tables = sim._cached_tables(state, types, key0)
-            if tables is None:
-                with obs.span("init_tables", cache="sweep-shared") as h:
-                    tables = table_fn.build_tables(
-                        state, types, sim.typical, key0
-                    )
-                    obs.settle(h, tables)
-            fn = _sweep_engine(table_fn.engine.replay, table=True)
-            sim._last_engine = f"table ({b}-config vmap sweep)"
-            (out, sweep.lane_writes, sweep.dense_accesses,
-             sweep.table_pass_events) = sim._dispatch_span(
-                lambda: _dispatch_counting_lane_sites(
-                    table_fn.engine.replay, fn, state, specs_d, types,
-                    ev_kind_d, ev_pod_d, sim.typical, keys, weights_d,
-                    ranks, tables,
-                ),
-                engine=sim._last_engine, events=e,
-            )
-        else:
-            fn = _sweep_engine(sim.replay_fn.engine, table=False)
-            sim._last_engine = f"sequential ({b}-config vmap sweep)"
-            out = sim._dispatch_span(
-                lambda: fn(
-                    state, specs_d, ev_kind_d, ev_pod_d, sim.typical, keys,
-                    weights_d, ranks,
-                ),
-                engine=sim._last_engine, events=e,
-            )
-        sweep.engine = sim._last_engine
-        obs.note_scan(sim._last_engine, counters=None, events=e * b)
-        sim.log.info(
-            f"[Engine] sweep of {b} configs x {e} events ran on: "
-            f"{sim._last_engine}"
-        )
-        with obs.span("frag_postpass") as h:
-            if cfg.report_per_event:
-                out = out._replace(
-                    metrics=_sweep_metrics_fn()(
-                        state, specs_d, ev_kind_d, ev_pod_d,
-                        out.event_node, out.event_dev, sim.typical,
-                    )
-                )
-            # per-lane frag of the final states in one vmapped call (the same
-            # reduction cluster_analysis reports), before the single fetch.
-            # The jit wraps a new function object in every call, so dispatch
-            # here is a trace, a lowering and a compile or a cache load.
-            amounts = jax.jit(
-                jax.vmap(_lane_frag_amounts, in_axes=(0, None))
-            )(out.state, sim.typical)
-            obs.settle(h, amounts, out.metrics)
-        with obs.span("fetch", events=e * b):
-            out = device_fetch(out)
-            amounts = np.asarray(amounts)
-
-        with obs.span("slice_lanes"):
-            pad_skips = e2 - e
-            return [
-                _slice_sweep_lane(
-                    out, amounts, i, w[i], seeds[i], p, e, pad_skips
-                )
-                for i in range(b)
-            ]
-
-
-# ---------------------------------------------------------------------------
-# Multi-trace sweep: the trace-operand lift (ISSUE 7)
-# ---------------------------------------------------------------------------
-#
-# schedule_pods_sweep broadcasts ONE workload across every lane (in_axes
-# None on specs/types/events) — so two what-if jobs differing in their
-# TUNE FACTOR (a different tuned pod list, hence different specs/events)
-# could not share its compiled scan. The multi-trace sweep lifts the
-# remaining scalar: each lane carries its own tuned trace as DATA —
-# per-lane specs [B, P], type_id [B, P], and event streams [B, E], all
-# padded to common buckets, vmapped alongside (key, weights, rank) —
-# while the cluster state, the DISTINCT type set (concat-dedup across
-# lanes, the dispatch_pods_batch discipline), the typical pods, and the
-# once-built score tables still broadcast. The jaxpr is the policy
-# family's at the padded shapes; the tune factor is an operand, so the
-# replay service packs tune-differing jobs onto one compiled sweep.
-
-_SWEEP_MULTI_WRAP_CACHE = {}
-_SWEEP_MULTI_FAULT_WRAP_CACHE = {}
-_SWEEP_MULTI_METRICS_FN = None
-
-
-def _sweep_engine_multi(engine, table: bool, donate: bool = True,
-                        donate_streams: bool = False):
-    """jit(vmap(engine)) over per-lane (specs, type_id, events, key,
-    weights, rank); cluster state, distinct type set, typical pods, and
-    the shared score tables broadcast (in_axes None). The trace-operand
-    generalization of _sweep_engine: lanes may replay different tuned
-    workloads and still share one compiled scan. donate=True donates
-    the per-lane rank like _sweep_engine.
-
-    donate_streams=True additionally donates the per-lane ev_pod stream
-    (ISSUE 15 satellite — the PR 11 run_chunk_donated pattern finishing
-    the ROADMAP's "sweep/service lane carries reallocate per wave"
-    leftover): the [B, E] i32 buffer's shape/dtype matches the
-    event_node output leaf exactly, so a repeated-wave caller (the svc
-    worker's batch loop) reuses it instead of reallocating per wave.
-    Only legal when nothing reads the stream after dispatch — the
-    metrics postpass does, so schedule_pods_sweep_multi passes it as
-    `not report_per_event`. The (engine, donate, donate_streams) cache
-    key keeps the zero-recompile bookkeeping intact: consecutive waves
-    of one family resolve to the same jitted wrapper, donation being
-    part of the executable's aliasing contract, not its jaxpr."""
-    from tpusim.sim.table_engine import PodTypes
-    from tpusim.types import PodSpec
-
-    ck = (engine, bool(donate), bool(donate_streams))
-    if ck not in _SWEEP_MULTI_WRAP_CACHE:
-        spec0 = PodSpec(0, 0, 0, 0, 0, 0)
-        none_spec = PodSpec(*(None,) * 6)
-        if table:
-            # (state, pods, types, ev_kind, ev_pod, tp, key, wts, rank,
-            #  tables) — type_id is per-lane, the distinct set broadcasts
-            in_axes = (None, spec0, PodTypes(none_spec, none_spec, 0),
-                       0, 0, None, 0, 0, 0, None)
-            dn = (8,) + ((4,) if donate_streams else ())
-        else:
-            # (state, pods, ev_kind, ev_pod, tp, key, wts, rank)
-            in_axes = (None, spec0, 0, 0, None, 0, 0, 0)
-            dn = (7,) + ((3,) if donate_streams else ())
-        _SWEEP_MULTI_WRAP_CACHE[ck] = jax.jit(
-            jax.vmap(engine, in_axes=in_axes),
-            donate_argnums=dn if donate else (),
-        )
-    return _SWEEP_MULTI_WRAP_CACHE[ck]
-
-
-def _sweep_multi_fault_engine(engine, table: bool, donate: bool = True,
-                              donate_streams: bool = True):
-    """The chaos x tune lift (ISSUE 12): jit(vmap(engine)) over per-lane
-    (specs, type_id, MERGED fault streams, key, weights, rank, fault
-    ops) — the union of _sweep_engine_multi's per-lane trace operands
-    and _sweep_fault_engine's per-lane fault operands. Cluster state,
-    the distinct type set, typical pods, the shared tables, and the
-    initial fault carry broadcast, so mixed fault/tune/weight jobs share
-    ONE compiled scan. donate_streams donates the per-lane merged pod
-    stream like _sweep_engine_multi — default ON here because the chaos
-    tail computes no metrics postpass and never re-reads it (the
-    disruption assembly reads out.fault_ys, not the operands)."""
-    from tpusim.sim.fault_lane import FaultOps
-    from tpusim.sim.table_engine import PodTypes
-    from tpusim.types import PodSpec
-
-    ck = (engine, bool(donate), bool(donate_streams))
-    if ck not in _SWEEP_MULTI_FAULT_WRAP_CACHE:
-        spec0 = PodSpec(0, 0, 0, 0, 0, 0)
-        none_spec = PodSpec(*(None,) * 6)
-        fops_axes = FaultOps(0, 0, 0, 0, 0, None)
-        if table:
-            # (state, pods, types, evk, evp, tp, key, wts, rank, tables,
-            #  fault_ops, fault_carry0)
-            in_axes = (None, spec0, PodTypes(none_spec, none_spec, 0),
-                       0, 0, None, 0, 0, 0, None, fops_axes, None)
-            dn = (8,) + ((4,) if donate_streams else ())
-        else:
-            # (state, pods, evk, evp, tp, key, wts, rank, fault_ops,
-            #  fault_carry0)
-            in_axes = (None, spec0, 0, 0, None, 0, 0, 0, fops_axes, None)
-            dn = (7,) + ((3,) if donate_streams else ())
-        _SWEEP_MULTI_FAULT_WRAP_CACHE[ck] = jax.jit(
-            jax.vmap(engine, in_axes=in_axes),
-            donate_argnums=dn if donate else (),
-        )
-    return _SWEEP_MULTI_FAULT_WRAP_CACHE[ck]
-
-
-def _sweep_multi_metrics_fn():
-    """compute_event_metrics vmapped over per-lane specs/events (the
-    _batched_metrics_fn axes): ONE cluster, per-lane workloads."""
-    global _SWEEP_MULTI_METRICS_FN
-    if _SWEEP_MULTI_METRICS_FN is None:
-        from tpusim.sim.metrics import compute_event_metrics
-        from tpusim.types import PodSpec
-
-        _SWEEP_MULTI_METRICS_FN = jax.jit(
-            jax.vmap(
-                compute_event_metrics,
-                in_axes=(None, PodSpec(0, 0, 0, 0, 0, 0), 0, 0, 0, 0, None),
-            )
-        )
-    return _SWEEP_MULTI_METRICS_FN
-
-
-def schedule_pods_sweep_multi(
-    sim: "Simulator", pods_list, weights, seeds=None, bucket: int = 512,
-    min_pods: int = 0, min_events: int = 0, fault_specs=None,
-) -> List[SweepLane]:
-    """Evaluate B what-if configurations that may each carry their OWN
-    workload (tuned trace variants of one cluster — the tune-factor
-    operand lift, ISSUE 7) in ONE vmapped replay: lane i replays
-    `pods_list[i]` under weight row i and seed i. Every lane must share
-    the Simulator's cluster, policy family, and typical-pod distribution
-    (the service's batching rule — jaxpr identity); the traces
-    themselves are data. Each lane's placements/counters/metrics are
-    bit-identical to a standalone run over that trace with those
-    weights/seed/tune baked into the config — the type table is the
-    concat-dedup across lanes (the schedule_pods_batch discipline, which
-    pins that a shared sorted type set replays identically) and the
-    weight-independent score tables are built once and broadcast.
-    Engine selection mirrors schedule_pods_sweep.
-
-    `fault_specs` (ISSUE 12, the chaos x tune lift): an optional
-    length-B list of per-lane fault schedules — FaultConfig /
-    (FaultConfig, events) per resolve_fault_spec, or None for a
-    fault-free lane riding the faulted build under an empty schedule.
-    Each lane's schedule is compiled against ITS OWN tuned base stream
-    (the merged per-lane streams replace the base event operands), so
-    mixed fault/tune/weight jobs share one compiled scan and each lane
-    stays bit-identical to the standalone run_with_faults run over that
-    tuned trace (given the sweep's unified retry-queue capacity —
-    explicit queue_capacity pins it, the chaos-sweep contract)."""
-    from tpusim.sim.table_engine import (
-        build_pod_types,
-        num_pod_types,
-        pad_pod_types,
-    )
-    from tpusim.types import PodSpec
-
-    cfg = sim.cfg
-    _reject_unsweepable(cfg)
-    w, b, seeds = _check_sweep_grid(cfg, weights, seeds)
-    if len(pods_list) != b:
-        raise ValueError(
-            f"pods_list has {len(pods_list)} traces for {b} weight rows "
-            "(want one workload per config lane)"
-        )
-    if fault_specs is not None:
-        if len(fault_specs) != b:
-            raise ValueError(
-                f"fault_specs has {len(fault_specs)} entries for {b} "
-                "weight rows (want one fault schedule — or None — per "
-                "lane)"
-            )
-        if cfg.use_timestamps:
-            raise ValueError(
-                "the chaos sweep replays creation-ordered traces "
-                "(use_timestamps=False)"
-            )
-    if sim.typical is None:
-        sim.set_typical_pods()
-
-    specs_list, ev_list = [], []
-    for pods in pods_list:
-        specs = pods_to_specs(pods, sim.node_index, device=False)
-        ev_kind_l, ev_pod_l = build_events(pods, cfg.use_timestamps)
-        validate_events(ev_kind_l, ev_pod_l, int(specs.cpu.shape[0]))
-        specs_list.append(specs)
-        ev_list.append((ev_kind_l, ev_pod_l))
-    # `min_pods`/`min_events` are sticky shape floors: below the 512
-    # bucket the padding targets are size-adaptive, so a service batch of
-    # slightly smaller tuned traces would otherwise land on a SMALLER
-    # padded shape than its predecessor and force a pointless recompile —
-    # the worker passes each job family's high-water marks here so
-    # consecutive batches share one executable (jaxpr identity includes
-    # the padded shapes)
-    p = max(max(int(s.cpu.shape[0]) for s in specs_list), int(min_pods))
-    e = max(max(len(k) for k, _ in ev_list), int(min_events))
-    p2, e2 = _bucket_sizes(p, e, bucket)
-
-    # one shared type table across the lanes: dedup over the concatenated
-    # specs (np.unique's sorted order is canonical, so any lane set that
-    # EQUALS the union — e.g. every tuned variant of one base trace —
-    # gets the exact table layout its standalone bucketed run builds);
-    # each lane's type_id is its segment of the concat build
-    cat = PodSpec(
-        *(
-            np.concatenate([np.asarray(getattr(s, f)) for s in specs_list])
-            for f in PodSpec._fields
-        )
-    )
-    types = build_pod_types(cat)
-    k = int(types.share.cpu.shape[0]) + int(types.whole.cpu.shape[0])
-    use_table = (
-        cfg.engine != "sequential"
-        and k > 0
-        and (
-            cfg.engine == "table"
-            or all(
-                len(kinds) >= 2 * num_pod_types(s)
-                for s, (kinds, _) in zip(specs_list, ev_list)
-            )
-        )
-    )
-
-    tids = [None] * b
-    if use_table:
-        offs = np.cumsum([0] + [int(s.cpu.shape[0]) for s in specs_list])
-        tid_all = np.asarray(types.type_id)
-        tids = [tid_all[offs[i]: offs[i + 1]] for i in range(b)]
-
-    padded = [
-        _pad_specs(s, p2, tid, xp=np) for s, tid in zip(specs_list, tids)
-    ]
-    specs_b = PodSpec(
-        *(
-            jnp.asarray(np.stack([np.asarray(getattr(sp, f))
-                                  for sp, _ in padded]))
-            for f in PodSpec._fields
-        )
-    )
-    keys = _lane_keys(seeds)
-    ranks = _lane_ranks(len(sim.nodes), seeds)
-    weights_d = jnp.asarray(w)
-    state = sim.init_state
-    true_events = sum(len(kk) for kk, _ in ev_list)
-
-    if fault_specs is not None:
-        if use_table:
-            types = types._replace(
-                type_id=jnp.asarray(np.stack([tid for _, tid in padded]))
-            )
-            types = pad_pod_types(types)
-        return _dispatch_sweep_multi_faults(
-            sim, fault_specs, specs_list, ev_list, specs_b, types,
-            use_table, keys, weights_d, ranks, w, seeds, state, p2,
-            bucket,
-        )
-
-    padded_ev = [
-        _pad_events(
-            np.asarray(kk, np.int32), np.asarray(pp, np.int32), e2, xp=np
-        )
-        for kk, pp in ev_list
-    ]
-    ev_kind_b = jnp.asarray(np.stack([kk for kk, _ in padded_ev]))
-    ev_pod_b = jnp.asarray(np.stack([pp for _, pp in padded_ev]))
-
-    if use_table:
-        types = types._replace(
-            type_id=jnp.asarray(np.stack([tid for _, tid in padded]))
-        )
-        # ALWAYS stabilize K (pad_pod_types works elementwise on the
-        # stacked [B, P] ids): consecutive service batches whose tuned
-        # traces differ slightly in K must hit one compiled executable
-        types = pad_pod_types(types)
-        key0 = jax.random.PRNGKey(seeds[0])
-        table_fn = sim._table_fn
-        if cfg.heartbeat_every:
-            # same contract as schedule_pods_sweep: the in-scan heartbeat
-            # cond has no batched form — replay the heartbeat-free build
-            from tpusim.sim.table_engine import make_table_replay
-
-            sim.log.info(
-                "[Sweep] in-scan heartbeat has no batched form; "
-                "disabled for the sweep replay"
-            )
-            table_fn = make_table_replay(
-                sim._policy_fns, gpu_sel=cfg.gpu_sel_method, report=False,
-                block_size=cfg.block_size,
-            )
-        # the tables broadcast: init_tables reads only the DISTINCT type
-        # set (never type_id), so one build — disk-cached under the
-        # type_id-free digest (ISSUE 7) — serves every tuned lane
-        tables = sim._cached_tables(state, types, key0)
-        if tables is None:
-            with sim.obs.span("init_tables", cache="sweep-shared") as h:
-                tables = table_fn.build_tables(
-                    state, types, sim.typical, key0
-                )
-                h.dispatched()
-        fn = _sweep_engine_multi(
-            table_fn.engine.replay, table=True,
-            donate_streams=not cfg.report_per_event,
-        )
-        sim._last_sweep_fn = fn  # executables() tracking (svc worker)
-        sim._last_engine = f"table ({b}-trace vmap sweep)"
-        out = sim._dispatch_span(
-            lambda: fn(
-                state, specs_b, types, ev_kind_b, ev_pod_b, sim.typical,
-                keys, weights_d, ranks, tables,
-            ),
-            engine=sim._last_engine, events=true_events,
-        )
-    else:
-        fn = _sweep_engine_multi(
-            sim.replay_fn.engine, table=False,
-            donate_streams=not cfg.report_per_event,
-        )
-        sim._last_sweep_fn = fn  # executables() tracking (svc worker)
-        sim._last_engine = f"sequential ({b}-trace vmap sweep)"
-        out = sim._dispatch_span(
-            lambda: fn(
-                state, specs_b, ev_kind_b, ev_pod_b, sim.typical, keys,
-                weights_d, ranks,
-            ),
-            engine=sim._last_engine, events=true_events,
-        )
-    sim.obs.note_scan(sim._last_engine, counters=None, events=true_events)
-    sim.log.info(
-        f"[Engine] sweep of {b} traces x <= {e} events ran on: "
-        f"{sim._last_engine}"
-    )
-    if cfg.report_per_event:
-        out = out._replace(
-            metrics=_sweep_multi_metrics_fn()(
-                state, specs_b, ev_kind_b, ev_pod_b,
-                out.event_node, out.event_dev, sim.typical,
-            )
-        )
-    amounts = jax.jit(
-        jax.vmap(_lane_frag_amounts, in_axes=(0, None))
-    )(out.state, sim.typical)
-    with sim.obs.span("fetch", events=true_events):
-        out = device_fetch(out)
-        amounts = np.asarray(amounts)
-
-    return [
-        _slice_sweep_lane(
-            out, amounts, i, w[i], seeds[i],
-            int(specs_list[i].cpu.shape[0]), len(ev_list[i][0]),
-            e2 - len(ev_list[i][0]),
-        )
-        for i in range(b)
-    ]
-
-
-def _dispatch_sweep_multi_faults(
-    sim, fault_specs, specs_list, ev_list, specs_b, types, use_table,
-    keys, weights_d, ranks, w, seeds, state, p2, bucket,
-):
-    """The fault tail of schedule_pods_sweep_multi (ISSUE 12): per-lane
-    fault plans compiled against each lane's OWN tuned base stream, the
-    merged streams replacing the base event operands. The sticky
-    per-Simulator chaos shape floors (`sim._chaos_hw` — merged-stream
-    length, draw rows, queue capacity, frag flag) are shared with
-    schedule_pods_sweep_faults, so a service family's consecutive mixed
-    fault/tune waves hold one compiled executable."""
-    from tpusim.sim import fault_lane
-    from tpusim.sim.engine import make_replay
-    from tpusim.sim.faults import FaultConfig
-    from tpusim.sim.table_engine import make_table_replay
-
-    cfg = sim.cfg
-    b = len(specs_list)
-    resolved = []
-    for spec, (kinds_l, _) in zip(fault_specs, ev_list):
-        if spec is None:
-            # a fault-free lane of a mixed batch: an empty schedule is
-            # an exact no-op on the fault lane (no merged steps beyond
-            # the base stream, the carry never moves)
-            resolved.append((FaultConfig(), []))
-        else:
-            resolved.append(
-                resolve_fault_spec(spec, len(sim.nodes), len(kinds_l))
-            )
-    hw_em, hw_rows, hw_cap, hw_rec = getattr(
-        sim, "_chaos_hw", (0, 0, 0, False)
-    )
-    capacity = max(
-        max(
-            fault_lane.resolve_capacity(fcfg, int(s.cpu.shape[0]))
-            for (fcfg, _), s in zip(resolved, specs_list)
-        ),
-        hw_cap,
-    )
-    plan_cache: dict = {}
-    plans = []
-    for (fcfg, events), (kinds_l, pods_l) in zip(resolved, ev_list):
-        key = (repr(fcfg), tuple(events), len(kinds_l))
-        plan = plan_cache.get(key)
-        if plan is None:
-            plan = fault_lane.compile_fault_plan(
-                kinds_l, pods_l, events, fcfg, len(sim.nodes),
-                int(specs_b.cpu.shape[1]), capacity=capacity,
-            )
-            plan_cache[key] = plan
-        plans.append(plan)
-    (kinds, idxs, poss, args, auxs, draws, params, capacity, has_rec) = (
-        fault_lane.pad_fault_plans(
-            plans, bucket=bucket, min_stream=hw_em, min_rows=hw_rows,
-        )
-    )
-    e_m = int(kinds.shape[1])
-    has_rec = bool(has_rec or hw_rec)
-    sim._chaos_hw = (e_m, int(draws.shape[1]), capacity, has_rec)
-
-    ops = fault_lane.FaultOps(
-        pos=jnp.asarray(poss), arg=jnp.asarray(args),
-        aux=jnp.asarray(auxs), draws=jnp.asarray(draws),
-        params=jnp.asarray(params), gcnt=jnp.asarray(state.gpu_cnt),
-    )
-    fc0 = fault_lane.init_fault_carry(p2, state.num_nodes, capacity)
-    kinds_d, idxs_d = jnp.asarray(kinds), jnp.asarray(idxs)
-    true_events = sum(len(kk) for kk, _ in ev_list)
-
-    if use_table:
-        key0 = jax.random.PRNGKey(seeds[0])
-        table_fn = make_table_replay(
-            sim._policy_fns, gpu_sel=cfg.gpu_sel_method, report=False,
-            block_size=cfg.block_size, faults=True, fault_frag=has_rec,
-        )
-        tables = sim._cached_tables(state, types, key0)
-        if tables is None:
-            with sim.obs.span("init_tables", cache="sweep-shared") as h:
-                tables = table_fn.engine.build_tables(
-                    state, types, sim.typical, key0
-                )
-                h.dispatched()
-        fn = _sweep_multi_fault_engine(table_fn.engine.replay, table=True)
-        sim._last_sweep_fn = fn  # executables() tracking (svc worker)
-        sim._last_engine = f"table ({b}-lane chaos x trace sweep)"
-        out = sim._dispatch_span(
-            lambda: fn(
-                state, specs_b, types, kinds_d, idxs_d, sim.typical,
-                keys, weights_d, ranks, tables, ops, fc0,
-            ),
-            engine=sim._last_engine, events=true_events,
-        )
-    else:
-        seq_fn = make_replay(
-            sim._policy_fns, gpu_sel=cfg.gpu_sel_method, report=False,
-            faults=True, fault_frag=has_rec,
-        )
-        fn = _sweep_multi_fault_engine(seq_fn.engine, table=False)
-        sim._last_sweep_fn = fn  # executables() tracking (svc worker)
-        sim._last_engine = f"sequential ({b}-lane chaos x trace sweep)"
-        out = sim._dispatch_span(
-            lambda: fn(
-                state, specs_b, kinds_d, idxs_d, sim.typical, keys,
-                weights_d, ranks, ops, fc0,
-            ),
-            engine=sim._last_engine, events=true_events,
-        )
-    sim.obs.note_scan(sim._last_engine, counters=None, events=true_events)
-    sim.log.info(
-        f"[Engine] chaos x trace sweep of {b} lanes (merged stream "
-        f"{e_m}) ran on: {sim._last_engine}"
-    )
-    amounts = jax.jit(
-        jax.vmap(_lane_frag_amounts, in_axes=(0, None))
-    )(out.state, sim.typical)
-    with sim.obs.span("fetch", events=true_events):
-        out = device_fetch(out)
-        amounts = np.asarray(amounts)
-
-    gcnt_h = np.asarray(state.gpu_cnt)
-    lanes = []
-    for i in range(b):
-        ys_i = jax.tree.map(lambda a: np.asarray(a)[i], out.fault_ys)
-        fc_i = jax.tree.map(lambda a: np.asarray(a)[i], out.fault_carry)
-        dm, dead, attempts_run = fault_lane.assemble_disruption(
-            plans[i], ys_i, fc_i, gcnt_h
-        )
-        p_i = int(specs_list[i].cpu.shape[0])
-        e_i = plans[i].num_events
-        lane = _slice_sweep_lane(
-            out, amounts, i, w[i], seeds[i], p_i, e_i,
-            e_m - e_i - attempts_run,
-        )
-        lane.disruption = dm
-        lane.events = e_i + attempts_run
-        lane.unscheduled = int(
-            ((lane.placed_node < 0)
-             & (lane.ever_failed | dead[:p_i])).sum()
-        )
-        lanes.append(lane)
-    return lanes
-
-
-# ---------------------------------------------------------------------------
-# Chaos sweep: fault schedules as sweep operands (ISSUE 10)
-# ---------------------------------------------------------------------------
-#
-# The config-axis sweep's last missing operand: a fault schedule used to
-# force one full compile+replay per scenario (the segmented host loop
-# cannot vmap). With the fault plane inside the scan
-# (tpusim.sim.fault_lane), a schedule is just five i32 streams + a draw
-# table + a param vector — per-lane DATA. B disruption what-ifs over one
-# trace (varying fault seed / MTBF / evict cadence / backoff) therefore
-# run as ONE compiled vmapped scan; each lane is bit-identical to the
-# standalone run_with_faults run with that schedule.
-
-_SWEEP_FAULT_WRAP_CACHE = {}
-
-
-def _sweep_fault_engine(engine, table: bool, donate: bool = True):
-    """jit(vmap(engine)) for the chaos sweep: per-lane (merged streams,
-    key, weights, rank, fault ops); cluster state, pod specs, types,
-    typical pods, tables, the initial fault carry, and the global
-    gpu-count row broadcast. donate=True donates the per-lane rank like
-    _sweep_engine."""
-    from tpusim.sim.fault_lane import FaultOps
-
-    ck = (engine, bool(donate))
-    if ck not in _SWEEP_FAULT_WRAP_CACHE:
-        fops_axes = FaultOps(0, 0, 0, 0, 0, None)
-        if table:
-            # (state, pods, types, evk, evp, tp, key, wts, rank, tables,
-            #  fault_ops, fault_carry0)
-            in_axes = (None, None, None, 0, 0, None, 0, 0, 0, None,
-                       fops_axes, None)
-            dn = (8,)
-        else:
-            # (state, pods, evk, evp, tp, key, wts, rank, fault_ops,
-            #  fault_carry0)
-            in_axes = (None, None, 0, 0, None, 0, 0, 0, fops_axes, None)
-            dn = (7,)
-        _SWEEP_FAULT_WRAP_CACHE[ck] = jax.jit(
-            jax.vmap(engine, in_axes=in_axes),
-            donate_argnums=dn if donate else (),
-        )
-    return _SWEEP_FAULT_WRAP_CACHE[ck]
-
-
 def resolve_fault_spec(spec, num_nodes: int, num_events: int):
     """One chaos-sweep lane spec -> (FaultConfig, [FaultEvent]): a bare
     FaultConfig generates its seeded MTBF schedule; a (FaultConfig,
@@ -4594,189 +3902,425 @@ def resolve_fault_spec(spec, num_nodes: int, num_events: int):
     )
 
 
-def schedule_pods_sweep_faults(
-    sim: "Simulator", pods, weights, fault_specs, seeds=None,
-    bucket: int = 512,
-) -> List[SweepLane]:
-    """Evaluate B fault what-ifs of ONE workload in ONE vmapped replay:
-    lane i replays the shared trace under weight row i, seed i, and
-    fault spec i (resolve_fault_spec). Lanes share the compiled scan —
-    the merged streams are padded to a common bucketed length (inert
-    EV_SKIP steps), draw tables to a common row count, and the retry
-    queue capacity is unified to the lanes' max — so a later sweep with
-    DIFFERENT schedules of similar size hits the same executable (the
-    chaos-smoke zero-recompile pin). Each SweepLane carries its
-    DisruptionMetrics, bit-identical to the standalone run_with_faults
-    run with that schedule (tests/test_fault_lane.py)."""
-    from tpusim.sim import fault_lane
-    from tpusim.sim.engine import make_replay
+class _SweepTraces(NamedTuple):
+    """What the host prep of a sweep's traces leaves (_sweep_traces)."""
+
+    specs: object  # PodSpec on the device: [P2], or [B, P2] one trace a lane
+    types: object  # PodTypes, type_id shaped like a specs leaf; None: sequential
+    streams: list  # one (ev_kind, ev_pod) a trace: host i32, true length
+    pods: list  # the traces' true pod counts
+    p2: int  # the padded pod axis
+    e2: int  # the padded event axis
+
+
+def _to_lanes(rows, stacked: bool):
+    """Host rows of one length, one a trace, as ONE device array: stacked a
+    lane, or the one shared row as it is."""
+    return jnp.asarray(np.stack(rows) if stacked else np.asarray(rows[0]))
+
+
+def _sweep_traces(sim, traces, stacked: bool, stable_k: bool, bucket: int,
+                  min_pods: int, min_events: int) -> _SweepTraces:
+    """The host prep of a sweep: specs and events of every trace (a shared
+    trace is a list of one), the padded sizes, the type table, the engine
+    choice, then padding and ONE upload a leaf.
+
+    `min_pods` / `min_events` are sticky shape floors: below the 512
+    bucket the padding targets are size-adaptive, so a service batch of
+    slightly smaller tuned traces would otherwise land on a SMALLER
+    padded shape than its predecessor and recompile; the worker passes
+    each job family's high-water marks (jaxpr identity includes the padded
+    shapes).
+
+    The type table is the dedup over the concatenated specs (np.unique's
+    sorted order is canonical, so any set of traces that EQUALS the union,
+    e.g. every tuned variant of one base trace, gets the table its
+    standalone bucketed run builds); each trace's type_id is its segment.
+    K, its length: a shared trace stabilizes K (pad_pod_types) only when
+    its pod or event axis was padded, for an exact-size run's shapes are
+    its own anyway, and both benchmark cells are such runs (K = 71 and
+    61: padding them would change the compiled program). `stable_k`
+    (per-lane traces, fault plans) always does: consecutive service
+    batches and tuner generations whose traces differ slightly in K must
+    hit one executable."""
     from tpusim.sim.table_engine import (
         build_pod_types,
-        make_table_replay,
         num_pod_types,
         pad_pod_types,
     )
     from tpusim.types import PodSpec
 
     cfg = sim.cfg
-    _reject_unsweepable(cfg)
-    if cfg.use_timestamps:
-        raise ValueError(
-            "the chaos sweep replays creation-ordered traces "
-            "(use_timestamps=False)"
-        )
-    w, b, seeds = _check_sweep_grid(cfg, weights, seeds)
-    if len(fault_specs) != b:
-        raise ValueError(
-            f"fault_specs has {len(fault_specs)} entries for {b} weight "
-            "rows (want one fault schedule per lane)"
-        )
-    if sim.typical is None:
-        sim.set_typical_pods()
+    specs_l, streams = [], []
+    for pods in traces:
+        specs = pods_to_specs(pods, sim.node_index, device=False)
+        ev_kind, ev_pod = build_events(pods, cfg.use_timestamps)
+        validate_events(ev_kind, ev_pod, int(specs.cpu.shape[0]))
+        specs_l.append(specs)
+        streams.append(
+            (np.asarray(ev_kind, np.int32), np.asarray(ev_pod, np.int32)))
+    pods_n = [int(s.cpu.shape[0]) for s in specs_l]
+    p, e = max(pods_n), max(len(k) for k, _ in streams)
+    p2, e2 = _bucket_sizes(
+        max(p, int(min_pods)), max(e, int(min_events)), bucket)
 
-    specs = pods_to_specs(pods, sim.node_index, device=False)
-    ev_kind_l, ev_pod_l = build_events(pods, False)
-    validate_events(ev_kind_l, ev_pod_l, int(specs.cpu.shape[0]))
-    p, e = int(specs.cpu.shape[0]), len(ev_kind_l)
-
-    resolved = [
-        resolve_fault_spec(s, len(sim.nodes), e) for s in fault_specs
+    types = build_pod_types(PodSpec(*(
+        np.concatenate([np.asarray(getattr(s, f)) for s in specs_l])
+        for f in PodSpec._fields
+    )))
+    k = int(types.share.cpu.shape[0]) + int(types.whole.cpu.shape[0])
+    use_table = (
+        cfg.engine != "sequential"
+        and k > 0
+        and (
+            cfg.engine == "table"
+            or all(len(kinds) >= 2 * num_pod_types(s)
+                   for s, (kinds, _) in zip(specs_l, streams))
+        )
+    )
+    tids = [None] * len(specs_l)
+    if use_table:
+        offs = np.cumsum([0] + pods_n)
+        tid_all = np.asarray(types.type_id)
+        tids = [tid_all[a:z] for a, z in zip(offs, offs[1:])]
+    padded = [
+        _pad_specs(s, p2, tid, xp=np) for s, tid in zip(specs_l, tids)
     ]
-    # sticky per-Simulator shape floors (the svc worker's min_pods/
-    # min_events discipline): queue capacity, padded stream length, and
-    # draw-table rows only ever grow, so consecutive chaos waves on one
-    # sim share one executable (the zero-recompile pin)
+    specs_d = PodSpec(*(
+        _to_lanes([getattr(s, f) for s, _ in padded], stacked)
+        for f in PodSpec._fields
+    ))
+    if not use_table:
+        return _SweepTraces(specs_d, None, streams, pods_n, p2, e2)
+    types = types._replace(
+        type_id=_to_lanes([tid for _, tid in padded], stacked))
+    if stable_k or p2 != p or e2 != e:
+        types = pad_pod_types(types)
+    return _SweepTraces(specs_d, types, streams, pods_n, p2, e2)
+
+
+def _sweep_fault_plans(sim, fault_specs, streams, pods_n, p2: int,
+                       bucket: int):
+    """The fault plans of a sweep, one a lane, each compiled against the
+    lane's OWN base stream (`streams[i]`, `pods_n[i]`: the lanes of a
+    shared trace repeat one), and the operands they become: (plans, merged
+    ev_kind [B, E_m], merged ev_pod, (FaultOps, initial FaultCarry),
+    fault_frag). A None spec is a fault-free lane riding the faulted build:
+    an empty schedule is an exact no-op on the fault lane (no merged steps
+    beyond the base stream, the carry never moves).
+
+    The merged streams are padded to a common bucketed length (inert
+    EV_SKIP steps), the draw tables to a common row count, and the retry
+    queue capacity is the lanes' max; all three, and the frag-delta
+    capture (a static build flag, so part of the engine's cache key), are
+    sticky a Simulator (`sim._chaos_hw`, like the worker's min_pods /
+    min_events): they only ever grow, so consecutive fault waves on one
+    sim share one executable (the zero-recompile pin), and a recover-free
+    wave after a recovering one reuses the recovering build (the extra ys
+    are zeros)."""
+    from tpusim.sim import fault_lane
+    from tpusim.sim.faults import FaultConfig
+
+    nodes = len(sim.nodes)
+    resolved = [
+        (FaultConfig(), []) if spec is None
+        else resolve_fault_spec(spec, nodes, len(kinds))
+        for spec, (kinds, _) in zip(fault_specs, streams)
+    ]
     hw_em, hw_rows, hw_cap, hw_rec = getattr(
         sim, "_chaos_hw", (0, 0, 0, False)
     )
     capacity = max(
-        max(fault_lane.resolve_capacity(fcfg, p) for fcfg, _ in resolved),
+        max(fault_lane.resolve_capacity(fcfg, n)
+            for (fcfg, _), n in zip(resolved, pods_n)),
         hw_cap,
     )
-    # dedup identical lane specs before compiling: a tuning population
-    # rolls EVERY lane under one schedule (learn.rollout), and each plan
-    # compile walks the merged stream + pre-draws victim tables — paying
-    # it once per distinct schedule instead of once per lane
+    # identical lanes compile once: a tuning population rolls EVERY lane
+    # under one schedule (learn.rollout), the service pads a short batch
+    # with its tail job, and each compile walks the merged stream and
+    # pre-draws the victim tables. The key is the schedule and the stream
+    # it is merged into
     plan_cache: dict = {}
     plans = []
-    for fcfg, events in resolved:
-        key = (repr(fcfg), tuple(events))
-        plan = plan_cache.get(key)
-        if plan is None:
-            plan = fault_lane.compile_fault_plan(
-                ev_kind_l, ev_pod_l, events, fcfg, len(sim.nodes), p,
-                capacity=capacity,
+    for (fcfg, events), (kinds, pods) in zip(resolved, streams):
+        key = (repr(fcfg), tuple(events), kinds.tobytes(), pods.tobytes())
+        if key not in plan_cache:
+            plan_cache[key] = fault_lane.compile_fault_plan(
+                kinds, pods, events, fcfg, nodes, p2, capacity=capacity,
             )
-            plan_cache[key] = plan
-        plans.append(plan)
+        plans.append(plan_cache[key])
     (kinds, idxs, poss, args, auxs, draws, params, capacity, has_rec) = (
         fault_lane.pad_fault_plans(
             plans, bucket=bucket, min_stream=hw_em, min_rows=hw_rows,
         )
     )
-    e_m = int(kinds.shape[1])
-    # the frag-delta capture is a static build flag (engine cache key) —
-    # sticky too, so a recover-free wave after a recovering one reuses
-    # the recovering build (the extra ys are just zeros)
     has_rec = bool(has_rec or hw_rec)
-    sim._chaos_hw = (e_m, int(draws.shape[1]), capacity, has_rec)
-
-    specs_d = PodSpec(
-        *(jnp.asarray(np.asarray(getattr(specs, f)))
-          for f in PodSpec._fields)
-    )
-    keys = _lane_keys(seeds)
-    ranks = _lane_ranks(len(sim.nodes), seeds)
-    weights_d = jnp.asarray(w)
+    sim._chaos_hw = (
+        int(kinds.shape[1]), int(draws.shape[1]), capacity, has_rec)
     state = sim.init_state
     ops = fault_lane.FaultOps(
         pos=jnp.asarray(poss), arg=jnp.asarray(args),
         aux=jnp.asarray(auxs), draws=jnp.asarray(draws),
         params=jnp.asarray(params), gcnt=jnp.asarray(state.gpu_cnt),
     )
-    fc0 = fault_lane.init_fault_carry(p, state.num_nodes, capacity)
-    kinds_d, idxs_d = jnp.asarray(kinds), jnp.asarray(idxs)
+    fc0 = fault_lane.init_fault_carry(p2, state.num_nodes, capacity)
+    return plans, jnp.asarray(kinds), jnp.asarray(idxs), (ops, fc0), has_rec
 
-    types = build_pod_types(specs)
-    k = int(types.share.cpu.shape[0]) + int(types.whole.cpu.shape[0])
-    use_table = (
-        cfg.engine != "sequential"
-        and k > 0
-        and (cfg.engine == "table" or e >= 2 * num_pod_types(specs))
+
+def _sweep_replay(sim, table: bool, fault_frag: Optional[bool]):
+    """The replayer whose weight-operand `.engine` a sweep vmaps: the
+    Simulator's own but for two builds. With fault plans (`fault_frag` not
+    None) it is the in-scan fault plane's. And the in-scan heartbeat cond
+    of the table engine doesn't survive vmap (a batched predicate executes
+    both branches, firing the host tick callback every event per lane), so
+    a heartbeat config replays on the heartbeat-free build of its family."""
+    from tpusim.sim.engine import make_replay
+    from tpusim.sim.table_engine import make_table_replay
+
+    cfg = sim.cfg
+    build = (
+        functools.partial(make_table_replay, block_size=cfg.block_size)
+        if table else make_replay
     )
-    if use_table:
-        types = pad_pod_types(types)  # stabilize K across chaos batches
-        key0 = jax.random.PRNGKey(seeds[0])
-        table_fn = make_table_replay(
+    if fault_frag is not None:
+        return build(
             sim._policy_fns, gpu_sel=cfg.gpu_sel_method, report=False,
-            block_size=cfg.block_size, faults=True, fault_frag=has_rec,
+            faults=True, fault_frag=fault_frag,
         )
-        tables = sim._cached_tables(state, types, key0)
-        if tables is None:
-            with sim.obs.span("init_tables", cache="sweep-shared") as h:
-                tables = table_fn.engine.build_tables(
-                    state, types, sim.typical, key0
+    if table and cfg.heartbeat_every:
+        sim.log.info(
+            "[Sweep] in-scan heartbeat has no batched form; "
+            "disabled for the sweep replay"
+        )
+        return build(
+            sim._policy_fns, gpu_sel=cfg.gpu_sel_method, report=False)
+    return sim._table_fn if table else sim.replay_fn
+
+
+def _slice_fault_lane(out, amounts, i, wrow, seed, p, plan, e_m, gcnt):
+    """Lane i of a fetched sweep with fault plans, whose merged streams
+    were padded to `e_m` steps: its SweepLane with the DisruptionMetrics
+    of its schedule, bit-identical to the standalone run_with_faults run
+    (tests/test_fault_lane.py)."""
+    from tpusim.sim import fault_lane
+
+    ys = jax.tree.map(lambda a: np.asarray(a)[i], out.fault_ys)
+    fc = jax.tree.map(lambda a: np.asarray(a)[i], out.fault_carry)
+    dm, dead, attempts_run = fault_lane.assemble_disruption(
+        plan, ys, fc, gcnt)
+    e = plan.num_events
+    lane = _slice_sweep_lane(
+        out, amounts, i, wrow, seed, p, e, e_m - e - attempts_run)
+    lane.disruption = dm
+    lane.events = e + attempts_run
+    # dead pods are terminal max-retries-exceeded: the standalone path's
+    # unscheduled accounting includes them
+    lane.unscheduled = int(
+        ((lane.placed_node < 0) & (lane.ever_failed | dead[:p])).sum()
+    )
+    return lane
+
+
+def schedule_pods_sweep(
+    sim: "Simulator", pods, weights, seeds=None, bucket: int = 512, *,
+    lane_pods=None, fault_specs=None, min_pods: int = 0,
+    min_events: int = 0,
+) -> List[SweepLane]:
+    """Evaluate B what-if configurations in ONE vmapped replay: `weights`
+    is a [B, num_pol] i32 matrix (one row per config, columns in
+    cfg.policies order), `seeds` an optional length-B list of per-config
+    seeds (default: cfg.seed for every lane; a lane's seed drives its PRNG
+    key AND its tie-break permutation, exactly like a standalone run's
+    cfg.seed). Each lane's placements/counters/metrics are bit-identical
+    to a standalone run with that weight vector in the config — same
+    kernels, same key splits, vmapped — and the whole batch shares one
+    compiled scan and one (weight-independent) table build. Engine
+    selection mirrors schedule_pods_batch: the table engine unless forced
+    sequential or the workload is too small to amortize the table init;
+    pallas has no batched form; extenders / mesh / decision-recording /
+    series configs are rejected.
+
+    What else a lane carries is data, and the one path reads it off its
+    operands (_sweep_engine):
+
+    `pods` is the trace every lane replays. `lane_pods` instead (pods
+    None) gives lane i its OWN workload (tuned variants of one cluster's
+    trace — the tune factor as an operand): specs, type_id and event
+    streams padded to common buckets and stacked a lane, while the cluster
+    state, the DISTINCT type set (concat-dedup across the lanes, the
+    dispatch_pods_batch discipline), the typical pods and the once-built
+    score tables still broadcast. Every lane shares the Simulator's
+    cluster, policy family and typical-pod distribution (the service's
+    batching rule — jaxpr identity); `min_pods` / `min_events` are the
+    service's sticky shape floors (_sweep_traces).
+
+    `fault_specs`: a length-B list of fault schedules — FaultConfig /
+    (FaultConfig, events) per resolve_fault_spec, or None for a fault-free
+    lane. A schedule is five i32 streams, a draw table and a param vector:
+    each is compiled against its lane's own base stream, the merged
+    streams replace the base event operands, and every SweepLane carries
+    its DisruptionMetrics, bit-identical to the standalone run_with_faults
+    run with that schedule (given the sweep's unified retry-queue
+    capacity: an explicit queue_capacity pins it). So mixed
+    fault/tune/weight jobs share one compiled scan
+    (_sweep_fault_plans)."""
+    cfg, obs = sim.cfg, sim.obs
+    _reject_unsweepable(cfg)
+    w, b, seeds = _check_sweep_grid(cfg, weights, seeds)
+    per_lane, faulted = lane_pods is not None, fault_specs is not None
+    if per_lane == (pods is not None):
+        raise ValueError(
+            "a sweep replays ONE shared trace (pods) or one trace a lane "
+            "(lane_pods, pods None)"
+        )
+    if per_lane and len(lane_pods) != b:
+        raise ValueError(
+            f"lane_pods has {len(lane_pods)} traces for {b} weight rows "
+            "(want one workload per config lane; Simulator.run_sweep "
+            "prepares one per tuning ratio)"
+        )
+    if faulted:
+        if len(fault_specs) != b:
+            raise ValueError(
+                f"fault_specs has {len(fault_specs)} entries for {b} "
+                "weight rows (want one fault schedule — or None — per "
+                "lane)"
+            )
+        if cfg.use_timestamps:
+            raise ValueError(
+                "the chaos sweep replays creation-ordered traces "
+                "(use_timestamps=False)"
+            )
+    if sim.typical is None:
+        sim.set_typical_pods()  # a span of its own, before the sweep's
+    lane_trace = list(range(b)) if per_lane else [0] * b  # lane -> trace
+    state = sim.init_state
+
+    # eight flat, back-to-back spans under one sweep record: specs,
+    # lane_keys, lane_ranks, init_tables, scan, frag_postpass, fetch,
+    # slice_lanes
+    with obs.sweep(lanes=b) as sweep:
+        with obs.span("specs") as h:
+            tr = _sweep_traces(
+                sim, lane_pods if per_lane else [pods], per_lane,
+                per_lane or faulted, bucket, min_pods, min_events,
+            )
+            plans, fault_args, fault_frag = None, (), None
+            if faulted:
+                plans, ev_kind, ev_pod, fault_args, fault_frag = (
+                    _sweep_fault_plans(
+                        sim, fault_specs,
+                        [tr.streams[t] for t in lane_trace],
+                        [tr.pods[t] for t in lane_trace], tr.p2, bucket,
+                    )
                 )
-                h.dispatched()
-        fn = _sweep_fault_engine(table_fn.engine.replay, table=True)
-        sim._last_sweep_fn = fn  # executables() tracking (learn.rollout)
-        sim._last_engine = f"table ({b}-lane chaos sweep)"
-        out = sim._dispatch_span(
-            lambda: fn(
-                state, specs_d, types, kinds_d, idxs_d, sim.typical,
-                keys, weights_d, ranks, tables, ops, fc0,
-            ),
-            engine=sim._last_engine, events=e * b,
-        )
-    else:
-        seq_fn = make_replay(
-            sim._policy_fns, gpu_sel=cfg.gpu_sel_method, report=False,
-            faults=True, fault_frag=has_rec,
-        )
-        fn = _sweep_fault_engine(seq_fn.engine, table=False)
-        sim._last_sweep_fn = fn  # executables() tracking (learn.rollout)
-        sim._last_engine = f"sequential ({b}-lane chaos sweep)"
-        out = sim._dispatch_span(
-            lambda: fn(
-                state, specs_d, kinds_d, idxs_d, sim.typical, keys,
-                weights_d, ranks, ops, fc0,
-            ),
-            engine=sim._last_engine, events=e * b,
-        )
-    sim.obs.note_scan(sim._last_engine, counters=None, events=e * b)
-    sim.log.info(
-        f"[Engine] chaos sweep of {b} fault lanes x {e} events "
-        f"(merged stream {e_m}) ran on: {sim._last_engine}"
-    )
-    amounts = jax.jit(
-        jax.vmap(_lane_frag_amounts, in_axes=(0, None))
-    )(out.state, sim.typical)
-    with sim.obs.span("fetch", events=e * b):
-        out = device_fetch(out)
-        amounts = np.asarray(amounts)
+            else:
+                padded = [
+                    _pad_events(kinds, idx, tr.e2, xp=np)
+                    for kinds, idx in tr.streams
+                ]
+                ev_kind = _to_lanes([kinds for kinds, _ in padded], per_lane)
+                ev_pod = _to_lanes([idx for _, idx in padded], per_lane)
+            obs.settle(h, tr.specs, ev_kind, ev_pod, tr.types, fault_args)
+        # what a lane replays of its trace, less padding, merged fault
+        # steps and retries
+        lane_events = [len(tr.streams[t][0]) for t in lane_trace]
+        sweep.events, true_events = max(lane_events), sum(lane_events)
+        steps = int(ev_kind.shape[-1])  # of the scan, padding included
+        with obs.span("lane_keys") as h:
+            keys = _lane_keys(seeds)
+            obs.settle(h, keys)
+        with obs.span("lane_ranks") as h:
+            ranks = _lane_ranks(len(sim.nodes), seeds)
+            weights_d = jnp.asarray(w)
+            obs.settle(h, ranks, weights_d)
 
-    gcnt_h = np.asarray(state.gpu_cnt)
-    lanes = []
-    for i in range(b):
-        ys_i = jax.tree.map(lambda a: np.asarray(a)[i], out.fault_ys)
-        fc_i = jax.tree.map(lambda a: np.asarray(a)[i], out.fault_carry)
-        dm, dead, attempts_run = fault_lane.assemble_disruption(
-            plans[i], ys_i, fc_i, gcnt_h
+        use_table = tr.types is not None
+        replay_fn = _sweep_replay(sim, use_table, fault_frag)
+        args = (ev_kind, ev_pod, sim.typical, keys, weights_d, ranks)
+        if use_table:
+            # ONE table build for the whole sweep: the tables hold raw
+            # per-policy scores (weight-independent) and init_tables reads
+            # only the DISTINCT type set (never type_id), so every lane
+            # shares them bit-identically — through the content-keyed
+            # disk cache when configured (under the type_id-free digest),
+            # else built here once instead of B times under the vmap
+            key0 = jax.random.PRNGKey(seeds[0])
+            tables = sim._cached_tables(state, tr.types, key0)
+            if tables is None:
+                with obs.span("init_tables", cache="sweep-shared") as h:
+                    tables = replay_fn.build_tables(
+                        state, tr.types, sim.typical, key0
+                    )
+                    obs.settle(h, tables)
+            engine = replay_fn.engine.replay
+            args = (state, tr.specs, tr.types) + args + (tables,)
+        else:
+            engine = replay_fn.engine
+            args = (state, tr.specs) + args
+        args += fault_args
+        # the post-pass re-reads the event streams; a fault sweep has none
+        # (its per-event rows would index the merged stream)
+        report = cfg.report_per_event and not faulted
+        fn = _sweep_engine(engine, args, keep_streams=report)
+        # the wrapper dispatched, for the executables census of the
+        # service, the tuner and the gate (fn._cache_size())
+        sim._last_sweep_fn = fn
+        what = (
+            ("lane chaos x trace" if per_lane else "lane chaos") if faulted
+            else ("trace vmap" if per_lane else "config vmap")
         )
-        lane = _slice_sweep_lane(
-            out, amounts, i, w[i], seeds[i], p, e,
-            e_m - plans[i].num_events - attempts_run,
+        sim._last_engine = (
+            f"{'table' if use_table else 'sequential'} ({b}-{what} sweep)"
         )
-        lane.disruption = dm
-        lane.events = plans[i].num_events + attempts_run
-        # dead pods are terminal max-retries-exceeded — the standalone
-        # path's unscheduled accounting includes them
-        lane.unscheduled = int(
-            ((lane.placed_node < 0)
-             & (lane.ever_failed | dead[:p])).sum()
+        (out, sweep.lane_writes, sweep.dense_accesses,
+         sweep.table_pass_events) = sim._dispatch_span(
+            lambda: _dispatch_counting_lane_sites(fn, b, *args),
+            engine=sim._last_engine, events=true_events,
         )
-        lanes.append(lane)
-    return lanes
+        sweep.engine = sim._last_engine
+        obs.note_scan(sim._last_engine, counters=None, events=true_events)
+        sim.log.info(
+            f"[Engine] sweep of {b} lanes x <= {sweep.events} events "
+            f"(stream {steps}) ran on: {sim._last_engine}"
+        )
+        with obs.span("frag_postpass") as h:
+            if report:
+                out = out._replace(
+                    metrics=_sweep_metrics_fn(_lane_axis(ev_kind, 1))(
+                        state, tr.specs, ev_kind, ev_pod,
+                        out.event_node, out.event_dev, sim.typical,
+                    )
+                )
+            # per-lane frag of the final states in one vmapped call (the same
+            # reduction cluster_analysis reports), before the single fetch.
+            # The jit wraps a new function object in every call, so dispatch
+            # here is a trace, a lowering and a compile or a cache load.
+            amounts = jax.jit(
+                jax.vmap(_lane_frag_amounts, in_axes=(0, None))
+            )(out.state, sim.typical)
+            obs.settle(h, amounts, out.metrics)
+        with obs.span("fetch", events=true_events):
+            out = device_fetch(out)
+            amounts = np.asarray(amounts)
+
+        with obs.span("slice_lanes"):
+            if faulted:
+                gcnt = np.asarray(state.gpu_cnt)
+                return [
+                    _slice_fault_lane(
+                        out, amounts, i, w[i], seeds[i], tr.pods[t],
+                        plans[i], steps, gcnt,
+                    )
+                    for i, t in enumerate(lane_trace)
+                ]
+            return [
+                _slice_sweep_lane(
+                    out, amounts, i, w[i], seeds[i], tr.pods[t],
+                    lane_events[i], steps - lane_events[i],
+                )
+                for i, t in enumerate(lane_trace)
+            ]
 
 
 def format_chaos_table(lanes: Sequence[SweepLane], policies) -> str:

@@ -9,8 +9,8 @@ a `dynamic_update_slice`, a `dynamic_slice` or an `.at[]` update, and stays
 exactly that: the functions here are `jax.custom_batching.custom_vmap`s whose
 unbatched expression is the one the step bodies always had.
 
-Under `jax.vmap` (every sweep: `_sweep_engine`, `_sweep_engine_multi`, the
-fault twins, the fork wave) each carried leaf gains a leading lane axis and
+Under `jax.vmap` (every sweep: `driver._sweep_engine`; the seed batch, the
+fork wave) each carried leaf gains a leading lane axis and
 the derived forms are a `scatter` for a write and a `gather` for a read. On
 the TPU the scatters run in place on the carry's own layout (tables
 row-major with nodes minor, `NodeState.gpu_left` / `aff_cnt` nodes minor

@@ -626,8 +626,8 @@ class _TableEngine(NamedTuple):
     every callable takes the i32[num_pol] weight vector as a traced
     argument (never baked), so the family compiles once.
 
-    `replay` is also the multi-trace sweep's vmap target (ISSUE 7,
-    driver._sweep_engine_multi): pods, types.type_id, and the event
+    `replay` is also the vmap target of a sweep with one trace a lane
+    (ISSUE 7, driver._sweep_engine): pods, types.type_id, and the event
     streams batch per lane while types.share/types.whole — the distinct
     type set the tables index — broadcast, so tuned trace variants are
     data, not jaxpr structure. Nothing in the engine reads type_id

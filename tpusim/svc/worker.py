@@ -2,7 +2,7 @@
 ISSUE 12).
 
 The worker drains the JobQueue batch by batch and dispatches each batch
-through the multi-trace vmapped sweep (driver.schedule_pods_sweep_multi)
+through the vmapped sweep, one trace a lane (driver.schedule_pods_sweep)
 — so a whole batch of what-if jobs costs one compiled scan, and across
 batches the one-jaxpr-per-family contract holds: per-family Simulators
 are cached (sharing the weight-operand engines, the content-keyed table
@@ -458,13 +458,14 @@ class Worker:
 
     def _dispatch(self, batch: List[Job]):
         """ONE dispatch path for fault-free AND fault batches (the
-        ISSUE 12 fold): every batch rides schedule_pods_sweep_multi, and
+        ISSUE 12 fold): every batch rides schedule_pods_sweep with one
+        tuned trace a lane (lane_pods), and
         a fault family simply adds per-lane fault schedules — compiled
         against each lane's OWN tuned stream — as operands. Mixed
         fault/tune/weight jobs of one family therefore share one
         compiled scan (the family key no longer pins a tune factor for
         fault jobs)."""
-        from tpusim.sim.driver import schedule_pods_sweep_multi
+        from tpusim.sim.driver import schedule_pods_sweep
 
         sim = self._sim_for(batch[0])
         key = batch[0].spec.family_key()
@@ -503,12 +504,12 @@ class Worker:
         # them a later batch of slightly smaller tuned traces would land
         # on a smaller padded shape and recompile. The event count is the
         # real build_events length under the family's event ordering
-        # (sweep_multi builds the same streams right after — this extra
+        # (the sweep builds the same streams right after — this extra
         # host-side O(P) pass per lane is noise next to the scan), not a
         # bound: an inflated floor would pad dead EV_SKIPs into every
         # future scan. Fault families additionally keep their merged-
         # stream/draw-table/capacity floors on the Simulator itself
-        # (sim._chaos_hw, the schedule_pods_sweep_faults discipline).
+        # (sim._chaos_hw, driver._sweep_fault_plans).
         from tpusim.io.trace import build_events
 
         p_max = max(len(p) for p in pods_list)
@@ -523,18 +524,15 @@ class Worker:
         sim._reset_run_state()
         if sim.typical is None:
             sim.set_typical_pods()
-        lanes = schedule_pods_sweep_multi(
-            sim, pods_list, np.asarray(weights, np.int32), seeds=seeds,
-            bucket=self.bucket, min_pods=hw_p, min_events=hw_e,
-            fault_specs=fault_specs,
+        lanes = schedule_pods_sweep(
+            sim, None, np.asarray(weights, np.int32), seeds=seeds,
+            bucket=self.bucket, lane_pods=pods_list, min_pods=hw_p,
+            min_events=hw_e, fault_specs=fault_specs,
         )[:n]
         # track the jitted sweep wrapper actually dispatched so /queue
         # can report the compiled-executable count (the PR 6
-        # jit._cache_size() zero-recompile check, now a live metric).
-        # Both paths record the wrapper on the sim (the fault tail
-        # always did; the plain path joined it when donate_streams made
-        # the wrapper choice depend on the report flag, ISSUE 15) — so
-        # the count follows the wrapper ACTUALLY dispatched
+        # jit._cache_size() zero-recompile check, now a live metric):
+        # every sweep leaves it on the sim
         self._sweep_fns.add(sim._last_sweep_fn)
         return lanes
 
