@@ -1,0 +1,100 @@
+"""`table_reuse_share` (benchmark/layer_metrics, ISSUE 31) as the harness
+reads it: the tiny openb cell's traced run reads 1.0 (the warm wave builds
+the score tables, every wave of the window reads them from the device), in
+the tiny 100k cell `table_build_s` is still a number and under 0.01 s, and a
+program whose sweep record lacks `tables_reused` reads as nothing. Here and not under benchmark/tests:
+the tier-1 lane runs it, and the benchmark gains the one reader only."""
+
+import importlib.util
+import json
+import os
+import types
+
+import jax
+import pytest
+
+from benchmark.lib import sweep_log
+from tpusim.obs import sweep_log as program_log
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+METRIC = "table_reuse_share"
+
+
+@pytest.fixture(scope="module")
+def bench_run():
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_run", os.path.join(REPO, "benchmark", "run.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_the_metric_is_the_last_entry_and_lists_openb_alone(bench_run):
+    """As PR 28's and PR 29's metrics do: the accepted
+    benchmark/tests/test_sweep_log.py pins what the 100k cell's line
+    holds, and there `table_build_s` already shows a hit."""
+    bench = bench_run.load_json(os.path.join(REPO, "BENCHMARK.json"))
+    build = next(m for m in bench["per_layer"] if m["name"] == "table_build_s")
+    assert bench["per_layer"][-1] == {
+        "name": METRIC, "unit": "share", "better": "higher",
+        "source": "program_counter", "layer": build["layer"],
+        "moves": "lane_events_per_s", "workloads": ["openb.fgd-seeds"]}
+    assert hasattr(bench_run.load_module("layer_metrics", METRIC), "read")
+
+
+@pytest.fixture
+def compile_cache_put_back():
+    """The harness is an entry point and places the process-wide compile
+    cache (tpusim.compile_cache: the window may load programs, not compile
+    them); in-process, put back what the test found."""
+    knobs = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs")
+    before = {k: getattr(jax.config, k) for k in knobs}
+    yield
+    for k, v in before.items():
+        jax.config.update(k, v)
+
+
+@pytest.mark.parametrize("cell", ["synth100k.fgd-seeds", "openb.fgd-seeds"])
+def test_every_wave_of_a_tiny_cells_window_reuses_the_tables(
+        bench_run, cell, capsys, compile_cache_put_back):
+    assert bench_run.main([
+        "--workload", cell, "--seed", "3000000019", "--seconds", "0.5",
+        "--trace", "1", "--rehearse"]) == 0
+    got = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert got["correct"] is True and got["failed"] == 0
+    if cell == "synth100k.fgd-seeds":
+        # still the init_tables span: the hand-over now, not a build
+        assert 0 < got["metrics"]["table_build_s"]["value"] < 0.01
+        assert METRIC not in got["metrics"]
+    else:
+        assert got["metrics"][METRIC] == {"value": 1.0, "unit": "share"}
+        assert "table_build_s" not in got["metrics"]
+    # the warm wave built, the window's waves and the traced one did not
+    tail = program_log()[-(got["attempted"] + 2):]
+    assert [rec.tables_reused for rec in tail] == [0] + [1] * (len(tail) - 1)
+    assert [[s.meta.get("cache") for s in rec.spans if s.name == "init_tables"]
+            for rec in tail] == [["sweep-shared"]] + [["resident"]] * (
+                len(tail) - 1)
+
+
+def test_a_record_without_the_field_reads_as_nothing(bench_run, monkeypatch):
+    """The parent's SweepRecord has no `tables_reused`: the reader finds
+    nothing, returns None and does not raise."""
+    metric = bench_run.load_module("layer_metrics", METRIC)
+
+    def rec(**fields):
+        return types.SimpleNamespace(lanes=3, events=64, spans=[], **fields)
+
+    old, built, reused = rec(), rec(tables_reused=0), rec(tables_reused=1)
+    monkeypatch.setattr(sweep_log, "records", lambda run: (old, [old, reused]))
+    assert metric.read({}) is None
+    monkeypatch.setattr(sweep_log, "records", lambda run: None)
+    assert metric.read({}) is None
+    # the warm wave's record is not the window's
+    monkeypatch.setattr(
+        sweep_log, "records", lambda run: (built, [reused, reused]))
+    assert metric.read({}) == 1.0
+    monkeypatch.setattr(
+        sweep_log, "records", lambda run: (reused, [built] + [reused] * 3))
+    assert metric.read({}) == 0.75

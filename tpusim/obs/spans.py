@@ -162,6 +162,10 @@ class SweepRecord:
     # writes: the flat body's group (table_engine.FLAT_GROUP_EVENTS), 1
     # where a column is written every event, 0 with no dense table write
     table_pass_events: int = 0
+    # 1 where the sweep read the score tables an earlier sweep of the
+    # Simulator left on the device (its init_tables span says
+    # cache="resident"), 0 where it built or loaded them
+    tables_reused: int = 0
 
     @property
     def compiled(self) -> int:
@@ -182,6 +186,7 @@ class SweepRecord:
             "lane_writes": self.lane_writes,
             "dense_accesses": self.dense_accesses,
             "table_pass_events": self.table_pass_events,
+            "tables_reused": self.tables_reused,
             "spans": [s.to_dict() for s in self.spans],
         }
 

@@ -443,6 +443,9 @@ class Simulator:
         # the jitted vmapped wrapper the last sweep dispatched: its
         # _cache_size() is the executables census (svc worker, tuner, gate)
         self._last_sweep_fn = None
+        # the score tables the last sweep built, alive on the device with
+        # their proof (_sweep_tables): one entry, replaced on a miss
+        self._resident_tables: Optional["_ResidentTables"] = None
         # run-level event offset the next heartbeat arm reports from
         # (the fault loop sets it per segment; plain runs leave it 0)
         self._hb_base = 0
@@ -1042,9 +1045,12 @@ class Simulator:
         engine from the content-keyed disk cache, building + persisting
         on miss — or None when caching is disabled (the engine then
         builds the tables inside init_carry exactly as before). A hit
-        skips the K-node-sweep build (~27 s at N=100k); results are
-        bit-identical either way because every downstream aggregate is a
-        pure function of the tables. obs records the outcome."""
+        skips the K-node-sweep build (0.62 s at N=100k, K=71) for a digest
+        that copies the state to the host, a file load and a transfer of
+        the tables; results are bit-identical either way because every
+        downstream aggregate is a pure function of the tables. obs records
+        the outcome. A sweep comes here through _sweep_tables, after the
+        tables its last sweep left on the device."""
         cache_dir = self._table_cache_dir()
         if not cache_dir:
             return None
@@ -1092,6 +1098,48 @@ class Simulator:
             f"[TableCache] saved init tables to {os.path.basename(path)}"
         )
         return tables
+
+    def _sweep_tables(self, engine, state, types, key):
+        """((score_tbl, sdev_tbl, feas_tbl), reused) for one sweep of the
+        table engine `engine`, under its one init_tables span: the tables
+        the last sweep of this Simulator left on the device when they are
+        PROVEN right for this one (cache="resident", reused 1), else the
+        disk cache where one is configured (_cached_tables: "hit" |
+        "miss"), else the engine's build ("sweep-shared"). Whatever a miss
+        obtains replaces the entry, so an unchanged cluster and type set
+        build once a Simulator and not once a wave (0.62 s of a 2.43 s
+        wave at 100,000 nodes, K = 71: PERF.md section 6, PR 31).
+
+        The proof runs ahead of the span, whose `cache=` it decides: a
+        hit's span holds the hand-over alone. The sweep wrapper broadcasts
+        the tables and donates neither them nor the state (_sweep_engine),
+        so a wave leaves both intact. A miss is always safe; what cannot
+        be proven misses."""
+        obs = self.obs
+        proof = _tables_proof(engine, state, types, self.typical)
+        held = self._resident_tables
+        if _same_build(held, proof):
+            with obs.span("init_tables", cache="resident"):
+                obs.count("table_resident_hit")
+                return held.tables, 1
+        # one entry a Simulator: the old set goes before the new one is
+        # built, so the two never share the device
+        self._resident_tables = held = None
+        tables = self._cached_tables(state, types, key)
+        if tables is None:
+            with obs.span("init_tables", cache="sweep-shared") as h:
+                tables = engine.build_tables(state, types, self.typical, key)
+                obs.settle(h, tables)
+        if proof is not None:
+            self._resident_tables = proof._replace(tables=tuple(tables))
+        return tables, 0
+
+    def drop_resident_tables(self):
+        """Free the score tables the last sweep left on the device (64 MB
+        at 100,000 nodes and K = 71); the next sweep builds, as a first
+        one does. For an owner of several Simulators that bounds what
+        they pin together (svc.worker)."""
+        self._resident_tables = None
 
     def _run_digest(self, state, specs, ev_kind, ev_pod, key, rank) -> str:
         """Content key of one replay run: the engine-source version salt +
@@ -3902,6 +3950,55 @@ def resolve_fault_spec(spec, num_nodes: int, num_events: int):
     )
 
 
+class _ResidentTables(NamedTuple):
+    """The score tables a sweep left on the device and everything their
+    build read, which is what proves them right for another sweep
+    (Simulator._sweep_tables). Not among it, because the build never
+    consumes them: the event stream, the PRNG key (only RandomScore draws
+    from it, and its table slot is zeros), the tie-break rank, the
+    weights and type_id (Simulator._tables_digest)."""
+
+    # what the builder closes over (_TableEngine.closes_over: the policy
+    # kernels, which the policy names, dim_ext_method and norm_method
+    # decide, and the selector index, which gpu_sel_method decides)
+    closes_over: tuple
+    # the initial NodeState's leaves, 9.6 MB at 100,000 nodes. jax arrays
+    # are immutable and the entry holds them, so no id is recycled: `is`
+    # proves equality without reading a byte
+    state_leaves: tuple
+    # host copies of types.share, types.whole (K rows of six i32 fields)
+    # and the typical pods, a few KB together. Their owners rebuild them
+    # (every call its types, Simulator.run_sweep the typical pods), so
+    # they are compared by content
+    rows: tuple
+    tables: tuple = ()
+
+
+def _tables_proof(engine, state, types, typical):
+    """The _ResidentTables of one build's inputs (its tables to come), or
+    None where identity proves nothing: a state leaf that is not an
+    immutable jax array."""
+    state_leaves = tuple(jax.tree.leaves(state))
+    if not all(isinstance(leaf, jax.Array) for leaf in state_leaves):
+        return None
+    rows = tuple(jax.device_get(jax.tree.leaves(
+        (types.share, types.whole, typical))))
+    return _ResidentTables(engine.closes_over, state_leaves, rows)
+
+
+def _same_build(held, proof) -> bool:
+    """Whether the build `proof` describes is the one the entry `held`
+    came from; either may be None (no entry, nothing provable)."""
+    return (
+        held is not None and proof is not None
+        and held.closes_over == proof.closes_over
+        and len(held.state_leaves) == len(proof.state_leaves)
+        and all(a is b for a, b in zip(held.state_leaves, proof.state_leaves))
+        and len(held.rows) == len(proof.rows)
+        and all(map(np.array_equal, held.rows, proof.rows))
+    )
+
+
 class _SweepTraces(NamedTuple):
     """What the host prep of a sweep's traces leaves (_sweep_traces)."""
 
@@ -4134,7 +4231,11 @@ def schedule_pods_sweep(
     cfg.seed). Each lane's placements/counters/metrics are bit-identical
     to a standalone run with that weight vector in the config — same
     kernels, same key splits, vmapped — and the whole batch shares one
-    compiled scan and one (weight-independent) table build. Engine
+    compiled scan and one (weight-independent) set of score tables, which
+    stays on the device for the Simulator's next sweep: a call builds it
+    only where the cluster's initial state, the distinct type set, the
+    typical pods or the scoring kernels are not the last call's
+    (Simulator._sweep_tables; SweepRecord.tables_reused). Engine
     selection mirrors schedule_pods_batch: the table engine unless forced
     sequential or the workload is too small to amortize the table init;
     pallas has no batched form; extenders / mesh / decision-recording /
@@ -4239,20 +4340,15 @@ def schedule_pods_sweep(
         replay_fn = _sweep_replay(sim, use_table, fault_frag)
         args = (ev_kind, ev_pod, sim.typical, keys, weights_d, ranks)
         if use_table:
-            # ONE table build for the whole sweep: the tables hold raw
+            # ONE table set for the whole sweep: the tables hold raw
             # per-policy scores (weight-independent) and init_tables reads
             # only the DISTINCT type set (never type_id), so every lane
-            # shares them bit-identically — through the content-keyed
-            # disk cache when configured (under the type_id-free digest),
-            # else built here once instead of B times under the vmap
+            # shares them bit-identically, and so does the next sweep of
+            # an unchanged cluster and type set: built at most once a
+            # call, and not at all where the last call's still hold
             key0 = jax.random.PRNGKey(seeds[0])
-            tables = sim._cached_tables(state, tr.types, key0)
-            if tables is None:
-                with obs.span("init_tables", cache="sweep-shared") as h:
-                    tables = replay_fn.build_tables(
-                        state, tr.types, sim.typical, key0
-                    )
-                    obs.settle(h, tables)
+            tables, sweep.tables_reused = sim._sweep_tables(
+                replay_fn.engine, state, tr.types, key0)
             engine = replay_fn.engine.replay
             args = (state, tr.specs, tr.types) + args + (tables,)
         else:

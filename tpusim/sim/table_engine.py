@@ -640,6 +640,12 @@ class _TableEngine(NamedTuple):
     run_chunk_donate: object  # run_chunk with the carry donated (ISSUE 11)
     finish: object  # (carry)
     build_tables: object  # (state, types, tp, key) — weight-independent
+    # what build_tables reads beside its operands (make_table_builders:
+    # the policy kernels and the selector index): two engines that agree
+    # here build the same tables from the same operands, whatever else
+    # they differ in (faults, heartbeat, block size). The driver keeps a
+    # sweep's tables on the device under it (Simulator._sweep_tables)
+    closes_over: tuple
 
 
 def _make_table_engine(
@@ -1590,4 +1596,5 @@ def _make_table_engine(
         build_tables=jax.jit(
             lambda state, types, tp, key: _init_tables(state, types, tp, key)
         ),
+        closes_over=(tuple(fn for fn, _ in policies), sel_idx),
     )

@@ -373,6 +373,13 @@ class Worker:
             sim.set_workload_pods(trace.pods)
             sim.set_typical_pods()
             self._sims[key] = sim
+        # a Simulator keeps its last sweep's score tables on the device
+        # (Simulator._sweep_tables: 64 MB at 100,000 nodes), and _sims
+        # grows with the families seen: only the family being served
+        # keeps its set, so a worker pins one set, not one a family
+        for other in self._sims.values():
+            if other is not sim:
+                other.drop_resident_tables()
         # tag scans with this worker's id (obs.heartbeat, ISSUE 12): a
         # fleet's /progress streams say WHICH worker is scanning
         sim._hb_worker = self.worker_id
