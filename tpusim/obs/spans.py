@@ -166,6 +166,13 @@ class SweepRecord:
     # Simulator left on the device (its init_tables span says
     # cache="resident"), 0 where it built or loaded them
     tables_reused: int = 0
+    # distinct traces the specs span prepared: 1 where the lanes share a
+    # trace, fewer than the lanes where some hand over one trace object
+    traces: int = 0
+    # typical-pod sets, and score-table sets, the sweep carried: 1 where
+    # every lane is scored against the Simulator's, F with lanes of F
+    # workload families (its init_tables span's cache counts them)
+    typical_sets: int = 1
 
     @property
     def compiled(self) -> int:
@@ -187,6 +194,8 @@ class SweepRecord:
             "dense_accesses": self.dense_accesses,
             "table_pass_events": self.table_pass_events,
             "tables_reused": self.tables_reused,
+            "traces": self.traces,
+            "typical_sets": self.typical_sets,
             "spans": [s.to_dict() for s in self.spans],
         }
 

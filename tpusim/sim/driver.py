@@ -1099,7 +1099,7 @@ class Simulator:
         )
         return tables
 
-    def _sweep_tables(self, engine, state, types, key):
+    def _sweep_tables(self, engine, state, types, typical, key):
         """((score_tbl, sdev_tbl, feas_tbl), reused) for one sweep of the
         table engine `engine`, under its one init_tables span: the tables
         the last sweep of this Simulator left on the device when they are
@@ -1110,26 +1110,50 @@ class Simulator:
         build once a Simulator and not once a wave (0.62 s of a 2.43 s
         wave at 100,000 nodes, K = 71: PERF.md section 6, PR 31).
 
+        `typical` is what the sweep scores against: the Simulator's set
+        ([T] leaves) or F sets stacked ([F, T], one a family of lanes:
+        schedule_pods_sweep's lane_typical). F sets build F table sets
+        from the one state and type set, stacked [F, ...] like them, and
+        the entry proves and keeps them together: the proof compares the
+        stacked rows by content, so one changed family misses and builds
+        all F, and reused is 1 only when every set's proof holds. The
+        span's `cache` then counts the sets ("resident F of F" | "built F
+        of F"); the disk tier keys the Simulator's own set and is not
+        asked for stacked ones.
+
         The proof runs ahead of the span, whose `cache=` it decides: a
         hit's span holds the hand-over alone. The sweep wrapper broadcasts
         the tables and donates neither them nor the state (_sweep_engine),
         so a wave leaves both intact. A miss is always safe; what cannot
         be proven misses."""
         obs = self.obs
-        proof = _tables_proof(engine, state, types, self.typical)
+        sets = int(typical.cpu.shape[0]) if typical.cpu.ndim == 2 else 0
+        proof = _tables_proof(engine, state, types, typical)
         held = self._resident_tables
         if _same_build(held, proof):
-            with obs.span("init_tables", cache="resident"):
+            cache = f"resident {sets} of {sets}" if sets else "resident"
+            with obs.span("init_tables", cache=cache):
                 obs.count("table_resident_hit")
                 return held.tables, 1
         # one entry a Simulator: the old set goes before the new one is
         # built, so the two never share the device
         self._resident_tables = held = None
-        tables = self._cached_tables(state, types, key)
-        if tables is None:
-            with obs.span("init_tables", cache="sweep-shared") as h:
-                tables = engine.build_tables(state, types, self.typical, key)
+        if sets:
+            with obs.span("init_tables", cache=f"built {sets} of {sets}") as h:
+                built = [
+                    engine.build_tables(
+                        state, types, jax.tree.map(lambda a: a[f], typical),
+                        key)
+                    for f in range(sets)
+                ]
+                tables = tuple(jnp.stack(tbl) for tbl in zip(*built))
                 obs.settle(h, tables)
+        else:
+            tables = self._cached_tables(state, types, key)
+            if tables is None:
+                with obs.span("init_tables", cache="sweep-shared") as h:
+                    tables = engine.build_tables(state, types, typical, key)
+                    obs.settle(h, tables)
         if proof is not None:
             self._resident_tables = proof._replace(tables=tuple(tables))
         return tables, 0
@@ -3433,8 +3457,12 @@ def _sweep_engine(engine, args, keep_streams: bool = False):
     `_cache_size()` is the family's executable count.
 
     in_axes are read off the operands. Key, weights and tie-break rank
-    always carry the lane axis; cluster state, the distinct type set,
-    typical pods and the shared score tables always broadcast. Pod specs
+    always carry the lane axis; cluster state and the distinct type set
+    always broadcast. Typical pods and score tables broadcast where the
+    lanes share one set; stacked a set ([F, T], [F, ...]: lanes of F
+    workload families) `args` ends in one more operand, `lane_set`
+    i32[B], and the wrapper hands each lane its family's before the vmap.
+    Pod specs
     and `types.type_id` follow the traces: stacked when every lane replays
     its own workload (tuned traces are data, not jaxpr structure: nothing
     in an engine reads type_id except as a per-pod gather key). The event
@@ -3462,18 +3490,24 @@ def _sweep_engine(engine, args, keep_streams: bool = False):
         # (state, pods, types, ev_kind, ev_pod, tp, key, wts, rank,
         #  tables[, fault_ops, fault_carry0])
         ev_pod_i, rank_i, plain = 4, 8, 10
+        a_set = (5, 9)  # typical pods, tables
         ev_ax = _lane_axis(args[3], 1)
         tid_ax = _lane_axis(args[2].type_id, 1)
+        set_ax = _lane_axis(args[5].cpu, 1)
         in_axes = (None, trace_ax, PodTypes(None, None, tid_ax), ev_ax,
-                   ev_ax, None, 0, 0, 0, None)
+                   ev_ax, set_ax, 0, 0, 0, set_ax)
     else:
         # (state, pods, ev_kind, ev_pod, tp, key, wts, rank
         #  [, fault_ops, fault_carry0])
         ev_pod_i, rank_i, plain = 3, 7, 8
+        a_set = (4,)  # typical pods
         ev_ax = _lane_axis(args[2], 1)
         tid_ax = None
-        in_axes = (None, trace_ax, ev_ax, ev_ax, None, 0, 0, 0)
-    faulted = len(args) > plain
+        set_ax = _lane_axis(args[4].cpu, 1)
+        in_axes = (None, trace_ax, ev_ax, ev_ax, set_ax, 0, 0, 0)
+    # stacked a set, the typical pods (and tables) bring one LAST operand:
+    # lane_set i32[B], each lane's set
+    faulted = len(args) - (set_ax == 0) > plain
     if faulted:
         in_axes += (FaultOps(0, 0, 0, 0, 0, None), None)
     donate = (rank_i,) + (
@@ -3490,6 +3524,16 @@ def _sweep_engine(engine, args, keep_streams: bool = False):
     ck = (engine, in_axes, donate)
     if ck not in _SWEEP_WRAP_CACHE:
         def swept(*operands):
+            if set_ax == 0:
+                # typical pods and tables stacked a SET ([F, ...], one a
+                # family of lanes): each lane takes its own. The pick is
+                # F selects over the lane axis, no gather (XLA may run one
+                # with an index a lane as a loop over the lanes)
+                *operands, lane_set = operands
+                for i in a_set:
+                    operands[i] = jax.tree.map(
+                        functools.partial(_rows_a_lane, lane_set),
+                        operands[i])
             group = ({"group": flat_group_events(*operands[rank_i].shape)}
                      if grouped else {})
             return jax.vmap(
@@ -3500,6 +3544,17 @@ def _sweep_engine(engine, args, keep_streams: bool = False):
         swept.__name__ = getattr(engine, "__name__", swept.__name__)
         _SWEEP_WRAP_CACHE[ck] = jax.jit(swept, donate_argnums=donate)
     return _SWEEP_WRAP_CACHE[ck]
+
+
+def _rows_a_lane(lane_set, sets):
+    """`sets[lane_set]` for a handful of stacked sets ([F, ...] -> [B,
+    ...]) as F selects over the lane axis: what a sweep does to hand each
+    lane its family's typical pods and score tables, inside its program."""
+    out = jnp.broadcast_to(sets[0], lane_set.shape + sets.shape[1:])
+    mask_shape = lane_set.shape + (1,) * (sets.ndim - 1)
+    for f in range(1, sets.shape[0]):
+        out = jnp.where((lane_set == f).reshape(mask_shape), sets[f], out)
+    return out
 
 
 # (wrapper, lanes) -> (write sites, dense sites, events a table pass) of
@@ -3537,16 +3592,18 @@ def _lane_frag_amounts(state, tp):
 
 
 @functools.lru_cache(maxsize=None)
-def _sweep_metrics_fn(trace_axis):
+def _sweep_metrics_fn(trace_axis, typical_axis=None):
     """compute_event_metrics vmapped over the lanes: ONE cluster, per-lane
     telemetry, of ONE workload (`trace_axis` None) or of the lanes' own
-    specs and event streams (0)."""
+    specs and event streams (0), against ONE typical-pod set or the
+    lanes' own (`typical_axis` 0)."""
     from tpusim.sim.metrics import compute_event_metrics
 
     return jax.jit(
         jax.vmap(
             compute_event_metrics,
-            in_axes=(None, trace_axis, trace_axis, trace_axis, 0, 0, None),
+            in_axes=(None, trace_axis, trace_axis, trace_axis, 0, 0,
+                     typical_axis),
         )
     )
 
@@ -4010,17 +4067,37 @@ class _SweepTraces(NamedTuple):
     e2: int  # the padded event axis
 
 
-def _to_lanes(rows, stacked: bool):
-    """Host rows of one length, one a trace, as ONE device array: stacked a
-    lane, or the one shared row as it is."""
-    return jnp.asarray(np.stack(rows) if stacked else np.asarray(rows[0]))
+def _distinct(objects):
+    """(the distinct objects, first seen first; i32[len] index of each
+    object among them), by identity: what several lanes of a sweep hand
+    over is prepared once."""
+    first = {}
+    for obj in objects:
+        first.setdefault(id(obj), obj)
+    place = {key: i for i, key in enumerate(first)}
+    return list(first.values()), np.asarray(
+        [place[id(obj)] for obj in objects], np.int32)
 
 
-def _sweep_traces(sim, traces, stacked: bool, stable_k: bool, bucket: int,
+def _to_lanes(rows, lane_trace):
+    """Host rows of one length, one a DISTINCT trace, as ONE device array:
+    the one shared row as it is (`lane_trace` None), else stacked a trace,
+    moved once, and picked a lane on the device by the lane-to-trace index
+    (i32[B]; no pick where every lane has a trace of its own)."""
+    if lane_trace is None:
+        return jnp.asarray(np.asarray(rows[0]))
+    stacked = jnp.asarray(np.stack(rows))
+    if np.array_equal(lane_trace, np.arange(len(rows))):
+        return stacked
+    return stacked[lane_trace]
+
+
+def _sweep_traces(sim, traces, lane_trace, stable_k: bool, bucket: int,
                   min_pods: int, min_events: int) -> _SweepTraces:
-    """The host prep of a sweep: specs and events of every trace (a shared
-    trace is a list of one), the padded sizes, the type table, the engine
-    choice, then padding and ONE upload a leaf.
+    """The host prep of a sweep: specs and events of every DISTINCT trace
+    (a shared trace is a list of one and `lane_trace` None; lanes that hand
+    over one trace object twice index it twice), the padded sizes, the
+    type table, the engine choice, then padding and ONE upload a leaf.
 
     `min_pods` / `min_events` are sticky shape floors: below the 512
     bucket the padding targets are size-adaptive, so a service batch of
@@ -4084,16 +4161,28 @@ def _sweep_traces(sim, traces, stacked: bool, stable_k: bool, bucket: int,
         _pad_specs(s, p2, tid, xp=np) for s, tid in zip(specs_l, tids)
     ]
     specs_d = PodSpec(*(
-        _to_lanes([getattr(s, f) for s, _ in padded], stacked)
+        _to_lanes([getattr(s, f) for s, _ in padded], lane_trace)
         for f in PodSpec._fields
     ))
     if not use_table:
         return _SweepTraces(specs_d, None, streams, pods_n, p2, e2)
     types = types._replace(
-        type_id=_to_lanes([tid for _, tid in padded], stacked))
+        type_id=_to_lanes([tid for _, tid in padded], lane_trace))
     if stable_k or p2 != p or e2 != e:
         types = pad_pod_types(types)
     return _SweepTraces(specs_d, types, streams, pods_n, p2, e2)
+
+
+def _stack_typical(sets):
+    """Typical-pod sets of any sizes as ONE TypicalPods with [F, T] leaves,
+    T the largest size on its 16-row bucket: the smaller sets end in
+    zero-frequency rows, which add nothing to any frag amount or score
+    (pad_typical_pods)."""
+    from tpusim.types import TypicalPods
+
+    t = -(-max(int(tp.cpu.shape[0]) for tp in sets) // 16) * 16
+    return TypicalPods(*map(
+        jnp.stack, zip(*(pad_typical_pods(tp, t) for tp in sets))))
 
 
 def _sweep_fault_plans(sim, fault_specs, streams, pods_n, p2: int,
@@ -4220,7 +4309,7 @@ def _slice_fault_lane(out, amounts, i, wrow, seed, p, plan, e_m, gcnt):
 
 def schedule_pods_sweep(
     sim: "Simulator", pods, weights, seeds=None, bucket: int = 512, *,
-    lane_pods=None, fault_specs=None, min_pods: int = 0,
+    lane_pods=None, lane_typical=None, fault_specs=None, min_pods: int = 0,
     min_events: int = 0,
 ) -> List[SweepLane]:
     """Evaluate B what-if configurations in ONE vmapped replay: `weights`
@@ -4250,10 +4339,25 @@ def schedule_pods_sweep(
     streams padded to common buckets and stacked a lane, while the cluster
     state, the DISTINCT type set (concat-dedup across the lanes, the
     dispatch_pods_batch discipline), the typical pods and the once-built
-    score tables still broadcast. Every lane shares the Simulator's
-    cluster, policy family and typical-pod distribution (the service's
-    batching rule — jaxpr identity); `min_pods` / `min_events` are the
-    service's sticky shape floors (_sweep_traces).
+    score tables still broadcast. Host prep goes with the DISTINCT trace
+    objects: lanes that hand over one list twice have it spec'd, padded
+    and moved once, and a lane-to-trace index carries the rest
+    (SweepRecord.traces). Every lane shares the Simulator's cluster and
+    policy family (the service's batching rule — jaxpr identity);
+    `min_pods` / `min_events` are the service's sticky shape floors
+    (_sweep_traces).
+
+    `lane_typical` gives lane i the typical pods it is scored and
+    frag-reported against (a TypicalPods, e.g. the `.typical` of a
+    Simulator built from the lane's own pod list; default: the
+    Simulator's for every lane): lanes of DIFFERENT workload families of
+    one cluster in one sweep. The distinct objects are the sweep's sets:
+    F of them stack to one [F, T] operand (_stack_typical), F table sets
+    are built from the one initial state and union type set and kept on
+    the device together (Simulator._sweep_tables), each lane's carry
+    starts from its family's, and the frag post-pass reads its family's
+    rows (SweepRecord.typical_sets). A lane equals the standalone run of a
+    Simulator holding ITS typical pods.
 
     `fault_specs`: a length-B list of fault schedules — FaultConfig /
     (FaultConfig, events) per resolve_fault_spec, or None for a fault-free
@@ -4292,9 +4396,16 @@ def schedule_pods_sweep(
                 "the chaos sweep replays creation-ordered traces "
                 "(use_timestamps=False)"
             )
-    if sim.typical is None:
+    if lane_typical is not None and len(lane_typical) != b:
+        raise ValueError(
+            f"lane_typical has {len(lane_typical)} typical-pod sets for "
+            f"{b} weight rows (want one TypicalPods per config lane)"
+        )
+    if sim.typical is None and lane_typical is None:
         sim.set_typical_pods()  # a span of its own, before the sweep's
-    lane_trace = list(range(b)) if per_lane else [0] * b  # lane -> trace
+    # lane -> distinct trace; None: every lane replays the one shared trace
+    traces, lane_trace = _distinct(lane_pods) if per_lane else ([pods], None)
+    trace_of = np.zeros(b, np.int32) if lane_trace is None else lane_trace
     state = sim.init_state
 
     # eight flat, back-to-back spans under one sweep record: specs,
@@ -4303,16 +4414,25 @@ def schedule_pods_sweep(
     with obs.sweep(lanes=b) as sweep:
         with obs.span("specs") as h:
             tr = _sweep_traces(
-                sim, lane_pods if per_lane else [pods], per_lane,
-                per_lane or faulted, bucket, min_pods, min_events,
+                sim, traces, lane_trace, per_lane or faulted, bucket,
+                min_pods, min_events,
             )
+            sweep.traces = len(traces)
+            # the typical pods the lanes are scored against: the
+            # Simulator's one set, or a set a family and each lane's index
+            typical, lane_set = sim.typical, ()
+            if lane_typical is not None:
+                sets, index = _distinct(lane_typical)
+                typical = _stack_typical(sets)
+                lane_set = (jnp.asarray(index),)
+                sweep.typical_sets = len(sets)
             plans, fault_args, fault_frag = None, (), None
             if faulted:
                 plans, ev_kind, ev_pod, fault_args, fault_frag = (
                     _sweep_fault_plans(
                         sim, fault_specs,
-                        [tr.streams[t] for t in lane_trace],
-                        [tr.pods[t] for t in lane_trace], tr.p2, bucket,
+                        [tr.streams[t] for t in trace_of],
+                        [tr.pods[t] for t in trace_of], tr.p2, bucket,
                     )
                 )
             else:
@@ -4320,12 +4440,14 @@ def schedule_pods_sweep(
                     _pad_events(kinds, idx, tr.e2, xp=np)
                     for kinds, idx in tr.streams
                 ]
-                ev_kind = _to_lanes([kinds for kinds, _ in padded], per_lane)
-                ev_pod = _to_lanes([idx for _, idx in padded], per_lane)
-            obs.settle(h, tr.specs, ev_kind, ev_pod, tr.types, fault_args)
+                ev_kind = _to_lanes(
+                    [kinds for kinds, _ in padded], lane_trace)
+                ev_pod = _to_lanes([idx for _, idx in padded], lane_trace)
+            obs.settle(h, tr.specs, ev_kind, ev_pod, tr.types, fault_args,
+                       typical)
         # what a lane replays of its trace, less padding, merged fault
         # steps and retries
-        lane_events = [len(tr.streams[t][0]) for t in lane_trace]
+        lane_events = [len(tr.streams[t][0]) for t in trace_of]
         sweep.events, true_events = max(lane_events), sum(lane_events)
         steps = int(ev_kind.shape[-1])  # of the scan, padding included
         with obs.span("lane_keys") as h:
@@ -4338,23 +4460,24 @@ def schedule_pods_sweep(
 
         use_table = tr.types is not None
         replay_fn = _sweep_replay(sim, use_table, fault_frag)
-        args = (ev_kind, ev_pod, sim.typical, keys, weights_d, ranks)
+        args = (ev_kind, ev_pod, typical, keys, weights_d, ranks)
         if use_table:
-            # ONE table set for the whole sweep: the tables hold raw
-            # per-policy scores (weight-independent) and init_tables reads
-            # only the DISTINCT type set (never type_id), so every lane
-            # shares them bit-identically, and so does the next sweep of
-            # an unchanged cluster and type set: built at most once a
-            # call, and not at all where the last call's still hold
+            # ONE table set for the lanes of a typical-pod set: the tables
+            # hold raw per-policy scores (weight-independent) and
+            # init_tables reads only the DISTINCT type set (never
+            # type_id), so those lanes share them bit-identically, and so
+            # does the next sweep of an unchanged cluster and type set:
+            # built at most once a call, and not at all where the last
+            # call's still hold
             key0 = jax.random.PRNGKey(seeds[0])
             tables, sweep.tables_reused = sim._sweep_tables(
-                replay_fn.engine, state, tr.types, key0)
+                replay_fn.engine, state, tr.types, typical, key0)
             engine = replay_fn.engine.replay
             args = (state, tr.specs, tr.types) + args + (tables,)
         else:
             engine = replay_fn.engine
             args = (state, tr.specs) + args
-        args += fault_args
+        args += fault_args + lane_set
         # the post-pass re-reads the event streams; a fault sweep has none
         # (its per-event rows would index the merged stream)
         report = cfg.report_per_event and not faulted
@@ -4381,11 +4504,16 @@ def schedule_pods_sweep(
             f"(stream {steps}) ran on: {sim._last_engine}"
         )
         with obs.span("frag_postpass") as h:
+            # each lane against the typical pods it was scored with: its
+            # family's rows of a stacked set (a few KB a lane)
+            tp_ax = 0 if lane_set else None
+            if lane_set:
+                typical = jax.tree.map(lambda a: a[lane_set[0]], typical)
             if report:
                 out = out._replace(
-                    metrics=_sweep_metrics_fn(_lane_axis(ev_kind, 1))(
+                    metrics=_sweep_metrics_fn(_lane_axis(ev_kind, 1), tp_ax)(
                         state, tr.specs, ev_kind, ev_pod,
-                        out.event_node, out.event_dev, sim.typical,
+                        out.event_node, out.event_dev, typical,
                     )
                 )
             # per-lane frag of the final states in one vmapped call (the same
@@ -4393,8 +4521,8 @@ def schedule_pods_sweep(
             # The jit wraps a new function object in every call, so dispatch
             # here is a trace, a lowering and a compile or a cache load.
             amounts = jax.jit(
-                jax.vmap(_lane_frag_amounts, in_axes=(0, None))
-            )(out.state, sim.typical)
+                jax.vmap(_lane_frag_amounts, in_axes=(0, tp_ax))
+            )(out.state, typical)
             obs.settle(h, amounts, out.metrics)
         with obs.span("fetch", events=true_events):
             out = device_fetch(out)
@@ -4408,14 +4536,14 @@ def schedule_pods_sweep(
                         out, amounts, i, w[i], seeds[i], tr.pods[t],
                         plans[i], steps, gcnt,
                     )
-                    for i, t in enumerate(lane_trace)
+                    for i, t in enumerate(trace_of)
                 ]
             return [
                 _slice_sweep_lane(
                     out, amounts, i, w[i], seeds[i], tr.pods[t],
                     lane_events[i], steps - lane_events[i],
                 )
-                for i, t in enumerate(lane_trace)
+                for i, t in enumerate(trace_of)
             ]
 
 
