@@ -1,7 +1,8 @@
 """Helpers for tests that look at the sweep's program without running it:
 capture the jitted vmapped replay with the shapes a cell calls it on, list
 the large `copy` operations of a compiled module's scan, its loops and how
-they nest, and the operations of a loop that produce a given shape."""
+they nest, and the operations of a loop that produce, or read, a given
+shape."""
 
 import json
 import math
@@ -148,6 +149,25 @@ def producers_in(text: str, root: str, shape: str) -> list:
             if m and re.search(shape, m.group(2)) and m.group(3) not in (
                     "parameter", "get-tuple-element", "tuple", "bitcast"):
                 found.append((name, m.group(1), m.group(3)))
+    return found
+
+
+def fusions_reading(text: str, root: str, shape: str) -> list:
+    """(computation, name, result shape) of every fusion in `root` and
+    the computations it calls whose fused computation takes a parameter
+    that matches the regular expression `shape`: what reads an array of
+    that shape there, and what it makes of it."""
+    comps, found = _computations(text), []
+    for name in sorted(_reachable(comps, root)):
+        for line in comps[name]:
+            m = _INSTRUCTION.match(line)
+            if not m or m.group(3) != "fusion":
+                continue
+            fused = comps[re.search(r"calls=%?([\w.\-]+)", line).group(1)]
+            if any(p and p.group(3) == "parameter"
+                   and re.search(shape, p.group(2))
+                   for p in map(_INSTRUCTION.match, fused)):
+                found.append((name, m.group(1), m.group(2).strip()))
     return found
 
 

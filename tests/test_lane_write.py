@@ -213,6 +213,62 @@ def test_read_entry_equals_vmap_of_the_plain_slice(leaf, pattern, size):
     _same(lw.read_entry(*one), plain(*one))
 
 
+@pytest.mark.parametrize("rows", ["a row a lane", "one shared row"])
+@pytest.mark.parametrize("leaf", ["sdev", "feas"])
+def test_a_dense_read_entry_takes_the_row_then_the_entry(leaf, rows):
+    """On a short leaf the entry is picked out of the lane's ROW, [L, N]:
+    the row the lanes share sliced, a row a lane (a sweep of a trace a
+    lane, ISSUE 33) gathered as the step gathers `feas_tbl[t_id]`. No
+    reduction runs over the whole [L, K, N] table, and the node index is
+    one a lane either way."""
+    n, n_pad = SIZES["short"]
+    tbl, _ = COLUMN_LEAVES[leaf](n_pad)
+    args, axes = _axes(
+        (tbl, jnp.asarray([0, K - 1, 2, 2, 5], jnp.int32), _node_idx(n)),
+        (True, rows == "a row a lane", True))
+    plain = lambda t, r, c: lax.dynamic_slice(  # noqa: E731
+        t, (r, c), (1, 1))[0, 0]
+    fn = jax.jit(jax.vmap(lw.read_entry, in_axes=axes))
+    _same(fn(*args), jax.vmap(plain, in_axes=axes)(*args))
+    kind = "i1" if leaf == "feas" else "i32"
+    text = fn.lower(*args).as_text()
+    reduces = [ln for ln in text.splitlines() if "stablehlo.reduce" in ln]
+    assert len(reduces) == 1 and f"tensor<{L}x{n_pad}x{kind}>" in reduces[0]
+    assert f"tensor<{L}x{K}x{n_pad}x{kind}>" not in reduces[0], reduces
+    row_reads = [ln for ln in text.splitlines()
+                 if f"(tensor<{L}x{K}x{n_pad}x{kind}>" in ln]
+    # one window of a row: across the lanes, or one a lane
+    window = f"1, 1, {n_pad}" if rows == "a row a lane" else f"{L}, 1, {n_pad}"
+    assert len(row_reads) == 1 and "stablehlo.gather" in row_reads[0]
+    assert f"slice_sizes = array<i64: {window}>" in row_reads[0], row_reads
+
+
+PENDING = {"sdev": lambda: _ints((L, 4, K), -1, 8),
+           "feas": lambda: _bools((L, 4, K))}
+
+
+@pytest.mark.parametrize("pattern", sorted(READ_PATTERNS))
+@pytest.mark.parametrize("leaf", sorted(PENDING))
+def test_read_pending_equals_vmap_of_the_plain_slice(leaf, pattern):
+    """Column `row` of a pending block [G, K]: the slice it always was,
+    and with a row a lane a masked reduction over K, no gather (which
+    would have the block carried slots-minor: ISSUE 33)."""
+    rows = jnp.asarray([0, K - 1, 2, 2, 5], jnp.int32)
+    args, axes = _axes((PENDING[leaf](), rows), READ_PATTERNS[pattern])
+    plain = lambda c, r: lax.dynamic_index_in_dim(  # noqa: E731
+        c, r, 1, keepdims=False)
+    fn = jax.jit(jax.vmap(lw.read_pending, in_axes=axes))
+    with lw.counting() as sites:
+        text = fn.lower(*args).as_text()
+    _same(fn(*args), jax.vmap(plain, in_axes=axes)(*args))
+    one = _one_lane(args, axes)
+    _same(lw.read_pending(*one), plain(*one))
+    a_lane = READ_PATTERNS[pattern][1]
+    assert (len(sites), len(sites.dense)) == (0, 1 if a_lane else 0)
+    assert ("stablehlo.reduce" in text) == a_lane
+    assert "stablehlo.gather" not in text or not a_lane
+
+
 # ------------------------------------------------------- the rule's shape
 def _step(tbl, left, col, idx, delta):
     tbl, blk = lw.write_column(tbl, col, idx, block=((idx // BSZ) * BSZ, BSZ))

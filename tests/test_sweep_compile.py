@@ -2,8 +2,9 @@
 the TPU's compiler is installed; nothing runs): the blocked scan of
 synth100k may hold no copy of a whole carried array (ISSUE 27), the flat
 scan of openb no loop over the lanes (ISSUE 28) and no whole-table
-operation inside its per-event step (ISSUE 29). tests/test_tpu.py holds the
-same checks on the chip itself."""
+operation inside its per-event step (ISSUE 29), with one shared trace or
+with a trace a lane (ISSUE 33). tests/test_tpu.py holds the same checks on
+the chip itself."""
 
 import re
 
@@ -18,6 +19,11 @@ NODES, LANES, DEPTH = 100_000, 40, 512
 # compiles in 9 s, the cell's 2,560 x 512 in 30 s; from 256 lanes the
 # compiler no longer keeps a whole table in fast memory between steps
 OPENB_LANES, OPENB_DEPTH = 256, 64
+# with a trace a lane the rows are read by a gather, and at 256 lanes the
+# compiler brings the whole 40 MB table into fast memory for it inside the
+# per-event loop; from 1,024 (159 MB a table, over the chip's 128 MiB) it
+# reads the rows from HBM, as it must with the family cell's 1.16 GB
+LANE_TRACE_LANES = 1024
 
 
 @pytest.fixture(scope="module")
@@ -88,7 +94,26 @@ def test_cell_sized_sweep_compiles_without_whole_carry_copies(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
 
 
-def test_the_openb_flat_sweep_loops_over_events_only(one_chip):
+def _lane_operands(operands, sim, trace, lanes):
+    """The sweep's keywords for `operands`: copies of the trace a lane,
+    and with "typical pods a family" two typical-pod sets, lanes in turn."""
+    if operands == "one shared trace":
+        return {}
+    kw = {"lane_pods": [trace] * lanes}
+    if operands == "typical pods a family":
+        from tpusim.sim.typical import pad_typical_pods
+        from tpusim.types import make_typical_pods
+
+        other = pad_typical_pods(make_typical_pods(
+            [(4000 + 100 * i, 250 + 10 * i, 1, 0, 1 / 70) for i in range(70)]))
+        assert (sim.typical.cpu.shape, other.cpu.shape) == ((48,), (80,))
+        kw["lane_typical"] = [sim.typical, other] * (lanes // 2)
+    return kw
+
+
+@pytest.mark.parametrize("operands", [
+    "one shared trace", "a trace a lane", "typical pods a family"])
+def test_the_openb_flat_sweep_loops_over_events_only(one_chip, operands):
     """1,213 nodes on the flat step body: XLA runs a scatter or a gather
     with one index row a lane as a `while` over the lanes (PR 27's program
     held 21 at 128 lanes and 32 at the cell's 2,560 lanes x 512 events,
@@ -97,27 +122,57 @@ def test_the_openb_flat_sweep_loops_over_events_only(one_chip):
     FLAT_GROUP_EVENTS events and, inside it, the scan over a group's
     events. The step inside the inner loop produces no array of a whole
     table's shape (its column goes into the pending block); the flush in
-    the outer loop is one fusion a table, written in place."""
+    the outer loop is one fusion a table, written in place.
+
+    With a trace a lane (ISSUE 33; pods, type ids and event streams carry
+    the lane axis, the bookkeeping rows' writes and the three picks out of
+    the pending block take the dense form: 31 dense sites where the shared
+    trace has 22) it is the same two loops: what reads a table inside the
+    per-event loop is a row gather a lane (`feas_tbl[t_id]`,
+    `score_tbl[i, t_id]`, and read_entry's row of `sdev_tbl`),
+    [lanes, K, N] -> [lanes, N], never a reduction of the whole table to
+    [lanes]. Lanes of two workload families (ISSUE 32:
+    typical pods and score tables stacked a SET and a set index a lane)
+    are the same program with two more picks in front of the scan: each
+    lane's rows of the stacked sets are selects over the lane axis, no
+    loop."""
     from tpusim.sim.table_engine import FLAT_GROUP_EVENTS
 
     sim, trace, cfg = sweep_program.cell_simulator(
         None, OPENB_DEPTH, config="openb")
     assert len(sim.nodes) == 1213
+    own = operands != "one shared trace"
+    families = operands == "typical pods a family"
+    lanes = LANE_TRACE_LANES if own else OPENB_LANES
     with lane_write.counting() as sites:
         fn, shapes, _ = sweep_program.capture_sweep(
-            sim, trace, sweep_program.cell_weights(cfg, OPENB_LANES),
-            list(range(OPENB_LANES)))
+            sim, None if own else trace,
+            sweep_program.cell_weights(cfg, lanes), list(range(lanes)),
+            **_lane_operands(operands, sim, trace, lanes))
         shapes = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                            sharding=one_chip), shapes)
         lowered = fn.lower(*shapes)
-    assert len(sites) == 17 and len(sites.dense) == 22
+    # counted while the program is traced: the epilogue's jit (`finish`,
+    # seven writes, all dense) is served from the process's cache where "a
+    # trace a lane" has traced it on the same shapes before
+    assert (len(sites), len(sites.dense)) in (
+        ((17, 31), (10, 24)) if families else ((17, 31 if own else 22),))
     assert sites.table_pass_events == FLAT_GROUP_EVENTS
     assert OPENB_DEPTH % FLAT_GROUP_EVENTS == 0  # no tail group to trace
+    assert shapes[1].cpu.shape == (
+        (lanes, OPENB_DEPTH) if own else (OPENB_DEPTH,))
+    assert len(shapes[3].shape) == (2 if own else 1)  # a stream a lane
+    if families:
+        # two sets on the larger one's bucket, two table sets, a set a lane
+        assert shapes[5].cpu.shape == (2, 80)
+        assert shapes[9][0].shape[:2] == (2, 1)
+        assert shapes[9][1].shape[0] == 2 and shapes[9][2].shape[0] == 2
+        assert shapes[-1].shape == (lanes,)
     compiled = lowered.compile()
     text = compiled.as_text()
-    k = shapes[9][0].shape[1]  # the trace's pod types at this depth
-    table = rf"\[{OPENB_LANES},(1,)?{k},1213\]"
+    k = shapes[9][0].shape[-2]  # the trace's pod types at this depth
+    table = rf"\[{lanes},(1,)?{k},1213\]"
 
     # the loops: one over the groups (it carries the node state and the
     # tables), one over a group's events inside it; none over the lanes
@@ -127,21 +182,31 @@ def test_the_openb_flat_sweep_loops_over_events_only(one_chip):
     (inner,) = [b for b, holder in bodies.items() if holder == outer]
     assert len(loops) == 2, loops
     for _, _, carried in loops:
-        assert f"s32[{OPENB_LANES},1213,9]" in carried  # the scan's carry
+        assert f"s32[{lanes},1213,9]" in carried  # the scan's carry
     held = {holder: carried for holder, _, carried in loops}
     assert re.search(rf"s32{table}", held[bodies[outer]])
-    assert f"s32[{OPENB_LANES},{FLAT_GROUP_EVENTS},{k}]" in held[outer]
+    assert f"s32[{lanes},{FLAT_GROUP_EVENTS},{k}]" in held[outer]
 
     # no whole-table operation inside the per-event step
     assert not sweep_program.producers_in(text, inner, table)
+    if own:
+        # what takes a table in there is a row gather, [lanes, N] out: the
+        # step's three reads an event (the scan unrolls by 4), none a
+        # reduction of the table to [lanes]. (The lanes of a shared trace
+        # slice their one row inside whatever fusion reads it.)
+        reads = sweep_program.fusions_reading(text, inner, table)
+        assert len(reads) == 3 * 4, reads
+        for _, name, out in reads:
+            assert re.match(rf"(s32|pred)\[{lanes},1213\]", out), (name, out)
     # the flush: one select-chain fusion a table, in the outer loop only
     flush = [(c, n, op) for c, n, op in sweep_program.producers_in(
         text, outer, table) if op == "fusion"]
     assert len(flush) == 3 and {c for c, _, _ in flush} == {outer}, flush
-    # written in place: the temporaries hold the tables (4 + 4 + 1 bytes an
-    # entry) once, not twice
-    tables = OPENB_LANES * k * 1213 * 9
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * tables
+    if not own:
+        # written in place: the temporaries hold the tables (4 + 4 + 1
+        # bytes an entry) once, not twice
+        tables = lanes * k * 1213 * 9
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * tables
 
 
 def _fault_specs(lanes):
@@ -153,57 +218,28 @@ def _fault_specs(lanes):
         queue_capacity=8) for i in range(lanes)]
 
 
-@pytest.mark.parametrize("operands", [
-    "a trace a lane", "a fault plan a lane", "typical pods a family"])
+@pytest.mark.parametrize("operands", ["a fault plan a lane"])
 def test_the_plain_flat_sweeps_loop_over_events_only(one_chip, operands):
-    """The sweeps that keep the plain flat body (driver._sweep_engine: a
-    trace a lane, or fault plans) hold ONE loop, the scan over the events,
-    and none over the lanes, although pods, type ids and event streams
-    carry the lane axis: `feas_tbl[t_id]` and `score_tbl[i, t_id]` become
-    a gather a lane, and the bookkeeping rows' writes, whose index the
-    lanes no longer share, take the dense form (28 dense sites where the
-    shared trace has 22). One dense column write an event, no group.
-    Lanes of two workload families (ISSUE 32: a trace a lane, typical pods
-    and score tables stacked a SET and a set index a lane) are the same
-    program with two more picks in front of the scan: each lane's rows of
-    the stacked sets are selects over the lane axis, no loop."""
+    """The sweep that keeps the plain flat body (driver._sweep_engine:
+    fault plans) holds ONE loop, the scan over the events, and none over
+    the lanes, although its event streams carry the lane axis: the
+    bookkeeping rows' writes, whose index the lanes no longer share, take
+    the dense form (28 dense sites where the shared trace has 22). One
+    dense column write an event, no group."""
     sim, trace, cfg = sweep_program.cell_simulator(
         None, OPENB_DEPTH, config="openb")
-    own = operands != "a fault plan a lane"
-    kw = ({"lane_pods": [trace] * OPENB_LANES} if own
-          else {"fault_specs": _fault_specs(OPENB_LANES)})
-    families = operands == "typical pods a family"
-    if families:
-        from tpusim.sim.typical import pad_typical_pods
-        from tpusim.types import make_typical_pods
-
-        other = pad_typical_pods(make_typical_pods(
-            [(4000 + 100 * i, 250 + 10 * i, 1, 0, 1 / 70) for i in range(70)]))
-        assert (sim.typical.cpu.shape, other.cpu.shape) == ((48,), (80,))
-        kw["lane_typical"] = [sim.typical, other] * (OPENB_LANES // 2)
     with lane_write.counting() as sites:
         fn, shapes, _ = sweep_program.capture_sweep(
-            sim, None if own else trace,
-            sweep_program.cell_weights(cfg, OPENB_LANES),
-            list(range(OPENB_LANES)), **kw)
+            sim, trace, sweep_program.cell_weights(cfg, OPENB_LANES),
+            list(range(OPENB_LANES)),
+            fault_specs=_fault_specs(OPENB_LANES))
         shapes = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                            sharding=one_chip), shapes)
         lowered = fn.lower(*shapes)
-    # counted while the program is traced: the epilogue's jit (`finish`,
-    # seven writes, all dense) is served from the process's cache where "a
-    # trace a lane" has traced it on the same shapes before
-    assert (len(sites), len(sites.dense)) in (
-        ((17, 28), (10, 21)) if families else ((17, 28),))
+    assert (len(sites), len(sites.dense)) == (17, 28)
     assert sites.table_pass_events == 1
-    assert shapes[1].cpu.shape == (
-        (OPENB_LANES, OPENB_DEPTH) if own else (OPENB_DEPTH,))
-    assert shapes[3].shape[0] == OPENB_LANES  # a stream a lane, both
-    if families:
-        # two sets on the larger one's bucket, two table sets, a set a lane
-        assert shapes[5].cpu.shape == (2, 80)
-        assert shapes[9][0].shape[:2] == (2, 1)
-        assert shapes[9][1].shape[0] == 2 and shapes[9][2].shape[0] == 2
-        assert shapes[-1].shape == (OPENB_LANES,)
+    assert shapes[1].cpu.shape == (OPENB_DEPTH,)
+    assert shapes[3].shape[0] == OPENB_LANES  # a stream a lane
     (loop,) = sweep_program.while_loops(lowered.compile().as_text())
     assert f"s32[{OPENB_LANES},1213,9]" in loop[2]  # the scan's carry

@@ -36,17 +36,18 @@ def pod_csv(family):
 class Wave:
     """2 families x 2 shuffles x 2 seeds on a 96-node cut of openb."""
 
-    def __init__(self):
+    def __init__(self, shuffles=SHUFFLES, per_shuffle=PER_SHUFFLE,
+                 depth=DEPTH):
         self.nodes = load_node_csv(inputs.NODE_CSV)[:NODES]
         self.pod_lists = [load_pod_csv(pod_csv(f)) for f in FAMILIES]
-        cfg = wave.simulator_config(SIM, SHUFFLES[0], profile=False)
+        cfg = wave.simulator_config(SIM, shuffles[0], profile=False)
         self.sims = [wave.build_simulator(self.nodes, pods, cfg)
                      for pods in self.pod_lists]
-        self.traces = [[sim.prepare_pods(tuning_seed=s)[:DEPTH]
-                        for s in SHUFFLES] for sim in self.sims]
+        self.traces = [[sim.prepare_pods(tuning_seed=s)[:depth]
+                        for s in shuffles] for sim in self.sims]
         self.lane_of = [(f, s) for f in range(len(FAMILIES))
-                        for s in range(len(SHUFFLES))
-                        for _ in range(PER_SHUFFLE)]
+                        for s in range(len(shuffles))
+                        for _ in range(per_shuffle)]
         self.lane_pods = [self.traces[f][s] for f, s in self.lane_of]
         self.lane_typical = [self.sims[f].typical for f, _ in self.lane_of]
         lanes = len(self.lane_of)
@@ -86,10 +87,50 @@ def test_the_families_typical_sets_differ_in_size(fam):
     assert (fam.rec.typical_sets, fam.rec.traces, fam.rec.lanes) == (2, 4, 8)
     assert fam.rec.to_dict()["typical_sets"] == 2
     assert fam.cache == "built 2 of 2" and fam.rec.tables_reused == 0
-    # a trace a lane keeps the plain flat body: one dense column write an
-    # event, 28 dense sites
+    # 8 lanes are under FLAT_GROUP_MIN_LANES: the plain flat body, one
+    # dense column write an event; a trace a lane has 28 dense sites
     assert "table" in fam.rec.engine
     assert (fam.rec.table_pass_events, fam.rec.dense_accesses) == (1, 28)
+
+
+def test_a_wide_wave_of_families_runs_grouped_and_equals_the_plain_body(
+        monkeypatch):
+    """From FLAT_GROUP_MIN_LANES lanes a trace a lane runs the grouped
+    flat body (ISSUE 33): 64 lanes of eight different traces and two
+    typical-pod sets, 42 events (two whole groups and a tail of 10), equal
+    the same sweep on the plain body (`replay(..., group=1)`) in every
+    leaf, where two events of one group land on one node (the later
+    pending column must win in the patch and in the flush)."""
+    from tpusim.sim import driver, table_engine
+    from tpusim.sim.table_engine import (
+        FLAT_GROUP_EVENTS, FLAT_GROUP_MIN_LANES)
+
+    depth = 2 * FLAT_GROUP_EVENTS + 10
+    w = Wave(shuffles=(42, 43, 44, 45), per_shuffle=8, depth=depth)
+    assert len(w.lane_of) == FLAT_GROUP_MIN_LANES
+    grouped, rec, _ = w.sweep()
+    assert (rec.lanes, rec.events, rec.traces, rec.typical_sets) == (
+        FLAT_GROUP_MIN_LANES, depth, 8, 2)
+    # 28 dense sites as on the plain body, and the three picks out of the
+    # pending block (lane_write.read_pending: feas, score, sdev)
+    assert (rec.table_pass_events, rec.dense_accesses) == (
+        FLAT_GROUP_EVENTS, 31)
+
+    # the plain body: the rule says 1, the wrapper is traced anew
+    monkeypatch.setattr(table_engine, "flat_group_events", lambda *_: 1)
+    monkeypatch.setattr(driver, "_SWEEP_WRAP_CACHE", {})
+    plain, rec, _ = w.sweep()
+    assert rec.table_pass_events == 1
+    # placed_node, dev_mask, ever_failed, every NodeState field, counters
+    assert_lanes_equal(grouped, plain)
+
+    def lands_twice(lane):  # in one group, on one node
+        first = lane.placed_node[:FLAT_GROUP_EVENTS]
+        first = first[first >= 0]
+        return len(set(first.tolist())) < len(first)
+
+    assert any(map(lands_twice, grouped))
+    assert len({lane.placed_node.tobytes() for lane in grouped}) > 8
 
 
 @pytest.mark.parametrize("lane", range(8))

@@ -2,8 +2,9 @@
 replays the shared trace or its own, under a fault plan or none. All four
 combinations run through one wrapper factory (driver._sweep_engine, which
 reads the vmap axes off the operands), one host prep, one dispatch and one
-tail, and leave one SweepRecord; only the shared fault-free sweep runs the
-flat step in groups."""
+tail, and leave one SweepRecord; the fault-free sweeps, of one shared trace
+or of one a lane, run the flat step in groups (ISSUE 33), fault plans keep
+the plain body."""
 
 import jax
 import numpy as np
@@ -84,15 +85,16 @@ def test_every_sweep_leaves_one_record_of_eight_spans(swept, combo):
 
 
 @pytest.mark.parametrize("combo", COMBOS)
-def test_only_the_shared_fault_free_sweep_runs_grouped(swept, combo):
-    """The flat group stays where the chip judged it: one shared trace, no
-    fault operands. Per-lane traces and fault plans keep the plain body,
-    one dense column write an event."""
+def test_the_fault_free_sweeps_run_grouped_and_fault_plans_plain(
+        swept, combo):
+    """The flat group is where the chip judged it: no fault operands, one
+    shared trace (PR 29) or a trace a lane (PR 33: type ids one a lane).
+    Fault plans keep the plain body, one dense column write an event."""
     rec = swept[combo][0].obs.sweeps[-1]
-    if combo == "shared":
-        assert rec.table_pass_events == FLAT_GROUP_EVENTS
+    if combo.endswith("faults"):
+        assert rec.table_pass_events == 1
     else:
-        assert 0 < rec.table_pass_events <= 1
+        assert rec.table_pass_events == FLAT_GROUP_EVENTS
 
 
 @pytest.mark.parametrize("faults", ["", "+faults"])

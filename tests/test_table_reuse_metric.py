@@ -61,11 +61,20 @@ def compile_cache_put_back():
 @pytest.mark.parametrize("cell", ["synth100k.fgd-seeds", "openb.fgd-seeds"])
 def test_every_wave_of_a_tiny_cells_window_reuses_the_tables(
         bench_run, cell, capsys, compile_cache_put_back):
-    assert bench_run.main([
-        "--workload", cell, "--seed", "3000000019", "--seconds", "0.5",
-        "--trace", "1", "--rehearse"]) == 0
-    got = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert got["correct"] is True and got["failed"] == 0
+    span_metric = ("table_build_s" if cell == "synth100k.fgd-seeds"
+                   else METRIC)
+    for _ in range(3):
+        assert bench_run.main([
+            "--workload", cell, "--seed", "3000000019", "--seconds", "0.5",
+            "--trace", "1", "--rehearse"]) == 0
+        got = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert got["correct"] is True and got["failed"] == 0
+        # a tiny wave is milliseconds: with the suite's other workers on
+        # the cores, one preemption between the driver's clock and the
+        # record's puts a wall outside sweep_log's 1 % and every span
+        # metric reads as nothing (seen once in two whole runs, PR 33)
+        if span_metric in got["metrics"]:
+            break
     if cell == "synth100k.fgd-seeds":
         # still the init_tables span: the hand-over now, not a build
         assert 0 < got["metrics"]["table_build_s"]["value"] < 0.01

@@ -3502,7 +3502,6 @@ def _sweep_engine(engine, args, keep_streams: bool = False):
         ev_pod_i, rank_i, plain = 3, 7, 8
         a_set = (4,)  # typical pods
         ev_ax = _lane_axis(args[2], 1)
-        tid_ax = None
         set_ax = _lane_axis(args[4].cpu, 1)
         in_axes = (None, trace_ax, ev_ax, ev_ax, set_ax, 0, 0, 0)
     # stacked a set, the typical pods (and tables) bring one LAST operand:
@@ -3513,13 +3512,15 @@ def _sweep_engine(engine, args, keep_streams: bool = False):
     donate = (rank_i,) + (
         (ev_pod_i,) if ev_ax == 0 and not keep_streams else ())
     # A wide sweep of a short cluster runs the flat step in groups
-    # (flat_group_events of the stacked ranks' [lanes, nodes]) exactly
-    # where the chip judged it (PERF.md section 6, PR 29): the table
-    # engine, one shared trace (type ids not batched), no fault operands.
-    # Per-lane type ids turn the group's patched reads into a gather a
-    # lane and no run has grouped a fault plan's steps: both keep the
-    # plain body until a cell or a chip run says otherwise (section 7)
-    grouped = table and tid_ax is None and not faulted
+    # (flat_group_events of the stacked ranks' [lanes, nodes]) where the
+    # chip judged it: the table engine with no fault operands, one shared
+    # trace (PERF.md section 6, PR 29) or a trace a lane (PR 33: type ids
+    # one a lane; the rows are row gathers, the picks out of the pending
+    # block lane_write.read_pending's dense form, and the program holds no
+    # loop over the lanes). No run has grouped a fault plan's steps: they
+    # keep the plain body until a cell or a chip run says otherwise
+    # (section 7)
+    grouped = table and not faulted
 
     ck = (engine, in_axes, donate)
     if ck not in _SWEEP_WRAP_CACHE:

@@ -46,7 +46,13 @@ mask on the node axis and no `scatter` or `gather` at all:
   event stream, stays the single update across the lanes vmap derives);
 - a read is the masked reduction over the node axis (a sum; an `any` for a
   `bool` leaf), the block `write_column` returns the masked reduction over
-  the block axis of the table viewed as whole blocks.
+  the block axis of the table viewed as whole blocks. `read_entry` takes
+  its ROW of the [K, N] table first and reduces over that row's nodes: a
+  slice where the lanes share the row (one event stream), with a row a
+  lane (a sweep of a trace a lane) the row gather the step's own
+  `feas_tbl[t_id]` is, which fuses and is no loop; never a reduction over
+  [lanes, K, N] (0.2 s an event's read at 600 lanes x K = 400; PERF.md
+  section 6, PR 33).
 
 A dense write is a pass over the whole leaf whatever it writes, so at sweep
 width (`table_engine.flat_group_events`) the flat step body does not write
@@ -59,6 +65,11 @@ s = 0..B-1, the node compare made once per (lane, node). Until the group is
 written, `patch_row` and `patch_entry` give a row or an entry of the table
 as it will read afterwards (the same chain over `[lanes, N]`, which fuses
 into the pass that reads the row); they are elementwise and need no rule.
+What the slots hold for that row comes from `read_pending(cols, row)`: the
+slice of the pending block [G, K] at `row`, and with a row a lane a masked
+reduction over K, for the block's sake and not the read's: a gather a lane
+makes XLA carry the block with its slot axis minor-most, and then every
+event's store of one slot rewrites the whole padded block.
 
 Which form an access takes follows from the leaf's static shape alone
 (`_short`): no option selects it.
@@ -318,26 +329,47 @@ def patch_entry(val, col, idxs, vals):
     return val
 
 
+def read_pending(cols, row):
+    """cols[:, row] of a pending block [G, K] (table_engine.LateColumns):
+    what the group's G slots hold for table row `row`, the `vals` of
+    patch_row and patch_entry. With a row a lane (a sweep of a trace a
+    lane) it is a masked reduction over K, whatever the nodes: the block
+    is small (G x K entries a lane), and a gather a lane would have the
+    block carried slots-minor, which makes every slot's store a pass over
+    the whole padded block (1.7 s of a 6.84 s scan at 600 lanes x K = 400;
+    PERF.md section 6, PR 33). A row the lanes share stays the slice."""
+    k = cols.shape[1]
+
+    def expr(cols, row):
+        return lax.dynamic_index_in_dim(cols, row, 1, keepdims=False)
+
+    def dense(cols, row):
+        return _picked(cols, _one_hot(k, row), axis=1)
+
+    return _lane_batched(
+        expr, expr, write=False,
+        dense=lambda in_batched: dense if in_batched[1] else None,
+    )(cols, row)
+
+
 def read_entry(tbl, row, col):
     """tbl[row, col] of a [K, N] table, as the step bodies slice it."""
-    k, n = tbl.shape
+    n = tbl.shape[1]
 
     def expr(tbl, row, col):
         return lax.dynamic_slice(tbl, (row, col), (1, 1))[0, 0]
 
-    def dense(in_batched):
-        if not in_batched[1]:
-            # the lanes share the row (one event stream): a slice of the
-            # row axis, then one pass over [lanes, N], not [lanes, K, N]
-            return lambda tbl, row, col: _picked(
-                lax.dynamic_index_in_dim(tbl, row, 0, keepdims=False),
-                _one_hot(n, col), axis=0)
-        return lambda tbl, row, col: _picked(
-            tbl, _one_hot(k, row, 1) & _one_hot(n, col), axis=(0, 1))
+    def dense(tbl, row, col):
+        # the row first, then one pass over [lanes, N], never [lanes, K, N]:
+        # a slice of the row axis where the lanes share the row (one event
+        # stream), and with a row a lane (a trace a lane) the row gather
+        # the step's own `feas_tbl[t_id]` is, which fuses
+        return _picked(lax.dynamic_index_in_dim(tbl, row, 0, keepdims=False),
+                       _one_hot(n, col), axis=0)
 
     return _lane_batched(
         expr, expr, write=False, per_lane=(1, 2),
-        dense=dense if _short(n) else None,
+        dense=(lambda _: dense) if _short(n) else None,
     )(tbl, row, col)
 
 

@@ -80,7 +80,9 @@ BLOCKED_MIN_NODES = 8192
 # a pass over the whole lane-batched table, so its cost falls with the
 # group; the patch costs one compare and select a slot on every row read,
 # so it rises with it (PERF.md section 6, PR 29: 16 beat 8 and 32 on the
-# chip). A replay that is not vmapped, or over few lanes, is a chain of
+# chip at 2,560 lanes x K = 61; PR 33: 16 and 32 level, 64 behind by 3 %,
+# at 600 lanes of a trace each x K = 400, so the size reads no K). A
+# replay that is not vmapped, or over few lanes, is a chain of
 # small operations and the group only adds to it (the standalone openb
 # replay: 49 us an event as it is, 110 grouped by 16), so it keeps writing
 # every event: flat_group_events() decides from the sweep's own shapes.
@@ -693,9 +695,7 @@ def _make_table_engine(
         if late is None:
             return row
         return lane_write.patch_row(
-            row, late.idx,
-            jax.lax.dynamic_index_in_dim(cols_of(late), t_id, 1, False),
-        )
+            row, late.idx, lane_write.read_pending(cols_of(late), t_id))
 
     def _sample_from_tables(state, score_tbl, feas_tbl, t_id, tp, ctr,
                             late=None):
@@ -1212,8 +1212,7 @@ def _make_table_engine(
                 if grouped:
                     dev_scalar = lane_write.patch_entry(
                         dev_scalar, sel, late.idx,
-                        jax.lax.dynamic_index_in_dim(
-                            late.sdev, t_id, 1, False),
+                        lane_write.read_pending(late.sdev, t_id),
                     )
                 dmask = choose_devices(
                     left, pod, dev_scalar, gpu_sel, k_sel,
