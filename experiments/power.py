@@ -29,6 +29,11 @@ Curves are seed-means, like the notebooks (sum(dfs)/len(dfs)); the load
 axis is the integer arrived-load percent of the *_discrete schema (the
 notebooks' cumulative_workload 0..1 maps to 0..100 here).
 
+A sweep's lanes do not feed this tool: `SweepLane.power_cpu_w` /
+`power_gpu_w` are the watts of a lane's FINAL cluster (at full depth, the
+tables' "watts at 100% load" of one seed), not the curve over arrived load
+that the discrete CSVs hold; `report_per_event` lanes carry the series.
+
     python experiments/power.py --merged experiments/analysis_results \
         --out experiments/analysis_results/power
 """

@@ -3,12 +3,14 @@ the TPU's compiler is installed; nothing runs): the blocked scan of
 synth100k may hold no copy of a whole carried array (ISSUE 27), the flat
 scan of openb no loop over the lanes (ISSUE 28) and no whole-table
 operation inside its per-event step (ISSUE 29), with one shared trace or
-with a trace a lane (ISSUE 33). tests/test_tpu.py holds the same checks on
-the chip itself."""
+with a trace a lane (ISSUE 33), under one raw-score policy or under two with
+a normalizer in the scan (ISSUE 34). tests/test_tpu.py holds the same checks
+on the chip itself."""
 
 import re
 
 import jax
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -207,6 +209,64 @@ def test_the_openb_flat_sweep_loops_over_events_only(one_chip, operands):
         # bytes an entry) once, not twice
         tables = lanes * k * 1213 * 9
         assert compiled.memory_analysis().temp_size_in_bytes < 2 * tables
+
+
+MIX = (("PWRScore", 500), ("FGDScore", 500))
+
+
+@pytest.mark.parametrize("operands", ["one shared trace", "a trace a lane"])
+def test_the_normalized_two_policy_sweep_loops_over_events_only(
+        one_chip, operands):
+    """The fork's PWR+FGD mix on openb (ISSUE 34): a second raw-score
+    table, PWR's NormalizeScore (global extrema over the feasible nodes,
+    every event) and the weighted total of two policies in the grouped flat
+    body, a weight row a lane. The module still holds the two event loops
+    and none over the lanes. Inside the per-event loop nothing produces a
+    whole table, and what takes a table in gives at least a row a lane: the
+    extrema reduce ROWS ([lanes, N] -> [lanes]), never a table."""
+    from tpusim.sim.table_engine import FLAT_GROUP_EVENTS
+
+    sim, trace, cfg = sweep_program.cell_simulator(
+        None, OPENB_DEPTH, config="openb", policies=MIX)
+    own = operands != "one shared trace"
+    lanes = LANE_TRACE_LANES if own else OPENB_LANES
+    rows = np.asarray([[500, 500], [100, 900], [50, 950]], np.int32)
+    with lane_write.counting() as sites:
+        fn, shapes, _ = sweep_program.capture_sweep(
+            sim, None if own else trace, rows[np.arange(lanes) % 3],
+            list(range(lanes)), **_lane_operands(operands, sim, trace, lanes))
+        shapes = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), shapes)
+        lowered = fn.lower(*shapes)
+    # with a type id a lane the second policy's pending column is one more
+    # pick out of the block (lane_write.read_pending's dense form)
+    assert (len(sites), len(sites.dense)) == (17, 32 if own else 22)
+    assert sites.table_pass_events == FLAT_GROUP_EVENTS
+    assert shapes[7].shape == (lanes, 2)  # a weight row a lane
+    assert "tpusim.normalize" in lowered.as_text(debug_info=True)
+    text = lowered.compile().as_text()
+    k = shapes[9][1].shape[-2]  # the trace's pod types at this depth
+    assert shapes[9][0].shape[-3:] == (2, k, 1213)  # two raw-score tables
+    table = rf"\[{lanes},(1,|2,)?{k},1213\]"
+
+    loops = sweep_program.while_loops(text)
+    bodies = sweep_program.loop_bodies(text)
+    (outer,) = [b for b, holder in bodies.items() if holder not in bodies]
+    (inner,) = [b for b, holder in bodies.items() if holder == outer]
+    assert len(loops) == 2, loops
+    for _, _, carried in loops:
+        assert f"s32[{lanes},1213,9]" in carried  # the scan's carry
+    assert not sweep_program.producers_in(text, inner, table)
+    if own:
+        # a row a lane, or both policies' rows. (The lanes of a shared
+        # trace slice their one row inside whatever fusion reads it, the
+        # extrema's reduction of that row to [lanes] among them.)
+        reads = sweep_program.fusions_reading(text, inner, table)
+        assert reads
+        for _, name, out in reads:
+            assert re.match(rf"(s32|pred)\[{lanes},(2,)?1213\]", out), (
+                name, out)
 
 
 def _fault_specs(lanes):

@@ -225,7 +225,7 @@ def test_every_stage_of_the_step_body_has_its_scope(scoped):
     assert "tpusim.table_build" in build.as_text(debug_info=True)
     states = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), lanes[0].state)
-    post = jax.jit(jax.vmap(driver._lane_frag_amounts, in_axes=(0, None)))
+    post = jax.jit(jax.vmap(driver._lane_postpass, in_axes=(0, None)))
     stacked = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct((3,) + a.shape, a.dtype), states)
     assert "tpusim.frag_postpass" in post.lower(stacked, tp).as_text(

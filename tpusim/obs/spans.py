@@ -173,6 +173,14 @@ class SweepRecord:
     # every lane is scored against the Simulator's, F with lanes of F
     # workload families (its init_tables span's cache counts them)
     typical_sets: int = 1
+    # distinct rows among the `weights` the caller gave: 1 where every
+    # lane is scored under one weight vector (a seed sweep), more where
+    # the lanes differ in it (a weight grid, a tuner's generation)
+    weight_rows: int = 0
+    # policies of the sweep's program whose normalizer runs in its scan
+    # (`normalize` minmax / pwr: feasible extrema, scale and weighted
+    # total every event), read off the policies; 0 for raw-score families
+    normalized_policies: int = 0
 
     @property
     def compiled(self) -> int:
@@ -196,6 +204,8 @@ class SweepRecord:
             "tables_reused": self.tables_reused,
             "traces": self.traces,
             "typical_sets": self.typical_sets,
+            "weight_rows": self.weight_rows,
+            "normalized_policies": self.normalized_policies,
             "spans": [s.to_dict() for s in self.spans],
         }
 

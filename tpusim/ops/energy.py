@@ -20,43 +20,61 @@ from tpusim.constants import (
 )
 
 
+def _gpu_model_watts(table, gpu_type):
+    """table[gpu_type] for a GPU model id, 0.0 for -1 (no GPU): compares
+    against the table's MAX_GPU_MODELS rows and one sum, in which every
+    term but one is 0.0, so the value is the row's bit for bit. Indexed, a
+    lookup a node stays a gather where the nodes are many ([40, 100000] in
+    the sweep's post-pass: 0.03 s a table on the chip); this form fuses."""
+    table = jnp.asarray(table)
+    hit = gpu_type[..., None] == jnp.arange(table.shape[0], dtype=jnp.int32)
+    return jnp.where(hit, table, 0.0).sum(-1)
+
+
 def gpu_power_watts(gpu_left, gpu_cnt, gpu_type):
     """GPU watts for one node (ref: resource.go:537-545): fully-idle devices
     draw idle watts, every other device draws full watts."""
-    gpu_idle_w = jnp.asarray(GPU_IDLE_W)
-    gpu_full_w = jnp.asarray(GPU_FULL_W)
     num_idle_gpus = (gpu_left == MILLI).sum().astype(jnp.float32)
     num_working = gpu_cnt.astype(jnp.float32) - num_idle_gpus
-    idle_w = jnp.where(gpu_type >= 0, gpu_idle_w[jnp.maximum(gpu_type, 0)], 0.0)
-    full_w = jnp.where(gpu_type >= 0, gpu_full_w[jnp.maximum(gpu_type, 0)], 0.0)
+    idle_w = _gpu_model_watts(GPU_IDLE_W, gpu_type)
+    full_w = _gpu_model_watts(GPU_FULL_W, gpu_type)
     return idle_w * num_idle_gpus + full_w * num_working
 
 
 def gpu_busy_delta_watts(gpu_type):
     """Per-device watts increase when a fully-idle device becomes working."""
-    gpu_idle_w = jnp.asarray(GPU_IDLE_W)
-    gpu_full_w = jnp.asarray(GPU_FULL_W)
-    return jnp.where(
-        gpu_type >= 0,
-        gpu_full_w[jnp.maximum(gpu_type, 0)] - gpu_idle_w[jnp.maximum(gpu_type, 0)],
-        0.0,
-    )
+    return (_gpu_model_watts(GPU_FULL_W, gpu_type)
+            - _gpu_model_watts(GPU_IDLE_W, gpu_type))
+
+
+def cpu_package_watts(cpu_left, cpu_cap, ncores, idle_w, full_w):
+    """CPU watts of one node from its model's (cores a package, idle W,
+    full W) (ref: resource.go:547-559): 2 vCPUs per physical core; whole
+    packages flip from idle to full wattage as cores become busy.
+
+    The core and package counts are INTEGER divisions of the milli values.
+    The reference's `ceil(cap / 1000 / 2)` in f32 is not safe under a
+    compiler: XLA turns it into `cap * 0.0005f`, which reads 48.000004
+    for 96,000 milli, so the ceil counted 49 cores and a fourth package on
+    every 96-vCPU node (165 W for an empty one where the reference has
+    45), and a TPU's own divide is a refined reciprocal."""
+    per_core = 2 * MILLI  # milli vCPU a physical core
+    ncores = ncores.astype(jnp.int32)
+    real_cores = -(-cpu_cap.astype(jnp.int32) // per_core)
+    idle_cores = cpu_left.astype(jnp.int32) // per_core
+    num_cpus = -(-real_cores // ncores)
+    active_cpus = -(-(real_cores - idle_cores) // ncores)
+    return (idle_w * (num_cpus - active_cpus).astype(jnp.float32)
+            + full_w * active_cpus.astype(jnp.float32))
 
 
 def cpu_power_watts(cpu_left, cpu_cap, cpu_type):
-    """CPU watts for one node (ref: resource.go:547-559): 2 vCPUs per
-    physical core; whole packages flip from idle to full wattage."""
-    cpu_idle_w = jnp.asarray(CPU_IDLE_W)
-    cpu_full_w = jnp.asarray(CPU_FULL_W)
-    cpu_ncores = jnp.asarray(CPU_NCORES)
-    real_cores = jnp.ceil(cpu_cap.astype(jnp.float32) / MILLI / 2)
-    idle_cores = jnp.floor(cpu_left.astype(jnp.float32) / MILLI / 2)
-    working_cores = real_cores - idle_cores
-    ncores = cpu_ncores[cpu_type]
-    num_cpus = jnp.ceil(real_cores / ncores)
-    active_cpus = jnp.ceil(working_cores / ncores)
-    idle_cpus = num_cpus - active_cpus
-    return cpu_idle_w[cpu_type] * idle_cpus + cpu_full_w[cpu_type] * active_cpus
+    """CPU watts for one node (ref: resource.go:547-559), by its model's
+    row of the energy tables."""
+    return cpu_package_watts(
+        cpu_left, cpu_cap, jnp.asarray(CPU_NCORES)[cpu_type],
+        jnp.asarray(CPU_IDLE_W)[cpu_type], jnp.asarray(CPU_FULL_W)[cpu_type],
+    )
 
 
 def node_power(cpu_left, cpu_cap, gpu_left, gpu_cnt, gpu_type, cpu_type):

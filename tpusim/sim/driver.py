@@ -3438,6 +3438,11 @@ class SweepLane:
     # (ISSUE 10; None on fault-free sweeps) — bit-identical to the
     # standalone run_with_faults run with the same schedule/seed.
     disruption: object = None
+    # the energy model (ops/energy.node_power) over the lane's FINAL node
+    # state, summed over its nodes: the reference's `[Power]; cluster;
+    # ClusterCPU; ClusterGPU` line is their sum and the two
+    power_cpu_w: float = 0.0
+    power_gpu_w: float = 0.0
 
 
 def _lane_axis(operand, rank: int):
@@ -3582,14 +3587,32 @@ def _dispatch_counting_lane_sites(fn, lanes: int, *args):
     return (out,) + _SWEEP_LANE_SITES.get((fn, lanes), (0, 0, 0))
 
 
-def _lane_frag_amounts(state, tp):
-    """One lane's frag amounts by category, summed over its nodes (the
-    reduction cluster_analysis reports): the sweeps vmap it over their
-    lanes' final states before the single fetch."""
+@jax.jit
+def _lane_watts(state):
+    """One lane's (CPU, GPU) watts over its node state: the reports' own
+    power_rows, summed over the nodes. Jitted HERE, once a process:
+    _lane_postpass is traced anew in every sweep (its caller wraps a new
+    function object), and with the energy model's hundred primitives
+    traced under two vmaps in every wave the family cell's frag_postpass
+    span held the host 0.200 s where it had held it 0.124 s, and its wave
+    was 0.84 % longer; as a call of a cached jaxpr they cost the outer
+    trace one equation."""
+    from tpusim.sim.engine import power_rows
+
+    with jax.named_scope("tpusim.power_postpass"):
+        return jnp.stack([rows.sum() for rows in power_rows(state)])
+
+
+def _lane_postpass(state, tp):
+    """One lane's frag amounts by category (the reduction cluster_analysis
+    reports) and its (CPU, GPU) watts, each summed over its nodes: the
+    sweeps vmap it over their lanes' final states, ONE program before the
+    single fetch."""
     from tpusim.ops.frag import cluster_frag_amounts
 
     with jax.named_scope("tpusim.frag_postpass"):
-        return cluster_frag_amounts(state, tp).sum(0)
+        amounts = cluster_frag_amounts(state, tp).sum(0)
+    return amounts, _lane_watts(state)
 
 
 @functools.lru_cache(maxsize=None)
@@ -3679,7 +3702,7 @@ def _lane_ranks(num_nodes: int, seeds):
     return jnp.asarray(np.stack([tiebreak_rank(num_nodes, s) for s in seeds]))
 
 
-def _slice_sweep_lane(out, amounts, i, wrow, seed, p, e, pad_skips):
+def _slice_sweep_lane(out, amounts, watts, i, wrow, seed, p, e, pad_skips):
     """Slice lane i out of a fetched (host) vmapped sweep result into its
     SweepLane — shared by the single-trace and multi-trace sweep paths
     (the latter passes per-lane true sizes, ISSUE 7)."""
@@ -3717,6 +3740,8 @@ def _slice_sweep_lane(out, amounts, i, wrow, seed, p, e, pad_skips):
         gpu_alloc_pct=alloc,
         frag_gpu_milli=float(frag_sum_except_q3(amounts[i])),
         unscheduled=int(((pn < 0) & failed_i).sum()),
+        power_cpu_w=float(watts[i][0]),
+        power_gpu_w=float(watts[i][1]),
     )
 
 
@@ -3729,7 +3754,7 @@ def lane_from_arrays(state, placed_node, dev_mask, ever_failed, counters,
     counters pad-correction, same gpu_alloc slot mask, same frag
     post-pass — so every result document of a family is field-for-field
     comparable regardless of which execution path produced it."""
-    from tpusim.ops.frag import cluster_frag_amounts, frag_sum_except_q3
+    from tpusim.ops.frag import frag_sum_except_q3
 
     pn = np.asarray(placed_node, np.int32)
     failed = np.asarray(ever_failed, bool)
@@ -3745,10 +3770,9 @@ def lane_from_arrays(state, placed_node, dev_mask, ever_failed, counters,
     alloc = 100.0 * float(
         np.where(slot, MILLI - st.gpu_left, 0).sum()
     ) / denom
-    amounts = np.asarray(
-        cluster_frag_amounts(
-            jax.tree.map(jnp.asarray, st), typical
-        ).sum(0)
+    amounts, watts = (
+        np.asarray(a)
+        for a in _lane_postpass(jax.tree.map(jnp.asarray, st), typical)
     )
     return SweepLane(
         weights=np.asarray(weights, np.int32).copy(),
@@ -3765,6 +3789,8 @@ def lane_from_arrays(state, placed_node, dev_mask, ever_failed, counters,
         gpu_alloc_pct=alloc,
         frag_gpu_milli=float(frag_sum_except_q3(amounts)),
         unscheduled=int(((pn < 0) & failed).sum()),
+        power_cpu_w=float(watts[0]),
+        power_gpu_w=float(watts[1]),
     )
 
 
@@ -4284,7 +4310,8 @@ def _sweep_replay(sim, table: bool, fault_frag: Optional[bool]):
     return sim._table_fn if table else sim.replay_fn
 
 
-def _slice_fault_lane(out, amounts, i, wrow, seed, p, plan, e_m, gcnt):
+def _slice_fault_lane(out, amounts, watts, i, wrow, seed, p, plan, e_m,
+                      gcnt):
     """Lane i of a fetched sweep with fault plans, whose merged streams
     were padded to `e_m` steps: its SweepLane with the DisruptionMetrics
     of its schedule, bit-identical to the standalone run_with_faults run
@@ -4297,7 +4324,7 @@ def _slice_fault_lane(out, amounts, i, wrow, seed, p, plan, e_m, gcnt):
         plan, ys, fc, gcnt)
     e = plan.num_events
     lane = _slice_sweep_lane(
-        out, amounts, i, wrow, seed, p, e, e_m - e - attempts_run)
+        out, amounts, watts, i, wrow, seed, p, e, e_m - e - attempts_run)
     lane.disruption = dm
     lane.events = e + attempts_run
     # dead pods are terminal max-retries-exceeded: the standalone path's
@@ -4413,6 +4440,9 @@ def schedule_pods_sweep(
     # lane_keys, lane_ranks, init_tables, scan, frag_postpass, fetch,
     # slice_lanes
     with obs.sweep(lanes=b) as sweep:
+        sweep.weight_rows = len(np.unique(w, axis=0))
+        sweep.normalized_policies = sum(
+            fn.normalize in ("minmax", "pwr") for fn, _ in sim._policy_fns)
         with obs.span("specs") as h:
             tr = _sweep_traces(
                 sim, traces, lane_trace, per_lane or faulted, bucket,
@@ -4517,31 +4547,31 @@ def schedule_pods_sweep(
                         out.event_node, out.event_dev, typical,
                     )
                 )
-            # per-lane frag of the final states in one vmapped call (the same
-            # reduction cluster_analysis reports), before the single fetch.
-            # The jit wraps a new function object in every call, so dispatch
-            # here is a trace, a lowering and a compile or a cache load.
-            amounts = jax.jit(
-                jax.vmap(_lane_frag_amounts, in_axes=(0, tp_ax))
+            # per-lane frag and watts of the final states in one vmapped
+            # call (the reductions cluster_analysis and the power report
+            # make), before the single fetch. The jit wraps a new function
+            # object in every call, so dispatch here is a trace, a lowering
+            # and a compile or a cache load.
+            amounts, watts = jax.jit(
+                jax.vmap(_lane_postpass, in_axes=(0, tp_ax))
             )(out.state, typical)
-            obs.settle(h, amounts, out.metrics)
+            obs.settle(h, amounts, watts, out.metrics)
         with obs.span("fetch", events=true_events):
-            out = device_fetch(out)
-            amounts = np.asarray(amounts)
+            out, amounts, watts = device_fetch((out, amounts, watts))
 
         with obs.span("slice_lanes"):
             if faulted:
                 gcnt = np.asarray(state.gpu_cnt)
                 return [
                     _slice_fault_lane(
-                        out, amounts, i, w[i], seeds[i], tr.pods[t],
+                        out, amounts, watts, i, w[i], seeds[i], tr.pods[t],
                         plans[i], steps, gcnt,
                     )
                     for i, t in enumerate(trace_of)
                 ]
             return [
                 _slice_sweep_lane(
-                    out, amounts, i, w[i], seeds[i], tr.pods[t],
+                    out, amounts, watts, i, w[i], seeds[i], tr.pods[t],
                     lane_events[i], steps - lane_events[i],
                 )
                 for i, t in enumerate(trace_of)
@@ -4576,12 +4606,14 @@ def format_chaos_table(lanes: Sequence[SweepLane], policies) -> str:
 def format_sweep_table(lanes: Sequence[SweepLane], policies) -> str:
     """Per-config summary table of a sweep — the `tpusim apply
     --sweep-weights` output: one row per lane with its weight vector,
-    seed, placed/failed counts, GPU allocation, and frag gpu-milli."""
+    seed, placed/failed counts, GPU allocation, frag gpu-milli and the
+    watts of its final cluster (the reference's `[Power]; cluster` value:
+    power_cpu_w + power_gpu_w)."""
     names = [n for n, _ in policies]
     head = (
         f"{'cfg':>4} {'weights(' + ','.join(names) + ')':<32} "
         f"{'seed':>6} {'placed':>7} {'failed':>7} "
-        f"{'gpu_alloc%':>10} {'frag_gpu_milli':>15}"
+        f"{'gpu_alloc%':>10} {'frag_gpu_milli':>15} {'power_w':>10}"
     )
     rows = [head, "-" * len(head)]
     for i, ln in enumerate(lanes):
@@ -4589,6 +4621,7 @@ def format_sweep_table(lanes: Sequence[SweepLane], policies) -> str:
         rows.append(
             f"{i:>4} {wstr:<32} {ln.seed:>6} {ln.placed:>7} "
             f"{ln.failed:>7} {ln.gpu_alloc_pct:>10.2f} "
-            f"{ln.frag_gpu_milli:>15.0f}"
+            f"{ln.frag_gpu_milli:>15.0f} "
+            f"{ln.power_cpu_w + ln.power_gpu_w:>10.0f}"
         )
     return "\n".join(rows)

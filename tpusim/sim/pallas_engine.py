@@ -70,6 +70,7 @@ from tpusim.constants import (
     MAX_SPEC_GPU,
     MILLI,
 )
+from tpusim.ops.energy import cpu_package_watts
 from tpusim.sim.engine import ReplayResult
 from tpusim.sim.step import SELF_SELECT_POLICIES
 from tpusim.sim.table_engine import PodTypes, reject_randomized
@@ -526,13 +527,8 @@ def _pwr_column(node: _NodeScalars, types: _TypeCols, tp, aux: _EnergyRows):
     cfull = look(aux.cfull, node.ctyp)
     ncores = look(aux.ncores, node.ctyp)
 
-    real_cores = jnp.ceil(node.cap.astype(jnp.float32) / MILLI / 2)
-    num_cpus = jnp.ceil(real_cores / ncores)
-
     def cpu_watts(cpu_left):
-        idle_cores = jnp.floor(cpu_left.astype(jnp.float32) / MILLI / 2)
-        active = jnp.ceil((real_cores - idle_cores) / ncores)
-        return cidle * (num_cpus - active) + cfull * active
+        return cpu_package_watts(cpu_left, node.cap, ncores, cidle, cfull)
 
     was_idle = node.g8.T == MILLI  # (1,8)
     n_idle = was_idle.astype(jnp.float32).sum()

@@ -735,17 +735,18 @@ def _make_table_engine(
         the shared minmax_scale_i32, so feasible entries match the
         oracle's minmax/pwr_normalize_i32 bit-for-bit whenever slo/shi
         equal the current feasible extrema."""
-        tot = jnp.zeros(feas.shape, jnp.int32)
-        for i, (fn, _) in enumerate(policies):
-            raw = raws[i]
-            if fn.normalize in ("minmax", "pwr"):
-                j = norm_idx.index(i)
-                raw = minmax_scale_i32(
-                    raw, feas, slo[j][..., None], shi[j][..., None],
-                    norm_deg[j],
-                )
-            tot = tot + wts[i] * raw
-        return jnp.where(feas, tot, -_INT_MAX)
+        with jax.named_scope("tpusim.normalize"):
+            tot = jnp.zeros(feas.shape, jnp.int32)
+            for i, (fn, _) in enumerate(policies):
+                raw = raws[i]
+                if fn.normalize in ("minmax", "pwr"):
+                    j = norm_idx.index(i)
+                    raw = minmax_scale_i32(
+                        raw, feas, slo[j][..., None], shi[j][..., None],
+                        norm_deg[j],
+                    )
+                tot = tot + wts[i] * raw
+            return jnp.where(feas, tot, -_INT_MAX)
 
     def make_blocked_body(
         pods, type_id, types, tp, rank_p, n, num_pods, bsz, k_types, nblk,
@@ -833,9 +834,10 @@ def _make_table_engine(
             with jax.named_scope("tpusim.summary"):
                 rank_blk = jax.lax.dynamic_slice(rank_p, (j0,), (bsz,))
                 if n_norm:
-                    selb = jnp.stack([raw_blk[i] for i in norm_idx])
-                    mn = jnp.where(feas_blk, selb, _INT_MAX).min(-1)
-                    mx = jnp.where(feas_blk, selb, -_INT_MAX).max(-1)
+                    with jax.named_scope("tpusim.normalize"):
+                        selb = jnp.stack([raw_blk[i] for i in norm_idx])
+                        mn = jnp.where(feas_blk, selb, _INT_MAX).min(-1)
+                        mx = jnp.where(feas_blk, selb, -_INT_MAX).max(-1)
                     brmin = jax.lax.dynamic_update_slice(
                         brmin, mn[:, :, None], (0, 0, blk)
                     )
@@ -863,13 +865,14 @@ def _make_table_engine(
                     brmax_row = jax.lax.dynamic_index_in_dim(
                         brmax, t_id, 1, False
                     )
-                    lo_cur = brmin_row.min(-1)
-                    hi_cur = brmax_row.max(-1)
                     slo_col = jax.lax.dynamic_index_in_dim(slo, t_id, 1, False)
                     shi_col = jax.lax.dynamic_index_in_dim(shi, t_id, 1, False)
-                    changed = jnp.any(
-                        (lo_cur != slo_col) | (hi_cur != shi_col)
-                    )
+                    with jax.named_scope("tpusim.normalize"):
+                        lo_cur = brmin_row.min(-1)
+                        hi_cur = brmax_row.max(-1)
+                        changed = jnp.any(
+                            (lo_cur != slo_col) | (hi_cur != shi_col)
+                        )
                     rebuilt = changed
 
                     def rebuild():
@@ -1193,16 +1196,19 @@ def _make_table_engine(
                             score_tbl[i, t_id], late,
                             lambda l: l.score[:, i], t_id,
                         )
-                    if fn.normalize == "minmax":
-                        nrm = minmax_normalize_i32(raw, feasible)
-                    elif fn.normalize == "pwr":
-                        nrm = pwr_normalize_i32(raw, feasible)
-                    else:
-                        nrm = raw
+                    # the plugin's NormalizeScore (feasible extrema, then
+                    # the scale) and the framework's weighted total
+                    with jax.named_scope("tpusim.normalize"):
+                        if fn.normalize == "minmax":
+                            nrm = minmax_normalize_i32(raw, feasible)
+                        elif fn.normalize == "pwr":
+                            nrm = pwr_normalize_i32(raw, feasible)
+                        else:
+                            nrm = raw
+                        total = total + wts[i] * nrm
                     if decisions:
                         raw_rows.append(raw)
                         norm_rows.append(nrm)
-                    total = total + wts[i] * nrm
                 # the oracle's selectHost + Reserve halves; the Bind
                 # scatter is deferred via PendingCommit
                 sel, _, ok = packed_argmax(total, feasible, tiebreak_rank)
