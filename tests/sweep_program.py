@@ -1,8 +1,8 @@
 """Helpers for tests that look at the sweep's program without running it:
 capture the jitted vmapped replay with the shapes a cell calls it on, list
 the large `copy` operations of a compiled module's scan, its loops and how
-they nest, and the operations of a loop that produce, or read, a given
-shape."""
+they nest, the operations of a loop that produce, or read, a given
+shape, and its gathers."""
 
 import json
 import math
@@ -137,19 +137,36 @@ def _reachable(comps: dict, root: str) -> set:
     return reach
 
 
+def _instructions_in(text: str, root: str):
+    """(computation, parsed instruction, line, computations) for every
+    instruction of `root` and the computations it calls (inner loops,
+    branches, fusions)."""
+    comps = _computations(text)
+    for name in sorted(_reachable(comps, root)):
+        for line in comps[name]:
+            m = _INSTRUCTION.match(line)
+            if m:
+                yield name, m, line, comps
+
+
 def producers_in(text: str, root: str, shape: str) -> list:
     """(computation, name, opcode) of every instruction in `root` and the
     computations it calls (inner loops, branches, fusions) whose result
     matches the regular expression `shape`, less those that only hand a
     buffer on (parameter, get-tuple-element, tuple, bitcast)."""
-    comps, found = _computations(text), []
-    for name in sorted(_reachable(comps, root)):
-        for line in comps[name]:
-            m = _INSTRUCTION.match(line)
-            if m and re.search(shape, m.group(2)) and m.group(3) not in (
-                    "parameter", "get-tuple-element", "tuple", "bitcast"):
-                found.append((name, m.group(1), m.group(3)))
-    return found
+    return [(name, m.group(1), m.group(3))
+            for name, m, _, _ in _instructions_in(text, root)
+            if re.search(shape, m.group(2)) and m.group(3) not in (
+                "parameter", "get-tuple-element", "tuple", "bitcast")]
+
+
+def gathers_in(text: str, root: str) -> list:
+    """(computation, name, result shape) of every `gather` in `root` and
+    the computations it calls: what the program there still reads through
+    an index."""
+    return [(name, m.group(1), m.group(2).strip())
+            for name, m, _, _ in _instructions_in(text, root)
+            if m.group(3) == "gather"]
 
 
 def fusions_reading(text: str, root: str, shape: str) -> list:
@@ -157,17 +174,15 @@ def fusions_reading(text: str, root: str, shape: str) -> list:
     the computations it calls whose fused computation takes a parameter
     that matches the regular expression `shape`: what reads an array of
     that shape there, and what it makes of it."""
-    comps, found = _computations(text), []
-    for name in sorted(_reachable(comps, root)):
-        for line in comps[name]:
-            m = _INSTRUCTION.match(line)
-            if not m or m.group(3) != "fusion":
-                continue
-            fused = comps[re.search(r"calls=%?([\w.\-]+)", line).group(1)]
-            if any(p and p.group(3) == "parameter"
-                   and re.search(shape, p.group(2))
-                   for p in map(_INSTRUCTION.match, fused)):
-                found.append((name, m.group(1), m.group(2).strip()))
+    found = []
+    for name, m, line, comps in _instructions_in(text, root):
+        if m.group(3) != "fusion":
+            continue
+        fused = comps[re.search(r"calls=%?([\w.\-]+)", line).group(1)]
+        if any(p and p.group(3) == "parameter"
+               and re.search(shape, p.group(2))
+               for p in map(_INSTRUCTION.match, fused)):
+            found.append((name, m.group(1), m.group(2).strip()))
     return found
 
 

@@ -269,3 +269,172 @@ def test_pwr_matches_direct_form():
         assert int(a[0]) == int(b[0]) and int(a[1]) == int(b[1]), (
             trial, gl, pod, int(a[0]), int(b[0]), int(a[1]), int(b[1])
         )
+
+
+# ------------------------------------------------------------------ first_max
+# ops/resource.first_max (ISSUE 35): the per-type device pick as reductions.
+# The kernels had `best = argmax(x); x[best]`, which the chip ran as a
+# serialized gather of one of eight for every (lane, type).
+
+from tpusim.policies.pwr import _NEG_INF as _PWR_NEG_INF  # noqa: E402
+
+
+def _element_at_argmax(x):
+    best = jnp.argmax(x).astype(jnp.int32)
+    return x[best], best
+
+
+def _device_score_rows(kind):
+    """int32[n, 8] rows of device scores as the two kernels make them: a
+    score (FGD: 0..100, PWR: a watt delta <= 0) where the device fits, -1 or
+    `_NEG_INF` where it does not."""
+    rng = np.random.default_rng(35)
+    if kind == "random":
+        return rng.integers(-400, 101, (64, 8)).astype(np.int32)
+    if kind == "ties":  # few distinct values: every row has a tied maximum
+        return rng.choice(np.asarray([-1, 0, 37, 100], np.int32), (64, 8))
+    if kind == "one fitting device":
+        x = np.full((16, 8), -1, np.int32)
+        x[np.arange(16), np.arange(16) % 8] = rng.integers(0, 101, 16)
+        return x
+    if kind == "none fitting (-1)":
+        return np.full((4, 8), -1, np.int32)
+    if kind == "none fitting (_NEG_INF)":
+        return np.full((4, 8), _PWR_NEG_INF, np.int32)
+    if kind == "watt deltas beside _NEG_INF":
+        x = rng.choice(np.asarray([-195, -75, 0], np.int32), (64, 8))
+        return np.where(rng.random((64, 8)) < 0.5, x, _PWR_NEG_INF).astype(
+            np.int32)
+    raise ValueError(kind)
+
+
+@pytest.mark.parametrize("kind", [
+    "random", "ties", "one fitting device", "none fitting (-1)",
+    "none fitting (_NEG_INF)", "watt deltas beside _NEG_INF"])
+def test_first_max_is_the_element_at_its_own_argmax(kind):
+    from tpusim.ops.resource import first_max
+
+    x = _device_score_rows(kind)
+    value, index = jax.jit(jax.vmap(first_max))(jnp.asarray(x))
+    want_value, want_index = jax.vmap(_element_at_argmax)(jnp.asarray(x))
+    assert value.dtype == want_value.dtype and index.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(value), np.asarray(want_value))
+    np.testing.assert_array_equal(np.asarray(index), np.asarray(want_index))
+    # and by numpy: the maximum, at its FIRST index
+    np.testing.assert_array_equal(np.asarray(value), x.max(1))
+    np.testing.assert_array_equal(np.asarray(index), x.argmax(1))
+
+
+@pytest.mark.parametrize("dtype", ["int32", "float32"])
+def test_first_max_over_lane_and_type(dtype):
+    """Under vmap(vmap(...)) over (lane, type), as the table sweep's column
+    computation calls it; float32 as DotProd has it: finite slots and -inf,
+    no NaN."""
+    from tpusim.ops.resource import first_max
+
+    rng = np.random.default_rng(8)
+    if dtype == "int32":
+        x = rng.choice(np.asarray([-1, 0, 50, 99], np.int32), (6, 15, 8))
+    else:
+        x = rng.choice(np.asarray([-np.inf, 0.0, 0.25, 0.75], np.float32),
+                       (6, 15, 9))
+    value, index = jax.jit(jax.vmap(jax.vmap(first_max)))(jnp.asarray(x))
+    want = jax.vmap(jax.vmap(_element_at_argmax))(jnp.asarray(x))
+    assert value.shape == index.shape == (6, 15) and value.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(value), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(index), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(index), x.argmax(2))
+
+
+def _share_kernel_rows():
+    """48 random node rows (many tied and full devices, CPU-only nodes
+    among them) and three share pods."""
+    from tpusim.types import PodSpec
+
+    rng = np.random.default_rng(3535)
+    n = 48
+    gcnt = rng.choice([0, 2, 4, 8], n)
+    st = make_node_state(
+        cpu_cap=rng.choice([32000, 96000], n).tolist(),
+        mem_cap=[262144] * n,
+        gpu_cnt=gcnt.tolist(),
+        gpu_type=[int(rng.integers(0, 4)) if g else -1 for g in gcnt],
+        cpu_type=rng.integers(0, 3, n).tolist(),
+    )
+    gl = rng.choice([0, 250, 500, 999, 1000], (n, 8)).astype(np.int32)
+    gl[np.arange(8)[None, :] >= gcnt[:, None]] = 0
+    st = st._replace(
+        gpu_left=jnp.asarray(gl),
+        cpu_left=jnp.asarray(rng.integers(0, 32000, n).astype(np.int32)))
+    pods = PodSpec(
+        cpu=jnp.asarray([100, 4000, 8000], jnp.int32),
+        mem=jnp.full(3, 1024, jnp.int32),
+        gpu_milli=jnp.asarray([250, 500, 1000], jnp.int32),
+        gpu_num=jnp.ones(3, jnp.int32),
+        gpu_mask=jnp.zeros(3, jnp.int32),
+        pinned=jnp.full(3, -1, jnp.int32))
+    return st, pods
+
+
+def _share_kernel(kernel):
+    """(module that holds the kernel's `first_max`, run): run() scores every
+    (pod, node) of _share_kernel_rows under a fresh jit (a new vmap object a
+    call), so a patched `first_max` is traced anew, and returns (score[3, 48], dev[3, 48])."""
+    from tpusim.policies import dotprod, fgd, pwr
+
+    st, pods = _share_kernel_rows()
+    tp = make_typical_pods([
+        (6000, 465, 1, 0, 0.4), (16000, 1000, 1, 0, 0.3),
+        (8000, 250, 1, 0, 0.2), (32000, 1000, 2, 0, 0.1)])
+    module, node = {
+        "fgd share": (fgd, lambda row, pod: fgd._fgd_share_node(
+            row.cpu_left, row.gpu_left, row.gpu_type, pod, tp)),
+        "pwr": (pwr, pwr._pwr_node),
+        "dotprod share": (dotprod, lambda row, pod: dotprod._share_divide_node(
+            row, pod, "max", False)),
+        "dotprod extend": (dotprod, lambda row, pod: dotprod._extend_node(
+            row, pod, "max")),
+    }[kernel]
+
+    def run():
+        rows = NodeState(*([0] * len(st)))
+        score, dev = jax.jit(jax.vmap(
+            jax.vmap(node, in_axes=(rows, None)),
+            in_axes=(None, 0)))(st, pods)
+        return np.asarray(score), np.asarray(dev)
+
+    return module, run
+
+
+# what the parent of ISSUE 35 (`x[argmax(x)]` in each kernel) gives on
+# _share_kernel_rows: (sum of the scores over fitting entries, sum of the
+# devices, entries with no device, the first pod's first twelve devices)
+PARENT_SHARE_VALUES = {
+    "fgd share": (4296.0, 18, 59, [-1, 0, 1, 5, -1, -1, 1, 1, 2, -1, 0, 0]),
+    "pwr": (-105.0, -66, 78, [-1, 0, 1, 0, -1, -1, 0, 0, 0, -1, 0, 0]),
+    "dotprod share": (
+        81.519, 48, 62, [-1, 0, 1, 5, -1, -1, 1, 1, 2, -1, 2, 1]),
+    "dotprod extend": (
+        81.775, 48, 62, [-1, 0, 1, 5, -1, -1, 1, 1, 2, -1, 2, 1]),
+}
+
+
+@pytest.mark.parametrize("kernel", [
+    "fgd share", "pwr", "dotprod share", "dotprod extend"])
+def test_the_share_kernels_keep_the_parent_s_values(kernel, monkeypatch):
+    """score and device of every kernel that picks through first_max: equal
+    to the same kernel with the parent's `x[argmax(x)]` in its place, entry
+    by entry, and to what the parent's tree gave."""
+    module, run = _share_kernel(kernel)
+    score, dev = run()
+    monkeypatch.setattr(module, "first_max", _element_at_argmax)
+    parent_score, parent_dev = run()
+    assert score.dtype == parent_score.dtype
+    np.testing.assert_array_equal(score, parent_score)
+    np.testing.assert_array_equal(dev, parent_dev)
+    assert (dev >= 0).any() and (dev < 0).any()  # both outcomes occur
+    placed = dev >= 0
+    score_sum, *ints = PARENT_SHARE_VALUES[kernel]
+    assert [int(dev.sum()), int((~placed).sum()), dev[0, :12].tolist()] == ints
+    assert float(score[placed].astype(np.float64).sum()) == pytest.approx(
+        score_sum, abs=1e-3)

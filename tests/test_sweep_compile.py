@@ -4,8 +4,9 @@ synth100k may hold no copy of a whole carried array (ISSUE 27), the flat
 scan of openb no loop over the lanes (ISSUE 28) and no whole-table
 operation inside its per-event step (ISSUE 29), with one shared trace or
 with a trace a lane (ISSUE 33), under one raw-score policy or under two with
-a normalizer in the scan (ISSUE 34). tests/test_tpu.py holds the same checks
-on the chip itself."""
+a normalizer in the scan (ISSUE 34), and no gather of an entry a (lane, type)
+in that step (ISSUE 35). tests/test_tpu.py holds the same checks on the chip
+itself."""
 
 import re
 
@@ -71,6 +72,31 @@ ENTRY %main (a: s32[8,64]) -> s32[8,64] {
         ("main", "w", "(s32[], s32[8,64]{1,0})")]
 
 
+def test_the_parser_finds_the_gathers_of_a_loop():
+    text = """HloModule m
+%fused.7 (a: s32[4,15,1,8], i: s32[60]) -> s32[60] {
+  %a = s32[4,15,1,8]{3,2,1,0} parameter(0)
+  %i = s32[60]{0} parameter(1)
+  ROOT %pick = s32[60]{0:T(1024)} gather(%a, %i), offset_dims={}
+}
+%body.1 (p: (s32[], s32[4,8])) -> (s32[], s32[4,8]) {
+  %p = (s32[], s32[4,8]{1,0}) parameter(0)
+  %g = s32[4,8]{1,0} get-tuple-element(%p), index=1
+  %f = s32[60]{0} fusion(%x, %y), kind=kCustom, calls=%fused.7
+  %row = s32[4,8]{1,0} gather(%g, %g), offset_dims={1}
+  ROOT %t = (s32[], s32[4,8]{1,0}) tuple(%g, %row)
+}
+ENTRY %main (a: s32[4,8]) -> s32[4,8] {
+  %a = s32[4,8]{1,0} parameter(0)
+  %outside = s32[4]{0} gather(%a, %a), offset_dims={}
+  ROOT %w = (s32[], s32[4,8]{1,0}) while(%a), condition=%cond.2, body=%body.1
+}
+"""
+    assert sweep_program.gathers_in(text, "body.1") == [
+        ("body.1", "row", "s32[4,8]{1,0}"),
+        ("fused.7", "pick", "s32[60]{0:T(1024)}")]
+
+
 def test_cell_sized_sweep_compiles_without_whole_carry_copies(one_chip):
     sim, trace, cfg = sweep_program.cell_simulator(NODES, DEPTH)
     fn, shapes, _ = sweep_program.capture_sweep(
@@ -94,6 +120,21 @@ def test_cell_sized_sweep_compiles_without_whole_carry_copies(one_chip):
                                 for c, n, s, _ in found)
     # the parent's program held 6.72 GB of temporaries, sixteen copies
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
+
+
+def _assert_no_gather_a_lane_and_type(text, inner, lanes):
+    """What the per-event loop reads through an index is one entry a lane
+    ([lanes]: a rank, a pick), a lane's eight devices ([lanes,8]: Sub's
+    `gpu_left[order]`) or a table row a lane ([lanes,(2,)1213]). The column
+    computation's device pick is ops/resource.first_max, reductions alone:
+    `dev_scores[argmax(dev_scores)]` was a gather to [lanes,k], k a type
+    group's size, which the chip ran serialized at 10 ns an element (a
+    third of the mix cell's scan: ISSUE 35)."""
+    gathers = sweep_program.gathers_in(text, inner)
+    assert gathers  # the loop is the right one: Sub's and the picks are there
+    for _, name, out in gathers:
+        assert re.match(rf"(s32|pred)\[{lanes}(,8|,(2,)?1213)?\]", out), (
+            name, out)
 
 
 def _lane_operands(operands, sim, trace, lanes):
@@ -191,6 +232,7 @@ def test_the_openb_flat_sweep_loops_over_events_only(one_chip, operands):
 
     # no whole-table operation inside the per-event step
     assert not sweep_program.producers_in(text, inner, table)
+    _assert_no_gather_a_lane_and_type(text, inner, lanes)
     if own:
         # what takes a table in there is a row gather, [lanes, N] out: the
         # step's three reads an event (the scan unrolls by 4), none a
@@ -258,6 +300,9 @@ def test_the_normalized_two_policy_sweep_loops_over_events_only(
     for _, _, carried in loops:
         assert f"s32[{lanes},1213,9]" in carried  # the scan's carry
     assert not sweep_program.producers_in(text, inner, table)
+    # PWR's kernel has no branches: its share path, and the pick in it, runs
+    # over the whole-GPU type group too, so two groups' sizes are at stake
+    _assert_no_gather_a_lane_and_type(text, inner, lanes)
     if own:
         # a row a lane, or both policies' rows. (The lanes of a shared
         # trace slice their one row inside whatever fusion reads it, the

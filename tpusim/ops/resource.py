@@ -29,6 +29,19 @@ def is_accessible(node_gpu_type, pod_gpu_mask):
     return (pod_gpu_mask == 0) | ((pod_gpu_mask & node_bit) != 0)
 
 
+def first_max(x):
+    """(value, index) of the first maximum of a vector: what
+    `i = argmax(x); x[i], i` gives, from reductions alone. Indexing the
+    vector with the index just computed is a gather of one of eight for
+    every (lane, type) once the kernels are vmapped, and the chip runs it
+    serialized (PERF.md section 6, PR 35). Ties take the first index, as
+    argmax does and fgd_score.go:111-134 asks; the value is the same on
+    every tied slot. The callers' vectors hold no NaN, so `max` and the
+    element at `argmax` are one number: `int32` device scores (FGD, PWR),
+    and DotProd's `float32` slots, each finite or `_NEG` (-inf)."""
+    return x.max(), jnp.argmax(x).astype(jnp.int32)
+
+
 def can_host_on_gpu(gpu_left, pod_gpu_milli, pod_gpu_num):
     """True if >= gpu_num devices each have >= gpu_milli free
     (ref: frag.go:447-458). Only meaningful for pod_gpu_milli > 0."""

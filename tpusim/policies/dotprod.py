@@ -33,6 +33,7 @@ from tpusim.constants import (
     MAX_SPEC_GPU,
     MILLI,
 )
+from tpusim.ops.resource import first_max
 from tpusim.policies.base import PolicyResult, ScoreContext
 from tpusim.types import NodeState, PodSpec
 
@@ -117,11 +118,11 @@ def _share_divide_node(row: NodeState, pod: PodSpec, norm: str, divide: bool):
     if norm == "pod":
         dots = jnp.tanh(dots / 10.0)
     scores = jnp.where((row.cpu_left >= pod.cpu) & active, 1.0 - dots, _NEG)
-    best = jnp.argmax(scores)
+    best_score, best = first_max(scores)
     share_dev = jnp.where(
-        best < MAX_GPUS_PER_NODE, best.astype(jnp.int32), _first_free_dev(row.gpu_left)
+        best < MAX_GPUS_PER_NODE, best, _first_free_dev(row.gpu_left)
     )
-    return scores[best], jnp.where(scores[best] == _NEG, -1, share_dev)
+    return best_score, jnp.where(best_score == _NEG, -1, share_dev)
 
 
 def _extend_node(row: NodeState, pod: PodSpec, norm: str):
@@ -163,11 +164,11 @@ def _extend_node(row: NodeState, pod: PodSpec, norm: str):
     if norm == "pod":
         dots = jnp.tanh(dots / 10.0)
     scores = jnp.where((row.cpu_left >= pod.cpu) & cand, 1.0 - dots, _NEG)
-    best = jnp.argmax(scores)
+    best_score, best = first_max(scores)
     share_dev = jnp.where(
-        best < MAX_GPUS_PER_NODE, best.astype(jnp.int32), _first_free_dev(row.gpu_left)
+        best < MAX_GPUS_PER_NODE, best, _first_free_dev(row.gpu_left)
     )
-    return scores[best], jnp.where(scores[best] == _NEG, -1, share_dev)
+    return best_score, jnp.where(best_score == _NEG, -1, share_dev)
 
 
 from functools import lru_cache

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from tpusim.constants import MAX_NODE_SCORE
 from tpusim.ops.frag import node_frag_score
-from tpusim.ops.resource import is_accessible, sub_pod
+from tpusim.ops.resource import first_max, is_accessible, sub_pod
 from tpusim.policies.base import PolicyResult, ScoreContext
 from tpusim.types import NodeState, PodSpec
 
@@ -91,8 +91,7 @@ def _fgd_share_node(cpu_left, gpu_left, gpu_type, pod: PodSpec, tp):
 
     fits = gpu_left >= p
     dev_scores = jnp.where(fits, _sigmoid_score(cur, new_per_dev), jnp.int32(-1))
-    best_dev = jnp.argmax(dev_scores).astype(jnp.int32)  # first max on ties
-    best_score = dev_scores[best_dev]
+    best_score, best_dev = first_max(dev_scores)
     ok = best_score >= 0  # == fits.any(): fitting devices always score >= 0
     score = jnp.where(ok, best_score, 0)
     dev = jnp.where(ok, best_dev, -1).astype(jnp.int32)

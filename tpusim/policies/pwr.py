@@ -15,7 +15,7 @@ import numpy as np
 
 from tpusim.constants import MILLI
 from tpusim.ops.energy import cpu_power_watts, gpu_busy_delta_watts, gpu_power_watts
-from tpusim.ops.resource import sub_pod
+from tpusim.ops.resource import first_max, sub_pod
 from tpusim.policies.base import PolicyResult, ScoreContext
 from tpusim.types import NodeState, PodSpec
 
@@ -45,8 +45,8 @@ def _pwr_node(row: NodeState, pod: PodSpec):
     )
     fits = row.gpu_left >= pod.gpu_milli
     dev_scores = jnp.where(fits, (old - new_per_dev).astype(jnp.int32), _NEG_INF)
-    best_dev = jnp.argmax(dev_scores).astype(jnp.int32)
-    share_score = jnp.where(fits.any(), dev_scores[best_dev], _NEG_INF)
+    best_score, best_dev = first_max(dev_scores)
+    share_score = jnp.where(fits.any(), best_score, _NEG_INF)
     share_dev = jnp.where(fits.any(), best_dev, -1).astype(jnp.int32)
 
     # whole-GPU / CPU-only: Sub's taken devices flip iff previously idle
