@@ -137,18 +137,190 @@ def test_spans_outside_a_sweep_carry_no_sweep_id():
     assert rec.spans[-1].sweep is None
 
 
-@pytest.mark.parametrize("profile", [False, True])
-def test_only_profile_blocks_on_the_phases(profile, monkeypatch):
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["unprofiled", "profiled"])
+def one_sweep(request):
+    """One sweep of a tiny Simulator in either mode: its record, the calls
+    of jax.block_until_ready it made, and the (shape, dtype) of every
+    device leaf it handed to device_fetch."""
+    profile = request.param
     sim, trace = _sim(block_size=-1, profile=profile)
-    blocked = []
-    real = jax.block_until_ready
-    monkeypatch.setattr(
-        jax, "block_until_ready", lambda x: blocked.append(1) or real(x))
-    schedule_pods_sweep(sim, trace, WEIGHTS, SEEDS)
-    rec = sweep_log()[-1]
+    blocked, fetched = [], []
+    real_block, real_fetch = jax.block_until_ready, driver.device_fetch
+
+    def fetch(tree, **kw):
+        fetched.extend((l.shape, l.dtype) for l in jax.tree.leaves(tree)
+                       if isinstance(l, jax.Array))
+        return real_fetch(tree, **kw)
+
+    jax.block_until_ready = lambda x: blocked.append(1) or real_block(x)
+    driver.device_fetch = fetch
+    try:
+        schedule_pods_sweep(sim, trace, WEIGHTS, SEEDS)
+    finally:
+        jax.block_until_ready, driver.device_fetch = real_block, real_fetch
+    return profile, sim, sweep_log()[-1], blocked, fetched
+
+
+def _span(rec, name):
+    return next(s for s in rec.spans if s.name == name)
+
+
+def test_only_profile_blocks_on_the_phases(one_sweep):
+    """Unprofiled no PHASE blocks: settle calls jax.block_until_ready not
+    once. The fetch waits for its packed buffer either way (the array's own
+    method, where np.asarray would have waited), and stamps `ready`."""
+    profile, _, rec, blocked, _ = one_sweep
     assert rec.blocked is profile
     # specs, lane_keys, lane_ranks, init_tables, scan, frag_postpass
     assert len(blocked) == (6 if profile else 0)
+    assert "ready" in _span(rec, "fetch").marks
+
+
+def test_the_fetchs_marks_are_ordered_and_partition_the_span(one_sweep):
+    _, _, rec, _, _ = one_sweep
+    fetch = _span(rec, "fetch")
+    assert list(fetch.marks) == ["ready", "copied"]
+    ready, copied = fetch.marks.values()
+    assert 0.0 < ready <= copied <= fetch.total_s
+    # wait, copy and unpack: three pieces, nothing between or beyond them
+    pieces = [ready, copied - ready, fetch.total_s - copied]
+    assert all(p >= 0.0 for p in pieces)
+    assert sum(pieces) == pytest.approx(fetch.total_s, abs=1e-12)
+    # the two other marked spans: one mark each, inside the dispatch half
+    for name, mark in (("lane_ranks", "stacked"),
+                       ("frag_postpass", "gathered")):
+        span = _span(rec, name)
+        assert list(span.marks) == [mark]
+        assert 0.0 < span.marks[mark] <= span.dispatch_s
+    assert [s.name for s in rec.spans if s.marks] == [
+        "lane_ranks", "frag_postpass", "fetch"]
+
+
+def test_the_derived_fields_account_for_the_call(one_sweep):
+    """Lead, covered, device block, device wait and tail: from the call's
+    start to the end of its last span, blocked or not; in to_dict() (so
+    in the run record's timing.sweeps) beside the marks and the bytes."""
+    from tpusim.obs.spans import DERIVED_FIELDS
+
+    profile, sim, rec, _, fetched = one_sweep
+    values = [getattr(rec, name) for name in DERIVED_FIELDS]
+    assert all(v is not None and v >= 0.0 for v in values), values
+    last = rec.spans[-1]
+    end = last.start_s + last.total_s - (rec.start_s - sim.obs.epoch)
+    assert sum(values) == pytest.approx(end, abs=1e-9)
+    assert 0.95 * rec.wall_s <= sum(values) <= rec.wall_s
+    scan, post, fetch = (_span(rec, n) for n in (
+        "scan", "frag_postpass", "fetch"))
+    assert rec.device_block_s == scan.block_s + post.block_s
+    assert rec.device_wait_s == fetch.marks["ready"]
+    assert rec.host_lead_s == pytest.approx(
+        scan.start_s + scan.dispatch_s - (rec.start_s - sim.obs.epoch))
+    # an unblocked wave waits for the device in the fetch alone
+    if not profile:
+        assert rec.device_block_s < 0.05 * rec.wall_s
+    # the packed buffer: every device leaf's bytes, a bool one byte
+    want = sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
+               for shape, dtype in fetched)
+    assert rec.fetch_bytes == want > 0
+    assert fetch.meta == {"events": 3 * rec.events, "bytes": want}
+    d = rec.to_dict()
+    assert d["fetch_bytes"] == want
+    for name, value in zip(DERIVED_FIELDS, values):
+        assert d[name] == round(value, 6)
+    by_name = {s["name"]: s for s in d["spans"]}
+    assert list(by_name["fetch"]["marks"]) == ["ready", "copied"]
+    assert [n for n, s in by_name.items() if "marks" in s] == [
+        "lane_ranks", "frag_postpass", "fetch"]
+    timing = sim.run_telemetry().to_record()["timing"]
+    assert timing["sweeps"][-1] == d
+
+
+def test_device_fetch_with_and_without_marks_returns_the_same_bits():
+    from tpusim.sim.fetch import device_fetch
+
+    rng = np.random.default_rng(3)
+    f32 = rng.standard_normal((5, 7)).astype(np.float32)
+    f32[0, :3] = [np.nan, -0.0, np.inf]
+    tree = {
+        "i": jax.numpy.asarray(rng.integers(-2**31, 2**31, (4, 3), np.int64)
+                               .astype(np.int32)),
+        "f": jax.numpy.asarray(f32),
+        "b": jax.numpy.asarray(rng.random((6, 5, 8)) < 0.5),
+        "none": None, "host": np.arange(3), "scalar": 7,
+        "nested": (jax.numpy.zeros((0,), np.int32), [jax.numpy.int32(-5)]),
+    }
+    rec = Recorder()
+    with rec.span("fetch") as h:
+        marked = device_fetch(tree, marks=h)
+    plain = device_fetch(tree)
+    a, ta = jax.tree_util.tree_flatten(marked)
+    b, tb = jax.tree_util.tree_flatten(plain)
+    want, tw = jax.tree_util.tree_flatten(tree)
+    assert ta == tb == tw
+    for x, y, w in zip(a, b, want):
+        if isinstance(w, jax.Array):
+            assert type(x) is type(y) is np.ndarray
+            assert x.dtype == y.dtype == w.dtype and x.shape == w.shape
+            assert x.tobytes() == y.tobytes() == np.asarray(w).tobytes()
+        else:
+            assert x is y is w
+    span = rec.spans[0]
+    assert list(span.marks) == ["ready", "copied"]
+    assert span.meta == {"bytes": 4 * 12 + 4 * 35 + 240 + 0 + 4}
+    # nothing to move: no mark, no bytes, the tree itself
+    with rec.span("fetch") as h:
+        assert device_fetch({"none": None, "n": 3}, marks=h) == {
+            "none": None, "n": 3}
+    assert rec.spans[1].marks == {} and rec.spans[1].meta == {}
+
+
+def test_a_span_without_marks_serializes_as_before():
+    """`marks` is emitted only where there are any, in the record and in
+    the Chrome trace, whose mark slices nest inside the span's two."""
+    from tpusim.obs import emitters
+
+    rec = Recorder()
+    with rec.span("scan", engine="table") as h:
+        h.dispatched()
+    with rec.span("report"):
+        pass
+    with rec.span("fetch") as h:
+        h.mark("ready", then="copy")
+        h.mark("copied")
+        h.dispatched()
+    plain, bare, marked = rec.spans
+    assert set(plain.to_dict()) == {
+        "name", "start_s", "dispatch_s", "block_s", "total_s", "meta"}
+    assert set(bare.to_dict()) == {
+        "name", "start_s", "dispatch_s", "block_s", "total_s"}
+    assert plain.marks == {} and bare.marks == {}
+    d = marked.to_dict()
+    assert list(d["marks"]) == ["ready", "copied"]
+    assert 0.0 <= d["marks"]["ready"] <= d["marks"]["copied"] <= d["total_s"]
+    marked.dispatch_s, marked.block_s = 0.25, 0.75  # a split to straddle
+    marked.marks = {"ready": 0.1, "copied": 0.4}
+    events = emitters.chrome_trace_events(rec.spans)
+    assert {e["name"] for e in events if ".." not in e["name"]} <= {
+        "scan:dispatch", "scan:block", "report:dispatch", "fetch:dispatch",
+        "fetch:block"}
+    assert all(e["name"].startswith("fetch:") for e in events
+               if ".." in e["name"])
+    t0 = marked.start_s * 1e6
+    slices = [(e["name"], round(e["ts"] - t0), round(e["dur"]))
+              for e in events if ".." in e["name"]]
+    assert slices == [
+        ("fetch:..ready", 0, 100000),
+        ("fetch:ready..copied", 100000, 150000),  # to the dispatch's end
+        ("fetch:ready..copied", 250000, 150000),
+        ("fetch:copied..", 400000, 600000),
+    ]
+    halves = {e["name"]: (e["ts"], e["ts"] + e["dur"]) for e in events
+              if e["name"] in ("fetch:dispatch", "fetch:block")}
+    for e in events:
+        if ".." in e["name"]:
+            assert any(lo - 1e-6 <= e["ts"] and e["ts"] + e["dur"] <= hi + 1e-6
+                       for lo, hi in halves.values()), e
 
 
 def test_note_compile_cache_exact_fields():
@@ -194,6 +366,9 @@ def test_spans_are_annotations_on_the_profilers_host_plane(
     host = next(p for p in data.planes if p.name == "/host:CPU")
     names = {ev.name for line in host.lines for ev in line.events}
     assert {f"tpusim/{n}" for n in SPAN_NAMES} <= names
+    # the sub-phases the marks open, nested in their span's annotation
+    assert {"tpusim/lane_ranks/transfer", "tpusim/frag_postpass/program",
+            "tpusim/fetch/copy", "tpusim/fetch/unpack"} <= names
 
 
 @pytest.fixture(scope="module", params=sorted(BODY_SCOPES))

@@ -293,7 +293,10 @@ def chrome_trace_events(spans: Iterable, pid: int = 1) -> List[dict]:
     """Span list -> Chrome trace "X" events (ts/dur in microseconds).
     Each span renders as two stacked slices — the dispatch (compile)
     half and the block (execute) half — so the compile/execute split is
-    visible directly on the timeline."""
+    visible directly on the timeline. A span's marks (Span.marks) cut it
+    into sub-phases, `<span>:<mark>..<mark>`, drawn as slices nested in
+    those two (one that straddles the dispatch/block boundary is drawn
+    in two parts, so that every slice nests)."""
     events = []
     for s in spans:
         d = s.to_dict() if hasattr(s, "to_dict") else dict(s)
@@ -312,6 +315,19 @@ def chrome_trace_events(spans: Iterable, pid: int = 1) -> List[dict]:
                 "dur": d["block_s"] * 1e6,
                 "args": d.get("meta", {}),
             })
+        marks = d.get("marks")
+        if not marks:
+            continue
+        split, end = d.get("dispatch_s", 0), d.get("total_s", 0)
+        edges = [("", 0.0), *marks.items(), ("", end)]
+        for (a, ta), (b, tb) in zip(edges, edges[1:]):
+            cuts = [ta, split, tb] if ta < split < tb else [ta, tb]
+            for lo, hi in zip(cuts, cuts[1:]):
+                if hi > lo:
+                    events.append({
+                        **base, "name": f"{d['name']}:{a}..{b}",
+                        "ts": t0 + lo * 1e6, "dur": (hi - lo) * 1e6,
+                    })
     return events
 
 

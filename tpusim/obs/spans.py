@@ -32,6 +32,13 @@ A sweep call wraps its body in Recorder.sweep: its spans carry the
 sweep's id and stay flat, and one SweepRecord (wall, spans, the compile
 counts over the call) goes to a bounded process-wide log that outlives
 the Simulator (sweep_log()).
+
+A span can carry marks: named instants its handle stamps inside it
+(`h.mark("copied")`), seconds since the span's start. Three spans of a
+sweep have them (fetch: ready, copied; lane_ranks: stacked;
+frag_postpass: gathered), and the SweepRecord derives from spans and
+marks what the host did before, under and after the device's work
+(host_lead_s, covered_s, device_block_s, device_wait_s, host_tail_s).
 """
 
 from __future__ import annotations
@@ -62,6 +69,9 @@ class Span:
     block_s: float  # wall waiting on the device result (execute); 0 = unknown
     meta: Dict[str, object] = field(default_factory=dict)
     sweep: Optional[int] = None  # id of the enclosing Recorder.sweep, if any
+    # instants stamped inside the span (_SpanHandle.mark): name -> seconds
+    # since the span's start, in the order they were stamped
+    marks: Dict[str, float] = field(default_factory=dict)
 
     @property
     def total_s(self) -> float:
@@ -79,6 +89,8 @@ class Span:
             d["meta"] = self.meta
         if self.sweep is not None:
             d["sweep"] = self.sweep
+        if self.marks:
+            d["marks"] = {k: round(v, 6) for k, v in self.marks.items()}
         return d
 
 
@@ -136,6 +148,14 @@ _sweep_log: deque = deque(maxlen=SWEEP_LOG_SIZE)
 _sweep_ids = itertools.count()
 
 
+DERIVED_FIELDS = ("host_lead_s", "covered_s", "device_block_s",
+                  "device_wait_s", "host_tail_s")
+
+
+def _rounded(seconds: Optional[float]) -> Optional[float]:
+    return None if seconds is None else round(seconds, 6)
+
+
 @dataclass
 class SweepRecord:
     """One schedule_pods_sweep call: its spans are flat and back to back
@@ -145,6 +165,7 @@ class SweepRecord:
     id: int
     start_s: float  # absolute time.perf_counter() at entry
     blocked: bool  # spans blocked on their results (Recorder.enabled)
+    epoch: float = 0.0  # the Recorder's, which the spans' start_s count from
     lanes: int = 0
     events: int = 0
     engine: str = ""
@@ -181,10 +202,82 @@ class SweepRecord:
     # (`normalize` minmax / pwr: feasible extrema, scale and weighted
     # total every event), read off the policies; 0 for raw-score families
     normalized_policies: int = 0
+    # bytes of the packed buffer the fetch span moved to the host
+    # (sim/fetch.device_fetch: every lane's result in one transfer)
+    fetch_bytes: int = 0
 
     @property
     def compiled(self) -> int:
         return self.programs_requested - self.cache_loads
+
+    # What the host did before, under and after the device's work, from the
+    # spans and their marks; None where the call left no such span or mark
+    # (a record no schedule_pods_sweep filled). Blocked or not,
+    #   host_lead_s + covered_s + device_block_s + device_wait_s
+    #   + host_tail_s
+    # is the time from the call's start to the end of its last span.
+
+    def _span(self, name: str) -> Optional[Span]:
+        return next((s for s in self.spans if s.name == name), None)
+
+    def _scan_dispatched_s(self) -> Optional[float]:
+        scan = self._span("scan")
+        return None if scan is None else scan.start_s + scan.dispatch_s
+
+    def _fetch_ready_s(self) -> Optional[float]:
+        fetch = self._span("fetch")
+        if fetch is None or "ready" not in fetch.marks:
+            return None
+        return fetch.start_s + fetch.marks["ready"]
+
+    @property
+    def host_lead_s(self) -> Optional[float]:
+        """From the call's start to the scan's dispatch: specs, keys,
+        ranks, the tables' hand-over, the sweep wrapper's dispatch and the
+        gaps between. The device has no scan to run yet, so nothing here
+        can hide behind it."""
+        at = self._scan_dispatched_s()
+        return None if at is None else at - (self.start_s - self.epoch)
+
+    @property
+    def device_block_s(self) -> Optional[float]:
+        """What the host waited on the device inside the scan and the
+        post-pass spans: their block halves in a blocked wave,
+        microseconds in one that did not block."""
+        scan, post = self._span("scan"), self._span("frag_postpass")
+        if scan is None or post is None:
+            return None
+        return scan.block_s + post.block_s
+
+    @property
+    def covered_s(self) -> Optional[float]:
+        """Host work between the scan's dispatch and the fetch's start,
+        less device_block_s: the post-pass's gather, trace, lowering and
+        cache load. Hidden iff the wave did not block and the scan
+        outlasts it."""
+        at, fetch = self._scan_dispatched_s(), self._span("fetch")
+        block = self.device_block_s
+        if at is None or fetch is None or block is None:
+            return None
+        return fetch.start_s - at - block
+
+    @property
+    def device_wait_s(self) -> Optional[float]:
+        """The fetch's start to its `ready` mark: the device finishing
+        what it still owed (the pack alone in a blocked wave; the scan's
+        tail, the post-pass and the pack in one that did not block)."""
+        fetch = self._span("fetch")
+        return None if fetch is None else fetch.marks.get("ready")
+
+    @property
+    def host_tail_s(self) -> Optional[float]:
+        """The fetch's `ready` mark to the end of the slice_lanes span:
+        copy, unpack and per-lane slicing, after the device's last
+        program, so nothing hides it."""
+        ready, last = self._fetch_ready_s(), self._span("slice_lanes")
+        if ready is None or last is None:
+            return None
+        return last.start_s + last.total_s - ready
 
     def to_dict(self) -> dict:
         return {
@@ -206,6 +299,8 @@ class SweepRecord:
             "typical_sets": self.typical_sets,
             "weight_rows": self.weight_rows,
             "normalized_policies": self.normalized_policies,
+            "fetch_bytes": self.fetch_bytes,
+            **{n: _rounded(getattr(self, n)) for n in DERIVED_FIELDS},
             "spans": [s.to_dict() for s in self.spans],
         }
 
@@ -217,17 +312,45 @@ def sweep_log() -> List[SweepRecord]:
 
 class _SpanHandle:
     """Yielded by Recorder.span(); call .dispatched() the moment the
-    device call returns to split compile/dispatch from execute/block."""
+    device call returns to split compile/dispatch from execute/block,
+    .mark(name) at any other instant worth keeping, .note(k=v) for what
+    the span learns about itself as it runs."""
 
-    __slots__ = ("_t0", "_t_dispatch")
+    __slots__ = ("_name", "_t0", "_t_dispatch", "_sub", "marks", "meta")
 
-    def __init__(self, t0: float):
+    def __init__(self, name: str, t0: float, meta: dict):
+        self._name = name
         self._t0 = t0
         self._t_dispatch = None
+        self._sub = None  # the open sub-phase annotation, if any
+        self.marks: Dict[str, float] = {}
+        self.meta = meta
 
     def dispatched(self):
         if self._t_dispatch is None:
             self._t_dispatch = time.perf_counter()
+
+    def mark(self, name: str, then: str = ""):
+        """Stamp the instant `name` (seconds since the span's start, into
+        Span.marks). `then` names the sub-phase that starts here: a
+        `tpusim/<span>/<then>` annotation nested in the span's own, until
+        the next mark or the span's end."""
+        self.marks[name] = time.perf_counter() - self._t0
+        self._close_sub()
+        if then:
+            from jax.profiler import TraceAnnotation
+
+            self._sub = TraceAnnotation(f"tpusim/{self._name}/{then}")
+            self._sub.__enter__()
+
+    def note(self, **meta):
+        """Add to the span's meta."""
+        self.meta.update(meta)
+
+    def _close_sub(self):
+        if self._sub is not None:
+            self._sub.__exit__(None, None, None)
+            self._sub = None
 
 
 class Recorder:
@@ -271,11 +394,12 @@ class Recorder:
 
         with TraceAnnotation(f"tpusim/{name}"):
             t0 = time.perf_counter()
-            h = _SpanHandle(t0)
+            h = _SpanHandle(name, t0, meta)
             try:
                 yield h
             finally:
                 t1 = time.perf_counter()
+                h._close_sub()
                 td = h._t_dispatch if h._t_dispatch is not None else t1
                 self.spans.append(Span(
                     name=name,
@@ -284,6 +408,7 @@ class Recorder:
                     block_s=t1 - td,
                     meta=meta,
                     sweep=self._sweep,
+                    marks=h.marks,
                 ))
 
     def settle(self, handle: _SpanHandle, *results):
@@ -306,7 +431,7 @@ class Recorder:
         process-wide log (sweep_log())."""
         rec = SweepRecord(
             id=next(_sweep_ids), start_s=time.perf_counter(),
-            blocked=self.enabled, lanes=int(lanes),
+            blocked=self.enabled, epoch=self.epoch, lanes=int(lanes),
         )
         first_span = len(self.spans)
         requested0, loads0 = compile_counts()

@@ -3695,11 +3695,14 @@ def _lane_keys(seeds):
     return jnp.stack([jax.random.PRNGKey(s) for s in seeds])
 
 
-def _lane_ranks(num_nodes: int, seeds):
+def _lane_ranks(num_nodes: int, seeds, marks=None):
     """[B, N] tie-break ranks, one permutation a lane, stacked on the host
     and moved in one transfer (fresh every call: the sweep engines donate
-    it)."""
-    return jnp.asarray(np.stack([tiebreak_rank(num_nodes, s) for s in seeds]))
+    it). `marks`: a span handle to stamp `stacked` on, between the two."""
+    stacked = np.stack([tiebreak_rank(num_nodes, s) for s in seeds])
+    if marks is not None:
+        marks.mark("stacked", then="transfer")
+    return jnp.asarray(stacked)
 
 
 def _slice_sweep_lane(out, amounts, watts, i, wrow, seed, p, e, pad_skips):
@@ -4437,8 +4440,9 @@ def schedule_pods_sweep(
     state = sim.init_state
 
     # eight flat, back-to-back spans under one sweep record: specs,
-    # lane_keys, lane_ranks, init_tables, scan, frag_postpass, fetch,
-    # slice_lanes
+    # lane_keys, lane_ranks (mark: stacked), init_tables, scan,
+    # frag_postpass (mark: gathered), fetch (marks: ready, copied),
+    # slice_lanes; the record derives the host's lead and tail from them
     with obs.sweep(lanes=b) as sweep:
         sweep.weight_rows = len(np.unique(w, axis=0))
         sweep.normalized_policies = sum(
@@ -4485,7 +4489,7 @@ def schedule_pods_sweep(
             keys = _lane_keys(seeds)
             obs.settle(h, keys)
         with obs.span("lane_ranks") as h:
-            ranks = _lane_ranks(len(sim.nodes), seeds)
+            ranks = _lane_ranks(len(sim.nodes), seeds, marks=h)
             weights_d = jnp.asarray(w)
             obs.settle(h, ranks, weights_d)
 
@@ -4540,6 +4544,7 @@ def schedule_pods_sweep(
             tp_ax = 0 if lane_set else None
             if lane_set:
                 typical = jax.tree.map(lambda a: a[lane_set[0]], typical)
+            h.mark("gathered", then="program")
             if report:
                 out = out._replace(
                     metrics=_sweep_metrics_fn(_lane_axis(ev_kind, 1), tp_ax)(
@@ -4556,8 +4561,9 @@ def schedule_pods_sweep(
                 jax.vmap(_lane_postpass, in_axes=(0, tp_ax))
             )(out.state, typical)
             obs.settle(h, amounts, watts, out.metrics)
-        with obs.span("fetch", events=true_events):
-            out, amounts, watts = device_fetch((out, amounts, watts))
+        with obs.span("fetch", events=true_events) as h:
+            out, amounts, watts = device_fetch((out, amounts, watts), marks=h)
+            sweep.fetch_bytes = h.meta.get("bytes", 0)
 
         with obs.span("slice_lanes"):
             if faulted:

@@ -4,8 +4,13 @@ Every device→host readback pays a fixed latency regardless of payload
 size, and a replay output is ~20 small leaves. device_fetch() packs every
 device leaf of a pytree into ONE uint8 buffer on device (bitcast, so
 f32/i32 bits survive exactly) and reads it back in a single transfer, then
-reslices host-side. What one packed transfer saves over per-leaf readbacks
-on the chip this repo runs on has not been measured (ROADMAP S1).
+reslices host-side. A caller's span handle (obs.Recorder.span) gets two
+marks, `ready` (the device has finished the pack and whatever it still
+owed before it) and `copied` (the bytes are on the host; the rest of the
+span is the reslicing and the bool leaves' casts), and the buffer's size as
+`bytes`; a sweep's record reads them as device_wait_s, fetch_bytes and the
+head of host_tail_s. What the chip gave for copy against unpack: PERF.md,
+sections 5 and 6 (PR 36).
 
 The reference has no equivalent host/device boundary — its "transfer" is
 the in-memory fake API server (SURVEY.md §5.8); this helper is the cost
@@ -38,17 +43,27 @@ def _packer(sig):
     return jax.jit(pack)
 
 
-def device_fetch(tree):
+def device_fetch(tree, marks=None):
     """Return `tree` with every jax.Array leaf replaced by a host numpy
     array, moving all of them in one device→host transfer. Non-array
-    leaves (None, python scalars, numpy arrays) pass through untouched."""
+    leaves (None, python scalars, numpy arrays) pass through untouched.
+    `marks`: a span handle to stamp `ready` and `copied` on and to note
+    the packed `bytes` in; the wait it stamps is the one the copy would
+    have made."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     idx = [i for i, l in enumerate(leaves) if isinstance(l, jax.Array)]
     if not idx:
         return tree
     dev = [leaves[i] for i in idx]
     sig = tuple((tuple(l.shape), str(l.dtype)) for l in dev)
-    buf = np.asarray(_packer(sig)(dev))
+    packed = _packer(sig)(dev)
+    packed.block_until_ready()
+    if marks is not None:
+        marks.mark("ready", then="copy")
+    buf = np.asarray(packed)
+    if marks is not None:
+        marks.mark("copied", then="unpack")
+        marks.note(bytes=int(buf.nbytes))
     off = 0
     for i, l in zip(idx, dev):
         if l.dtype == jnp.bool_:
