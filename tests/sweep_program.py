@@ -169,6 +169,16 @@ def gathers_in(text: str, root: str) -> list:
             if m.group(3) == "gather"]
 
 
+def gather_slices_in(text: str, root: str) -> list:
+    """gathers_in with each gather's `slice_sizes`: (computation, name,
+    result shape, (sizes))."""
+    return [(name, m.group(1), m.group(2).strip(), tuple(
+                int(d) for d in re.search(
+                    r"slice_sizes=\{([\d,]*)\}", line).group(1).split(",")))
+            for name, m, line, _ in _instructions_in(text, root)
+            if m.group(3) == "gather"]
+
+
 def fusions_reading(text: str, root: str, shape: str) -> list:
     """(computation, name, result shape) of every fusion in `root` and
     the computations it calls whose fused computation takes a parameter
@@ -183,6 +193,24 @@ def fusions_reading(text: str, root: str, shape: str) -> list:
                and re.search(shape, p.group(2))
                for p in map(_INSTRUCTION.match, fused)):
             found.append((name, m.group(1), m.group(2).strip()))
+    return found
+
+
+def fusion_shapes(text: str, root: str) -> list:
+    """(fusion, [dims of its result(s) and of its fused computation's
+    parameters]) for every fusion in `root` and the computations it calls:
+    the arrays the program moves between fusions there."""
+    found = []
+    for _, m, line, comps in _instructions_in(text, root):
+        if m.group(3) != "fusion":
+            continue
+        fused = comps[re.search(r"calls=%?([\w.\-]+)", line).group(1)]
+        shapes = _SHAPE.findall(m.group(2))
+        for p in map(_INSTRUCTION.match, fused):
+            if p and p.group(3) == "parameter":
+                shapes += _SHAPE.findall(p.group(2))
+        found.append((m.group(1), [
+            tuple(int(d) for d in dims.split(",") if d) for dims in shapes]))
     return found
 
 

@@ -438,3 +438,88 @@ def test_the_share_kernels_keep_the_parent_s_values(kernel, monkeypatch):
     assert [int(dev.sum()), int((~placed).sum()), dev[0, :12].tolist()] == ints
     assert float(score[placed].astype(np.float64).sum()) == pytest.approx(
         score_sum, abs=1e-3)
+
+
+# every kind of whole-branch request a type set can hold: (gpu_milli, gpu_num)
+WHOLE_REQUESTS = {
+    "cpu only": (0, 0),
+    "one gpu": (1000, 1),
+    "two gpus": (1000, 2),
+    "four gpus": (1000, 4),
+    "eight gpus": (1000, 8),
+    "fractional multi-gpu": (500, 2),
+    "more devices than fit": (1000, 6),
+    "padded dummy": (0, 0),
+}
+
+
+@pytest.fixture(scope="module")
+def whole_split_scores():
+    """(kind of each type, what FGD's whole kernel gives a (type, node), what
+    its two steps give) over _share_kernel_rows' 48 node rows and three CPU
+    requests of every kind of WHOLE_REQUESTS; the dummy as pad_pod_types
+    makes it."""
+    from tpusim.policies.fgd import fgd_score
+    from tpusim.sim.table_engine import _take_request, _whole_requests
+    from tpusim.types import PodSpec
+
+    st, _ = _share_kernel_rows()
+    tp = make_typical_pods([
+        (6000, 465, 1, 0, 0.4), (16000, 1000, 1, 0, 0.3),
+        (8000, 250, 1, 0, 0.2), (32000, 1000, 2, 0, 0.1)])
+    kinds = [k for k in WHOLE_REQUESTS for _ in range(3)]
+    cpus = [2**30 if k == "padded dummy" else c
+            for k in WHOLE_REQUESTS for c in (100, 9000, 40000)]
+    pods = PodSpec(
+        cpu=jnp.asarray(cpus, jnp.int32),
+        mem=jnp.asarray([2**30 if k == "padded dummy" else 1024
+                         for k in kinds], jnp.int32),
+        gpu_milli=jnp.asarray([WHOLE_REQUESTS[k][0] for k in kinds], jnp.int32),
+        gpu_num=jnp.asarray([WHOLE_REQUESTS[k][1] for k in kinds], jnp.int32),
+        gpu_mask=jnp.zeros(len(kinds), jnp.int32),
+        pinned=jnp.full(len(kinds), -1, jnp.int32))
+    requests, request_of = _whole_requests(pods)
+    # seven distinct requests among the eight kinds (the dummy is CPU-only),
+    # on their bucket of eight
+    assert requests.shape == (8, 2) and int(request_of.max()) == 6
+    ctx = ctx_for(st, tp)
+    whole = fgd_score.branches["whole"]
+    request, finish = fgd_score.branches["whole_split"]
+
+    @jax.jit
+    def both(st, pods, requests, request_of):
+        plain = jax.vmap(lambda pod: whole(st, pod, ctx))(pods)
+        terms = jax.vmap(lambda q: request(st, q[0], q[1], ctx))(requests)
+        split = jax.vmap(lambda pod, r: finish(
+            st, pod, _take_request(terms, r), ctx))(pods, request_of)
+        return plain, split
+
+    plain, split = both(st, pods, requests, request_of)
+    return kinds, st, pods, jax.tree.map(np.asarray, (plain, split))
+
+
+@pytest.mark.parametrize("kind", list(WHOLE_REQUESTS))
+def test_the_whole_split_keeps_the_whole_kernel_s_values(
+        kind, whole_split_scores):
+    """Sub's hypothetical once a REQUEST and the finish once a type: equal to
+    _fgd_whole_node entry by entry, score and dtype, for every kind of
+    whole-branch request: fitting or not (Sub's `ok` false: the kernel never
+    read it), CPU-only, and the dummy type pad_pod_types appends."""
+    from tpusim.ops.resource import sub_devices
+
+    kinds, st, pods, (plain, split) = whole_split_scores
+    rows = np.flatnonzero(np.asarray(kinds) == kind)
+    assert len(rows) == 3
+    assert split.raw_scores.dtype == plain.raw_scores.dtype == np.int32
+    np.testing.assert_array_equal(split.raw_scores[rows], plain.raw_scores[rows])
+    np.testing.assert_array_equal(split.share_dev[rows], plain.share_dev[rows])
+    assert (split.share_dev[rows] == -1).all()
+    # the scores are not one number: the rows tell nodes and CPU requests apart
+    assert len(np.unique(plain.raw_scores[rows])) > 1
+    milli, num = WHOLE_REQUESTS[kind]
+    _, _, ok = jax.vmap(sub_devices, in_axes=(0, None, None))(
+        st.gpu_left, milli, num)
+    if kind == "more devices than fit":
+        assert not np.asarray(ok).all()  # Sub fails on some node
+    if num == 0:
+        assert np.asarray(ok).all()

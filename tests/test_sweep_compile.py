@@ -129,12 +129,50 @@ def _assert_no_gather_a_lane_and_type(text, inner, lanes):
     computation's device pick is ops/resource.first_max, reductions alone:
     `dev_scores[argmax(dev_scores)]` was a gather to [lanes,k], k a type
     group's size, which the chip ran serialized at 10 ns an element (a
-    third of the mix cell's scan: ISSUE 35)."""
-    gathers = sweep_program.gathers_in(text, inner)
+    third of the mix cell's scan: ISSUE 35). The take by request (ISSUE 37:
+    each whole type's terms out of the terms of the type set's distinct
+    requests) goes through ONE index the lanes share: a slice of it holds
+    every lane, [lanes,1,(1,)T] out of [lanes,G,(1,)T], K_whole of them."""
+    gathers = sweep_program.gather_slices_in(text, inner)
     assert gathers  # the loop is the right one: Sub's and the picks are there
-    for _, name, out in gathers:
-        assert re.match(rf"(s32|pred)\[{lanes}(,8|,(2,)?1213)?\]", out), (
-            name, out)
+    shared = [g for g in gathers if g[3][0] == lanes]
+    for _, name, out, sizes in gathers:
+        assert sizes[0] == lanes or re.match(
+            rf"(s32|pred)\[{lanes}(,8|,(2,)?1213)?\]", out), (name, out, sizes)
+    return shared
+
+
+def _assert_one_hypothetical_a_request(text, inner, lanes, types, t):
+    """FGD's whole-branch hypothetical (Sub, then the stacked [T,8,2] terms
+    of the device vector it leaves) runs once a distinct REQUEST of the type
+    set: no fusion of the per-event loop takes or gives an array of
+    [lanes, K_whole, .., T, .., 8, ..] any more (the parent materialized
+    f32[lanes,K_whole,1,T,8,1] and reduced it to [lanes,K_whole,T,2], the
+    largest piece of every flat cell's scan); the block is
+    [lanes, G, .., T, .., 8, ..], and each type takes its request's terms by
+    the index the lanes share."""
+    ks, kw = types.share.cpu.shape[-1], types.whole.cpu.shape[-1]
+    g = types.requests.shape[0]
+    assert g == 8 and types.request_of.shape == (kw,)  # no lane axis
+    found = sweep_program.fusion_shapes(text, inner)
+
+    def stacked(group):
+        """_share_terms' stack and its sum, as the compiler shapes them."""
+        return [(name, dims) for name, shapes in found for dims in shapes
+                if dims in ((lanes, group, 1, t, 8, 1), (lanes, group, t, 2))]
+
+    assert kw != g and not stacked(kw), stacked(kw)
+    assert stacked(g)  # the same block, a request
+    if kw != ks:
+        # (the share branch holds a [lanes,K_share,1,T,8] block of its own)
+        held = [(name, dims) for name, shapes in found for dims in shapes
+                if dims[:2] == (lanes, kw) and t in dims[2:]
+                and 8 in dims[dims.index(t, 2) + 1:]]
+        assert not held, held
+    shared = _assert_no_gather_a_lane_and_type(text, inner, lanes)
+    # two [T] terms and the total a column; the scan unrolls by 4
+    assert sorted(out.split("{")[0] for _, _, out, _ in shared) == sorted(
+        [f"f32[{lanes},{kw},{t}]"] * 8 + [f"f32[{lanes},{kw}]"] * 4), shared
 
 
 def _lane_operands(operands, sim, trace, lanes):
@@ -232,7 +270,8 @@ def test_the_openb_flat_sweep_loops_over_events_only(one_chip, operands):
 
     # no whole-table operation inside the per-event step
     assert not sweep_program.producers_in(text, inner, table)
-    _assert_no_gather_a_lane_and_type(text, inner, lanes)
+    _assert_one_hypothetical_a_request(
+        text, inner, lanes, shapes[2], shapes[5].cpu.shape[-1])
     if own:
         # what takes a table in there is a row gather, [lanes, N] out: the
         # step's three reads an event (the scan unrolls by 4), none a
@@ -301,8 +340,10 @@ def test_the_normalized_two_policy_sweep_loops_over_events_only(
         assert f"s32[{lanes},1213,9]" in carried  # the scan's carry
     assert not sweep_program.producers_in(text, inner, table)
     # PWR's kernel has no branches: its share path, and the pick in it, runs
-    # over the whole-GPU type group too, so two groups' sizes are at stake
-    _assert_no_gather_a_lane_and_type(text, inner, lanes)
+    # over the whole-GPU type group too, so two groups' sizes are at stake;
+    # FGD takes its whole types by request beside it
+    _assert_one_hypothetical_a_request(
+        text, inner, lanes, shapes[2], shapes[5].cpu.shape[-1])
     if own:
         # a row a lane, or both policies' rows. (The lanes of a shared
         # trace slice their one row inside whatever fusion reads it, the

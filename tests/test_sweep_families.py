@@ -86,6 +86,9 @@ def test_the_families_typical_sets_differ_in_size(fam):
     assert [int(s.typical.cpu.shape[0]) for s in fam.sims] == [48, 144]
     assert (fam.rec.typical_sets, fam.rec.traces, fam.rec.lanes) == (2, 4, 8)
     assert fam.rec.to_dict()["typical_sets"] == 2
+    # FGD takes its whole-branch types by request: the union type set's
+    # distinct (gpu_milli, gpu_num), on their bucket of eight (ISSUE 37)
+    assert fam.rec.sub_requests == fam.rec.to_dict()["sub_requests"] == 8
     assert fam.cache == "built 2 of 2" and fam.rec.tables_reused == 0
     # 8 lanes are under FLAT_GROUP_MIN_LANES: the plain flat body, one
     # dense column write an event; a trace a lane has 28 dense sites

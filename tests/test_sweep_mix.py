@@ -95,6 +95,10 @@ def test_the_mix_wave_runs_the_grouped_body_under_three_weight_rows(mix):
     assert (rec.table_pass_events, rec.dense_accesses) == (16, 32)
     assert rec.to_dict()["weight_rows"] == 3
     assert rec.to_dict()["normalized_policies"] == 1
+    # PWR has no whole_split: it tries Sub once a whole-branch pod TYPE, so
+    # a column holds K_whole hypotheticals though FGD's go by request
+    assert rec.sub_requests == rec.to_dict()["sub_requests"] > 8
+    assert rec.sub_requests % 16 == 0  # the padded whole group
     for lane, row in zip(mix.lanes, mix.weights):
         np.testing.assert_array_equal(lane.weights, row)
     # the rows matter: lanes of one (shuffle, seed offset) differ by row
@@ -175,8 +179,10 @@ def test_every_sweeps_lanes_carry_their_final_watts(sweep, blocked):
     lanes = sweep()
     rec = sweep_log()[-1]
     assert (rec.table_pass_events == 0) == blocked
-    # an FGD seed sweep: one weight row, no normalizer in the scan
+    # an FGD seed sweep: one weight row, no normalizer in the scan, Sub's
+    # hypotheticals by request
     assert (rec.weight_rows, rec.normalized_policies) == (1, 0)
+    assert rec.sub_requests == 8
     assert_watts(lanes)
     if any(lane.disruption is not None for lane in lanes):
         assert any(lane.disruption.evicted_pods for lane in lanes)

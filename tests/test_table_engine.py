@@ -516,3 +516,131 @@ def test_the_flat_group_follows_from_the_sweeps_shapes():
     assert flat_group_events(1, 1213) == 1
     assert flat_group_events(2560, BLOCKED_MIN_NODES) == 1
     assert flat_group_events(40, 100_000) == 1
+
+
+# ---- the whole group's distinct GPU requests (ISSUE 37) ----
+
+def _shuffled(pods, rng):
+    order = rng.permutation(int(pods.cpu.shape[0]))
+    return jax.tree.map(lambda a: a[order], pods)
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["built", "padded"])
+def test_every_whole_type_knows_its_request(padded):
+    """build_pod_types / pad_pod_types give each whole type the row of its
+    own (gpu_milli, gpu_num) among the group's distinct requests; G sits on
+    its bucket whichever shuffle of the pod list was loaded, and the rows
+    past the distinct requests are no type's."""
+    from tpusim.sim.table_engine import REQUEST_BUCKET, pad_pod_types
+
+    rng = np.random.default_rng(37)
+    pods = random_pods(rng, num_pods=50)
+    shapes = set()
+    for trace in (pods, _shuffled(pods, rng), jax.tree.map(
+            lambda a: a[:20], _shuffled(pods, rng))):
+        t = build_pod_types(trace)
+        if padded:
+            t = pad_pod_types(t)
+        kw = int(t.whole.cpu.shape[0])
+        requests, request_of = np.asarray(t.requests), np.asarray(t.request_of)
+        assert request_of.shape == (kw,) and request_of.dtype == np.int32
+        np.testing.assert_array_equal(
+            requests[request_of],
+            np.stack([np.asarray(t.whole.gpu_milli),
+                      np.asarray(t.whole.gpu_num)], 1))
+        distinct = len({tuple(r) for r in requests[request_of]})
+        # random_pods: CPU-only and 1, 2 or 4 whole GPUs
+        assert 1 < distinct <= 4 < kw
+        assert requests.shape == (REQUEST_BUCKET, 2)
+        assert int(request_of.max()) == distinct - 1  # the rest: no type's
+        assert (requests[distinct:] == 0).all()
+        if padded:
+            assert kw % 16 == 0
+            dummy = np.asarray(t.whole.cpu) == 2**30
+            assert dummy.any() and (requests[request_of[dummy]] == 0).all()
+        shapes.add(requests.shape)
+    assert len(shapes) == 1
+
+
+def _stub_policy(state, pod, ctx):
+    """A scoring kernel with no branches and no split: free CPU after the
+    pod, in cores."""
+    from tpusim.policies import PolicyResult
+
+    return PolicyResult(
+        jnp.maximum(state.cpu_left - pod.cpu, 0) // 1000,
+        jnp.full(state.num_nodes, -1, jnp.int32))
+
+
+_stub_policy.normalize = "none"
+_stub_policy.policy_name = "StubScore"
+
+
+class _Counted:
+    """A policy's whole_split with its request step counted."""
+
+    def __init__(self, split):
+        self.calls, (self._request, self.finish) = 0, split
+
+    def request(self, *args):
+        self.calls += 1
+        return self._request(*args)
+
+
+@pytest.mark.parametrize("policies,gpu_sel", [
+    ([("FGDScore", 1000)], "FGDScore"),
+    ([("PWRScore", 500), ("FGDScore", 500)], "FGDScore"),
+    ([("BestFitScore", 1000)], "best"),
+    ([("FGDScore", 700), (_stub_policy, 300)], "FGDScore"),
+], ids=["fgd", "pwr+fgd", "bestfit", "fgd+stub"])
+@pytest.mark.parametrize("builder", ["init_tables", "columns"])
+def test_the_split_builds_what_the_per_type_path_builds(
+        builder, policies, gpu_sel, monkeypatch):
+    """Tables and columns through the split (Sub's hypothetical once a
+    distinct request) equal the per-type fallback bit for bit: scores,
+    sharedev, feas; FGD alone and beside PWR, which has no split and goes
+    type by type in the same group; a policy family without one builds as
+    it did."""
+    from tpusim.policies.fgd import fgd_score
+    from tpusim.sim.table_engine import (
+        make_table_builders, pad_pod_types, selector_index, sub_requests)
+
+    rng = np.random.default_rng(3737)
+    state, tp = random_cluster(rng, num_nodes=24)
+    # a used cluster: some devices taken or partly taken, some CPU gone
+    left = np.asarray(state.gpu_left) * rng.choice(
+        [0, 0.25, 0.5, 1, 1], state.gpu_left.shape)
+    state = state._replace(
+        gpu_left=jnp.asarray(left.astype(np.int32)),
+        cpu_left=jnp.asarray(rng.integers(0, 32000, 24).astype(np.int32)))
+    types = pad_pod_types(build_pod_types(random_pods(rng, num_pods=60)))
+    bare = types._replace(requests=None, request_of=None)
+    pol = [(n if callable(n) else make_policy(n), w) for n, w in policies]
+    has_fgd = any(fn is fgd_score for fn, _ in pol)
+    counted = _Counted(fgd_score.branches["whole_split"])
+    monkeypatch.setitem(fgd_score.branches, "whole_split",
+                        (counted.request, counted.finish))
+    columns, init_tables = make_table_builders(
+        pol, selector_index(pol, gpu_sel))
+    key = jax.random.PRNGKey(0)
+    if builder == "init_tables":
+        build = jax.jit(init_tables)
+        got, want = build(state, types, tp, key), build(state, bare, tp, key)
+    else:
+        from tpusim.sim.table_engine import _row_state
+
+        def build(types):
+            return jax.jit(jax.vmap(lambda i: columns(
+                _row_state(state, i), types, tp, key)))(jnp.arange(24))
+        got, want = build(types), build(bare)
+    assert counted.calls == (1 if has_fgd else 0)  # and none for `bare`
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    scores = np.asarray(got[0])
+    assert len(np.unique(scores)) > 2 and np.asarray(got[2]).any()
+    kw = int(types.whole.cpu.shape[0])
+    assert kw == 32 and types.requests.shape == (8, 2)
+    assert sub_requests(pol, types) == (8 if policies == [
+        ("FGDScore", 1000)] else kw)
+    assert sub_requests(pol, bare) == kw

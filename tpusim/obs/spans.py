@@ -205,6 +205,12 @@ class SweepRecord:
     # bytes of the packed buffer the fetch span moved to the host
     # (sim/fetch.device_fetch: every lane's result in one transfer)
     fetch_bytes: int = 0
+    # Sub hypotheticals ONE column computation of the sweep's program
+    # evaluates a lane (table_engine.sub_requests): the type set's distinct
+    # (gpu_milli, gpu_num) requests where every scoring kernel takes its
+    # whole-branch types by request, the whole group's size where one goes
+    # type by type; 0 on the sequential engine (no columns)
+    sub_requests: int = 0
 
     @property
     def compiled(self) -> int:
@@ -300,6 +306,7 @@ class SweepRecord:
             "weight_rows": self.weight_rows,
             "normalized_policies": self.normalized_policies,
             "fetch_bytes": self.fetch_bytes,
+            "sub_requests": self.sub_requests,
             **{n: _rounded(getattr(self, n)) for n in DERIVED_FIELDS},
             "spans": [s.to_dict() for s in self.spans],
         }

@@ -88,6 +88,22 @@ def select_devices_packed(gpu_left, pod_gpu_milli, pod_gpu_num):
     return dev_mask, ok
 
 
+def sub_devices(gpu_left, pod_gpu_milli, pod_gpu_num):
+    """The device half of Sub: what the node's device vector reads after a
+    (gpu_milli, gpu_num) request took its devices. It depends on the pod
+    through that pair alone, so callers that try many pods on one node try
+    it once a distinct request (policies/fgd.py).
+
+    Returns (gpu_left', dev_mask: bool[8], ok)."""
+    dev_mask, gpu_ok = select_devices_packed(gpu_left, pod_gpu_milli, pod_gpu_num)
+    new_gpu = gpu_left - dev_mask.astype(jnp.int32) * pod_gpu_milli
+    return (
+        jnp.where(pod_gpu_num > 0, new_gpu, gpu_left),
+        dev_mask & (pod_gpu_num > 0),
+        (pod_gpu_num == 0) | gpu_ok,
+    )
+
+
 def sub_pod(cpu_left, mem_left, gpu_left, pod):
     """Schedule the pod onto the node (ref: resource.go:454-480 Sub).
 
@@ -95,15 +111,13 @@ def sub_pod(cpu_left, mem_left, gpu_left, pod):
     the returned state must be discarded by the caller (Go returns an error).
     Note Sub itself does not check memory; the scheduler's Filter does.
     """
-    dev_mask, gpu_ok = select_devices_packed(gpu_left, pod.gpu_milli, pod.gpu_num)
-    ok = (cpu_left >= pod.cpu) & ((pod.gpu_num == 0) | gpu_ok)
-    new_gpu = gpu_left - dev_mask.astype(jnp.int32) * pod.gpu_milli
+    new_gpu, dev_mask, gpu_ok = sub_devices(gpu_left, pod.gpu_milli, pod.gpu_num)
     return (
         cpu_left - pod.cpu,
         mem_left - pod.mem,
-        jnp.where(pod.gpu_num > 0, new_gpu, gpu_left),
-        dev_mask & (pod.gpu_num > 0),
-        ok,
+        new_gpu,
+        dev_mask,
+        (cpu_left >= pod.cpu) & gpu_ok,
     )
 
 

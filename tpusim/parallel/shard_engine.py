@@ -847,7 +847,7 @@ def make_shardmap_table_replay(policies, mesh, gpu_sel: str = "best",
 
     state_specs = NodeState(*([P(NODE_AXIS)] * len(NodeState._fields)))
     spec_r = PodSpec(*([P()] * 6))
-    types_specs = PodTypes(spec_r, spec_r, P())
+    types_specs = PodTypes(spec_r, spec_r, P(), P(), P())
     from tpusim.types import TypicalPods
 
     tp_specs = TypicalPods(*([P()] * len(TypicalPods._fields)))

@@ -21,6 +21,13 @@ evaluations (~4× fewer element-ops). The share and whole branches are split
 behind a lax.cond on the (scalar, per-pod) branch predicate so only the
 branch the pod actually needs is executed. Equivalence with the direct form
 is pinned by tests/test_policies.py golden values and the cross-check test.
+
+The whole branch also comes in two steps (branches["whole_split"]): Sub's
+hypothetical device vector and its fit terms depend on the pod through
+(gpu_milli, gpu_num) alone, so a caller that scores every pod type of a trace
+on one node (the table engine's column, every event) evaluates them once a
+distinct request and finishes each type from its request's terms. The shipped
+pod lists hold five requests among 25-329 whole-branch types.
 """
 
 from __future__ import annotations
@@ -30,7 +37,7 @@ import jax.numpy as jnp
 
 from tpusim.constants import MAX_NODE_SCORE
 from tpusim.ops.frag import node_frag_score
-from tpusim.ops.resource import first_max, is_accessible, sub_pod
+from tpusim.ops.resource import first_max, is_accessible, sub_devices, sub_pod
 from tpusim.policies.base import PolicyResult, ScoreContext
 from tpusim.types import NodeState, PodSpec
 
@@ -98,14 +105,26 @@ def _fgd_share_node(cpu_left, gpu_left, gpu_type, pod: PodSpec, tp):
     return score, dev
 
 
+def _device_terms(gpu_left, tp):
+    """What the decomposed score reads of a device vector: (fitcnt[T],
+    fitsum[T], total). Counts and milli sums are whole numbers under 2^24,
+    exact in f32."""
+    _, fitcnt, fitsum = _share_terms(gpu_left, tp)
+    return fitcnt, fitsum, gpu_left.sum().astype(jnp.float32)
+
+
+def _score_of_terms(cpu_left, gpu_type, terms, tp):
+    """The decomposed score of a node whose device vector has `terms`."""
+    fitcnt, fitsum, total = terms
+    acc = is_accessible(gpu_type, tp.gpu_mask)
+    isq3 = (tp.gpu_milli > 0) & acc & (fitcnt >= tp.gpu_num) & (cpu_left >= tp.cpu)
+    return (tp.freq * jnp.where(isq3, total - fitsum, total)).sum()
+
+
 def _decomposed_score(cpu_left, gpu_left, gpu_type, tp):
     """node_frag_score via the fit/fitsum decomposition (same value; pinned
     against ops.frag.node_frag_score by tests/test_policies.py)."""
-    acc = is_accessible(gpu_type, tp.gpu_mask)
-    fit, fitcnt, fitsum = _share_terms(gpu_left, tp)
-    total = gpu_left.sum().astype(jnp.float32)
-    isq3 = (tp.gpu_milli > 0) & acc & (fitcnt >= tp.gpu_num) & (cpu_left >= tp.cpu)
-    return (tp.freq * jnp.where(isq3, total - fitsum, total)).sum()
+    return _score_of_terms(cpu_left, gpu_type, _device_terms(gpu_left, tp), tp)
 
 
 def _fgd_whole_node(cpu_left, mem_left, gpu_left, gpu_type, pod: PodSpec, tp):
@@ -116,8 +135,32 @@ def _fgd_whole_node(cpu_left, mem_left, gpu_left, gpu_type, pod: PodSpec, tp):
     return score, jnp.int32(-1)
 
 
+# _fgd_whole_node in two steps (the module docstring says for whom): its own
+# expressions, evaluated once where they repeated bit for bit
+# (tests/test_policies.py holds the two equal).
+
+
+def _fgd_request_node(gpu_left, gpu_milli, gpu_num, tp):
+    """The hypothetical: the terms of the device vector Sub leaves behind a
+    (gpu_milli, gpu_num) request, fitting or not (as _fgd_whole_node, which
+    never reads Sub's `ok`)."""
+    g2, _, _ = sub_devices(gpu_left, gpu_milli, gpu_num)
+    return _device_terms(g2, tp)
+
+
+def _fgd_finish_node(cpu_left, gpu_left, gpu_type, pod_cpu, terms, tp):
+    """The finish: one pod's score from its request's terms, against the
+    node's own score (which reads nothing of the pod: under a vmap over pods
+    it is computed once)."""
+    cur = _decomposed_score(cpu_left, gpu_left, gpu_type, tp)
+    new = _score_of_terms(cpu_left - pod_cpu, gpu_type, terms, tp)
+    return _sigmoid_score(cur, new), jnp.int32(-1)
+
+
 _share_nodes = jax.vmap(_fgd_share_node, in_axes=(0, 0, 0, None, None))
 _whole_nodes = jax.vmap(_fgd_whole_node, in_axes=(0, 0, 0, 0, None, None))
+_request_nodes = jax.vmap(_fgd_request_node, in_axes=(0, None, None, None))
+_finish_nodes = jax.vmap(_fgd_finish_node, in_axes=(0, 0, 0, None, 0, None))
 
 
 def _fgd_share(state: NodeState, pod: PodSpec, ctx: ScoreContext) -> PolicyResult:
@@ -130,6 +173,23 @@ def _fgd_share(state: NodeState, pod: PodSpec, ctx: ScoreContext) -> PolicyResul
 def _fgd_whole(state: NodeState, pod: PodSpec, ctx: ScoreContext) -> PolicyResult:
     scores, dev = _whole_nodes(
         state.cpu_left, state.mem_left, state.gpu_left, state.gpu_type, pod, ctx.tp
+    )
+    return PolicyResult(scores, dev)
+
+
+def _fgd_whole_request(state: NodeState, gpu_milli, gpu_num, ctx: ScoreContext):
+    """Every node's terms after one request: (fitcnt[N,T], fitsum[N,T],
+    total[N])."""
+    return _request_nodes(state.gpu_left, gpu_milli, gpu_num, ctx.tp)
+
+
+def _fgd_whole_finish(
+    state: NodeState, pod: PodSpec, terms, ctx: ScoreContext
+) -> PolicyResult:
+    """_fgd_whole's result for `pod`, given _fgd_whole_request's terms of
+    (pod.gpu_milli, pod.gpu_num)."""
+    scores, dev = _finish_nodes(
+        state.cpu_left, state.gpu_left, state.gpu_type, pod.cpu, terms, ctx.tp
     )
     return PolicyResult(scores, dev)
 
@@ -149,4 +209,13 @@ fgd_score.policy_name = "FGDScore"
 # branch-specialized kernels for callers that know the pod's branch
 # statically (the table engine partitions pod types host-side, avoiding the
 # cond→select duplication under a type-axis vmap)
-fgd_score.branches = {"share": _fgd_share, "whole": _fgd_whole}
+# "whole_split": the whole branch in two steps, (request, finish), for a
+# caller that scores a whole SET of pod types on the same nodes and knows the
+# set's distinct (gpu_milli, gpu_num) requests (PodTypes.requests):
+# finish(state, pod, request(state, pod.gpu_milli, pod.gpu_num, ctx), ctx) is
+# branches["whole"](state, pod, ctx). A policy without it goes pod by pod.
+fgd_score.branches = {
+    "share": _fgd_share,
+    "whole": _fgd_whole,
+    "whole_split": (_fgd_whole_request, _fgd_whole_finish),
+}

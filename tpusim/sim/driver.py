@@ -4504,6 +4504,9 @@ def schedule_pods_sweep(
             # does the next sweep of an unchanged cluster and type set:
             # built at most once a call, and not at all where the last
             # call's still hold
+            from tpusim.sim.table_engine import sub_requests
+
+            sweep.sub_requests = sub_requests(sim._policy_fns, tr.types)
             key0 = jax.random.PRNGKey(seeds[0])
             tables, sweep.tables_reused = sim._sweep_tables(
                 replay_fn.engine, state, tr.types, typical, key0)

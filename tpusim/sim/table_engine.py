@@ -129,11 +129,22 @@ class PodTypes(NamedTuple):
     [0, Ks)), whole-GPU / CPU-only types after ([Ks, Ks+Kw)). The static
     partition lets branch-aware policies (fgd_score.branches) run each
     group through its specialized kernel instead of a cond→select that
-    computes both branches for every type."""
+    computes both branches for every type.
+
+    `requests` / `request_of` are the whole group's distinct (gpu_milli,
+    gpu_num) pairs and each whole type's row among them (a function of
+    `whole`: _whole_requests). Sub's hypothetical device vector reads nothing
+    else of a pod, so a kernel that offers the split
+    (fgd_score.branches["whole_split"]) tries it once a request, G times a
+    column where it was Kw (the shipped pod lists: 5 requests among 25-329
+    whole types). Both broadcast over the lanes of a sweep as `share` and
+    `whole` do; a PodTypes built without them (None) goes type by type."""
 
     share: PodSpec  # [Ks] arrays, pinned == -1
     whole: PodSpec  # [Kw] arrays, pinned == -1
     type_id: jnp.ndarray  # i32[P] pod -> global type index
+    requests: jnp.ndarray = None  # i32[G, 2] distinct (gpu_milli, gpu_num)
+    request_of: jnp.ndarray = None  # i32[Kw] whole type -> its row of requests
 
 
 def _to_specs(uniq: np.ndarray) -> PodSpec:
@@ -170,8 +181,29 @@ def num_pod_types(specs: PodSpec) -> int:
     return int(np.unique(_type_cols(specs), axis=0).shape[0])
 
 
+# The distinct requests go to a bucket of their own: the shipped pod lists
+# have five, so every type set of theirs carries eight, whichever shuffle,
+# seed or depth it was cut from (G is a shape of the compiled program)
+REQUEST_BUCKET = 8
+
+
+def _whole_requests(whole: PodSpec):
+    """(requests i32[G, 2], request_of i32[Kw]): host-side dedup of the whole
+    group's (gpu_milli, gpu_num) pairs, G on its bucket: the rows past the
+    distinct pairs are inert (0, 0) and no type points at them."""
+    pairs = np.stack(
+        [np.asarray(whole.gpu_milli), np.asarray(whole.gpu_num)], axis=1)
+    uniq, inv = np.unique(pairs, axis=0, return_inverse=True)
+    g = -(-uniq.shape[0] // REQUEST_BUCKET) * REQUEST_BUCKET
+    uniq = np.concatenate(
+        [uniq, np.zeros((g - uniq.shape[0], 2), uniq.dtype)])
+    return (jnp.asarray(uniq.astype(np.int32)),
+            jnp.asarray(inv.reshape(-1).astype(np.int32)))
+
+
 def build_pod_types(specs: PodSpec) -> PodTypes:
-    """Host-side dedup of pod resource specs."""
+    """Host-side dedup of pod resource specs, and of the whole group's GPU
+    requests."""
     cols = _type_cols(specs)
     uniq, inv = np.unique(cols, axis=0, return_inverse=True)
     # is_gpu_share (types.py): exactly one GPU, fractional milli
@@ -179,10 +211,12 @@ def build_pod_types(specs: PodSpec) -> PodTypes:
     order = np.concatenate([np.flatnonzero(is_share), np.flatnonzero(~is_share)])
     rank = np.empty_like(order)
     rank[order] = np.arange(order.size)
+    whole = _to_specs(uniq[~is_share])
     return PodTypes(
         _to_specs(uniq[is_share]),
-        _to_specs(uniq[~is_share]),
+        whole,
         jnp.asarray(rank[inv].astype(np.int32)),
+        *_whole_requests(whole),
     )
 
 
@@ -190,7 +224,9 @@ def pad_pod_types(types: PodTypes, multiple: int = 16) -> PodTypes:
     """Pad each type group to a `multiple` with inert dummy types so sweeps
     over seeds/traces (whose K varies slightly) share one compiled replay.
     Dummies request 2^30 milli-CPU — infeasible on any node — and are never
-    referenced by type_id, so they only cost dead table columns."""
+    referenced by type_id, so they only cost dead table columns. The whole
+    group's requests are those of the padded group: the dummies are the
+    CPU-only request they ask for."""
 
     def pad_group(spec: PodSpec, share: bool) -> PodSpec:
         k = int(spec.cpu.shape[0])
@@ -219,7 +255,8 @@ def pad_pod_types(types: PodTypes, multiple: int = 16) -> PodTypes:
     ks2 = int(share2.cpu.shape[0])
     tid = types.type_id
     tid = jnp.where(tid >= ks, tid + (ks2 - ks), tid)
-    return PodTypes(share2, pad_group(types.whole, False), tid)
+    whole2 = pad_group(types.whole, False)
+    return PodTypes(share2, whole2, tid, *_whole_requests(whole2))
 
 
 def _row_state(state: NodeState, node) -> NodeState:
@@ -353,6 +390,37 @@ def _group_fn(fn, which: str):
     return getattr(fn, "branches", {}).get(which, fn)
 
 
+def _whole_split(fn):
+    """The policy's whole branch in two steps, (request, finish), where it
+    offers them (policies/fgd.py), else None."""
+    return getattr(fn, "branches", {}).get("whole_split")
+
+
+def _fills_its_table(fn) -> bool:
+    """False for RandomScore: its score row is a per-event draw the replay
+    body recomputes, and the table slot is never read."""
+    return fn.policy_name != "RandomScore"
+
+
+def sub_requests(policies, types: PodTypes) -> int:
+    """Sub hypotheticals ONE column computation evaluates (a lane, an
+    event): the type set's distinct requests G where every kernel that
+    scores the whole group takes them by request, the group's size where one
+    goes type by type (SweepRecord.sub_requests)."""
+    kw = int(types.whole.cpu.shape[0])
+    by_request = types.request_of is not None and all(
+        _whole_split(fn) is not None
+        for fn, _ in policies if _fills_its_table(fn))
+    return int(types.requests.shape[0]) if by_request else kw
+
+
+def _take_request(terms, r):
+    """A whole type's terms out of the [G, ...] terms of the distinct
+    requests. Under the column's vmap over the types `r` is the group's
+    i32[Kw] index, shared by the lanes of a sweep."""
+    return jax.tree.map(lambda a: a[r], terms)
+
+
 def make_table_builders(policies, sel_idx: int):
     """(columns, init_tables) score-table constructors for a static policy
     list — single-sourced table builders for the incremental engine.
@@ -361,50 +429,73 @@ def make_table_builders(policies, sel_idx: int):
       -> (scores i32[num_pol, K], sharedev i32[K], feas bool[K]).
     init_tables(state, types, tp, key): full [*, K, N] tables via a K-serial
       map (bounds peak memory to one node-sweep's intermediates per type).
+
+    Both map ONE definition (group) over a type group: a kernel that offers
+    its whole branch in two steps evaluates Sub's hypothetical once a
+    distinct request of the type set (types.requests, G rows) and each type
+    finishes from its request's terms; every other kernel, and a type set
+    without the index, goes type by type. The values are the same either
+    way (tests/test_table_engine.py).
     """
 
-    def one_type_fn(state: NodeState, tp, key, which: str):
+    def group(state: NodeState, types: PodTypes, tp, key, which: str, over):
+        """(scores [k, num_pol, N], sharedev [k, N], feas [k, N]) of one
+        type group. `over(f)(xs)` maps f over the leading axis of xs:
+        jax.vmap for one node's column, a serial lax.map for whole tables."""
         ctx_feas = jnp.ones(state.num_nodes, jnp.bool_)
         ctx = ScoreContext(tp=tp, feasible=ctx_feas, rng=key)
+        request_of = types.request_of if which == "whole" else None
+        # policy index -> (finish, its [G, ...] terms of the requests)
+        by_request = {}
+        if request_of is not None:
+            for i, (fn, _) in enumerate(policies):
+                split = _whole_split(fn)
+                if split is not None and _fills_its_table(fn):
+                    request, finish = split
+                    by_request[i] = finish, over(
+                        lambda req, request=request: request(
+                            state, req[0], req[1], ctx)
+                    )(types.requests)
 
-        def one_type(tpod):
+        def one_type(tpod_r):
+            tpod, r = tpod_r
             feas = filter_nodes(state, tpod)
             scores = []
             sdev = jnp.full(state.num_nodes, -1, jnp.int32)
             for i, (fn, _) in enumerate(policies):
-                if fn.policy_name == "RandomScore":
-                    # its score row is a per-event draw the replay body
-                    # recomputes; the table slot is never read
+                if not _fills_its_table(fn):
                     scores.append(jnp.zeros(state.num_nodes, jnp.int32))
                     continue
-                res = _group_fn(fn, which)(state, tpod, ctx)
+                if i in by_request:
+                    finish, terms = by_request[i]
+                    res = finish(state, tpod, _take_request(terms, r), ctx)
+                else:
+                    res = _group_fn(fn, which)(state, tpod, ctx)
                 scores.append(res.raw_scores)
                 if i == sel_idx:
                     sdev = res.share_dev
             return jnp.stack(scores), sdev, feas
 
-        return one_type
+        return over(one_type)((getattr(types, which), request_of))
+
+    def groups(state, types, tp, key, over):
+        outs = [
+            group(state, types, tp, key, which, over)
+            for which in ("share", "whole")
+            if getattr(types, which).cpu.shape[0]
+        ]
+        return [jnp.concatenate([o[i] for o in outs], 0) for i in range(3)]
 
     def columns(state1: NodeState, types: PodTypes, tp, key):
-        outs = []
-        for which, specs in (("share", types.share), ("whole", types.whole)):
-            if specs.cpu.shape[0]:
-                outs.append(jax.vmap(one_type_fn(state1, tp, key, which))(specs))
-        scores = jnp.concatenate([o[0][:, :, 0] for o in outs], 0)  # [K,π]
-        sdev = jnp.concatenate([o[1][:, 0] for o in outs], 0)  # [K]
-        feas = jnp.concatenate([o[2][:, 0] for o in outs], 0)  # [K]
-        return scores.T, sdev, feas
+        scores, sdev, feas = groups(state1, types, tp, key, jax.vmap)
+        return scores[:, :, 0].T, sdev[:, 0], feas[:, 0]  # [π,K], [K], [K]
 
     @jax.named_scope("tpusim.table_build")
     def init_tables(state: NodeState, types: PodTypes, tp, key):
-        outs = []
-        for which, specs in (("share", types.share), ("whole", types.whole)):
-            if specs.cpu.shape[0]:
-                outs.append(jax.lax.map(one_type_fn(state, tp, key, which), specs))
-        scores = jnp.concatenate([o[0] for o in outs], 0)  # [K,π,N]
-        sdev = jnp.concatenate([o[1] for o in outs], 0)  # [K,N]
-        feas = jnp.concatenate([o[2] for o in outs], 0)  # [K,N]
-        return jnp.swapaxes(scores, 0, 1), sdev, feas
+        scores, sdev, feas = groups(
+            state, types, tp, key,
+            lambda f: functools.partial(jax.lax.map, f))
+        return jnp.swapaxes(scores, 0, 1), sdev, feas  # [π,K,N], [K,N] x 2
 
     return columns, init_tables
 
