@@ -389,3 +389,51 @@ def test_the_plain_flat_sweeps_loop_over_events_only(one_chip, operands):
     assert shapes[3].shape[0] == OPENB_LANES  # a stream a lane
     (loop,) = sweep_program.while_loops(lowered.compile().as_text())
     assert f"s32[{OPENB_LANES},1213,9]" in loop[2]  # the scan's carry
+
+
+@pytest.mark.parametrize("operands", ["one shared trace", "a trace a lane"])
+def test_a_stream_with_deletions_loops_over_events_only(one_chip, operands):
+    """openb by its own clock (ISSUE 38: `use_timestamps`, so the stream
+    holds a deletion a pod and the pod axis is half the event axis): the
+    delete branch reads `placed[idx]` and `masks[idx]`, lane-batched
+    bookkeeping rows, at an index the lanes share or, with a trace a lane,
+    at one a lane; the commit gives the node back through the same rows it
+    binds through. The module holds the two event loops and none over the
+    lanes, and the per-event step produces no whole table."""
+    from tpusim.io.trace import build_events
+    from tpusim.sim.table_engine import FLAT_GROUP_EVENTS
+
+    sim, trace, cfg = sweep_program.cell_simulator(
+        None, OPENB_DEPTH, config="openb-clock", use_timestamps=True)
+    kinds, _ = build_events(trace, True)
+    assert (len(kinds), int((kinds == 1).sum())) == (2 * OPENB_DEPTH,
+                                                     OPENB_DEPTH)
+    own = operands != "one shared trace"
+    lanes = LANE_TRACE_LANES if own else OPENB_LANES
+    with lane_write.counting() as sites:
+        fn, shapes, _ = sweep_program.capture_sweep(
+            sim, None if own else trace,
+            sweep_program.cell_weights(cfg, lanes), list(range(lanes)),
+            **_lane_operands(operands, sim, trace, lanes))
+        shapes = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), shapes)
+        lowered = fn.lower(*shapes)
+    # the sites of a creation stream: a delete adds no access of its own
+    assert (len(sites), len(sites.dense)) == (17, 31 if own else 22)
+    assert sites.table_pass_events == FLAT_GROUP_EVENTS
+    # pods on the events' bucket, a stream a lane where the traces are
+    assert shapes[1].cpu.shape[-1] == shapes[3].shape[-1] == 2 * OPENB_DEPTH
+    assert len(shapes[3].shape) == (2 if own else 1)
+    text = lowered.compile().as_text()
+    k = shapes[9][0].shape[-2]
+    table = rf"\[{lanes},(1,)?{k},1213\]"
+    loops = sweep_program.while_loops(text)
+    bodies = sweep_program.loop_bodies(text)
+    (outer,) = [b for b, holder in bodies.items() if holder not in bodies]
+    (inner,) = [b for b, holder in bodies.items() if holder == outer]
+    assert len(loops) == 2, loops
+    for _, _, carried in loops:
+        assert f"s32[{lanes},1213,9]" in carried  # the scan's carry
+    assert not sweep_program.producers_in(text, inner, table)
+    _assert_no_gather_a_lane_and_type(text, inner, lanes)

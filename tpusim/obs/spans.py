@@ -211,6 +211,12 @@ class SweepRecord:
     # whole-branch types by request, the whole group's size where one goes
     # type by type; 0 on the sequential engine (no columns)
     sub_requests: int = 0
+    # deletions among the record's real events, summed over its lanes:
+    # counted on the host from the streams the specs span built (a stream
+    # by timestamp, SimulatorConfig.use_timestamps, holds a deletion a pod
+    # that has a deletion time), so an unblocked wave needs no sync for it;
+    # 0 where every lane replays creations in list order
+    delete_events: int = 0
 
     @property
     def compiled(self) -> int:
@@ -307,6 +313,7 @@ class SweepRecord:
             "normalized_policies": self.normalized_policies,
             "fetch_bytes": self.fetch_bytes,
             "sub_requests": self.sub_requests,
+            "delete_events": self.delete_events,
             **{n: _rounded(getattr(self, n)) for n in DERIVED_FIELDS},
             "spans": [s.to_dict() for s in self.spans],
         }

@@ -31,7 +31,7 @@ from tpusim.io.trace import (
     tiebreak_rank,
 )
 from tpusim.policies import make_policy
-from tpusim.sim.engine import make_replay
+from tpusim.sim.engine import EV_DELETE, make_replay
 from tpusim.sim.fetch import device_fetch
 from tpusim.sim.reports import (
     LogSink,
@@ -3443,6 +3443,14 @@ class SweepLane:
     # ClusterCPU; ClusterGPU` line is their sum and the two
     power_cpu_w: float = 0.0
     power_gpu_w: float = 0.0
+    # the lane's record of its events, as ReplayResult keeps it: the node
+    # chosen (a creation) or freed (a deletion) at every real event, -1
+    # where nothing moved, and the devices touched. With deletions in the
+    # stream placed_node names only who is placed at the END; this is what
+    # a reference walks a lane by. Views of the one fetched buffer; None on
+    # a lane built from a chunked run's final arrays (lane_from_arrays)
+    event_node: Optional[np.ndarray] = None  # i32[E]
+    event_dev: Optional[np.ndarray] = None  # bool[E, 8]
 
 
 def _lane_axis(operand, rank: int):
@@ -3745,6 +3753,8 @@ def _slice_sweep_lane(out, amounts, watts, i, wrow, seed, p, e, pad_skips):
         unscheduled=int(((pn < 0) & failed_i).sum()),
         power_cpu_w=float(watts[i][0]),
         power_gpu_w=float(watts[i][1]),
+        event_node=np.asarray(out.event_node[i][:e]),
+        event_dev=np.asarray(out.event_dev[i][:e]),
     )
 
 
@@ -4330,6 +4340,9 @@ def _slice_fault_lane(out, amounts, watts, i, wrow, seed, p, plan, e_m,
         out, amounts, watts, i, wrow, seed, p, e, e_m - e - attempts_run)
     lane.disruption = dm
     lane.events = e + attempts_run
+    # the scan's steps are the MERGED stream's here (fault transitions and
+    # retry slots among the base events): fault_ys is that record
+    lane.event_node = lane.event_dev = None
     # dead pods are terminal max-retries-exceeded: the standalone path's
     # unscheduled accounting includes them
     lane.unscheduled = int(
@@ -4484,6 +4497,8 @@ def schedule_pods_sweep(
         # steps and retries
         lane_events = [len(tr.streams[t][0]) for t in trace_of]
         sweep.events, true_events = max(lane_events), sum(lane_events)
+        deletes = [int((kinds == EV_DELETE).sum()) for kinds, _ in tr.streams]
+        sweep.delete_events = sum(deletes[t] for t in trace_of)
         steps = int(ev_kind.shape[-1])  # of the scan, padding included
         with obs.span("lane_keys") as h:
             keys = _lane_keys(seeds)
