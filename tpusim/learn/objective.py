@@ -134,7 +134,7 @@ def terms_from_simulate(res, total_gpu_milli: int, typical) -> dict:
     """SimulateResult -> the same term vocabulary, for runs that did not
     go through the sweep (the robustness evaluator's run_with_faults
     outcome). Recomputes gpu_alloc/frag from the final state exactly as
-    _slice_sweep_lane does."""
+    _slice_sweep_lanes does (there without the slot mask: the pads are 0)."""
     from tpusim.constants import MILLI
     from tpusim.ops.frag import cluster_frag_amounts, frag_sum_except_q3
 

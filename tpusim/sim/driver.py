@@ -3413,7 +3413,11 @@ class SweepLane:
     per-lane slice of the vmapped replay plus the summary scalars the
     CLI table prints. Placements are bit-identical to a standalone run
     with `weights` baked into the config and `seed` as cfg.seed
-    (tests/test_sweep.py pins this per engine)."""
+    (tests/test_sweep.py pins this per engine). Out of a sweep every
+    array here, the leaves of `state` and `metrics` too, is a VIEW: of the
+    one fetched buffer, or of an array the sweep made once for all its
+    lanes (`weights`, `counters`, the bool leaves' casts); copy before
+    writing what another lane must not see (_slice_sweep_lanes)."""
 
     weights: np.ndarray  # i32[num_pol] this lane's weight vector
     seed: int
@@ -3713,49 +3717,68 @@ def _lane_ranks(num_nodes: int, seeds, marks=None):
     return jnp.asarray(stacked)
 
 
-def _slice_sweep_lane(out, amounts, watts, i, wrow, seed, p, e, pad_skips):
-    """Slice lane i out of a fetched (host) vmapped sweep result into its
-    SweepLane — shared by the single-trace and multi-trace sweep paths
-    (the latter passes per-lane true sizes, ISSUE 7)."""
+def _slice_sweep_lanes(out, amounts, watts, w, seeds, pods_n, events_n,
+                       pad_skips) -> List["SweepLane"]:
+    """A fetched (host) vmapped sweep result cut into one SweepLane a lane,
+    lane i with its true sizes `pods_n[i]` / `events_n[i]` and its
+    `pad_skips[i]` bucket-padding skips. The summary math is ONE pass an
+    array over the lane axis, the loop after it builds views and objects
+    only: every array a lane holds is a view of the fetched buffer or of a
+    per-sweep summary array. Each field equals lane_from_arrays' on that
+    lane's own arrays (tests/test_sweep_slice.py)."""
     from tpusim.ops.frag import frag_sum_except_q3
 
-    pn = np.asarray(out.placed_node[i][:p])
-    failed_i = np.asarray(out.ever_failed[i][:p])
+    st = out.state
+    b = st.gpu_cnt.shape[0]
+    # the allocation ratio without a slot mask: gpu_left is 0 beyond
+    # gpu_cnt devices (types.NodeState), so the used milli of the real
+    # slots is MILLI a device less all that is left. No [B, N, 8]
+    # temporary: three of them leave the cache a lane's own stayed in, and
+    # the batched masked form read slower than the loop (PERF.md, PR 39)
+    cnt = st.gpu_cnt.sum(1, dtype=np.int64)
+    used = MILLI * cnt - st.gpu_left.reshape(b, -1).sum(1, dtype=np.int64)
+    alloc = (100.0 * used.astype(np.float64)) / np.maximum(cnt * MILLI, 1)
+    # a lane's pods are the first pods_n[i] of the padded pod axis
+    live = np.arange(out.placed_node.shape[1]) < np.asarray(pods_n)[:, None]
+    on_node = (out.placed_node >= 0) & live
+    failed = out.ever_failed & live
     ctr = None
     if out.counters is not None:
-        ctr = np.asarray(out.counters[i]).astype(np.int64).copy()
-        ctr[4] = max(int(ctr[4]) - pad_skips, 0)  # bucket-padding skips
-    st = jax.tree.map(lambda a: np.asarray(a[i]), out.state)
-    slot = (
-        np.arange(st.gpu_left.shape[1])[None, :] < st.gpu_cnt[:, None]
-    )
-    denom = max(int(st.gpu_cnt.sum()) * MILLI, 1)
-    alloc = 100.0 * float(
-        np.where(slot, MILLI - st.gpu_left, 0).sum()
-    ) / denom
-    metrics_i = None
-    if out.metrics is not None:
-        metrics_i = jax.tree.map(lambda a: np.asarray(a[i][:e]), out.metrics)
-    return SweepLane(
-        weights=np.asarray(wrow, np.int32).copy(),
-        seed=int(seed),
-        placed_node=pn,
-        dev_mask=np.asarray(out.dev_mask[i][:p]),
-        ever_failed=failed_i,
-        counters=ctr,
-        metrics=metrics_i,
-        state=st,
-        events=e,
-        placed=int((pn >= 0).sum()),
-        failed=int(failed_i.sum()),
-        gpu_alloc_pct=alloc,
-        frag_gpu_milli=float(frag_sum_except_q3(amounts[i])),
-        unscheduled=int(((pn < 0) & failed_i).sum()),
-        power_cpu_w=float(watts[i][0]),
-        power_gpu_w=float(watts[i][1]),
-        event_node=np.asarray(out.event_node[i][:e]),
-        event_dev=np.asarray(out.event_dev[i][:e]),
-    )
+        ctr = out.counters.astype(np.int64)
+        ctr[:, 4] = np.maximum(ctr[:, 4] - np.asarray(pad_skips), 0)
+    weights = np.array(w, np.int32)
+    frag = frag_sum_except_q3(amounts).tolist()
+    alloc, watts = alloc.tolist(), watts.tolist()
+    placed = on_node.sum(1).tolist()
+    unscheduled = (failed & ~on_node).sum(1).tolist()
+    failed = failed.sum(1).tolist()
+
+    lanes = []
+    for i, (p, e) in enumerate(zip(pods_n, events_n)):
+        metrics_i = None
+        if out.metrics is not None:
+            metrics_i = type(out.metrics)(*(a[i, :e] for a in out.metrics))
+        lanes.append(SweepLane(
+            weights=weights[i],
+            seed=seeds[i],
+            placed_node=out.placed_node[i, :p],
+            dev_mask=out.dev_mask[i, :p],
+            ever_failed=out.ever_failed[i, :p],
+            counters=None if ctr is None else ctr[i],
+            metrics=metrics_i,
+            state=NodeState(*(leaf[i] for leaf in st)),
+            events=e,
+            placed=placed[i],
+            failed=failed[i],
+            gpu_alloc_pct=alloc[i],
+            frag_gpu_milli=frag[i],
+            unscheduled=unscheduled[i],
+            power_cpu_w=watts[i][0],
+            power_gpu_w=watts[i][1],
+            event_node=out.event_node[i, :e],
+            event_dev=out.event_dev[i, :e],
+        ))
+    return lanes
 
 
 def lane_from_arrays(state, placed_node, dev_mask, ever_failed, counters,
@@ -3763,10 +3786,12 @@ def lane_from_arrays(state, placed_node, dev_mask, ever_failed, counters,
                      pad_skips: int = 0) -> SweepLane:
     """SweepLane from raw final-run arrays — the shared summary math of
     lane_from_run (standalone/forked chunked runs) and the ChunkWave
-    serving path (ISSUE 16). Mirrors _slice_sweep_lane exactly: same
-    counters pad-correction, same gpu_alloc slot mask, same frag
-    post-pass — so every result document of a family is field-for-field
-    comparable regardless of which execution path produced it."""
+    serving path (ISSUE 16). One lane's form of what _slice_sweep_lanes
+    gives a sweep's lanes, field for field: same counters pad-correction,
+    same gpu_alloc (here under the slot mask; the sweep's pass leans on the
+    zero pads instead), same frag post-pass, so every result document of a
+    family is field-for-field comparable regardless of which execution
+    path produced it."""
     from tpusim.ops.frag import frag_sum_except_q3
 
     pn = np.asarray(placed_node, np.int32)
@@ -4323,32 +4348,43 @@ def _sweep_replay(sim, table: bool, fault_frag: Optional[bool]):
     return sim._table_fn if table else sim.replay_fn
 
 
-def _slice_fault_lane(out, amounts, watts, i, wrow, seed, p, plan, e_m,
-                      gcnt):
-    """Lane i of a fetched sweep with fault plans, whose merged streams
-    were padded to `e_m` steps: its SweepLane with the DisruptionMetrics
+def _slice_fault_lanes(out, amounts, watts, w, seeds, pods_n, plans, e_m,
+                       gcnt) -> List[SweepLane]:
+    """The lanes of a fetched sweep with fault plans, whose merged streams
+    were padded to `e_m` steps: each SweepLane with the DisruptionMetrics
     of its schedule, bit-identical to the standalone run_with_faults run
-    (tests/test_fault_lane.py)."""
+    (tests/test_fault_lane.py). The telemetry is assembled a lane first
+    (the retry attempts that ran decide a lane's padding skips), then the
+    lanes are cut as every sweep's are."""
     from tpusim.sim import fault_lane
 
-    ys = jax.tree.map(lambda a: np.asarray(a)[i], out.fault_ys)
-    fc = jax.tree.map(lambda a: np.asarray(a)[i], out.fault_carry)
-    dm, dead, attempts_run = fault_lane.assemble_disruption(
-        plan, ys, fc, gcnt)
-    e = plan.num_events
-    lane = _slice_sweep_lane(
-        out, amounts, watts, i, wrow, seed, p, e, e_m - e - attempts_run)
-    lane.disruption = dm
-    lane.events = e + attempts_run
-    # the scan's steps are the MERGED stream's here (fault transitions and
-    # retry slots among the base events): fault_ys is that record
-    lane.event_node = lane.event_dev = None
-    # dead pods are terminal max-retries-exceeded: the standalone path's
-    # unscheduled accounting includes them
-    lane.unscheduled = int(
-        ((lane.placed_node < 0) & (lane.ever_failed | dead[:p])).sum()
+    assembled = [
+        fault_lane.assemble_disruption(
+            plan,
+            jax.tree.map(lambda a: a[i], out.fault_ys),
+            jax.tree.map(lambda a: a[i], out.fault_carry),
+            gcnt,
+        )
+        for i, plan in enumerate(plans)
+    ]
+    events_n = [plan.num_events for plan in plans]
+    lanes = _slice_sweep_lanes(
+        out, amounts, watts, w, seeds, pods_n, events_n,
+        [e_m - e - attempts
+         for e, (_, _, attempts) in zip(events_n, assembled)],
     )
-    return lane
+    for lane, p, (dm, dead, attempts_run) in zip(lanes, pods_n, assembled):
+        lane.disruption = dm
+        lane.events += attempts_run
+        # the scan's steps are the MERGED stream's here (fault transitions
+        # and retry slots among the base events): fault_ys is that record
+        lane.event_node = lane.event_dev = None
+        # dead pods are terminal max-retries-exceeded: the standalone
+        # path's unscheduled accounting includes them
+        lane.unscheduled = int(
+            ((lane.placed_node < 0) & (lane.ever_failed | dead[:p])).sum()
+        )
+    return lanes
 
 
 def schedule_pods_sweep(
@@ -4584,22 +4620,16 @@ def schedule_pods_sweep(
             sweep.fetch_bytes = h.meta.get("bytes", 0)
 
         with obs.span("slice_lanes"):
+            pods_n = [tr.pods[t] for t in trace_of]
             if faulted:
-                gcnt = np.asarray(state.gpu_cnt)
-                return [
-                    _slice_fault_lane(
-                        out, amounts, watts, i, w[i], seeds[i], tr.pods[t],
-                        plans[i], steps, gcnt,
-                    )
-                    for i, t in enumerate(trace_of)
-                ]
-            return [
-                _slice_sweep_lane(
-                    out, amounts, watts, i, w[i], seeds[i], tr.pods[t],
-                    lane_events[i], steps - lane_events[i],
+                return _slice_fault_lanes(
+                    out, amounts, watts, w, seeds, pods_n, plans, steps,
+                    np.asarray(state.gpu_cnt),
                 )
-                for i, t in enumerate(trace_of)
-            ]
+            return _slice_sweep_lanes(
+                out, amounts, watts, w, seeds, pods_n, lane_events,
+                [steps - e for e in lane_events],
+            )
 
 
 def format_chaos_table(lanes: Sequence[SweepLane], policies) -> str:

@@ -27,7 +27,10 @@ class NodeState(NamedTuple):
     gpu_left rows are padded with 0 beyond gpu_cnt devices; 0-milli pads are
     inert in every kernel (a pod's per-GPU request is >0 whenever GPU math
     runs, so pads never fit, never count as fully-free capacity, and add 0 to
-    totals).
+    totals). A sweep's allocation ratio sums whole rows on that ground
+    (driver._slice_sweep_lanes); tests/test_sweep_slice.py holds the pads at
+    0 in the loaders' initial state and in every lane's final state, on every
+    body of the step and under fault plans.
     """
 
     cpu_left: jnp.ndarray  # i32[N] milli-CPU free
