@@ -437,3 +437,58 @@ def test_a_stream_with_deletions_loops_over_events_only(one_chip, operands):
         assert f"s32[{lanes},1213,9]" in carried  # the scan's carry
     assert not sweep_program.producers_in(text, inner, table)
     _assert_no_gather_a_lane_and_type(text, inner, lanes)
+
+
+def test_a_whole_tuned_trace_a_lane_loops_over_events_only(one_chip):
+    """The load cell's program at its own shape (ISSUE 41): 320 lanes, each
+    a WHOLE tuned trace, so the bookkeeping rows hold 11,265 pods, over
+    `lane_write`'s line for a short leaf: their three writes an event are
+    the scatters `vmap` derives (25 + 1 dense sites where a short pod axis
+    has 31), in place on the pods-minor layout the carry holds. The delete
+    branch's read of `masks` is `lane_write.read_pod`'s masked reduction
+    over that layout: as a plain gather it wanted the eight devices minor,
+    and the parent's program copied the whole pred[320,11265,8] leaf to
+    that layout after every event's write (9.6 s of a 20.2 s scan on the
+    chip); as `read_row`'s tile gather it ran as four `while`s over the
+    lanes' 640 windows. The module holds the two event loops, none over
+    the lanes, and no copy of the leaf to another layout."""
+    import json
+    import os
+
+    from benchmark.drivers import wave
+    from benchmark.lib import inputs
+    from tpusim.io.trace import load_node_csv, load_pod_csv
+
+    with open(os.path.join(sweep_program.CONFIGS, "openb-load130.json")) as f:
+        config = json.load(f)
+    cfg = wave.simulator_config(config["simulator"], 42, profile=False,
+                                report_per_event=True)
+    sim = wave.build_simulator(load_node_csv(inputs.NODE_CSV),
+                               load_pod_csv(inputs.POD_CSV), cfg)
+    traces = [sim.prepare_pods(tuning_seed=s)
+              for s in config["workload"]["tuning_seeds"]]
+    lane_pods = [t for t in traces for _ in range(32)]
+    lanes = len(lane_pods)
+    with lane_write.counting() as sites:
+        fn, shapes, _ = sweep_program.capture_sweep(
+            sim, None, sweep_program.cell_weights(cfg, lanes),
+            list(range(lanes)), lane_pods=lane_pods)
+        shapes = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), shapes)
+        lowered = fn.lower(*shapes)
+    assert (len(sites), len(sites.dense)) == (17, 26)
+    assert shapes[1].cpu.shape == (lanes, 11264)  # a whole trace a lane
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    loops = sweep_program.while_loops(text)
+    assert len(loops) == 2, loops
+    for _, _, carried in loops:
+        assert f"s32[{lanes},1213,9]" in carried  # the scan's carry
+    masks = rf"pred\[{lanes},(11265,8|8,11265)\]"
+    relaid = [(c, n, s) for c, n, s, _ in sweep_program.big_copies_in_scan(
+        text, lanes * 11265 * 8)
+        if re.match(masks, s) and not n.startswith("copy-start")]
+    assert not relaid, relaid
+    # the parent's program held 1.89 GB of temporaries, the four copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9

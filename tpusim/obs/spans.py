@@ -39,6 +39,9 @@ sweep have them (fetch: ready, copied; lane_ranks: stacked;
 frag_postpass: gathered), and the SweepRecord derives from spans and
 marks what the host did before, under and after the device's work
 (host_lead_s, covered_s, device_block_s, device_wait_s, host_tail_s).
+A sweep with the per-event report on has one span more, `event_metrics`,
+between frag_postpass and fetch: the report program's dispatch and, in a
+blocked wave, its device time, apart from the lane post-pass.
 """
 
 from __future__ import annotations
@@ -217,6 +220,15 @@ class SweepRecord:
     # that has a deletion time), so an unblocked wave needs no sync for it;
     # 0 where every lane replays creations in list order
     delete_events: int = 0
+    # creations the sweep's lanes rejected, summed over the lanes: counted
+    # on the host from the fetched `ever_failed` flags as the lanes are cut
+    # (SweepLane.failed), so an unblocked wave needs no sync for it; what
+    # says a cluster filled up
+    rejected_creates: int = 0
+    # bytes of fetch_bytes that are per-event report series (the nine
+    # EventMetrics leaves over the padded event axis, SimulatorConfig.
+    # report_per_event); 0 where the sweep reports no series
+    series_bytes: int = 0
 
     @property
     def compiled(self) -> int:
@@ -253,20 +265,23 @@ class SweepRecord:
 
     @property
     def device_block_s(self) -> Optional[float]:
-        """What the host waited on the device inside the scan and the
-        post-pass spans: their block halves in a blocked wave,
-        microseconds in one that did not block."""
+        """What the host waited on the device inside the scan, the
+        post-pass and (with the per-event report on) the event_metrics
+        spans: their block halves in a blocked wave, microseconds in one
+        that did not block."""
         scan, post = self._span("scan"), self._span("frag_postpass")
         if scan is None or post is None:
             return None
-        return scan.block_s + post.block_s
+        report = self._span("event_metrics")
+        return scan.block_s + post.block_s + (
+            0.0 if report is None else report.block_s)
 
     @property
     def covered_s(self) -> Optional[float]:
         """Host work between the scan's dispatch and the fetch's start,
         less device_block_s: the post-pass's gather, trace, lowering and
-        cache load. Hidden iff the wave did not block and the scan
-        outlasts it."""
+        cache load, and the report program's dispatch. Hidden iff the wave
+        did not block and the scan outlasts it."""
         at, fetch = self._scan_dispatched_s(), self._span("fetch")
         block = self.device_block_s
         if at is None or fetch is None or block is None:
@@ -314,6 +329,8 @@ class SweepRecord:
             "fetch_bytes": self.fetch_bytes,
             "sub_requests": self.sub_requests,
             "delete_events": self.delete_events,
+            "rejected_creates": self.rejected_creates,
+            "series_bytes": self.series_bytes,
             **{n: _rounded(getattr(self, n)) for n in DERIVED_FIELDS},
             "spans": [s.to_dict() for s in self.spans],
         }

@@ -4491,7 +4491,8 @@ def schedule_pods_sweep(
     # eight flat, back-to-back spans under one sweep record: specs,
     # lane_keys, lane_ranks (mark: stacked), init_tables, scan,
     # frag_postpass (mark: gathered), fetch (marks: ready, copied),
-    # slice_lanes; the record derives the host's lead and tail from them
+    # slice_lanes; the record derives the host's lead and tail from them.
+    # With the per-event report on, event_metrics is a ninth, before fetch
     with obs.sweep(lanes=b) as sweep:
         sweep.weight_rows = len(np.unique(w, axis=0))
         sweep.normalized_policies = sum(
@@ -4599,13 +4600,6 @@ def schedule_pods_sweep(
             if lane_set:
                 typical = jax.tree.map(lambda a: a[lane_set[0]], typical)
             h.mark("gathered", then="program")
-            if report:
-                out = out._replace(
-                    metrics=_sweep_metrics_fn(_lane_axis(ev_kind, 1), tp_ax)(
-                        state, tr.specs, ev_kind, ev_pod,
-                        out.event_node, out.event_dev, typical,
-                    )
-                )
             # per-lane frag and watts of the final states in one vmapped
             # call (the reductions cluster_analysis and the power report
             # make), before the single fetch. The jit wraps a new function
@@ -4614,22 +4608,41 @@ def schedule_pods_sweep(
             amounts, watts = jax.jit(
                 jax.vmap(_lane_postpass, in_axes=(0, tp_ax))
             )(out.state, typical)
-            obs.settle(h, amounts, watts, out.metrics)
+            obs.settle(h, amounts, watts)
+        if report:
+            # the per-event series of every lane, rebuilt from its
+            # telemetry: a span of its own, so a blocked wave times the
+            # report program apart from the lane post-pass
+            with obs.span("event_metrics", events=true_events) as h:
+                out = out._replace(
+                    metrics=_sweep_metrics_fn(_lane_axis(ev_kind, 1), tp_ax)(
+                        state, tr.specs, ev_kind, ev_pod,
+                        out.event_node, out.event_dev, typical,
+                    )
+                )
+                obs.settle(h, out.metrics)
         with obs.span("fetch", events=true_events) as h:
             out, amounts, watts = device_fetch((out, amounts, watts), marks=h)
             sweep.fetch_bytes = h.meta.get("bytes", 0)
+            if out.metrics is not None:
+                sweep.series_bytes = sum(a.nbytes for a in out.metrics)
+                h.note(series_bytes=sweep.series_bytes)
 
-        with obs.span("slice_lanes"):
+        with obs.span("slice_lanes") as h:
             pods_n = [tr.pods[t] for t in trace_of]
             if faulted:
-                return _slice_fault_lanes(
+                lanes = _slice_fault_lanes(
                     out, amounts, watts, w, seeds, pods_n, plans, steps,
                     np.asarray(state.gpu_cnt),
                 )
-            return _slice_sweep_lanes(
-                out, amounts, watts, w, seeds, pods_n, lane_events,
-                [steps - e for e in lane_events],
-            )
+            else:
+                lanes = _slice_sweep_lanes(
+                    out, amounts, watts, w, seeds, pods_n, lane_events,
+                    [steps - e for e in lane_events],
+                )
+            sweep.rejected_creates = sum(lane.failed for lane in lanes)
+            h.note(rejected_creates=sweep.rejected_creates)
+            return lanes
 
 
 def format_chaos_table(lanes: Sequence[SweepLane], policies) -> str:

@@ -71,6 +71,19 @@ reduction over K, for the block's sake and not the read's: a gather a lane
 makes XLA carry the block with its slot axis minor-most, and then every
 event's store of one slot rewrites the whole padded block.
 
+The bookkeeping rows (`placed`, `masks`, `failed`: pods on the first axis)
+are short in every sweep of a trace's first events, and their writes are
+dense. A WHOLE tuned trace a lane (10,9xx pods) puts them over the line:
+the writes are then the scatters `vmap` derives, in place on the layout the
+carry holds (`masks[lanes, P, 8]` pods minor, like `gpu_left`). The delete
+branch's `masks[idx]` was a plain gather, which wants the 8 devices minor,
+so the whole leaf was copied to that layout after every event's write (four
+copies of pred[320, 11265, 8] a group of four events, 9.6 s of a 20.2 s
+scan; PERF.md section 6, PR 41). `read_pod` is that read: on a long pod
+axis the masked reduction over the pods, which reads the layout the writes
+keep (read_row's tile gather would too, but runs as a `while` over the
+lanes' windows there); on a short one the plain index it always was.
+
 Which form an access takes follows from the leaf's static shape alone
 (`_short`): no option selects it.
 
@@ -436,4 +449,30 @@ def read_row(leaf, idx, keepdims: bool = True):
     return _lane_batched(
         expr, lanes, write=False, per_lane=(1,),
         dense=(lambda _: dense) if _short(n) else None,
+    )(leaf, idx)
+
+
+def read_pod(leaf, idx):
+    """leaf[idx] of a bookkeeping row (pods on the first axis: `placed`,
+    `masks`, `failed`), as the delete branch reads what a pod holds. On a
+    long pod axis (a whole tuned trace a lane) a row of `masks[P, 8]` with
+    an index a lane is the masked reduction over the pods, one pass over
+    the leaf in the pods-minor layout its scatters keep (no tile gather
+    either: XLA runs that one as a `while` over the lanes' windows). A
+    short axis, a 1-D leaf and an index the lanes share stay the plain
+    index."""
+    n = leaf.shape[0]
+    if _short(n) or leaf.ndim == 1:
+        return leaf[idx]
+
+    def expr(leaf, idx):
+        return leaf[idx]
+
+    def dense(leaf, idx):
+        at = jnp.where(idx < 0, idx + n, idx)  # leaf[idx] wraps once
+        return _picked(leaf, _one_hot(n, at, leaf.ndim - 1), axis=0)
+
+    return _lane_batched(
+        expr, expr, write=False,
+        dense=lambda in_batched: dense if in_batched[1] else None,
     )(leaf, idx)

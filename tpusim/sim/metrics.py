@@ -87,6 +87,7 @@ _power_rows_b = jax.vmap(node_power)
 
 
 @jax.jit
+@jax.named_scope("tpusim.event_metrics")
 def compute_event_metrics(
     init_state: NodeState,
     specs: PodSpec,

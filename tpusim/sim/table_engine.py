@@ -1091,7 +1091,8 @@ def _make_table_engine(
                 return node_f, dmask, dec._replace(block=win_blk)
 
             def do_delete():
-                base = placed[idx], masks[idx]
+                base = (lane_write.read_pod(placed, idx),
+                        lane_write.read_pod(masks, idx))
                 return base + ((no_decision(num_pol),) if decisions else ())
 
             def do_skip():
@@ -1334,11 +1335,12 @@ def _make_table_engine(
                 is_delete = kc == 1
                 node = jnp.where(
                     is_create, outs_c[0],
-                    jnp.where(is_delete, placed[idx], jnp.int32(-1)),
+                    jnp.where(is_delete, lane_write.read_pod(placed, idx),
+                              jnp.int32(-1)),
                 ).astype(jnp.int32)
                 dev = jnp.where(
                     is_create, outs_c[1],
-                    jnp.where(is_delete, masks[idx],
+                    jnp.where(is_delete, lane_write.read_pod(masks, idx),
                               jnp.zeros(MAX_GPUS_PER_NODE, jnp.bool_)),
                 )
                 if decisions:
@@ -1351,7 +1353,8 @@ def _make_table_engine(
                 # single-device CPU flat path — ENGINES.md Round 18)
 
                 def do_delete():
-                    base = placed[idx], masks[idx]
+                    base = (lane_write.read_pod(placed, idx),
+                            lane_write.read_pod(masks, idx))
                     return base + (
                         (no_decision(num_pol),) if decisions else ()
                     )
