@@ -91,9 +91,12 @@ def test_the_families_typical_sets_differ_in_size(fam):
     assert fam.rec.sub_requests == fam.rec.to_dict()["sub_requests"] == 8
     assert fam.cache == "built 2 of 2" and fam.rec.tables_reused == 0
     # 8 lanes are under FLAT_GROUP_MIN_LANES: the plain flat body, one
-    # dense column write an event; a trace a lane has 28 dense sites
+    # dense column write an event; a trace a lane has 27 dense sites (28
+    # before ISSUE 42: the flat commit's aff_cnt add left the event loop,
+    # FGD does not read the leaf)
     assert "table" in fam.rec.engine
-    assert (fam.rec.table_pass_events, fam.rec.dense_accesses) == (1, 28)
+    assert (fam.rec.table_pass_events, fam.rec.dense_accesses) == (1, 27)
+    assert fam.rec.affinity_deferred == 1
 
 
 def test_a_wide_wave_of_families_runs_grouped_and_equals_the_plain_body(
@@ -114,10 +117,11 @@ def test_a_wide_wave_of_families_runs_grouped_and_equals_the_plain_body(
     grouped, rec, _ = w.sweep()
     assert (rec.lanes, rec.events, rec.traces, rec.typical_sets) == (
         FLAT_GROUP_MIN_LANES, depth, 8, 2)
-    # 28 dense sites as on the plain body, and the three picks out of the
+    # 27 dense sites as on the plain body (28 before ISSUE 42 took the
+    # aff_cnt add out of the event loop), and the three picks out of the
     # pending block (lane_write.read_pending: feas, score, sdev)
     assert (rec.table_pass_events, rec.dense_accesses) == (
-        FLAT_GROUP_EVENTS, 31)
+        FLAT_GROUP_EVENTS, 30)
 
     # the plain body: the rule says 1, the wrapper is traced anew
     monkeypatch.setattr(table_engine, "flat_group_events", lambda *_: 1)

@@ -92,7 +92,10 @@ def test_the_mix_wave_runs_the_grouped_body_under_three_weight_rows(mix):
     rec = mix.rec
     assert (rec.lanes, rec.events, rec.traces) == (66, DEPTH, 2)
     assert (rec.weight_rows, rec.normalized_policies) == (3, 1)
-    assert (rec.table_pass_events, rec.dense_accesses) == (16, 32)
+    # 31 dense sites where ISSUE 34 counted 32: neither PWR nor FGD reads
+    # aff_cnt, so the commit's add into it left the event loop (ISSUE 42)
+    assert (rec.table_pass_events, rec.dense_accesses) == (16, 31)
+    assert rec.affinity_deferred == rec.to_dict()["affinity_deferred"] == 1
     assert rec.to_dict()["weight_rows"] == 3
     assert rec.to_dict()["normalized_policies"] == 1
     # PWR has no whole_split: it tries Sub once a whole-branch pod TYPE, so

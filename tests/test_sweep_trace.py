@@ -21,7 +21,8 @@ SPAN_NAMES = ["specs", "lane_keys", "lane_ranks", "init_tables", "scan",
               "frag_postpass", "fetch", "slice_lanes"]
 BODY_SCOPES = {
     # block_size: the scopes its step body carries
-    -1: {"tpusim.commit", "tpusim.refresh", "tpusim.select"},
+    -1: {"tpusim.commit", "tpusim.refresh", "tpusim.select",
+         "tpusim.affinity"},
     8: {"tpusim.commit", "tpusim.refresh", "tpusim.summary", "tpusim.select"},
 }
 WEIGHTS = [[1000], [1000], [700]]
@@ -387,6 +388,13 @@ def test_every_stage_of_the_step_body_has_its_scope(scoped):
     for scope in BODY_SCOPES[block_size]:
         assert scope in text, scope
     assert ("tpusim.summary" in text) == (block_size > 0)
+    # the chunk's affinity counts, after the scan: the flat body of a
+    # program whose kernels do not read aff_cnt (FGD); the blocked body
+    # keeps the add in its commit
+    assert ("tpusim.affinity" in text) == (block_size < 0)
+    assert sim.obs.sweeps[-1].affinity_deferred == (block_size < 0)
+    assert sim.obs.sweeps[-1].to_dict()["affinity_deferred"] == int(
+        block_size < 0)
     # the dense forms of sim/lane_write.py sit inside the stage that calls
     # them: the commit's masked adds, the refresh's column writes and row
     # reads, the select's row and entry reads
@@ -405,6 +413,26 @@ def test_every_stage_of_the_step_body_has_its_scope(scoped):
         lambda a: jax.ShapeDtypeStruct((3,) + a.shape, a.dtype), states)
     assert "tpusim.frag_postpass" in post.lower(stacked, tp).as_text(
         debug_info=True)
+
+
+def test_a_program_whose_kernel_reads_aff_cnt_keeps_the_add_in_its_loop():
+    """GpuClustering scores by the affinity counts: its flat sweep's record
+    reads affinity_deferred 0, its program has no tpusim.affinity scope and
+    all seventeen write sites (tests/test_affinity_deferred.py holds its
+    lanes to the oracle)."""
+    rng = np.random.default_rng(5)
+    sim = Simulator(_mk_cluster(rng), _cfg(
+        42, (("GpuClusteringScore", 1000),), "best", engine="table",
+        block_size=-1))
+    sim.set_workload_pods(_mk_pods(rng))
+    sim.set_typical_pods()
+    fn, shapes, _ = capture_sweep(
+        sim, sim.prepare_pods(), WEIGHTS, SEEDS, run=True)
+    rec = sim.obs.sweeps[-1]
+    assert rec.affinity_deferred == rec.to_dict()["affinity_deferred"] == 0
+    assert rec.lane_writes == WRITE_SITES[8]  # the whole commit, twice
+    text = fn.lower(*shapes).as_text(debug_info=True)
+    assert "tpusim.commit" in text and "tpusim.affinity" not in text
 
 
 def test_a_scoped_sweep_equals_the_sequential_oracle(scoped):
@@ -433,19 +461,23 @@ def test_a_scoped_sweep_equals_the_sequential_oracle(scoped):
 # the step body: three column writes (score, device, feasibility: the
 # blocked body's write_column, the flat body's write_columns in its flush)
 # and the commit's add_row x4 (cpu_left, mem_left, gpu_left, aff_cnt) and set_row x3
-# (placed, masks, failed); the commit once more in the replay's epilogue
-WRITE_SITES = 3 + 7 + 7
+# (placed, masks, failed); the commit once more in the replay's epilogue.
+# Since ISSUE 42 the FLAT body's commit (block size -1) has one add_row
+# fewer, 17 -> 16: where no kernel reads aff_cnt (FGD) its add left the
+# event loop for table_engine.chunk_affinity, which is no write site; the
+# epilogue's commit and the blocked body's are whole
+WRITE_SITES = {-1: 3 + 6 + 7, 8: 3 + 7 + 7}
 
 
 def test_lane_writes_counts_the_sites_the_batching_rule_lowered(
         scoped, two_sweeps):
-    _, sim, _, _ = scoped
-    assert sim.obs.sweeps[-1].lane_writes == WRITE_SITES
+    block_size, sim, _, _ = scoped
+    assert sim.obs.sweeps[-1].lane_writes == WRITE_SITES[block_size]
     assert sim.run_telemetry().to_record()["timing"]["sweeps"][-1][
-        "lane_writes"] == WRITE_SITES
+        "lane_writes"] == WRITE_SITES[block_size]
     # a warm sweep traces nothing and reports what its program's trace saw
     _, _, calls = two_sweeps
-    assert [rec.lane_writes for rec, _, _ in calls] == [WRITE_SITES] * 2
+    assert [rec.lane_writes for rec, _, _ in calls] == [WRITE_SITES[-1]] * 2
 
 
 def test_the_standalone_replay_lowers_as_it_always_did(scoped):
@@ -459,7 +491,7 @@ def test_the_standalone_replay_lowers_as_it_always_did(scoped):
     dynamic_update_slices."""
     from tpusim.sim import lane_write
 
-    _, sim, _, called = scoped
+    block_size, sim, _, called = scoped
     shapes = list(called["shapes"])
     for i in (6, 7, 8):  # key, weights, tie-break rank: one lane's
         shapes[i] = jax.ShapeDtypeStruct(shapes[i].shape[1:], shapes[i].dtype)
@@ -468,7 +500,8 @@ def test_the_standalone_replay_lowers_as_it_always_did(scoped):
     assert not sites and not sites.dense
     assert "custom_call" not in text
     assert text.count("stablehlo.dynamic_update_slice") >= 3
-    assert text.count('"stablehlo.scatter"') >= 14  # the commit, twice
+    # the commit, twice; the flat body's without its aff_cnt add (ISSUE 42)
+    assert text.count('"stablehlo.scatter"') >= 14 - (block_size < 0)
     swept = called["fn"].lower(*called["shapes"]).as_text()
     assert swept.count('"stablehlo.scatter"') <= text.count(
         '"stablehlo.scatter"') - 5
@@ -481,7 +514,7 @@ def test_the_standalone_replay_lowers_as_it_always_did(scoped):
 # the bookkeeping row's index: vmap's one update serves them all), and the
 # reads: the dirty row of the nine NodeState leaves, the selected gpu_left
 # row and the device table's entry
-DENSE_SITES = {-1: WRITE_SITES - 2 * 3 + 9 + 2}
+DENSE_SITES = {-1: WRITE_SITES[-1] - 2 * 3 + 9 + 2}  # 21; 22 before ISSUE 42
 
 
 def test_dense_accesses_counts_the_sites_lowered_in_the_dense_form(scoped):
@@ -490,7 +523,8 @@ def test_dense_accesses_counts_the_sites_lowered_in_the_dense_form(scoped):
     from 8,192 nodes (tests/test_sweep_compile.py), at none."""
     block_size, sim, _, _ = scoped
     rec = sim.obs.sweeps[-1]
-    assert rec.lane_writes == WRITE_SITES and rec.dense_accesses > 0
+    assert rec.lane_writes == WRITE_SITES[block_size]
+    assert rec.dense_accesses > 0
     if block_size in DENSE_SITES:
         assert rec.dense_accesses == DENSE_SITES[block_size]
     assert sim.run_telemetry().to_record()["timing"]["sweeps"][-1][
@@ -521,7 +555,7 @@ def test_table_pass_events_is_the_flat_bodys_group(scoped):
         sim, sim.prepare_pods(), [WEIGHTS[i % 3] for i in range(wide)],
         [SEEDS[i % 3] for i in range(wide)])
     rec = sim.obs.sweeps[-1]
-    assert rec.lanes == wide and rec.lane_writes == WRITE_SITES
+    assert rec.lanes == wide and rec.lane_writes == WRITE_SITES[block_size]
     assert rec.table_pass_events == (
         FLAT_GROUP_EVENTS if block_size < 0 else 1)
     assert rec.to_dict()["table_pass_events"] == rec.table_pass_events

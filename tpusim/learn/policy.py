@@ -261,6 +261,7 @@ def feature_policy(feature: str):
 
         kernel.normalize = "none"
         kernel.policy_name = learned_policy_name(feature)
+        kernel.reads_affinity = False
         if feature == "frag_delta":
             # branch-specialized halves for the table engine's static
             # share/whole type partition (the fgd idiom)

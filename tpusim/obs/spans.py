@@ -186,6 +186,14 @@ class SweepRecord:
     # writes: the flat body's group (table_engine.FLAT_GROUP_EVENTS), 1
     # where a column is written every event, 0 with no dense table write
     table_pass_events: int = 0
+    # 1 where the sweep's program leaves the commit's add into
+    # NodeState.aff_cnt out of its event loop and makes the leaf once a
+    # chunk from the events' own record (table_engine.chunk_affinity,
+    # scope tpusim.affinity): the flat table body where no scoring kernel
+    # reads the leaf and no fault step rewrites it; 0 where the add runs
+    # every event (the blocked body, fault plans, a GpuClustering program,
+    # the sequential engine)
+    affinity_deferred: int = 0
     # 1 where the sweep read the score tables an earlier sweep of the
     # Simulator left on the device (its init_tables span says
     # cache="resident"), 0 where it built or loaded them
@@ -321,6 +329,7 @@ class SweepRecord:
             "lane_writes": self.lane_writes,
             "dense_accesses": self.dense_accesses,
             "table_pass_events": self.table_pass_events,
+            "affinity_deferred": self.affinity_deferred,
             "tables_reused": self.tables_reused,
             "traces": self.traces,
             "typical_sets": self.typical_sets,

@@ -16,6 +16,7 @@ from tpusim.policies.base import (
     feasible_min_max,
     minmax_normalize_i32,
     minmax_scale_i32,
+    policies_read_affinity,
     pwr_normalize_i32,
 )
 from tpusim.policies.bestfit import bestfit_score
@@ -43,7 +44,7 @@ def jit_policy(fn):
         j.policy_name = fn.policy_name
         # config attrs (DotProduct carries dim_ext/norm; the pallas-engine
         # column resolver reads them)
-        for attr in ("dim_ext", "norm"):
+        for attr in ("dim_ext", "norm", "reads_affinity"):
             if hasattr(fn, attr):
                 setattr(j, attr, getattr(fn, attr))
         _JIT_CACHE[fn] = j
@@ -125,6 +126,7 @@ __all__ = [
     "minmax_scale_i32",
     "pwr_normalize_i32",
     "NORMALIZE_DEGENERATE",
+    "policies_read_affinity",
     "POLICY_NAMES",
     "is_policy_name",
 ]

@@ -48,8 +48,19 @@ class PolicyResult(NamedTuple):
 
 
 # A policy is (state, pod, ctx) -> PolicyResult, plus a `normalize` mode
-# consumed by the step: "none" | "minmax" | "pwr".
+# consumed by the step: "none" | "minmax" | "pwr", and `reads_affinity`:
+# whether the kernel (any of its `branches` too) reads NodeState.aff_cnt.
+# The flat table replay leaves the per-event add into that leaf out of its
+# event loop where no kernel of the program reads it (policies_read_affinity
+# below); tests/test_affinity_readers.py holds every registered kernel's
+# declaration to its jaxpr.
 PolicyFn = Callable[[NodeState, PodSpec, ScoreContext], PolicyResult]
+
+
+def policies_read_affinity(policies) -> bool:
+    """Whether some kernel of [(policy_fn, weight)] reads
+    NodeState.aff_cnt. A kernel that says nothing counts as a reader."""
+    return any(getattr(fn, "reads_affinity", True) for fn, _ in policies)
 
 
 def feasible_min_max(scores, feasible):

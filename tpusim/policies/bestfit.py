@@ -30,3 +30,4 @@ def bestfit_score(state: NodeState, pod: PodSpec, ctx: ScoreContext) -> PolicyRe
 
 bestfit_score.normalize = "minmax"
 bestfit_score.policy_name = "BestFitScore"
+bestfit_score.reads_affinity = False

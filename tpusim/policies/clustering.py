@@ -52,3 +52,4 @@ def clustering_score(state: NodeState, pod: PodSpec, ctx: ScoreContext) -> Polic
 
 clustering_score.normalize = "none"
 clustering_score.policy_name = "GpuClusteringScore"
+clustering_score.reads_affinity = True

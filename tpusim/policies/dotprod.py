@@ -205,6 +205,7 @@ def make_dotprod(dim_ext: str = "share", norm: str = "max"):
 
     dotprod_score.normalize = "none"
     dotprod_score.policy_name = "DotProductScore"
+    dotprod_score.reads_affinity = False
     dotprod_score.dim_ext = dim_ext
     dotprod_score.norm = norm
     return dotprod_score

@@ -206,6 +206,7 @@ def fgd_score(state: NodeState, pod: PodSpec, ctx: ScoreContext) -> PolicyResult
 
 fgd_score.normalize = "none"
 fgd_score.policy_name = "FGDScore"
+fgd_score.reads_affinity = False
 # branch-specialized kernels for callers that know the pod's branch
 # statically (the table engine partitions pod types host-side, avoiding the
 # cond→select duplication under a type-axis vmap)

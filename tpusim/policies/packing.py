@@ -59,3 +59,4 @@ def packing_score(state: NodeState, pod: PodSpec, ctx: ScoreContext) -> PolicyRe
 
 packing_score.normalize = "none"
 packing_score.policy_name = "GpuPackingScore"
+packing_score.reads_affinity = False

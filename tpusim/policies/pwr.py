@@ -71,3 +71,4 @@ def pwr_score(state: NodeState, pod: PodSpec, ctx: ScoreContext) -> PolicyResult
 
 pwr_score.normalize = "pwr"
 pwr_score.policy_name = "PWRScore"
+pwr_score.reads_affinity = False

@@ -26,3 +26,4 @@ def random_score(state: NodeState, pod: PodSpec, ctx: ScoreContext) -> PolicyRes
 
 random_score.normalize = "none"
 random_score.policy_name = "RandomScore"
+random_score.reads_affinity = False

@@ -47,3 +47,4 @@ def simon_score(state: NodeState, pod: PodSpec, ctx: ScoreContext) -> PolicyResu
 
 simon_score.normalize = "minmax"
 simon_score.policy_name = "Simon"
+simon_score.reads_affinity = False

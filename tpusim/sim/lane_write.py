@@ -54,7 +54,15 @@ mask on the node axis and no `scatter` or `gather` at all:
   [lanes, K, N] (0.2 s an event's read at 600 lanes x K = 400; PERF.md
   section 6, PR 33).
 
-A dense write is a pass over the whole leaf whatever it writes, so at sweep
+A dense write is a pass over the whole leaf whatever it writes. One of them
+left the flat step body for that reason (PERF.md section 6, PR 42): the
+commit's add into `aff_cnt`, with a trace a lane the largest operation of a
+wave (its class index is then a lane's own, so the pass took all of
+s32[lanes, N, 9] every event), wrote a leaf that no kernel but
+GpuClustering's reads. Where the program's kernels say they do not, the
+flat body's commit leaves it out and `table_engine.chunk_affinity` sums the
+chunk's events once, a contraction of two one-hots over the event axis:
+no write site of this module, no scatter, no index row a lane. And at sweep
 width (`table_engine.flat_group_events`) the flat step body does not write
 its tables every event: it holds the dirty columns of a group of events
 (`table_engine.LateColumns`) and puts them down together. `write_columns(tbl, cols, idxs)` is that access: unbatched, the

@@ -290,6 +290,17 @@ class PendingCommit(NamedTuple):
     (ENGINES.md, PR 27; per-lane update loops and a layout constraint
     were tried and deleted).
 
+    What a commit adds into aff_cnt is commit_affinity(node, cls, rs). The
+    flat table replay does not make that add event by event where no kernel
+    of its program reads the leaf (apply_commit's static `affinity`): its
+    run_chunk sums the commits its scan applied, the incoming register and
+    its own events but the last, after the scan
+    (table_engine.chunk_affinity), from the scan's own record: `node` is
+    the event_node it emits, `rs` the event's kind, `cls` its pod's class.
+    The register itself means what it always did: the last event's commit
+    lands whole in the next chunk or in finish, so a carry written by
+    either form resumes under the other.
+
     node == -1 encodes a no-op state commit (failed create / skip / the
     pre-first-event initial value). pod_write is the bookkeeping row index
     (the P-th dummy row for skip events); failed_write is the row for the
@@ -355,19 +366,31 @@ def make_pending_commit(
     )
 
 
-def apply_commit(state: NodeState, placed, masks, failed, p: "PendingCommit"):
+def apply_commit(state: NodeState, placed, masks, failed, p: "PendingCommit",
+                 affinity: bool = True):
     """Apply a PendingCommit's scatters — the write-only half of the
     pipelined event loop. placed/masks/failed carry one extra dummy row
     ([P]) that absorbs skip-event writes. The global view of
     apply_commit_sharded (offset 0, the full node window), so the commit
     arithmetic exists exactly once."""
     return apply_commit_sharded(
-        state, placed, masks, failed, p, jnp.int32(0), state.num_nodes
+        state, placed, masks, failed, p, jnp.int32(0), state.num_nodes,
+        affinity,
     )
 
 
+def commit_affinity(node, cls, rs):
+    """What a commit (PendingCommit.node / .cls / .rs) adds into
+    aff_cnt[node, max(cls, 0)]: +1 for a bind, -1 for a release, 0 where
+    it touched no node or the pod has no affinity class. ONE definition
+    for the per-event add below and for the flat table replay's sum over
+    a chunk's events (table_engine.chunk_affinity)."""
+    return jnp.where((node >= 0) & (cls >= 0), -rs, 0)
+
+
 def apply_commit_sharded(state: NodeState, placed, masks, failed,
-                         p: "PendingCommit", offset, nloc: int):
+                         p: "PendingCommit", offset, nloc: int,
+                         affinity: bool = True):
     """apply_commit for a node-axis-sharded carry (the shard_map engine's
     software pipeline, ISSUE 11): `p.node` is a GLOBAL node id, so each
     shard lands the state scatters owner-masked on its local row window
@@ -376,7 +399,12 @@ def apply_commit_sharded(state: NodeState, placed, masks, failed,
     identically on every shard. Strictly write-only on every touched
     buffer, like apply_commit, so the scatters alias in place under scan.
     With offset == 0 and nloc == N this IS apply_commit on a global view
-    (the shard engine's finish epilogue uses apply_commit directly)."""
+    (the shard engine's finish epilogue uses apply_commit directly).
+
+    `affinity` (static) False leaves the add into aff_cnt out: the flat
+    table replay's event loop, where no kernel of its program reads that
+    leaf, makes it once a chunk from the events' own record. Every other
+    caller commits whole."""
     li = p.node - offset
     owns = (p.node >= 0) & (li >= 0) & (li < nloc)
     sel = jnp.clip(li, 0, nloc - 1)
@@ -392,11 +420,12 @@ def apply_commit_sharded(state: NodeState, placed, masks, failed,
             jnp.where(owns, p.rs, 0) * p.dev_mask.astype(jnp.int32)
             * p.gpu_milli,
         ),
-        aff_cnt=add_row(
-            state.aff_cnt, (sel, jnp.maximum(p.cls, 0)),
-            jnp.where(owns & (p.cls >= 0), -p.rs, 0),
-        ),
     )
+    if affinity:
+        state = state._replace(aff_cnt=add_row(
+            state.aff_cnt, (sel, jnp.maximum(p.cls, 0)),
+            jnp.where(owns, commit_affinity(p.node, p.cls, p.rs), 0),
+        ))
     placed = set_row(placed, p.pod_write, p.placed_val)
     masks = set_row(masks, p.pod_write, p.mask_val)
     failed = set_row(failed, p.failed_write, p.failed_val)

@@ -48,8 +48,10 @@ from tpusim.policies import (
     ScoreContext,
     minmax_normalize_i32,
     minmax_scale_i32,
+    policies_read_affinity,
     pwr_normalize_i32,
 )
+from tpusim.policies.clustering import pod_affinity_class
 from tpusim.sim import lane_write
 from tpusim.sim.engine import EV_RETRY, ReplayResult
 from tpusim.sim.step import (
@@ -59,6 +61,7 @@ from tpusim.sim.step import (
     block_reduce,
     build_decision,
     choose_devices,
+    commit_affinity,
     filter_nodes,
     make_pending_commit,
     no_pending_commit,
@@ -98,6 +101,64 @@ def flat_group_events(lanes: int, nodes: int) -> int:
     if nodes < BLOCKED_MIN_NODES and lanes >= FLAT_GROUP_MIN_LANES:
         return FLAT_GROUP_EVENTS
     return 1
+
+
+# Where no kernel of the program reads NodeState.aff_cnt the flat replay
+# does not add into it every event: chunk_affinity sums the chunk's events
+# once, this many at a time, so that their one-hot over the nodes
+# ([lanes, AFFINITY_EVENTS, N] in a sweep) never stands whole.
+AFFINITY_EVENTS = 128
+
+
+def chunk_affinity(pend: PendingCommit, pods: PodSpec, ev_kind, ev_pod,
+                   event_node, num_nodes: int, classes: int):
+    """i32[N, classes]: what the commits that one chunk's scan APPLIED add
+    to NodeState.aff_cnt. The commit is one event deep, so those are the
+    incoming `pend` and the chunk's own events but the last (whose commit
+    leaves in the outgoing pend, for the next chunk or finish): node_e is
+    the scan's own record (`event_node`, the value that went into
+    PendingCommit.node), the sign the event's kind, the class its pod's.
+    Integer counts in any order, so bit for bit what a per-event
+    apply_commit(affinity=True) leaves.
+
+    One expression at every width, with no batching rule: a contraction of
+    two one-hots over the event axis, aff[n, c] = sum_e [node_e == n] * s_e
+    * [cls_e == c], int8 operands (0, +-1) accumulated in int32, cut into
+    blocks of AFFINITY_EVENTS events. No scatter and no gather with an index
+    row a lane (sim/lane_write.py: a `while` over the lanes of a sweep)."""
+    events = ev_kind.shape[0]
+    zero = jnp.zeros((num_nodes, classes), jnp.int32)
+    if not events:  # nothing ran: the incoming pend is still pending
+        return zero
+    with jax.named_scope("tpusim.affinity"):
+        cls_e = pod_affinity_class(pods)[ev_pod[:-1]]
+        rs_e = jnp.where(jnp.clip(ev_kind[:-1], 0, 2) == 1, 1, -1)
+        node = jnp.concatenate([pend.node[None], event_node[:-1]])
+        cls = jnp.concatenate([pend.cls[None], cls_e])
+        rs = jnp.concatenate([pend.rs[None], rs_e])
+        sign = commit_affinity(node, cls, rs).astype(jnp.int8)
+        # whole blocks: the pad touches no node and adds 0
+        pad = -events % AFFINITY_EVENTS
+        blocks = [
+            jnp.pad(a, (0, pad), constant_values=fill).reshape(
+                -1, AFFINITY_EVENTS)
+            for a, fill in ((node, -1), (cls, -1), (sign, 0))
+        ]
+        node_iota = jax.lax.iota(jnp.int32, num_nodes)
+        cls_iota = jax.lax.iota(jnp.int32, classes)
+
+        def add_block(acc, block):
+            node_b, cls_b, sign_b = block
+            # the sign rides the node one-hot: with one shared trace the
+            # class one-hot is then the same for every lane of a sweep
+            hot_n = jnp.where(
+                node_b[:, None] == node_iota, sign_b[:, None], jnp.int8(0))
+            hot_c = (cls_b[:, None] == cls_iota).astype(jnp.int8)
+            return acc + jax.lax.dot_general(
+                hot_n, hot_c, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32), None
+
+        return jax.lax.scan(add_block, zero, tuple(blocks))[0]
 
 
 def resolve_block_size(block_size: int, num_nodes: int, num_types: int) -> int:
@@ -259,6 +320,11 @@ def pad_pod_types(types: PodTypes, multiple: int = 16) -> PodTypes:
     return PodTypes(share2, whole2, tid, *_whole_requests(whole2))
 
 
+def _num_types(types: PodTypes) -> int:
+    """K: the rows of the tables `types` index (both groups, pads too)."""
+    return int(types.share.cpu.shape[0]) + int(types.whole.cpu.shape[0])
+
+
 def _row_state(state: NodeState, node) -> NodeState:
     """1-node slice of the cluster state at a dynamic index."""
     return jax.tree.map(lambda a: lane_write.read_row(a, node), state)
@@ -284,7 +350,13 @@ class FlatTableCarry(NamedTuple):
     `scan(body, c, ev[:k]); scan(body, ·, ev[k:])` IS `scan(body, c, ev)`.
 
     All leaves are exact dtypes (i32 / bool / u32 PRNG key) — serialization
-    cannot perturb them."""
+    cannot perturb them.
+
+    `state` holds every commit but the one in `pend`, aff_cnt included,
+    whether the event loop added into that leaf event by event or
+    _run_chunk_impl added the chunk's counts after its scan
+    (chunk_affinity: a program whose kernels do not read the leaf): the
+    two forms hand each other the same carry at every event boundary."""
 
     state: NodeState
     score_tbl: jnp.ndarray  # i32[num_pol, K, N]
@@ -739,6 +811,12 @@ class _TableEngine(NamedTuple):
     # they differ in (faults, heartbeat, block size). The driver keeps a
     # sweep's tables on the device under it (Simulator._sweep_tables)
     closes_over: tuple
+    # (num_nodes, types) -> bool: whether run_chunk / replay on a cluster
+    # and type set of that size leaves the add into aff_cnt out of the event
+    # loop and makes it once a chunk (chunk_affinity): the flat body of a
+    # program in which no kernel reads that leaf and no fault step
+    # rewrites it (SweepRecord.affinity_deferred)
+    affinity_deferred: object
 
 
 def _make_table_engine(
@@ -767,6 +845,19 @@ def _make_table_engine(
     sel_idx = selector_index(policies, gpu_sel)
     _columns, _init_tables = make_table_builders(policies, sel_idx)
     has_random = any(fn.policy_name == "RandomScore" for fn, _ in policies)
+    # The flat body's commit leaves aff_cnt alone and _run_chunk_impl adds
+    # the chunk's counts after its scan where nothing reads the leaf
+    # between events: no kernel of the program (read off the kernels'
+    # own declaration) and no fault step (fault_lane zeroes and rewrites
+    # aff_cnt rows mid-scan, and overwrites the record of the touched
+    # node). Never a caller's choice.
+    defer_affinity = not faults and not policies_read_affinity(policies)
+
+    def block_size_of(num_nodes: int, num_types: int) -> int:
+        """The block size init_carry lays the carry out with; 0: flat."""
+        return 0 if has_random else resolve_block_size(
+            block_size, num_nodes, num_types)
+
     # policies whose normalizer needs global (lo, hi) extrema over feasible
     # nodes; the blocked path maintains these via block min/max aggregates
     norm_idx = [
@@ -1156,6 +1247,10 @@ def _make_table_engine(
                        wts, fault_ops=None, grouped: bool = False):
         """Scan body of the flat O(N) select path.
 
+        Under defer_affinity its commit leaves the add into aff_cnt to
+        _run_chunk_impl's epilogue: the leaf passes through the loop unread
+        and unwritten.
+
         `grouped` (a wide sweep: flat_group_events) makes it one event of
         a group (_run_flat_group). Its carry is then (table carry,
         LateColumns) and its xs lead with the event's slot in the group:
@@ -1225,7 +1320,8 @@ def _make_table_engine(
             # iteration, so all updates alias in place (PendingCommit)
             with jax.named_scope("tpusim.commit"):
                 state, placed, masks, failed = apply_commit(
-                    state, placed, masks, failed, pend
+                    state, placed, masks, failed, pend,
+                    affinity=not defer_affinity,
                 )
 
             # refresh the one column whose node changed last event (from
@@ -1489,8 +1585,8 @@ def _make_table_engine(
         kernel consumes rng, so init can reuse the root key as-is."""
         n = state.num_nodes
         num_pods = pods.cpu.shape[0]
-        k_types = int(types.share.cpu.shape[0]) + int(types.whole.cpu.shape[0])
-        bsz = 0 if has_random else resolve_block_size(block_size, n, k_types)
+        k_types = _num_types(types)
+        bsz = block_size_of(n, k_types)
         if tiebreak_rank is None:
             tiebreak_rank = jnp.arange(n, dtype=jnp.int32)
         if tables is None:
@@ -1568,7 +1664,11 @@ def _make_table_engine(
         the carry was initialized under (the blocked summaries embed it).
         `group` (static; flat_group_events) makes the flat step write its
         columns that many events at a time; the carry that comes back is
-        the same either way."""
+        the same either way, and so is its aff_cnt where the flat body
+        left the per-event add out (defer_affinity): the segment's counts
+        go in here, after its scan, so every carry a caller sees, between
+        two chunks, in a checkpoint or into finish, holds the leaf the
+        per-event commit would have left at that event."""
         base = carry[0] if faults else carry
         n = base.state.num_nodes
         num_pods = pods.cpu.shape[0]
@@ -1579,7 +1679,8 @@ def _make_table_engine(
             (ev_kind, ev_pod, fault_ops.pos, fault_ops.arg, fault_ops.aux)
             if faults else (ev_kind, ev_pod)
         )
-        if isinstance(base, BlockedTableCarry):
+        blocked = isinstance(base, BlockedTableCarry)
+        if blocked:
             group = 1  # its column writes hand back the block it reduces
             k_types, nblk = base.bt.shape
             bsz = base.score_tbl.shape[2] // nblk
@@ -1594,6 +1695,18 @@ def _make_table_engine(
                 pods, type_id, types, tp, tiebreak_rank, n, num_pods, wts,
                 fault_ops, grouped=group > 1,
             )
+        out, ys = _scan_events(body, carry, xs, group)
+        if defer_affinity and not blocked:
+            state = out.state
+            out = out._replace(state=state._replace(
+                aff_cnt=state.aff_cnt + chunk_affinity(
+                    base.pend, pods, ev_kind, ev_pod, ys[0], n,
+                    state.aff_cnt.shape[1])))
+        return out, ys
+
+    def _scan_events(body, carry, xs, group: int):
+        """The segment's events through `body`: one scan, or the flat
+        body's groups."""
         if group <= 1:
             # unroll amortizes per-iteration fixed costs (~20% wall on the
             # openb replay); higher factors showed no further gain
@@ -1602,7 +1715,7 @@ def _make_table_engine(
         # and one of r, each ending in its flush. No skip events are padded
         # in: every event splits the key, and the chain must stay the
         # oracle's
-        q, r = divmod(ev_kind.shape[0], group)
+        q, r = divmod(xs[0].shape[0], group)
         parts = []
         if q:
             carry, ys = jax.lax.scan(
@@ -1696,4 +1809,6 @@ def _make_table_engine(
             lambda state, types, tp, key: _init_tables(state, types, tp, key)
         ),
         closes_over=(tuple(fn for fn, _ in policies), sel_idx),
+        affinity_deferred=lambda num_nodes, types: (
+            defer_affinity and not block_size_of(num_nodes, _num_types(types))),
     )
