@@ -2,7 +2,7 @@
 capture the jitted vmapped replay with the shapes a cell calls it on, list
 the large `copy` operations of a compiled module's scan, its loops and how
 they nest, the operations of a loop that produce, or read, a given
-shape, and its gathers."""
+shape, its gathers, and the arrays the module gives back."""
 
 import json
 import math
@@ -147,6 +147,25 @@ def _instructions_in(text: str, root: str):
             m = _INSTRUCTION.match(line)
             if m:
                 yield name, m, line, comps
+
+
+_ROOT = re.compile(r"^\s*ROOT %?[\w.\-]+ = (.*?) [\w\-]+\(")
+
+
+def entry_results(text: str) -> list:
+    """The arrays a compiled module gives back, in order: ("s32", (4, 10))
+    for every element of its ENTRY computation's ROOT."""
+    lines = text.splitlines()
+    at = next(i for i, line in enumerate(lines) if line.startswith("ENTRY "))
+    for line in lines[at + 1:]:
+        if line.startswith("}"):
+            break
+        m = _ROOT.match(line)
+        if m:
+            return [(dt, tuple(int(d) for d in dims.split(",") if d))
+                    for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                               m.group(1))]
+    raise ValueError("the module's ENTRY computation has no ROOT")
 
 
 def producers_in(text: str, root: str, shape: str) -> list:

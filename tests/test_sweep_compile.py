@@ -114,12 +114,30 @@ def test_cell_sized_sweep_compiles_without_whole_carry_copies(one_chip):
     assert (len(sites), len(sites.dense)) == (17, 0)
     assert sites.table_pass_events == 0  # no table is written densely
     compiled = lowered.compile()
+    _assert_the_capacity_leaves_leave_without_a_lane_axis(
+        compiled.as_text(), LANES, NODES)
     found = sweep_program.big_copies_in_scan(
         compiled.as_text(), LANES * NODES)
     assert not found, "\n".join(f"{c}: {n} = copy -> {s}"
                                 for c, n, s, _ in found)
     # the parent's program held 6.72 GB of temporaries, sixteen copies
     assert compiled.memory_analysis().temp_size_in_bytes < 3.5e9
+
+
+def _assert_the_capacity_leaves_leave_without_a_lane_axis(
+        text, lanes, nodes, kept=2):
+    """ISSUE 43: of a final state's seven [nodes] leaves the program gives
+    back `kept` with the lane axis (cpu_left and mem_left; a fault plan's
+    carry has one more, the step each node went down at) and the five that
+    no step writes (types.CAPACITY_LEAVES) once, as they came in: the
+    parent's module gave seven s32[lanes,nodes] and broadcast five of them
+    from its own parameters. The other two leaves keep their lanes too.
+    tests/test_sweep_shared.py holds the rule by value on every body."""
+    results = sweep_program.entry_results(text)
+    assert results.count(("s32", (lanes, nodes))) == kept, results
+    assert results.count(("s32", (nodes,))) == 5, results
+    for rest in ((lanes, nodes, 8), (lanes, nodes, 9)):  # gpu_left, aff_cnt
+        assert results.count(("s32", rest)) == 1, results
 
 
 def _assert_no_gather_a_lane_and_type(text, inner, lanes):
@@ -285,6 +303,7 @@ def test_the_openb_flat_sweep_loops_over_events_only(one_chip, operands):
     # (64 events are one block of chunk_affinity's sum: no third loop)
     assert len(loops) == 2, loops
     _assert_the_affinity_add_left_the_event_loops(text, loops, outer, lanes)
+    _assert_the_capacity_leaves_leave_without_a_lane_axis(text, lanes, 1213)
     held = {holder: carried for holder, _, carried in loops}
     assert re.search(rf"s32{table}", held[bodies[outer]])
     assert f"s32[{lanes},{FLAT_GROUP_EVENTS},{k}]" in held[outer]
@@ -408,8 +427,11 @@ def test_the_plain_flat_sweeps_loop_over_events_only(one_chip, operands):
     assert sites.table_pass_events == 1
     assert shapes[1].cpu.shape == (OPENB_DEPTH,)
     assert shapes[3].shape[0] == OPENB_LANES  # a stream a lane
-    (loop,) = sweep_program.while_loops(lowered.compile().as_text())
+    text = lowered.compile().as_text()
+    (loop,) = sweep_program.while_loops(text)
     assert f"s32[{OPENB_LANES},1213,9]" in loop[2]  # the scan's carry
+    _assert_the_capacity_leaves_leave_without_a_lane_axis(
+        text, OPENB_LANES, 1213, kept=3)
 
 
 @pytest.mark.parametrize("operands", ["one shared trace", "a trace a lane"])
@@ -456,6 +478,7 @@ def test_a_stream_with_deletions_loops_over_events_only(one_chip, operands):
     (inner,) = [b for b, holder in bodies.items() if holder == outer]
     assert len(loops) == 2, loops
     _assert_the_affinity_add_left_the_event_loops(text, loops, outer, lanes)
+    _assert_the_capacity_leaves_leave_without_a_lane_axis(text, lanes, 1213)
     assert not sweep_program.producers_in(text, inner, table)
     _assert_no_gather_a_lane_and_type(text, inner, lanes)
 

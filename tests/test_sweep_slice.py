@@ -9,7 +9,10 @@ here, on every form a sweep takes:
      == 0` for `d >= gpu_cnt[n]`, in the loaders' initial state and in
      every lane's final state, on every body of the step;
   3. the structure: no `[B, N, 8]` temporary and no copy a lane (plain
-     numpy, a synthetic fetched result).
+     numpy, a synthetic fetched result);
+  4. what the lanes share (ISSUE 43): the five capacity leaves come
+     fetched without a lane axis, and every lane's state holds the SAME
+     read-only view of each, equal to the start state's.
 """
 
 import dataclasses
@@ -30,7 +33,7 @@ from tpusim.sim import driver
 from tpusim.sim.driver import Simulator, lane_from_arrays, schedule_pods_sweep
 from tpusim.sim.engine import EventMetrics, ReplayResult
 from tpusim.sim.table_engine import FLAT_GROUP_EVENTS, FLAT_GROUP_MIN_LANES
-from tpusim.types import NodeState
+from tpusim.types import CAPACITY_LEAVES, NodeState
 
 WEIGHTS = [[1000], [700], [1000], [850]]
 SEEDS = [11, 12, 13, 2**31 + 5]
@@ -156,6 +159,14 @@ def swept(what):
                  list(range(lanes)))
 
 
+def _lane_state(state, i, copy=False):
+    """Lane i's NodeState out of a fetched sweep's: its own row of the
+    four leaves a step writes, the shared [N] capacity leaves whole."""
+    leaves = (leaf if f in CAPACITY_LEAVES else leaf[i]
+              for f, leaf in zip(NodeState._fields, state))
+    return NodeState(*(np.array(a) if copy else a for a in leaves))
+
+
 def _assert_arrays_equal(a, b, what):
     a, b = np.asarray(a), np.asarray(b)
     assert a.shape == b.shape and a.dtype == b.dtype, what
@@ -178,7 +189,7 @@ def test_every_lane_equals_the_per_lane_form_of_its_own_arrays(form):
         assert any(ln.disruption.node_recoveries for ln in s.lanes)
     for i, got in enumerate(s.lanes):
         p, e = pods_n[i], events_n[i]
-        state = NodeState(*(np.array(leaf[i]) for leaf in out.state))
+        state = _lane_state(out.state, i, copy=True)
         want = lane_from_arrays(
             state, np.array(out.placed_node[i][:p]),
             np.array(out.dev_mask[i][:p]), np.array(out.ever_failed[i][:p]),
@@ -251,7 +262,9 @@ def _pads(state):
     """gpu_left beyond each node's devices: [..., N, 8] entries that the
     allocation ratio counts as nothing."""
     gpu_left, gpu_cnt = np.asarray(state.gpu_left), np.asarray(state.gpu_cnt)
-    beyond = np.arange(gpu_left.shape[-1]) >= gpu_cnt[..., None]
+    # (a fetched sweep's gpu_cnt is the cluster's, [N], for every lane)
+    beyond = np.broadcast_to(
+        np.arange(gpu_left.shape[-1]) >= gpu_cnt[..., None], gpu_left.shape)
     assert beyond.any() and not beyond.all()
     return gpu_left[beyond]
 
@@ -285,6 +298,46 @@ def test_gpu_left_is_zero_beyond_a_nodes_devices(what):
         assert any((ln.state.mem_left < 0).any() for ln in s.lanes)
 
 
+@pytest.mark.parametrize("what", list(FORMS) + list(BODIES))
+def test_the_lanes_share_one_read_only_view_of_the_capacity_leaves(what):
+    """The fetched state holds the five never-written leaves once, [N],
+    equal to the start state's in value and dtype; every lane's state is a
+    whole NodeState of today's shapes whose five are that one array (the
+    same object, so `np.shares_memory`), not writeable, while the four
+    leaves a step writes are the lane's own rows."""
+    s = swept(what)
+    fetched, start = s.args[0].state, s.sim.init_state
+    lanes, n = len(s.lanes), start.num_nodes
+    first = s.lanes[0].state
+    for f in NodeState._fields:
+        whole, own = getattr(fetched, f), getattr(first, f)
+        want = np.asarray(getattr(start, f))
+        assert own.shape == want.shape and own.dtype == want.dtype, f
+        if f in CAPACITY_LEAVES:
+            assert whole.shape == (n,), f
+            _assert_arrays_equal(whole, want, f)
+            assert not whole.flags.writeable, f
+            for lane in s.lanes:
+                leaf = getattr(lane.state, f)
+                assert leaf is whole and not leaf.flags.writeable, f
+            assert np.shares_memory(own, getattr(s.lanes[-1].state, f)), f
+        else:
+            assert whole.shape == (lanes,) + want.shape, f
+            for i, lane in enumerate(s.lanes):
+                leaf = getattr(lane.state, f)
+                assert np.shares_memory(leaf, whole), f
+                assert not leaf.flags.writeable, f
+                _assert_arrays_equal(leaf, whole[i], f)
+            assert not np.shares_memory(own, getattr(s.lanes[-1].state, f))
+    with pytest.raises(ValueError, match="read-only"):
+        first.gpu_cnt[0] = 0
+    # one sum of the cluster's devices serves every lane's allocation ratio
+    cnt = int(np.asarray(start.gpu_cnt).sum())
+    for lane in s.lanes:
+        assert lane.gpu_alloc_pct == 100.0 * float(
+            MILLI * cnt - int(lane.state.gpu_left.sum())) / (cnt * MILLI)
+
+
 def test_the_csv_loaders_cluster_starts_with_zero_pads():
     nodes = load_node_csv(
         os.path.join(REPO, "data/csv/openb_node_list_gpu_node.csv"))
@@ -295,18 +348,19 @@ def test_the_csv_loaders_cluster_starts_with_zero_pads():
 
 def _synthetic_fetch(lanes, nodes, pods, events, rng):
     """A fetched sweep result in plain numpy: random cluster states with
-    zero pads, random placements, counters, frag amounts and watts."""
+    zero pads on one cluster's capacities, random placements, counters,
+    frag amounts and watts."""
     def i32(*shape, low=-5, high=50000):
         return rng.integers(low, high, shape, dtype=np.int32)
 
-    cnt = i32(lanes, nodes, low=0, high=9)
+    cnt = i32(nodes, low=0, high=9)
     left = i32(lanes, nodes, 8, low=0, high=MILLI + 1)
-    left[np.arange(8) >= cnt[..., None]] = 0
+    left[:, np.arange(8) >= cnt[:, None]] = 0
     state = NodeState(
-        cpu_left=i32(lanes, nodes), cpu_cap=i32(lanes, nodes),
-        mem_left=i32(lanes, nodes), mem_cap=i32(lanes, nodes),
-        gpu_left=left, gpu_cnt=cnt, gpu_type=i32(lanes, nodes),
-        cpu_type=i32(lanes, nodes), aff_cnt=i32(lanes, nodes, 9))
+        cpu_left=i32(lanes, nodes), cpu_cap=i32(nodes),
+        mem_left=i32(lanes, nodes), mem_cap=i32(nodes),
+        gpu_left=left, gpu_cnt=cnt, gpu_type=i32(nodes),
+        cpu_type=i32(nodes), aff_cnt=i32(lanes, nodes, 9))
     out = ReplayResult(
         state=state,
         placed_node=i32(lanes, pods, low=-1, high=nodes),
@@ -360,7 +414,10 @@ def test_the_slice_makes_no_lane_by_node_temporary_and_no_copy_a_lane():
                  ln.event_node, ln.event_dev]
         for view, whole in zip(views, fetched):
             assert np.shares_memory(view, whole)
-            np.testing.assert_array_equal(view, whole[i][:len(view)])
+            if view.shape == whole.shape:  # a capacity leaf: no lane axis
+                assert view is whole
+            else:
+                np.testing.assert_array_equal(view, whole[i][:len(view)])
         # against the loop it replaced, a lane at a time with the mask
         slot = np.arange(8) < ln.state.gpu_cnt[:, None]
         used = int(np.where(slot, MILLI - ln.state.gpu_left, 0).sum())

@@ -224,7 +224,19 @@ def test_the_derived_fields_account_for_the_call(one_sweep):
     want = sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
                for shape, dtype in fetched)
     assert rec.fetch_bytes == want > 0
-    assert fetch.meta == {"events": 3 * rec.events, "bytes": want}
+    # of them, what the lanes share: the five capacity leaves came to the
+    # fetch ONCE, [N] (ISSUE 43), 20 bytes a node; with the lane axis on
+    # them, as every other leaf has it, the buffer were (B - 1) x 20 x N
+    # bytes larger (tests/test_sweep_shared.py builds that program)
+    n, b = len(sim.nodes), rec.lanes
+    assert n != b and all(shape[0] == b for shape, _ in fetched
+                          if shape != (n,))
+    shared = [dtype for shape, dtype in fetched if shape == (n,)]
+    assert shared == [np.dtype(np.int32)] * 5
+    a_lane = {shape[1:] for shape, _ in fetched if shape != (n,)}
+    assert {(n,), (n, 8), (n, 9)} <= a_lane  # cpu_left, gpu_left, aff_cnt
+    assert fetch.meta == {"events": 3 * rec.events, "bytes": want,
+                          "shared_bytes": 20 * n}
     d = rec.to_dict()
     assert d["fetch_bytes"] == want
     for name, value in zip(DERIVED_FIELDS, values):

@@ -45,7 +45,7 @@ from tpusim.sim.typical import (
     pad_typical_pods,
 )
 from tpusim.sim.workload import sort_cluster_pods, tune_pods
-from tpusim.types import NodeState, TypicalPods
+from tpusim.types import CAPACITY_LEAVES, NodeState, TypicalPods
 
 
 @dataclass
@@ -3416,8 +3416,10 @@ class SweepLane:
     (tests/test_sweep.py pins this per engine). Out of a sweep every
     array here, the leaves of `state` and `metrics` too, is a VIEW: of the
     one fetched buffer, or of an array the sweep made once for all its
-    lanes (`weights`, `counters`, the bool leaves' casts); copy before
-    writing what another lane must not see (_slice_sweep_lanes)."""
+    lanes (`weights`, `counters`, the bool leaves' casts); the five
+    capacity leaves of `state` (types.CAPACITY_LEAVES) are the SAME
+    read-only view in every lane. Copy before writing what another lane
+    must not see (_slice_sweep_lanes)."""
 
     weights: np.ndarray  # i32[num_pol] this lane's weight vector
     seed: int
@@ -3457,6 +3459,14 @@ class SweepLane:
     event_dev: Optional[np.ndarray] = None  # bool[E, 8]
 
 
+# The lane axis of a sweep's final states, a leaf: 0 on the four leaves a
+# step can write, None on the capacity leaves, which leave the program as
+# they entered it, [N] (_sweep_engine). The post-pass's in_axes and the
+# slicing read it; device_fetch packs the shapes it is given.
+_LANE_STATE_AXES = NodeState(*(
+    None if f in CAPACITY_LEAVES else 0 for f in NodeState._fields))
+
+
 def _lane_axis(operand, rank: int):
     """The vmap axis of a sweep operand, read off the operand itself: 0
     when it was stacked a lane (one axis more than the engine takes), None
@@ -3488,8 +3498,25 @@ def _sweep_engine(engine, args, keep_streams: bool = False):
     operands: the per-lane FaultOps (whose gpu-count row is the
     cluster's) and the initial fault carry, which broadcasts.
 
+    What leaves WITHOUT a lane axis, by one rule for every sweep: the
+    final states' five capacity leaves (types.CAPACITY_LEAVES: cpu_cap,
+    mem_cap, gpu_cnt, gpu_type, cpu_type). No step of any body writes
+    them, fault steps included, so after the vmap the wrapper puts the
+    start state's own [N] leaves in the output's place and XLA drops
+    whatever gave them a lane axis: the vmap's broadcast today (out_axes
+    None on the five traces on every body tier-1 runs: none batches them),
+    and a select over a whole state under a lane's predicate if a body
+    ever makes one, which out_axes None would refuse although it writes
+    nothing. The lanes' states keep the lane
+    axis on cpu_left, mem_left, gpu_left and aff_cnt (_LANE_STATE_AXES);
+    B copies of the cluster's capacities, 20 bytes a node a lane, are
+    neither made on the device nor packed nor copied to the host.
+    tests/test_sweep_shared.py holds "nothing writes them" by value on
+    every body, and the compiled outputs to the shapes.
+
     Donation, by one rule: the stacked tie-break rank always (the [B, N]
-    buffer matches output state leaves, so a repeated-wave caller — the
+    buffer matches the output state's cpu_left and mem_left, which keep
+    the lane axis, so a repeated-wave caller — the
     svc worker's batch loop, a tuning run's generations — reuses it
     instead of reallocating per wave; keys and weights are byte-tiny and
     alias nothing), and a per-lane event stream (its [B, E] i32 buffer
@@ -3554,14 +3581,25 @@ def _sweep_engine(engine, args, keep_streams: bool = False):
                         operands[i])
             group = ({"group": flat_group_events(*operands[rank_i].shape)}
                      if grouped else {})
-            return jax.vmap(
+            out = jax.vmap(
                 functools.partial(engine, **group), in_axes=in_axes,
             )(*operands)
+            return _share_capacity(out, operands[0])
 
         # the program keeps the engine's name (traces, compile cache)
         swept.__name__ = getattr(engine, "__name__", swept.__name__)
         _SWEEP_WRAP_CACHE[ck] = jax.jit(swept, donate_argnums=donate)
     return _SWEEP_WRAP_CACHE[ck]
+
+
+def _share_capacity(out, state):
+    """A vmapped replay's result with the capacity leaves of its final
+    states as they entered, `state`'s own [N]: no step writes them, so
+    every lane's are the start state's, and nothing downstream of the
+    program (the post-pass, the pack, the copy, the slicing) handles B
+    copies of them."""
+    return out._replace(state=out.state._replace(**{
+        f: getattr(state, f) for f in CAPACITY_LEAVES}))
 
 
 def _rows_a_lane(lane_set, sets):
@@ -3724,20 +3762,24 @@ def _slice_sweep_lanes(out, amounts, watts, w, seeds, pods_n, events_n,
     `pad_skips[i]` bucket-padding skips. The summary math is ONE pass an
     array over the lane axis, the loop after it builds views and objects
     only: every array a lane holds is a view of the fetched buffer or of a
-    per-sweep summary array. Each field equals lane_from_arrays' on that
-    lane's own arrays (tests/test_sweep_slice.py)."""
+    per-sweep summary array; the five capacity leaves of `out.state` come
+    without a lane axis (_LANE_STATE_AXES) and are ONE view shared by all
+    the lanes' states, read-only as the fetched buffer is. Each field
+    equals lane_from_arrays' on that lane's own arrays
+    (tests/test_sweep_slice.py)."""
     from tpusim.ops.frag import frag_sum_except_q3
 
     st = out.state
-    b = st.gpu_cnt.shape[0]
+    b = st.gpu_left.shape[0]
     # the allocation ratio without a slot mask: gpu_left is 0 beyond
     # gpu_cnt devices (types.NodeState), so the used milli of the real
     # slots is MILLI a device less all that is left. No [B, N, 8]
     # temporary: three of them leave the cache a lane's own stayed in, and
-    # the batched masked form read slower than the loop (PERF.md, PR 39)
-    cnt = st.gpu_cnt.sum(1, dtype=np.int64)
+    # the batched masked form read slower than the loop (PERF.md, PR 39).
+    # The devices are the cluster's, one sum for all the lanes
+    cnt = int(st.gpu_cnt.sum(dtype=np.int64))
     used = MILLI * cnt - st.gpu_left.reshape(b, -1).sum(1, dtype=np.int64)
-    alloc = (100.0 * used.astype(np.float64)) / np.maximum(cnt * MILLI, 1)
+    alloc = (100.0 * used.astype(np.float64)) / max(cnt * MILLI, 1)
     # a lane's pods are the first pods_n[i] of the padded pod axis
     live = np.arange(out.placed_node.shape[1]) < np.asarray(pods_n)[:, None]
     on_node = (out.placed_node >= 0) & live
@@ -3766,7 +3808,9 @@ def _slice_sweep_lanes(out, amounts, watts, w, seeds, pods_n, events_n,
             ever_failed=out.ever_failed[i, :p],
             counters=None if ctr is None else ctr[i],
             metrics=metrics_i,
-            state=NodeState(*(leaf[i] for leaf in st)),
+            state=NodeState(*(
+                leaf if ax is None else leaf[i]
+                for leaf, ax in zip(st, _LANE_STATE_AXES))),
             events=e,
             placed=placed[i],
             failed=failed[i],
@@ -4348,8 +4392,8 @@ def _sweep_replay(sim, table: bool, fault_frag: Optional[bool]):
     return sim._table_fn if table else sim.replay_fn
 
 
-def _slice_fault_lanes(out, amounts, watts, w, seeds, pods_n, plans, e_m,
-                       gcnt) -> List[SweepLane]:
+def _slice_fault_lanes(out, amounts, watts, w, seeds, pods_n, plans,
+                       e_m) -> List[SweepLane]:
     """The lanes of a fetched sweep with fault plans, whose merged streams
     were padded to `e_m` steps: each SweepLane with the DisruptionMetrics
     of its schedule, bit-identical to the standalone run_with_faults run
@@ -4363,7 +4407,7 @@ def _slice_fault_lanes(out, amounts, watts, w, seeds, pods_n, plans, e_m,
             plan,
             jax.tree.map(lambda a: a[i], out.fault_ys),
             jax.tree.map(lambda a: a[i], out.fault_carry),
-            gcnt,
+            out.state.gpu_cnt,  # the cluster's, as fetched: [N]
         )
         for i, plan in enumerate(plans)
     ]
@@ -4608,7 +4652,7 @@ def schedule_pods_sweep(
             # object in every call, so dispatch here is a trace, a lowering
             # and a compile or a cache load.
             amounts, watts = jax.jit(
-                jax.vmap(_lane_postpass, in_axes=(0, tp_ax))
+                jax.vmap(_lane_postpass, in_axes=(_LANE_STATE_AXES, tp_ax))
             )(out.state, typical)
             obs.settle(h, amounts, watts)
         if report:
@@ -4626,6 +4670,9 @@ def schedule_pods_sweep(
         with obs.span("fetch", events=true_events) as h:
             out, amounts, watts = device_fetch((out, amounts, watts), marks=h)
             sweep.fetch_bytes = h.meta.get("bytes", 0)
+            # of them, what the lanes share: the capacity leaves, once
+            h.note(shared_bytes=sum(
+                getattr(out.state, f).nbytes for f in CAPACITY_LEAVES))
             if out.metrics is not None:
                 sweep.series_bytes = sum(a.nbytes for a in out.metrics)
                 h.note(series_bytes=sweep.series_bytes)
@@ -4634,9 +4681,7 @@ def schedule_pods_sweep(
             pods_n = [tr.pods[t] for t in trace_of]
             if faulted:
                 lanes = _slice_fault_lanes(
-                    out, amounts, watts, w, seeds, pods_n, plans, steps,
-                    np.asarray(state.gpu_cnt),
-                )
+                    out, amounts, watts, w, seeds, pods_n, plans, steps)
             else:
                 lanes = _slice_sweep_lanes(
                     out, amounts, watts, w, seeds, pods_n, lane_events,

@@ -1,10 +1,16 @@
 """Single-transfer device→host fetch.
 
 Every device→host readback pays a fixed latency regardless of payload
-size, and a replay output is ~20 small leaves. device_fetch() packs every
+size, and a single replay's output is ~20 small leaves; a sweep's is the
+same leaves a lane, 0.06-0.35 GB at the benchmark's widths (0.27 GB for
+2,560 lanes of 1,213 nodes, 0.31 GB for 40 lanes of 100,000), where the
+cost is the bytes (PERF.md section 7). device_fetch() packs every
 device leaf of a pytree into ONE uint8 buffer on device (bitcast, so
 f32/i32 bits survive exactly) and reads it back in a single transfer, then
-reslices host-side. A caller's span handle (obs.Recorder.span) gets two
+reslices host-side. It packs the shapes it is given and knows nothing of
+lanes: what a sweep's lanes share reaches it once, without a lane axis
+(driver._sweep_engine: the five capacity leaves of the final states), and
+leaves as one read-only view. A caller's span handle (obs.Recorder.span) gets two
 marks, `ready` (the device has finished the pack and whatever it still
 owed before it) and `copied` (the bytes are on the host; the rest of the
 span is the reslicing and the bool leaves' casts), and the buffer's size as

@@ -56,6 +56,15 @@ class NodeState(NamedTuple):
         return (self.gpu_left == MILLI).sum(axis=-1)
 
 
+# The leaves of a NodeState that NO step of any engine writes, fault steps
+# included (a bind, a release, an eviction, a node's loss and return move
+# the *_left leaves and aff_cnt alone): the cluster's own capacities and
+# models, 20 of a node's 96 bytes. A sweep's lanes share them, so its program
+# gives them no lane axis (driver._sweep_engine; tests/test_sweep_shared.py
+# holds every body to it by value).
+CAPACITY_LEAVES = ("cpu_cap", "mem_cap", "gpu_cnt", "gpu_type", "cpu_type")
+
+
 def make_node_state(
     cpu_cap,
     mem_cap,
