@@ -20,8 +20,8 @@ after one compile run; raw samples ship alongside (wall_samples_s).
 
 `--all` additionally measures every sweep policy (the 6 reference-cached
 methods + PWR), pinning the sequential path's throughput (RandomScore /
-gpu_sel=random cannot use the table engine) and the 16-seed batched
-aggregate, writing the rows to BENCH_DETAILS.json (stderr shows them too).
+gpu_sel=random cannot use the table engine), writing the rows to
+BENCH_DETAILS.json (stderr shows them too).
 """
 
 import argparse
@@ -172,77 +172,11 @@ def measure_policy(nodes, pods, name, policies, gpu_sel, dim_ext, norm,
     return row
 
 
-def measure_batched(nodes, pods, seeds=16, report=False):
-    """Aggregate throughput of the seed-batched vmapped replay (FGD config;
-    see ENGINES.md) — the sweep's execution mode. report=True measures the
-    full-report configuration (replay + the vectorized metrics post-pass),
-    i.e. the device phase of the artifact protocol's seed groups."""
-    import jax
-    import numpy as np
-
-    from tpusim.sim.driver import (
-        Simulator,
-        SimulatorConfig,
-        schedule_pods_batch,
-    )
-    from tpusim.sim.typical import TypicalPodsConfig
-
-    def mk(seed):
-        cfg = SimulatorConfig(
-            policies=(("FGDScore", 1000),),
-            gpu_sel_method="FGDScore",
-            tuning_ratio=1.3,
-            tuning_seed=seed,
-            seed=seed,
-            shuffle_pod=True,
-            report_per_event=report,
-            typical_pods=TypicalPodsConfig(pod_popularity_threshold=95),
-        )
-        sim = Simulator(nodes, cfg)
-        sim.set_workload_pods(pods)
-        return sim
-
-    sims = [mk(42 + s) for s in range(seeds)]
-    pods_lists = [s.prepare_pods() for s in sims]
-    box = {}
-    dev_walls = []
-
-    def run():
-        box["results"] = schedule_pods_batch(sims, pods_lists)
-        dev_walls.append(sims[0]._last_batch_device_s)
-
-    # same shared cold + stable-minimum protocol as measure_policy; the
-    # warm samples here are the DEVICE phase (dispatch + fetch) — the
-    # like-for-like number against a single run_events call
-    m = obs_bench.measure(run, WARM_RUNS)
-    results = box["results"]
-    warm_dev = dev_walls[1:]  # drop the compile run's sample
-    device_wall = min(warm_dev)
-    placements = sum(
-        r.events - len(r.unscheduled_pods) for r in results
-    )
-    return obs_bench.round_row({
-        "policy": "FGD",
-        "engine": f"table, {seeds}-seed vmap batch"
-        + (" + report post-pass" if report else ""),
-        "events": sum(r.events for r in results),
-        "placements": placements,
-        "wall_s": device_wall,
-        "wall_samples_s": warm_dev,
-        "wall_incl_host_prep_s": m["min_s"],
-        "placements_per_sec": round(placements / device_wall, 1),
-        "gpu_alloc_pct": round(
-            float(np.mean([gpu_alloc_pct(r.state) for r in results])), 2
-        ),
-        **obs_bench.device_stamp(),
-    })
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument(
         "--all", action="store_true",
-        help="per-policy + batched rows -> BENCH_DETAILS.json",
+        help="per-policy rows -> BENCH_DETAILS.json",
     )
     args = ap.parse_args()
     from tpusim.compile_cache import enable_compile_cache
@@ -274,10 +208,6 @@ def main():
             )
             rows.append(row)
             print(f"[bench-all] {json.dumps(row)}", file=sys.stderr)
-        rows.append(measure_batched(nodes, pods))
-        print(f"[bench-all] {json.dumps(rows[-1])}", file=sys.stderr)
-        rows.append(measure_batched(nodes, pods, report=True))
-        print(f"[bench-all] {json.dumps(rows[-1])}", file=sys.stderr)
         obs_bench.write_json(
             os.path.join(REPO, "BENCH_DETAILS.json"),
             {

@@ -315,56 +315,26 @@ def run_experiment(args) -> dict:
     return _post_run(sim, args, outdir, pod_csv, policies, t0)
 
 
-def dispatch_experiment_batch(args_list) -> dict:
-    """Host prep + async device dispatch of a seed group (same trace/
-    policy/knobs, different seeds → ONE vmapped replay). The device work
-    runs while the caller processes other groups' host tails — the sweep
-    pipelines finish_experiment_batch(group i) under group i+1's replay."""
-    from tpusim.sim.driver import dispatch_run_batch
+def run_experiment_batch(args_list) -> list:
+    """Run a seed group (same trace/policy/knobs, different seeds) as ONE
+    vmapped replay, the sweep's (driver.run_batch). Produces per-experiment
+    outputs identical to run_experiment: the group only changes how the
+    main schedules execute on the chip."""
+    from tpusim.sim.driver import run_batch
 
     t0 = time.perf_counter()
     built = [_build_sim(a) for a in args_list]
-    handle = dispatch_run_batch([b[0] for b in built])
-    return {
-        "args_list": args_list,
-        "built": built,
-        "handle": handle,
-        # dispatch-phase host wall: the pipelined sweep interleaves other
-        # groups' work before finish, so per-experiment wall attribution
-        # sums the two phases instead of spanning them
-        "prep_s": time.perf_counter() - t0,
-    }
-
-
-def finish_experiment_batch(st: dict) -> list:
-    """Block on a dispatch_experiment_batch handle and write every
-    per-experiment output (simon.log + analysis CSVs)."""
-    from tpusim.sim.driver import finish_run_batch
-
-    t_fin = time.perf_counter()
-    finish_run_batch(st["handle"])
-    batch_s = st["prep_s"] + (time.perf_counter() - t_fin)
-    shared = batch_s / len(st["built"])
-    results = []
-    for args, (sim, outdir, pod_csv, policies) in zip(
-        st["args_list"], st["built"]
-    ):
-        # report each experiment's fair share of the batched phase plus its
-        # own post-run stages, not the whole batch's elapsed time
-        results.append(
-            _post_run(
-                sim, args, outdir, pod_csv, policies,
-                time.perf_counter() - shared,
-            )
+    run_batch([b[0] for b in built])
+    # each experiment reports its fair share of the group's phase plus its
+    # own post-run stages, not the whole group's elapsed time
+    shared = (time.perf_counter() - t0) / len(built)
+    return [
+        _post_run(
+            sim, args, outdir, pod_csv, policies,
+            time.perf_counter() - shared,
         )
-    return results
-
-
-def run_experiment_batch(args_list) -> list:
-    """Run a seed group through ONE vmapped device replay. Produces
-    per-experiment outputs identical to run_experiment — the batch only
-    changes how the main schedules execute on the chip."""
-    return finish_experiment_batch(dispatch_experiment_batch(args_list))
+        for args, (sim, outdir, pod_csv, policies) in zip(args_list, built)
+    ]
 
 
 if __name__ == "__main__":

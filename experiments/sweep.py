@@ -56,30 +56,6 @@ def main(argv=None):
     total = len(groups) * len(seeds)
     t_all = time.perf_counter()
     done = 0
-    # pipelined groups: group i's host tails (bellman, log/CSV writes) run
-    # while groups i+1/i+2's vmapped replays execute on the chip.
-    # Two groups of lookahead cover the case where one group's device
-    # phase outlasts the next group's host build, so the eventual fetch
-    # never blocks. Each entry: {"trace","mid","pending","st","t0"}
-    from collections import deque
-
-    LOOKAHEAD = 2
-    inflight = deque()
-
-    def flush(entry):
-        nonlocal done
-        runner.finish_experiment_batch(entry["st"])
-        for _, argv_exp, marker in entry["pending"]:
-            marker.write_text(" ".join(argv_exp))
-        done += len(entry["pending"])
-        print(
-            f"[sweep {done}/{total}] {entry['trace']} {entry['mid']} "
-            f"seeds={[s for s, _, _ in entry['pending']]} "
-            f"{time.perf_counter() - entry['t0']:.1f}s "
-            f"(total {time.perf_counter() - t_all:.0f}s)",
-            flush=True,
-        )
-
     for trace, (mid, flags, gpusel, dimext, norm) in groups:
         # one group = the same experiment across seeds; uncached seeds run
         # as ONE vmapped device replay (driver.run_batch) unless --no-batch
@@ -110,33 +86,25 @@ def main(argv=None):
             continue
         t0 = time.perf_counter()
         if len(pending) > 1 and not args.no_batch:
-            st = runner.dispatch_experiment_batch(
+            runner.run_experiment_batch(
                 [runner.get_args(a) for _, a, _ in pending]
             )
-            inflight.append({
-                "trace": trace, "mid": mid, "pending": pending,
-                "st": st, "t0": t0,
-            })
-            while len(inflight) > LOOKAHEAD:
-                flush(inflight.popleft())
+            for _, argv_exp, marker in pending:
+                marker.write_text(" ".join(argv_exp))
         else:
-            while inflight:
-                flush(inflight.popleft())
             # per-seed markers: a failure on a late seed must not discard
             # earlier seeds' completion records
             for _, argv_exp, marker in pending:
                 runner.run_experiment(runner.get_args(argv_exp))
                 marker.write_text(" ".join(argv_exp))
-            done += len(pending)
-            print(
-                f"[sweep {done}/{total}] {trace} {mid} "
-                f"seeds={[s for s, _, _ in pending]} "
-                f"{time.perf_counter() - t0:.1f}s "
-                f"(total {time.perf_counter() - t_all:.0f}s)",
-                flush=True,
-            )
-    while inflight:
-        flush(inflight.popleft())
+        done += len(pending)
+        print(
+            f"[sweep {done}/{total}] {trace} {mid} "
+            f"seeds={[s for s, _, _ in pending]} "
+            f"{time.perf_counter() - t0:.1f}s "
+            f"(total {time.perf_counter() - t_all:.0f}s)",
+            flush=True,
+        )
     print(f"[sweep] {total} experiments in {time.perf_counter() - t_all:.0f}s")
 
 

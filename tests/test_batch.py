@@ -1,5 +1,6 @@
-"""Seed-batched execution (driver.schedule_pods_batch / run_batch) must give
-each seed exactly what a standalone run gives: same placements, device
+"""A seed group (driver.run_batch: per-sim prep and reporting around ONE
+schedule_pods_sweep, a trace and a seed a lane) must give each seed exactly
+what a standalone run gives: same placements, device
 masks, final state, unscheduled lists, and reference-format log content
 (metric float rows may differ in last-ulp reduce order, which the log's
 fixed-precision formatting absorbs)."""
@@ -132,3 +133,54 @@ def test_batch_no_report_mode():
     results = run_batch(sims)
     for single, res in zip(singles, results):
         assert np.array_equal(single.placed_node, res.placed_node)
+
+
+def _group(seeds, pods_n=30):
+    rng = np.random.default_rng(13)
+    nodes = _mk_cluster(rng)
+    pods = _mk_pods(rng, pods_n)
+    sims = []
+    for s in seeds:
+        sim = Simulator(nodes, _cfg(s, report=False))
+        sim.set_workload_pods(pods)
+        sims.append(sim)
+    return sims
+
+
+def test_a_seed_group_is_one_sweep():
+    """The group runs on the sweep every cell measures: one call, so one
+    SweepRecord, a lane and a trace a member; and what a member keeps is
+    its own, not a view of the sweep's buffer or of a leaf the lanes
+    share."""
+    from tpusim.obs import sweep_log
+
+    sims = _group([42, 43, 44])
+    seen = {r.id for r in sweep_log()}
+    results = run_batch(sims)
+    new = [r for r in sweep_log() if r.id not in seen]
+    assert len(new) == 1
+    assert new[0].lanes == 3 and new[0].traces == 3
+    # the sweep's own line stays out of every member's log, the lead's too
+    for sim in sims:
+        engine_lines = [l for l in sim.log.lines if "[Engine]" in l]
+        assert len(engine_lines) == 1 and "replay of" in engine_lines[0]
+    leaves = [
+        a for r in results for a in (*r.state, r.placed_node, r.dev_mask)
+    ]
+    assert all(a.flags.owndata and a.flags.writeable for a in leaves)
+    assert len({id(a) for a in leaves}) == len(leaves)
+
+
+def test_batch_refuses_a_member_that_records():
+    """ANY member that records decisions or the in-scan series is refused,
+    not the lead alone: the sweep replays on the lead's engine and would
+    hand the member None for its stream."""
+    import dataclasses
+
+    for knob in ({"record_decisions": True}, {"series_every": 4}):
+        sims = _group([42, 43])
+        sims[1].cfg = dataclasses.replace(sims[1].cfg, **knob)
+        logged = list(sims[0].log.lines)
+        with pytest.raises(ValueError, match="decisions|series"):
+            run_batch(sims)
+        assert sims[0].log.lines == logged  # refused before any prep

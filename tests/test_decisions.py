@@ -616,7 +616,7 @@ def test_engine_guards():
             record_decisions=True,
             extenders=(ExtenderConfig(url_prefix="http://x"),),
         ))
-    from tpusim.sim.driver import dispatch_pods_batch
+    from tpusim.sim.driver import run_batch
 
     sim = Simulator(nodes, SimulatorConfig(
         policies=(("FGDScore", 1000),), gpu_sel_method="FGDScore",
@@ -624,4 +624,4 @@ def test_engine_guards():
     ))
     sim.set_workload_pods(pods)
     with pytest.raises(ValueError, match="record decisions"):
-        dispatch_pods_batch([sim], [pods])
+        run_batch([sim])

@@ -230,6 +230,40 @@ def test_analysis_lanes_byte_identical(tmp_path):
         assert a == b, f"{name} differs between analysis lanes"
 
 
+def test_seed_group_writes_what_single_runs_write(tmp_path):
+    """The artifact's batched route (experiments/sweep.py's unit:
+    run_experiment_batch, the seeds of one trace and method as ONE sweep)
+    writes a simon.log and analysis CSVs byte-identical to one
+    run_experiment a seed, on a shuffled trace tuned past what the cluster
+    holds (rejected creates in every seed)."""
+    run = _load("exp_run_group", EXP / "run.py")
+    node_csv, pod_csv = _write_tiny_trace(tmp_path)
+
+    def argv(root, seed):
+        return run.get_args(
+            ["-d", str(tmp_path / root / str(seed)), "-f", str(pod_csv),
+             "--node-trace", str(node_csv), "-FGD", "1000", "-gpusel",
+             "FGDScore", "-tune", "1.3", "-tuneseed", str(seed),
+             "--shuffle-pod", "true"]
+        )
+
+    seeds = (42, 43)
+    for seed in seeds:
+        run.run_experiment(argv("single", seed))
+    results = run.run_experiment_batch([argv("group", s) for s in seeds])
+    assert len(results) == 2
+    assert all(r["summary"]["unscheduled"] > 0 for r in results)
+    for seed in seeds:
+        single = tmp_path / "single" / str(seed)
+        group = tmp_path / "group" / str(seed)
+        files = sorted(p.name for p in single.iterdir())
+        assert files == sorted(p.name for p in group.iterdir())
+        assert "simon.log" in files and "analysis_frag.csv" in files
+        for name in files:
+            assert (single / name).read_bytes() == (
+                group / name).read_bytes(), f"{name} differs for seed {seed}"
+
+
 def test_reused_simulator_lanes_stay_identical(tmp_path):
     """Calling run() twice on one Simulator must not double-count the
     direct-CSV stashes vs the log lane (ADVICE r4): both lanes reflect the

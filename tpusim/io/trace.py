@@ -173,7 +173,7 @@ def pods_to_specs(
     pinned to index len(node_index) which no arange(num_nodes) entry matches
     (-1 is reserved for "unconstrained"). device=False keeps the arrays on
     host (numpy) — callers that pad/stack several spec sets before one
-    upload (driver.schedule_pods_batch) avoid per-leaf round-trips."""
+    upload (driver._sweep_traces) avoid per-leaf round-trips."""
     import jax.numpy as jnp
 
     def pin(p: PodRow) -> int:

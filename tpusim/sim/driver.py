@@ -75,9 +75,9 @@ class SimulatorConfig:
     # workloads (zero distinct pod types / fewer events than types) always
     # run the sequential path — the table init would cost more than it
     # saves; a forced table/pallas engine still applies whenever at least
-    # one pod type exists. The seed-batched sweep (schedule_pods_batch)
-    # honors `sequential`; `pallas` has no batched form and batches run
-    # the (bit-identical) table engine instead.
+    # one pod type exists. A sweep (schedule_pods_sweep; a seed group,
+    # run_batch, is one) honors `sequential`; `pallas` has no batched form
+    # and sweeps run the (bit-identical) table engine instead.
     engine: str = "auto"
     # table-engine select layout (tpusim.sim.table_engine.resolve_block_size):
     # 0 = auto (blocked incremental reductions over ~sqrt(N/K)-node blocks
@@ -174,7 +174,7 @@ class SimulatorConfig:
     # shard engines, and transparent to checkpoint kill/resume and fault
     # segmentation. Unsupported by the fused Pallas kernel (auto falls
     # back to the table engine; a forced engine: pallas raises) and by
-    # extender configs / the seed-batched sweep path.
+    # extender configs / the sweep path.
     record_decisions: bool = False
     # In-scan cluster time-series plane (ISSUE 5; tpusim.obs.series):
     # > 0 makes every replay emit one bounded-shape SeriesSample each
@@ -190,7 +190,7 @@ class SimulatorConfig:
     # bakes into the jaxpr): 0 = off, scan bodies compile identical to
     # pre-series builds. Unsupported by the fused Pallas kernel (auto
     # falls back to the table engine; a forced engine: pallas raises)
-    # and by extender configs / the seed-batched sweep path.
+    # and by extender configs / the sweep path.
     series_every: int = 0
     # Fault-replay execution mode (ISSUE 10): "auto" runs fault
     # schedules INSIDE the compiled scan (tpusim.sim.fault_lane — fault
@@ -433,10 +433,6 @@ class Simulator:
             decisions=self.cfg.record_decisions,
             series_every=self.cfg.series_every,
         )
-        # device-phase wall of the last schedule_pods_batch call this sim
-        # led (dispatch + fetch, excluding host spec prep/result slicing);
-        # read by bench.py's batched row for like-for-like throughput
-        self._last_batch_device_s = None
         # which engine the last run_events call dispatched to
         # (pallas | table | sequential) — bench/log labeling
         self._last_engine = None
@@ -1485,10 +1481,10 @@ class Simulator:
     def adopt_typical_pods(self, other: "Simulator"):
         """set_typical_pods, copying the (immutable) distribution from a
         same-workload sibling instead of recomputing + re-uploading it —
-        the seed-batched sweep path, where all S sims share the workload
-        the distribution derives from (schedule_pods_batch validates
-        that). Emits the same log lines; the Bellman evaluator stays
-        per-experiment (its memo embeds evaluation-order context)."""
+        a seed group, where all S sims share the workload the distribution
+        derives from (run_batch validates that). Emits the same log lines;
+        the Bellman evaluator stays per-experiment (its memo embeds
+        evaluation-order context)."""
         self.typical = other.typical
         self._typical_info = other._typical_info
         self._typical_host = other._typical_host
@@ -2929,19 +2925,19 @@ class Simulator:
         }
         return requested, allocatable
 
-    def cluster_analysis(self, tag: str = "InitSchedule", _amounts=None):
+    def cluster_analysis(self, tag: str = "InitSchedule", amounts=None):
         """The end-of-stage 16-line analysis block (analysis.go:145-199).
 
-        `_amounts` lets run_batch supply precomputed cluster frag amounts
-        (one vmapped device call + one fetch for the whole seed group,
-        instead of a device round trip per sim)."""
+        `amounts`: the state's seven frag amounts where the caller holds
+        them already (a sweep's post-pass fetched every lane's with the
+        lanes, SweepLane.frag_amounts); computed here otherwise."""
         from tpusim.ops.frag import cluster_frag_report
 
         state = (
             self.last_result.state if hasattr(self, "last_result") else self.init_state
         )
-        if _amounts is not None:
-            amounts = np.asarray(_amounts)
+        if amounts is not None:
+            amounts = np.asarray(amounts)
         else:
             state_j = jax.tree.map(jnp.asarray, state)
             amounts = np.asarray(cluster_frag_report(state_j, self.typical)[0])
@@ -2956,7 +2952,7 @@ class Simulator:
 
 
 # ---------------------------------------------------------------------------
-# Shared replay-shape plumbing (single path + seed-batched path)
+# Shared replay-shape plumbing (single runs + sweeps)
 # ---------------------------------------------------------------------------
 
 
@@ -2971,9 +2967,8 @@ def _bucket_sizes(p: int, e: int, bucket: int) -> Tuple[int, int]:
 def _pad_specs(specs, p2: int, type_id=None, xp=jnp):
     """Pad pod specs (and their type ids) to p2 rows with inert zero pods
     (pinned -1, never referenced by any event). xp=jnp pads on device
-    (single runs); xp=np keeps host arrays (the batched path stacks several
-    padded sets before ONE upload instead of a device round trip per
-    leaf)."""
+    (single runs); xp=np keeps host arrays (a sweep stacks several padded
+    sets before ONE upload instead of a device round trip per leaf)."""
     from tpusim.types import PodSpec
 
     p = int(specs.cpu.shape[0])
@@ -3037,372 +3032,28 @@ def _slice_result(out, p: int, e: int):
 
 
 # ---------------------------------------------------------------------------
-# Seed-batched execution (TPU-native sweep acceleration)
-# ---------------------------------------------------------------------------
-#
-# The reference parallelizes its 1020-experiment sweep across processes on a
-# 256-vCPU machine (experiments/README.md step 2, xargs --max-procs). The
-# TPU-native equivalent is batching the replays themselves: the per-event
-# scan is kernel-launch-bound on one chip (~40 small fused kernels per
-# event, see ENGINES.md), so running S same-shape experiments under one
-# jax.vmap amortizes every launch S-fold. Measured on the openb FGD replay:
-# ~4x aggregate throughput at S=16, per-seed placements bit-identical to
-# single runs (metric float rows agree to ~1e-5 relative — vmapped
-# reductions may order f32 partial sums differently).
-
-_BATCH_WRAP_CACHE = {}
-_BATCHED_METRICS_FN = None
-
-
-def _batched_metrics_fn():
-    """compute_event_metrics vmapped over the seed axis (shared cluster +
-    typical pods, per-seed specs/events/telemetry)."""
-    global _BATCHED_METRICS_FN
-    if _BATCHED_METRICS_FN is None:
-        from tpusim.sim.metrics import compute_event_metrics
-        from tpusim.types import PodSpec
-
-        _BATCHED_METRICS_FN = jax.jit(
-            jax.vmap(
-                compute_event_metrics,
-                in_axes=(None, PodSpec(0, 0, 0, 0, 0, 0), 0, 0, 0, 0, None),
-            )
-        )
-    return _BATCHED_METRICS_FN
-
-
-def _batched_engine(fn, table: bool):
-    from tpusim.sim.table_engine import PodTypes
-    from tpusim.types import PodSpec
-
-    if fn not in _BATCH_WRAP_CACHE:
-        spec0 = PodSpec(0, 0, 0, 0, 0, 0)
-        none_spec = PodSpec(*(None,) * 6)
-        if table:
-            in_axes = (None, spec0, PodTypes(none_spec, none_spec, 0),
-                       0, 0, None, 0, 0)
-        else:
-            in_axes = (None, spec0, 0, 0, None, 0, 0)
-        _BATCH_WRAP_CACHE[fn] = jax.jit(jax.vmap(fn, in_axes=in_axes))
-    return _BATCH_WRAP_CACHE[fn]
-
-
-def schedule_pods_batch(
-    sims: Sequence["Simulator"], pods_list, bucket: int = 512
-) -> List[SimulateResult]:
-    """Run the main schedule of S same-config experiments (different seeds:
-    shuffle order, tuning, tie-break permutation) in ONE vmapped replay.
-
-    Every sim must share the full scheduling configuration and the node
-    cluster; pod counts may differ slightly (tuning variance) — all axes
-    are padded to common bucketed shapes, exactly like
-    Simulator.run_events does for a single run. Results are bit-identical
-    to per-sim schedule_pods calls (same engine kernels, vmapped)."""
-    return finish_pods_batch(dispatch_pods_batch(sims, pods_list, bucket))
-
-
-def dispatch_pods_batch(
-    sims: Sequence["Simulator"], pods_list, bucket: int = 512
-) -> dict:
-    """The host-prep + device-dispatch half of schedule_pods_batch. JAX
-    dispatch is asynchronous, so the returned handle's device work runs
-    while the caller does host work (the sweep pipelines group i's host
-    tails under group i+1's replay — the only concurrency available on a
-    1-vCPU host driving a remote chip). finish_pods_batch(handle) blocks
-    on the results and completes the per-sim bookkeeping."""
-    from tpusim.sim.table_engine import build_pod_types, pad_pod_types
-    from tpusim.types import PodSpec
-
-    lead = sims[0]
-    if lead.cfg.extenders:
-        raise ValueError(
-            "schedule_pods_batch cannot run extender configs (per-cycle "
-            "HTTP round-trips do not batch); run each sim's run() instead"
-        )
-    if lead.cfg.mesh:
-        raise ValueError(
-            "schedule_pods_batch cannot run mesh configs (the shard_map "
-            "engine owns the device axis); run each sim's run() instead"
-        )
-    if any(s.cfg.record_decisions for s in sims):
-        # ANY recording sim (not just the lead): the batch replays on the
-        # lead's engine, so a non-lead recorder would silently get
-        # decisions=None instead of its stream
-        raise ValueError(
-            "schedule_pods_batch cannot record decisions (the vmapped "
-            "replay has no per-seed provenance surface); run each sim's "
-            "run() instead"
-        )
-    if any(s.cfg.series_every for s in sims):
-        raise ValueError(
-            "schedule_pods_batch cannot emit the in-scan series (the "
-            "vmapped replay has no per-seed sampling surface); run each "
-            "sim's run() instead"
-        )
-    for s in sims[1:]:
-        same = (
-            s.cfg.policies == lead.cfg.policies
-            and s.cfg.gpu_sel_method == lead.cfg.gpu_sel_method
-            and s.cfg.dim_ext_method == lead.cfg.dim_ext_method
-            and s.cfg.norm_method == lead.cfg.norm_method
-            and s.cfg.report_per_event == lead.cfg.report_per_event
-            and s.cfg.use_timestamps == lead.cfg.use_timestamps
-            and s.cfg.engine == lead.cfg.engine
-            and s.cfg.block_size == lead.cfg.block_size
-            and s.cfg.typical_pods == lead.cfg.typical_pods
-            and s.nodes == lead.nodes
-            # the batched replay scores every seed against lead's typical
-            # pods (vmap in_axes None), which is only sound when the seeds
-            # share the workload the distribution derives from
-            and s.workload_pods == lead.workload_pods
-        )
-        if not same:
-            raise ValueError(
-                "schedule_pods_batch requires same-config sims (policies, "
-                "gpu/dim/norm methods, report flag, typical-pod knobs, the "
-                "node cluster, and the workload may not differ across the "
-                "batch)"
-            )
-    t0 = time.perf_counter()
-    specs_list, ev_list = [], []
-    for sim, pods in zip(sims, pods_list):
-        if sim.typical is None:
-            sim.set_typical_pods()
-        specs_list.append(pods_to_specs(pods, sim.node_index, device=False))
-        ev_list.append(build_events(pods, sim.cfg.use_timestamps))
-
-    p = max(int(s.cpu.shape[0]) for s in specs_list)
-    e = max(len(k) for k, _ in ev_list)
-    p2, e2 = _bucket_sizes(p, e, bucket)
-
-    # engine knob: `sequential` is honored; `pallas` has no batched form
-    # (vmap over the fused kernel is untested), so batches run the
-    # bit-identical table engine (SimulatorConfig.engine docstring)
-    use_table = lead.cfg.engine != "sequential"
-    tids = [None] * len(sims)
-    if use_table:
-        # one shared type table across the batch: dedup over the
-        # concatenated specs; each seed's type_id is its segment of the
-        # concat build
-        cat = PodSpec(
-            *(
-                np.concatenate([getattr(s, f) for s in specs_list])
-                for f in PodSpec._fields
-            )
-        )
-        types = build_pod_types(cat)
-        k = int(types.share.cpu.shape[0]) + int(types.whole.cpu.shape[0])
-        # auto: same amortization heuristic run_events applies, per seed
-        # (table init costs K node-sweeps; only worth it with enough
-        # events); a forced engine='table' is honored whenever any type
-        # exists, exactly like the single-run path — so the [Engine] log
-        # lines cannot diverge between batched and standalone execution
-        from tpusim.sim.table_engine import num_pod_types
-
-        if k == 0 or (
-            lead.cfg.engine != "table"
-            and any(
-                len(kinds) < 2 * num_pod_types(s)
-                for s, (kinds, _) in zip(specs_list, ev_list)
-            )
-        ):
-            use_table = False
-        else:
-            offs = np.cumsum([0] + [int(s.cpu.shape[0]) for s in specs_list])
-            tid_all = np.asarray(types.type_id)
-            tids = [
-                tid_all[offs[i] : offs[i + 1]] for i in range(len(sims))
-            ]
-
-    padded = [
-        _pad_specs(specs, p2, tid, xp=np)
-        for specs, tid in zip(specs_list, tids)
-    ]
-    padded_ev = [
-        _pad_events(
-            np.asarray(k, np.int32), np.asarray(pd, np.int32), e2, xp=np
-        )
-        for k, pd in ev_list
-    ]
-
-    specs_b = PodSpec(
-        *(
-            jnp.asarray(np.stack([getattr(sp, f) for sp, _ in padded]))
-            for f in PodSpec._fields
-        )
-    )
-    ev_kind_b = jnp.asarray(np.stack([k for k, _ in padded_ev]))
-    ev_pod_b = jnp.asarray(np.stack([pd for _, pd in padded_ev]))
-    keys = jnp.stack([jax.random.PRNGKey(s.cfg.seed) for s in sims])
-    ranks = jnp.stack([s.rank for s in sims])
-
-    if use_table:
-        types_b = types._replace(
-            type_id=jnp.asarray(np.stack([tid for _, tid in padded]))
-        )
-        # stabilize K across sweep groups like run_events does (the
-        # type_id remap works elementwise on the stacked [S, P] ids)
-        types_b = pad_pod_types(types_b)
-        fn = _batched_engine(lead._table_fn, table=True)
-        t_dev = time.perf_counter()
-        out = fn(
-            lead.init_state, specs_b, types_b, ev_kind_b, ev_pod_b,
-            lead.typical, keys, ranks,
-        )
-    else:
-        fn = _batched_engine(lead.replay_fn, table=False)
-        t_dev = time.perf_counter()
-        out = fn(
-            lead.init_state, specs_b, ev_kind_b, ev_pod_b,
-            lead.typical, keys, ranks,
-        )
-    if lead.cfg.report_per_event:
-        out = out._replace(
-            metrics=_batched_metrics_fn()(
-                lead.init_state, specs_b, ev_kind_b, ev_pod_b,
-                out.event_node, out.event_dev, lead.typical,
-            )
-        )
-    return {
-        "sims": sims, "pods_list": pods_list, "ev_list": ev_list,
-        "out": out, "use_table": use_table, "t0": t0, "t_dev": t_dev,
-        # dispatch-phase host wall: under the sweep's pipeline, unrelated
-        # groups' work runs between dispatch and finish, so wall clocks
-        # must sum the two phases rather than span them
-        "prep_s": time.perf_counter() - t0,
-    }
-
-
-def finish_pods_batch(handle: dict) -> List[SimulateResult]:
-    """Block on a dispatch_pods_batch handle and finish per-sim host work
-    (fetch, slicing, report emission, result recording)."""
-    sims = handle["sims"]
-    pods_list = handle["pods_list"]
-    ev_list = handle["ev_list"]
-    use_table = handle["use_table"]
-    lead = sims[0]
-    t_fin = time.perf_counter()
-    out = device_fetch(handle["out"])
-    # device-phase wall (replay dispatch + fetch), excluding the host-side
-    # spec padding and result slicing — the like-for-like number against a
-    # single run_events call (bench.py batched row). Only meaningful when
-    # dispatch and finish run back-to-back (schedule_pods_batch, the bench
-    # path); a pipelined caller interleaves other work in between
-    lead._last_batch_device_s = time.perf_counter() - handle["t_dev"]
-    wall = handle["prep_s"] + (time.perf_counter() - t_fin)
-
-    # the logged name is the engine SEMANTICS (what a cross-backend result
-    # diff needs) and must match a single run's line exactly — the batch
-    # tests pin line-for-line log equality across execution modes; the
-    # batched-execution detail stays in _last_engine for bench labeling
-    engine_name = "table" if use_table else "sequential"
-    results = []
-    for i, (sim, pods) in enumerate(zip(sims, pods_list)):
-        ev_kind_i, ev_pod_i = ev_list[i]
-        o = _slice_result(
-            jax.tree.map(lambda a: a[i], out), len(pods), len(ev_kind_i)
-        )
-        sim._last_engine = f"{engine_name} ({len(sims)}-seed vmap batch)"
-        sim.log.info(
-            f"[Engine] replay of {len(ev_kind_i)} events ran on: {engine_name}"
-        )
-        res, events, unscheduled, rank = sim._finish_replay(
-            o, pods, ev_kind_i, ev_pod_i, sim.init_state
-        )
-        results.append(
-            sim._record_result(
-                res, pods, events, unscheduled, rank, wall / len(sims)
-            )
-        )
-    return results
-
-
-_FRAG_BATCH_FN = None
-
-
-def _batched_frag_amounts(sims) -> np.ndarray:
-    """Cluster frag amounts for every sim's final state in ONE vmapped
-    device call + ONE fetch (instead of one cluster_analysis round trip
-    per sim)."""
-    global _FRAG_BATCH_FN
-    from tpusim.ops.frag import cluster_frag_amounts
-
-    if _FRAG_BATCH_FN is None:
-        _FRAG_BATCH_FN = jax.jit(
-            jax.vmap(lambda s, tp: cluster_frag_amounts(s, tp).sum(0), (0, None))
-        )
-    states = jax.tree.map(
-        lambda *xs: jnp.asarray(np.stack([np.asarray(x) for x in xs])),
-        *[s.last_result.state for s in sims],
-    )
-    return np.asarray(
-        device_fetch(_FRAG_BATCH_FN(states, sims[0].typical))
-    )
-
-
-def run_batch(sims: Sequence["Simulator"]) -> List[SimulateResult]:
-    """run() for a seed batch: per-sim host prep and reporting, one
-    batched device replay (see schedule_pods_batch)."""
-    return finish_run_batch(dispatch_run_batch(sims))
-
-
-def dispatch_run_batch(sims: Sequence["Simulator"]) -> dict:
-    """Host prep + async device dispatch of a seed batch (the dispatch
-    half of run_batch; see dispatch_pods_batch). The typical-pod
-    distribution is computed once on the lead sim and adopted by its
-    same-workload siblings."""
-    pods_list = []
-    lead = sims[0]
-    for sim in sims:
-        sim._reset_run_state()
-        if (
-            sim is lead
-            or sim.workload_pods != lead.workload_pods
-            or sim.cfg.typical_pods != lead.cfg.typical_pods
-        ):
-            sim.set_typical_pods()
-        else:
-            sim.adopt_typical_pods(lead)
-        sim.set_skyline_pods()
-        pods_list.append(sim.prepare_pods())
-        sim.log.info(
-            f"Number of original workload pods: {len(sim.workload_pods)}"
-        )
-    return dispatch_pods_batch(sims, pods_list)
-
-
-def finish_run_batch(handle: dict) -> List[SimulateResult]:
-    sims = handle["sims"]
-    results = finish_pods_batch(handle)
-    amounts = _batched_frag_amounts(sims)
-    for i, (sim, res) in enumerate(zip(sims, results)):
-        sim.report_failed([u.pod for u in res.unscheduled_pods])
-        sim.cluster_analysis("InitSchedule", _amounts=amounts[i])
-    return results
-
-
-# ---------------------------------------------------------------------------
 # Config-axis sweep: one compiled scan, B what-if configurations (ISSUE 6)
 # ---------------------------------------------------------------------------
 #
-# schedule_pods_batch vmaps S same-config experiments whose SEEDS differ
-# (per-seed specs/events/keys/ranks). The config-axis sweep generalizes it
-# along the axis the reference grids with a process per experiment
-# (1020 policy × weight × seed replays): the per-policy WEIGHT VECTOR is
-# now a traced engine operand (sim.step.resolve_weights), so a [B, num_pol]
+# The reference grids its 1020 policy × weight × seed replays with a
+# process per experiment (experiments/README.md step 2, xargs --max-procs).
+# Here the replays themselves are the batch: the per-policy WEIGHT VECTOR
+# is a traced engine operand (sim.step.resolve_weights), so a [B, num_pol]
 # weight matrix plus per-config seeds vmaps over ONE workload and ONE
 # compiled replay — the jaxpr is the policy family's, the weights are
 # data. The weight-independent score tables are built once and shared
 # across every lane (in_axes None), so the marginal what-if costs only
 # its share of the vmapped scan, never a table build or a compile.
 #
-# Two more scalars ride the same sweep as operands (schedule_pods_sweep):
+# Three more things ride the same sweep as operands (schedule_pods_sweep):
 # the TUNE FACTOR (ISSUE 7: a lane's own tuned trace, per-lane specs,
-# type_id and event streams) and a FAULT SCHEDULE (ISSUE 10, 12: with the
-# fault plane inside the scan, tpusim.sim.fault_lane, a schedule is five
-# i32 streams, a draw table and a param vector). There is one path for
-# all of it: one wrapper factory that reads the vmap axes off its
-# operands, one host prep, one dispatch, one tail, one sweep record.
+# type_id and event streams), which is also how a seed group's shuffles
+# run (run_batch: a trace and a seed a lane), a FAULT SCHEDULE (ISSUE 10,
+# 12: with the fault plane inside the scan, tpusim.sim.fault_lane, a
+# schedule is five i32 streams, a draw table and a param vector), and the
+# typical pods a lane is scored against. There is one path for all of it:
+# one wrapper factory that reads the vmap axes off its operands, one host
+# prep, one dispatch, one tail, one sweep record.
 
 _SWEEP_WRAP_CACHE = {}
 
@@ -3457,6 +3108,9 @@ class SweepLane:
     # a lane built from a chunked run's final arrays (lane_from_arrays)
     event_node: Optional[np.ndarray] = None  # i32[E]
     event_dev: Optional[np.ndarray] = None  # bool[E, 8]
+    # the lane's frag amounts by category over its final state, f32[7]:
+    # what frag_gpu_milli sums, and what cluster_analysis prints
+    frag_amounts: Optional[np.ndarray] = None
 
 
 # The lane axis of a sweep's final states, a leaf: 0 on the four leaves a
@@ -3821,6 +3475,7 @@ def _slice_sweep_lanes(out, amounts, watts, w, seeds, pods_n, events_n,
             power_gpu_w=watts[i][1],
             event_node=out.event_node[i, :e],
             event_dev=out.event_dev[i, :e],
+            frag_amounts=amounts[i],
         ))
     return lanes
 
@@ -3873,6 +3528,7 @@ def lane_from_arrays(state, placed_node, dev_mask, ever_failed, counters,
         unscheduled=int(((pn < 0) & failed).sum()),
         power_cpu_w=float(watts[0]),
         power_gpu_w=float(watts[1]),
+        frag_amounts=amounts,
     )
 
 
@@ -4449,10 +4105,10 @@ def schedule_pods_sweep(
     only where the cluster's initial state, the distinct type set, the
     typical pods or the scoring kernels are not the last call's
     (Simulator._sweep_tables; SweepRecord.tables_reused). Engine
-    selection mirrors schedule_pods_batch: the table engine unless forced
-    sequential or the workload is too small to amortize the table init;
-    pallas has no batched form; extenders / mesh / decision-recording /
-    series configs are rejected.
+    selection is run_events' rule, a trace: the table engine unless forced
+    sequential or some trace is too small to amortize the table init
+    (_sweep_traces); pallas has no batched form; extenders / mesh /
+    decision-recording / series configs are rejected.
 
     What else a lane carries is data, and the one path reads it off its
     operands (_sweep_engine):
@@ -4461,12 +4117,12 @@ def schedule_pods_sweep(
     None) gives lane i its OWN workload (tuned variants of one cluster's
     trace — the tune factor as an operand): specs, type_id and event
     streams padded to common buckets and stacked a lane, while the cluster
-    state, the DISTINCT type set (concat-dedup across the lanes, the
-    dispatch_pods_batch discipline), the typical pods and the once-built
-    score tables still broadcast. Host prep goes with the DISTINCT trace
-    objects: lanes that hand over one list twice have it spec'd, padded
-    and moved once, and a lane-to-trace index carries the rest
-    (SweepRecord.traces). Every lane shares the Simulator's cluster and
+    state, the DISTINCT type set (one dedup over the lanes' concatenated
+    specs, each lane's type_id its segment), the typical pods and the
+    once-built score tables still broadcast. Host prep goes with the
+    DISTINCT trace objects: lanes that hand over one list twice have it
+    spec'd, padded and moved once, and a lane-to-trace index carries the
+    rest (SweepRecord.traces). Every lane shares the Simulator's cluster and
     policy family (the service's batching rule — jaxpr identity);
     `min_pods` / `min_events` are the service's sticky shape floors
     (_sweep_traces).
@@ -4690,6 +4346,98 @@ def schedule_pods_sweep(
             sweep.rejected_creates = sum(lane.failed for lane in lanes)
             h.note(rejected_creates=sweep.rejected_creates)
             return lanes
+
+
+def run_batch(sims: Sequence["Simulator"]) -> List[SimulateResult]:
+    """run() for a seed group, S experiments of one configuration whose
+    seeds differ (shuffle order, tuning, tie-break permutation): per-sim
+    host prep and reporting around ONE schedule_pods_sweep on the lead, a
+    trace and a seed a lane. Every member ends as its own run() would, in
+    placements, device masks, final state, unscheduled list, creation
+    ranks and log (tests/test_batch.py)."""
+    from tpusim.sim.engine import ReplayResult
+
+    def shared(s):
+        # what the one compiled replay, its tables and its typical pods are
+        # made from; every lane is scored against the lead's typical pods,
+        # which is only sound when the seeds share the workload the
+        # distribution derives from
+        c = s.cfg
+        return (
+            c.policies, c.gpu_sel_method, c.dim_ext_method, c.norm_method,
+            c.report_per_event, c.use_timestamps, c.engine, c.block_size,
+            c.typical_pods, s.nodes, s.workload_pods,
+        )
+
+    lead = sims[0]
+    for s in sims:
+        # any member, not the lead alone: the sweep replays on the lead's
+        # engine, so a member that records would get None for its stream
+        _reject_unsweepable(s.cfg)
+        if shared(s) != shared(lead):
+            raise ValueError(
+                "run_batch requires same-config sims (policies, "
+                "gpu/dim/norm methods, report flag, typical-pod knobs, the "
+                "node cluster, and the workload may not differ across the "
+                "group)"
+            )
+    pods_list = []
+    for sim in sims:
+        sim._reset_run_state()
+        if sim is lead:
+            sim.set_typical_pods()
+        else:
+            sim.adopt_typical_pods(lead)
+        sim.set_skyline_pods()
+        pods_list.append(sim.prepare_pods())
+        sim.log.info(
+            f"Number of original workload pods: {len(sim.workload_pods)}"
+        )
+    t0 = time.perf_counter()
+    # the sweep names itself in its Simulator's log; a member's log is a
+    # standalone run's, so the lead lends the call another
+    keep, lead.log = lead.log, LogSink()
+    try:
+        lanes = schedule_pods_sweep(
+            lead, None,
+            [[w for _, w in lead.cfg.policies]] * len(sims),
+            [s.cfg.seed for s in sims], lane_pods=pods_list,
+        )
+    finally:
+        lead.log = keep
+    wall = (time.perf_counter() - t0) / len(sims)
+    # the logged name is the engine SEMANTICS, a standalone run's line
+    engine_name = lead._last_engine.split()[0]
+    results = []
+    for sim, pods, lane in zip(sims, pods_list, lanes):
+        ev_kind, ev_pod = build_events(pods, sim.cfg.use_timestamps)
+        sim._last_engine = lead._last_engine
+        sim.log.info(
+            f"[Engine] replay of {len(ev_kind)} events ran on: {engine_name}"
+        )
+        # a lane comes cut to its own pods and events, its counters
+        # pad-corrected, as VIEWS of the sweep's one buffer with capacity
+        # leaves all lanes share: what a Simulator keeps, and later stages
+        # write to (deschedule, inflation, schedule_additional), is its own
+        out = ReplayResult(
+            state=jax.tree.map(np.array, lane.state),
+            placed_node=lane.placed_node.copy(),
+            dev_mask=lane.dev_mask.copy(),
+            ever_failed=lane.ever_failed.copy(),
+            metrics=lane.metrics,
+            event_node=lane.event_node,
+            event_dev=lane.event_dev,
+            counters=lane.counters,
+        )
+        out, events, unscheduled, rank = sim._finish_replay(
+            out, pods, ev_kind, ev_pod, sim.init_state
+        )
+        results.append(
+            sim._record_result(out, pods, events, unscheduled, rank, wall)
+        )
+        sim.report_failed([u.pod for u in unscheduled])
+        sim.cluster_analysis("InitSchedule", amounts=lane.frag_amounts)
+    return results
 
 
 def format_chaos_table(lanes: Sequence[SweepLane], policies) -> str:
