@@ -398,14 +398,19 @@ def _fgd_sweep():
     return sim, lanes, oracle
 
 
-@pytest.mark.parametrize("sweep, deferred", [
-    (_fgd_sweep, 1), (_clustering_sweep, 0), (_fault_sweep, 0)],
+@pytest.mark.parametrize("sweep, deferred, readers", [
+    (_fgd_sweep, 1, 0), (_clustering_sweep, 0, 1), (_fault_sweep, 0, 0)],
     ids=["FGD", "GpuClustering", "fault plans"])
-def test_the_sweep_record_says_which_form_its_program_took(sweep, deferred):
+def test_the_sweep_record_says_which_form_its_program_took(
+        sweep, deferred, readers):
     sim, lanes, oracle = sweep()
     rec = sim.obs.sweeps[-1]
     assert rec.affinity_deferred == deferred
     assert rec.to_dict()["affinity_deferred"] == deferred
+    # and why: how many kernels of the program read the counts (a fault
+    # plan keeps the add for its fault steps, with no reader)
+    assert rec.affinity_readers == readers
+    assert rec.to_dict()["affinity_readers"] == readers
     # the flat body's commit: four adds and three sets, less the aff_cnt add
     # where it left the loop; the epilogue's commit is whole
     assert rec.lane_writes == 3 + (6 if deferred else 7) + 7
@@ -429,5 +434,7 @@ def test_the_blocked_body_and_the_sequential_engine_keep_the_add():
     schedule_pods_sweep(seq, seq.prepare_pods(), [[1000]] * 2, [1, 2])
     assert "sequential" in seq._last_engine
     assert seq.obs.sweeps[-1].affinity_deferred == 0
-    assert SweepRecord(id=0, start_s=0.0, blocked=False).to_dict()[
-        "affinity_deferred"] == 0
+    assert blocked.obs.sweeps[-1].affinity_readers == 0
+    assert seq.obs.sweeps[-1].affinity_readers == 0
+    empty = SweepRecord(id=0, start_s=0.0, blocked=False).to_dict()
+    assert (empty["affinity_deferred"], empty["affinity_readers"]) == (0, 0)

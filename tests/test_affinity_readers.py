@@ -15,6 +15,7 @@ from tpusim.learn.policy import FEATURE_NAMES, learned_policy_name
 from tpusim.policies import (
     POLICY_NAMES,
     ScoreContext,
+    affinity_readers,
     make_policy,
     policies_read_affinity,
 )
@@ -82,6 +83,7 @@ def test_a_kernel_that_reads_aff_cnt_is_counted_as_a_reader(name):
     # and the declarations are exact, so no program pays the per-event add
     # for a kernel that never looks
     assert counted == reads == (name == "GpuClusteringScore")
+    assert affinity_readers([(fn, 1000)]) == int(reads)
 
 
 def test_a_kernel_that_says_nothing_counts_as_a_reader():
@@ -101,6 +103,29 @@ def test_a_kernel_that_says_nothing_counts_as_a_reader():
     assert jit_policy(make_policy("GpuClusteringScore")).reads_affinity
 
 
+@pytest.mark.parametrize("names, want", [
+    ((), 0),
+    (("FGDScore",), 0),
+    (("GpuClusteringScore",), 1),
+    (("FGDScore", "GpuClusteringScore"), 1),
+    (("GpuClusteringScore", "PWRScore", "GpuClusteringScore"), 2),
+    (("PWRScore", "FGDScore"), 0),
+    (("FGDScore", None), 1),
+    ((None, "GpuClusteringScore", None), 3),
+], ids=["none", "FGD", "GpuClustering", "FGD+GpuClustering", "twice",
+        "PWR+FGD", "a silent kernel", "silent kernels count"])
+def test_affinity_readers_counts_the_kernels_that_read(names, want):
+    """SweepRecord.affinity_readers (ISSUE 45): the count behind
+    policies_read_affinity; None stands for a kernel that declares
+    nothing, which counts as a reader."""
+    def silent(state, pod, ctx):
+        return make_policy("FGDScore")(state, pod, ctx)
+
+    policies = [(silent if n is None else make_policy(n), 1000) for n in names]
+    assert affinity_readers(policies) == want
+    assert policies_read_affinity(policies) == (want > 0)
+
+
 def test_the_probe_sees_a_read():
     """The probe itself: a kernel that only adds aff_cnt into its score is
     seen, one that carries the state through untouched is not."""
@@ -111,3 +136,39 @@ def test_the_probe_sees_a_read():
         return res._replace(raw_scores=res.raw_scores + state.aff_cnt[:, 0])
 
     assert _uses_aff_cnt(peeks) and not _uses_aff_cnt(fgd)
+
+
+@pytest.mark.parametrize("lanes", [None, 3], ids=["standalone", "vmapped"])
+def test_the_commits_scope_is_a_name_and_no_operation(lanes):
+    """COMMIT_AFFINITY_SCOPE names the add into aff_cnt where it stands:
+    `scoped` changes the program's debug info and nothing else, so a
+    program lowered with it is the module (and the compile-cache entry) it
+    was without it. PR 45's first form moved the add and every standing
+    cell ran another program than its parent's."""
+    from tpusim.sim import step
+
+    state, _, _ = _abstract_operands()
+    pods = 5
+    operands = (
+        state,
+        jax.ShapeDtypeStruct((pods + 1,), jnp.int32),
+        jax.ShapeDtypeStruct((pods + 1, 8), jnp.bool_),
+        jax.ShapeDtypeStruct((pods + 1,), jnp.bool_),
+        jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                     step.no_pending_commit(pods)),
+    )
+    if lanes:
+        operands = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct((lanes,) + a.shape, a.dtype),
+            operands)
+
+    def lowered(scoped, debug_info):
+        def commit(*args):
+            return step.apply_commit(*args, scoped=scoped)
+
+        fn = jax.vmap(commit) if lanes else commit
+        return jax.jit(fn).lower(*operands).as_text(debug_info=debug_info)
+
+    assert lowered(True, False) == lowered(False, False)
+    assert step.COMMIT_AFFINITY_SCOPE in lowered(True, True)
+    assert step.COMMIT_AFFINITY_SCOPE not in lowered(False, True)

@@ -338,17 +338,23 @@ def test_the_report_program_runs_under_its_named_scope(loaded):
 # ---------------------------------------------------------------- (d)
 def test_the_cell_and_its_nine_metrics_stand_as_entered(bench_run):
     bench = bench_run.load_json(os.path.join(REPO, "BENCHMARK.json"))
-    assert bench["workloads"][-1] == {
+    # by name, after the clock cell's: later PRs append after them (PR 45:
+    # the clustering cell and its eight metrics)
+    cells = [w["name"] for w in bench["workloads"]]
+    at = cells.index(CELL)
+    assert cells[at - 1] == "openb-clock.fgd-seeds"
+    assert bench["workloads"][at] == {
         "name": CELL, "config": "openb-load130",
         "traffic": "report-seeds-320", "chips": 1,
-        "why": bench["workloads"][-1]["why"]}
-    entry = bench["configs"][-1]
-    assert (entry["name"], entry["file"], entry["reduced"]) == (
-        "openb-load130", "benchmark/configs/openb-load130.json",
-        ["families", "policies"])
+        "why": bench["workloads"][at]["why"]}
+    entry = next(c for c in bench["configs"] if c["name"] == "openb-load130")
+    assert (entry["file"], entry["reduced"]) == (
+        "benchmark/configs/openb-load130.json", ["families", "policies"])
     assert "depth_events" not in json.dumps(_config(False)["reduced"])
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(NEW):] == NEW
+    first = names.index(NEW[0])
+    assert names[first - 1] == "clock_fetch_bytes"
+    assert names[first:first + len(NEW)] == NEW
     by_name = dict(zip(names, bench["per_layer"]))
     for name in NEW:
         assert by_name[name]["workloads"] == [CELL]

@@ -404,7 +404,13 @@ def test_every_stage_of_the_step_body_has_its_scope(scoped):
     # program whose kernels do not read aff_cnt (FGD); the blocked body
     # keeps the add in its commit
     assert ("tpusim.affinity" in text) == (block_size < 0)
+    # and where the add stays in the loop it has a scope of its own inside
+    # the commit's (ISSUE 45); FGD's flat program has no such scope at all:
+    # its epilogue's whole commit is outside both
+    assert ("tpusim.commit/tpusim.commit.affinity" in text) == (block_size > 0)
+    assert ("tpusim.commit.affinity" in text) == (block_size > 0)
     assert sim.obs.sweeps[-1].affinity_deferred == (block_size < 0)
+    assert sim.obs.sweeps[-1].affinity_readers == 0
     assert sim.obs.sweeps[-1].to_dict()["affinity_deferred"] == int(
         block_size < 0)
     # the dense forms of sim/lane_write.py sit inside the stage that calls
@@ -445,6 +451,10 @@ def test_a_program_whose_kernel_reads_aff_cnt_keeps_the_add_in_its_loop():
     assert rec.lane_writes == WRITE_SITES[8]  # the whole commit, twice
     text = fn.lower(*shapes).as_text(debug_info=True)
     assert "tpusim.commit" in text and "tpusim.affinity" not in text
+    # the add it cannot defer, under its own scope inside the commit's
+    assert rec.affinity_readers == rec.to_dict()["affinity_readers"] == 1
+    assert re.search(
+        r'tpusim\.commit/tpusim\.commit\.affinity/vmap[^"/]*/add', text)
 
 
 def test_a_scoped_sweep_equals_the_sequential_oracle(scoped):

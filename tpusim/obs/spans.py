@@ -194,6 +194,12 @@ class SweepRecord:
     # every event (the blocked body, fault plans, a GpuClustering program,
     # the sequential engine)
     affinity_deferred: int = 0
+    # policies of the sweep's program whose kernel reads NodeState.aff_cnt
+    # (policies.affinity_readers, off the kernels' own `reads_affinity`):
+    # 0 for every built-in but GpuClustering. A program with a reader keeps
+    # the commit's add in its event loop (scope tpusim.commit.affinity), so
+    # a record with readers and affinity_deferred 1 is a wrong program
+    affinity_readers: int = 0
     # 1 where the sweep read the score tables an earlier sweep of the
     # Simulator left on the device (its init_tables span says
     # cache="resident"), 0 where it built or loaded them
@@ -330,6 +336,7 @@ class SweepRecord:
             "dense_accesses": self.dense_accesses,
             "table_pass_events": self.table_pass_events,
             "affinity_deferred": self.affinity_deferred,
+            "affinity_readers": self.affinity_readers,
             "tables_reused": self.tables_reused,
             "traces": self.traces,
             "typical_sets": self.typical_sets,

@@ -13,6 +13,7 @@ from tpusim.policies.base import (
     PolicyFn,
     PolicyResult,
     ScoreContext,
+    affinity_readers,
     feasible_min_max,
     minmax_normalize_i32,
     minmax_scale_i32,
@@ -126,6 +127,7 @@ __all__ = [
     "minmax_scale_i32",
     "pwr_normalize_i32",
     "NORMALIZE_DEGENERATE",
+    "affinity_readers",
     "policies_read_affinity",
     "POLICY_NAMES",
     "is_policy_name",

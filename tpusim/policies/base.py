@@ -57,10 +57,17 @@ class PolicyResult(NamedTuple):
 PolicyFn = Callable[[NodeState, PodSpec, ScoreContext], PolicyResult]
 
 
+def affinity_readers(policies) -> int:
+    """How many kernels of [(policy_fn, weight)] read NodeState.aff_cnt
+    (SweepRecord.affinity_readers). A kernel that says nothing counts as a
+    reader."""
+    return sum(bool(getattr(fn, "reads_affinity", True)) for fn, _ in policies)
+
+
 def policies_read_affinity(policies) -> bool:
     """Whether some kernel of [(policy_fn, weight)] reads
-    NodeState.aff_cnt. A kernel that says nothing counts as a reader."""
-    return any(getattr(fn, "reads_affinity", True) for fn, _ in policies)
+    NodeState.aff_cnt."""
+    return affinity_readers(policies) > 0
 
 
 def feasible_min_max(scores, feasible):

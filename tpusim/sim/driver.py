@@ -30,7 +30,7 @@ from tpusim.io.trace import (
     pods_to_specs,
     tiebreak_rank,
 )
-from tpusim.policies import make_policy
+from tpusim.policies import affinity_readers, make_policy
 from tpusim.sim.engine import EV_DELETE, make_replay
 from tpusim.sim.fetch import device_fetch
 from tpusim.sim.reports import (
@@ -4197,6 +4197,7 @@ def schedule_pods_sweep(
         sweep.weight_rows = len(np.unique(w, axis=0))
         sweep.normalized_policies = sum(
             fn.normalize in ("minmax", "pwr") for fn, _ in sim._policy_fns)
+        sweep.affinity_readers = affinity_readers(sim._policy_fns)
         with obs.span("specs") as h:
             tr = _sweep_traces(
                 sim, traces, lane_trace, per_lane or faulted, bucket,

@@ -9,6 +9,7 @@ Reserve/Bind become a scatter update of the NodeState arrays.
 
 from __future__ import annotations
 
+import contextlib
 from typing import NamedTuple, Sequence, Tuple
 
 import jax
@@ -366,8 +367,14 @@ def make_pending_commit(
     )
 
 
+# the commit's add into aff_cnt where an event loop keeps it: named inside
+# tpusim.commit so that a by-scope reading of a device trace finds the one
+# add a program whose kernels read the counts cannot defer
+COMMIT_AFFINITY_SCOPE = "tpusim.commit.affinity"
+
+
 def apply_commit(state: NodeState, placed, masks, failed, p: "PendingCommit",
-                 affinity: bool = True):
+                 affinity: bool = True, scoped: bool = False):
     """Apply a PendingCommit's scatters — the write-only half of the
     pipelined event loop. placed/masks/failed carry one extra dummy row
     ([P]) that absorbs skip-event writes. The global view of
@@ -375,7 +382,7 @@ def apply_commit(state: NodeState, placed, masks, failed, p: "PendingCommit",
     arithmetic exists exactly once."""
     return apply_commit_sharded(
         state, placed, masks, failed, p, jnp.int32(0), state.num_nodes,
-        affinity,
+        affinity, scoped,
     )
 
 
@@ -390,7 +397,7 @@ def commit_affinity(node, cls, rs):
 
 def apply_commit_sharded(state: NodeState, placed, masks, failed,
                          p: "PendingCommit", offset, nloc: int,
-                         affinity: bool = True):
+                         affinity: bool = True, scoped: bool = False):
     """apply_commit for a node-axis-sharded carry (the shard_map engine's
     software pipeline, ISSUE 11): `p.node` is a GLOBAL node id, so each
     shard lands the state scatters owner-masked on its local row window
@@ -404,7 +411,10 @@ def apply_commit_sharded(state: NodeState, placed, masks, failed,
     `affinity` (static) False leaves the add into aff_cnt out: the flat
     table replay's event loop, where no kernel of its program reads that
     leaf, makes it once a chunk from the events' own record. Every other
-    caller commits whole."""
+    caller commits whole. `scoped` (static) names the add's operations
+    COMMIT_AFFINITY_SCOPE: the table bodies' event loops ask for it
+    (table_engine._scoped_commit), an epilogue's commit does not. A name
+    is all it is: the operations and their order are the same either way."""
     li = p.node - offset
     owns = (p.node >= 0) & (li >= 0) & (li < nloc)
     sel = jnp.clip(li, 0, nloc - 1)
@@ -422,10 +432,12 @@ def apply_commit_sharded(state: NodeState, placed, masks, failed,
         ),
     )
     if affinity:
-        state = state._replace(aff_cnt=add_row(
-            state.aff_cnt, (sel, jnp.maximum(p.cls, 0)),
-            jnp.where(owns, commit_affinity(p.node, p.cls, p.rs), 0),
-        ))
+        with (jax.named_scope(COMMIT_AFFINITY_SCOPE) if scoped
+              else contextlib.nullcontext()):
+            state = state._replace(aff_cnt=add_row(
+                state.aff_cnt, (sel, jnp.maximum(p.cls, 0)),
+                jnp.where(owns, commit_affinity(p.node, p.cls, p.rs), 0),
+            ))
     placed = set_row(placed, p.pod_write, p.placed_val)
     masks = set_row(masks, p.pod_write, p.mask_val)
     failed = set_row(failed, p.failed_write, p.failed_val)

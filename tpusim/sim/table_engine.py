@@ -110,6 +110,17 @@ def flat_group_events(lanes: int, nodes: int) -> int:
 AFFINITY_EVENTS = 128
 
 
+def _scoped_commit(state, placed, masks, failed, pend, affinity: bool = True):
+    """apply_commit as an event loop's bodies call it: under the scope
+    tpusim.commit, the add into aff_cnt (where the program keeps it in the
+    loop: `affinity`) under step.COMMIT_AFFINITY_SCOPE inside it. The
+    epilogue's commit (finish) is outside both."""
+    with jax.named_scope("tpusim.commit"):
+        return apply_commit(
+            state, placed, masks, failed, pend, affinity=affinity,
+            scoped=True)
+
+
 def chunk_affinity(pend: PendingCommit, pods: PodSpec, ev_kind, ev_pod,
                    event_node, num_nodes: int, classes: int):
     """i32[N, classes]: what the commits that one chunk's scan APPLIED add
@@ -983,10 +994,8 @@ def _make_table_engine(
             # apply the PREVIOUS event's deferred scatters first — every
             # carried buffer is written before anything reads it, so all
             # updates alias in place (PendingCommit)
-            with jax.named_scope("tpusim.commit"):
-                state, placed, masks, failed = apply_commit(
-                    state, placed, masks, failed, pend
-                )
+            state, placed, masks, failed = _scoped_commit(
+                state, placed, masks, failed, pend)
 
             # dirty-column refresh — same kernels, same order as the flat
             # path; dirty < n always, so sentinel columns are never written
@@ -1318,11 +1327,9 @@ def _make_table_engine(
             # apply the PREVIOUS event's deferred scatters first: every
             # carried buffer is written before anything reads it this
             # iteration, so all updates alias in place (PendingCommit)
-            with jax.named_scope("tpusim.commit"):
-                state, placed, masks, failed = apply_commit(
-                    state, placed, masks, failed, pend,
-                    affinity=not defer_affinity,
-                )
+            state, placed, masks, failed = _scoped_commit(
+                state, placed, masks, failed, pend,
+                affinity=not defer_affinity)
 
             # refresh the one column whose node changed last event (from
             # the just-committed state). Grouped, the tables are not
