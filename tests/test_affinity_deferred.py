@@ -126,11 +126,11 @@ CASES = {
 }
 
 
-def _engine(policies, lanes):
+def _engine(policies, lanes, gpu_sel="FGDScore"):
     """(init_carry, run_chunk, finish, replay, deferred) of the flat table
     engine for `policies`, vmapped as a sweep with a trace a lane where
     `lanes` is given (from FLAT_GROUP_MIN_LANES on its grouped body)."""
-    tab = make_table_replay(policies, gpu_sel="FGDScore", block_size=-1)
+    tab = make_table_replay(policies, gpu_sel=gpu_sel, block_size=-1)
     eng, wts = tab.engine, resolve_weights(policies, None)
     if lanes is None:
         return (
@@ -365,21 +365,20 @@ def _clustering_sweep():
     return sim, lanes, oracle
 
 
-def _fault_sweep():
+def _fault_sweep(policies=(("FGDScore", 1000),), gpu_sel="FGDScore"):
     """A fault plan a lane: fault steps zero and rewrite aff_cnt rows
     mid-scan, so the add stays in the commit; each lane equals the
     standalone run under its schedule."""
     from tests.test_sweep_paths import _faults
 
-    policies = (("FGDScore", 1000),)
-    sim = _driver_sim(policies, "FGDScore")
+    sim = _driver_sim(policies, gpu_sel)
     specs = _faults(2)
     lanes = sim.run_sweep(
         np.asarray([[1000]] * 2, np.int32), seeds=[42] * 2, faults=specs)
     assert sim._last_engine.endswith("chaos sweep)")
     oracle = []
     for spec in specs:
-        solo = _driver_sim(policies, "FGDScore")
+        solo = _driver_sim(policies, gpu_sel)
         oracle.append(solo.run_with_faults(fault_cfg=spec))
     assert any(lane.disruption.evicted_pods for lane in lanes)
     return sim, lanes, oracle

@@ -331,6 +331,70 @@ def _every_access_args(size):
             _node_idx(n), _ints((L, 8)), _pod_idx(POD_ROWS[size]))
 
 
+# the affinity counts as the flat event loop holds them where a kernel
+# reads them: a small leaf with the NODES on its last axis, one entry added
+# and one column read an event. Operands: which of (leaf, class, node,
+# delta) carry the lane axis
+NODES_LAST = {
+    "unbatched": None,
+    "a shared index": (True, False, False, False),
+    "an index and a class a lane": (True, True, True, True),
+    "the leaf shared": (False, True, True, True),
+    # a commit that touched no node (`owns` False): 0 at the clipped row
+    "a no-op delta a lane": (True, True, True, True),
+}
+
+
+@pytest.mark.parametrize("size", sorted(SIZES))
+@pytest.mark.parametrize("form", sorted(NODES_LAST))
+def test_the_nodes_last_pair_equals_the_plain_add_and_the_plain_column(
+        form, size):
+    """add_entry is `.at[c, n].add(d)` and read_column `leaf[:, n]` as a
+    [1, C] row, unbatched and under vmap; on a short node axis at width
+    both are dense (no scatter, no gather, no loop), on a long one what
+    vmap derives."""
+    n = SIZES[size][0]
+    leaf, node = _ints((L, 9, n)), _node_idx(n)
+    cls = jnp.asarray([8, 0, 2, 2, 5], jnp.int32)
+    delta = jnp.asarray([1, -1, 1, -1, 1], jnp.int32)
+    if form.startswith("a no-op"):
+        node, cls, delta = (jnp.zeros(L, jnp.int32),) * 3
+
+    def plain_add(a, c, i, d):
+        return a.at[c, i].add(d)
+
+    def plain_column(a, i):
+        return a[:, i][None]
+
+    if NODES_LAST[form] is None:
+        args = (leaf[0], cls[0], node[0], delta[0])
+        _same(lw.add_entry(*args), plain_add(*args))
+        _same(lw.read_column(leaf[0], node[0]), plain_column(leaf[0], node[0]))
+        return
+    args, axes = _axes((leaf, cls, node, delta), NODES_LAST[form])
+    with lw.counting() as sites:
+        add = jax.jit(jax.vmap(lw.add_entry, in_axes=axes))
+        text = add.lower(*args).as_text()
+    got = add(*args)
+    _same(got, jax.vmap(plain_add, in_axes=axes)(*args))
+    if form.startswith("a no-op"):
+        _same(got, leaf)
+    # (an index the lanes share stays the one update vmap derives)
+    dense = size == "short" and any(NODES_LAST[form][1:])
+    assert (len(sites), len(sites.dense)) == (1, int(dense))
+    if dense:
+        assert "stablehlo.scatter" not in text and "while" not in text
+    read_axes = (axes[0], axes[2])
+    with lw.counting() as sites:
+        read = jax.jit(jax.vmap(lw.read_column, in_axes=read_axes))
+        text = read.lower(args[0], args[2]).as_text()
+    _same(read(args[0], args[2]),
+          jax.vmap(plain_column, in_axes=read_axes)(args[0], args[2]))
+    assert (len(sites), len(sites.dense)) == (0, int(size == "short"))
+    if size == "short":
+        assert "stablehlo.gather" not in text and "while" not in text
+
+
 @pytest.mark.parametrize("size", sorted(SIZES))
 def test_a_short_node_axis_is_served_without_scatter_or_gather(size):
     """The dense forms: on a short node axis the vmapped program of every

@@ -5,8 +5,9 @@ scan of openb no loop over the lanes (ISSUE 28) and no whole-table
 operation inside its per-event step (ISSUE 29), with one shared trace or
 with a trace a lane (ISSUE 33), under one raw-score policy or under two with
 a normalizer in the scan (ISSUE 34), and no gather of an entry a (lane, type)
-in that step (ISSUE 35). tests/test_tpu.py holds the same checks on the chip
-itself."""
+in that step (ISSUE 35); under GpuClustering the event loops hold the
+affinity counts nodes minor (ISSUE 46). tests/test_tpu.py holds the same
+checks on the chip itself."""
 
 import re
 
@@ -393,6 +394,71 @@ def test_the_normalized_two_policy_sweep_loops_over_events_only(
         for _, name, out in reads:
             assert re.match(rf"(s32|pred)\[{lanes},(2,)?1213\]", out), (
                 name, out)
+
+
+CLUSTERING = (("GpuClusteringScore", 1000),)
+
+
+def test_the_clustering_sweep_carries_the_counts_nodes_minor(one_chip):
+    """GpuClustering with `best` devices and a trace a lane on openb's
+    1,213 nodes (ISSUE 46): its kernel reads the dirty node's nine affinity
+    counts every event, so the commit's add stays in the event loop
+    (`tpusim.commit.affinity` inside it) and so does the read. The loops
+    carry the leaf as the body holds it, s32[lanes,9,1213] with the nodes
+    (or the lanes) minor: every tile full, where s32[lanes,1213,9]{2,1,0}
+    used nine of a tile's 128 minor entries in each of the five passes an
+    unrolled iteration makes (four column reads, one of them fused with the
+    four adds). Nothing inside the loops produces or reads an array of the
+    [N, 9] form, which is transposed before and after them, and no copy of
+    the leaf stands in a loop."""
+    from tpusim.sim.step import COMMIT_AFFINITY_SCOPE
+    from tpusim.sim.table_engine import FLAT_GROUP_EVENTS
+
+    sim, trace, cfg = sweep_program.cell_simulator(
+        None, OPENB_DEPTH, config="openb", policies=CLUSTERING,
+        gpu_sel_method="best")
+    lanes = LANE_TRACE_LANES
+    with lane_write.counting() as sites:
+        fn, shapes, _ = sweep_program.capture_sweep(
+            sim, None, sweep_program.cell_weights(cfg, lanes),
+            list(range(lanes)), **_lane_operands("a trace a lane", sim,
+                                                 trace, lanes))
+        shapes = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), shapes)
+        lowered = fn.lower(*shapes)
+    # the FGD program's sites and the add, a dense write, back among them;
+    # the column read is one site where the row read of [N, 9] was one
+    assert (len(sites), len(sites.dense)) == (17, 31)
+    assert sites.table_pass_events == FLAT_GROUP_EVENTS
+    text = lowered.compile().as_text()
+    loops = sweep_program.while_loops(text)
+    bodies = sweep_program.loop_bodies(text)
+    (outer,) = [b for b, holder in bodies.items() if holder not in bodies]
+    (inner,) = [b for b, holder in bodies.items() if holder == outer]
+    assert len(loops) == 2, loops
+
+    rows, held = rf"s32\[{lanes},1213,9\]", rf"s32\[{lanes},9,1213\]"
+    for holder, _, carried in loops:
+        assert not re.search(rows, carried), (holder, carried[:200])
+        (minor,) = set(re.findall(held + r"\{(\d)", carried))
+        assert minor in "20", carried[:200]  # nodes or lanes; 1: the classes
+    assert not sweep_program.producers_in(text, outer, rows)
+    assert not sweep_program.fusions_reading(text, outer, rows)
+    copied = [(c, n, s) for c, n, s, _ in sweep_program.big_copies_in_scan(
+        text, lanes * 9 * 1213) if re.match(held, s)]
+    assert not copied, copied
+    # the add is in the per-event loop, under its scope, on the held form
+    adds = [line for _, m, line, _ in sweep_program._instructions_in(
+        text, inner) if COMMIT_AFFINITY_SCOPE in line
+        and m.group(3) == "add"]
+    assert adds and all(re.match(held, m.group(2)) for m in map(
+        sweep_program._INSTRUCTION.match, adds))
+    # and the reads: four an unrolled iteration, a column a lane each
+    reads = sweep_program.fusions_reading(text, inner, held)
+    assert len(reads) == 4, reads
+    for _, name, out in reads:
+        assert re.match(rf"\(?s32\[{lanes},9\]", out), (name, out)
 
 
 def _fault_specs(lanes):

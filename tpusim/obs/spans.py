@@ -200,6 +200,11 @@ class SweepRecord:
     # the commit's add in its event loop (scope tpusim.commit.affinity), so
     # a record with readers and affinity_deferred 1 is a wrong program
     affinity_readers: int = 0
+    # 1 where that loop holds the leaf nodes minor, i32[classes, N] a lane
+    # (transposed once a chunk on the way in and out: the flat table body
+    # with a reader and no fault step), 0 where the add goes into [N, 9]
+    # rows or has left the loop. A static property of the program
+    affinity_nodes_minor: int = 0
     # 1 where the sweep read the score tables an earlier sweep of the
     # Simulator left on the device (its init_tables span says
     # cache="resident"), 0 where it built or loaded them
@@ -337,6 +342,7 @@ class SweepRecord:
             "table_pass_events": self.table_pass_events,
             "affinity_deferred": self.affinity_deferred,
             "affinity_readers": self.affinity_readers,
+            "affinity_nodes_minor": self.affinity_nodes_minor,
             "tables_reused": self.tables_reused,
             "traces": self.traces,
             "typical_sets": self.typical_sets,

@@ -4262,6 +4262,9 @@ def schedule_pods_sweep(
             sweep.sub_requests = sub_requests(sim._policy_fns, tr.types)
             sweep.affinity_deferred = int(
                 replay_fn.engine.affinity_deferred(len(sim.nodes), tr.types))
+            sweep.affinity_nodes_minor = int(
+                replay_fn.engine.affinity_nodes_minor(
+                    len(sim.nodes), tr.types))
             key0 = jax.random.PRNGKey(seeds[0])
             tables, sweep.tables_reused = sim._sweep_tables(
                 replay_fn.engine, state, tr.types, typical, key0)

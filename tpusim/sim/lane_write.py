@@ -13,10 +13,11 @@ Under `jax.vmap` (every sweep: `driver._sweep_engine`; the seed batch, the
 fork wave) each carried leaf gains a leading lane axis and
 the derived forms are a `scatter` for a write and a `gather` for a read. On
 the TPU the scatters run in place on the carry's own layout (tables
-row-major with nodes minor, `NodeState.gpu_left` / `aff_cnt` nodes minor
-too). The READERS were the cost: XLA's gather wants the axis it windows
-major-most, so a block read of `[lanes, n_pol, K, N]` asked for the score
-table with N major, a row read of `gpu_left[lanes, N, 8]` for the 8 minor,
+row-major with nodes minor, `NodeState.gpu_left` nodes minor too, and
+`aff_cnt` on a long node axis). The READERS were the cost: XLA's gather
+wants the axis it windows major-most, so a block read of
+`[lanes, n_pol, K, N]` asked for the score table with N major, a row
+read of `gpu_left[lanes, N, 8]` for the 8 minor,
 a `dynamic_slice` with an index shared by the lanes for the lanes minor, and
 each got a copy of the whole carried array, every event (PERF.md section 5:
 sixteen copies, 7.7 s of a 9.86 s scan at 100,000 nodes x 40 lanes). The
@@ -26,8 +27,9 @@ rule below gives every access ONE shape the carry's layout serves as it is:
 - a read is a gather, batched over the lane axis (and a table's policy
   axis), of (rows, nodes) windows that never hold all the rows of the leaf
   (`_windows`: two windows of half the rows each), with its index made
-  per-lane even when the lanes share it. A row of `gpu_left` or `aff_cnt`
-  is picked out of the 128-node tile that holds it. The dirty block comes
+  per-lane even when the lanes share it. A row of `gpu_left` (or of
+  `aff_cnt`, whose [N, 9] rows the blocked body keeps) is picked out of
+  the 128-node tile that holds it. The dirty block comes
   back from `write_column` itself, so the step body never gathers a block
   from the table.
 
@@ -62,7 +64,15 @@ s32[lanes, N, 9] every event), wrote a leaf that no kernel but
 GpuClustering's reads. Where the program's kernels say they do not, the
 flat body's commit leaves it out and `table_engine.chunk_affinity` sums the
 chunk's events once, a contraction of two one-hots over the event axis:
-no write site of this module, no scatter, no index row a lane. And at sweep
+no write site of this module, no scatter, no index row a lane. Where a
+kernel does read it (GpuClustering) the add and the dirty node's read stay
+in the loop, and what was wrong there was the leaf's shape: with a class a
+lane XLA carried s32[lanes, N, 9] classes minor, nine of a tile's 128
+minor entries in use, and the add and the four reads of an unrolled
+iteration each passed over 14 x the useful bytes (59 % of that scan;
+PERF.md section 6, PR 46). The flat loop holds that leaf [9, N] a lane and
+touches it through `add_entry` and `read_column`: the same two accesses
+with the nodes on the LAST axis, every tile full. And at sweep
 width (`table_engine.flat_group_events`) the flat step body does not write
 its tables every event: it holds the dirty columns of a group of events
 (`table_engine.LateColumns`) and puts them down together. `write_columns(tbl, cols, idxs)` is that access: unbatched, the
@@ -395,7 +405,7 @@ def read_entry(tbl, row, col):
 
 
 # ------------------------------------------------------------------ rows
-def _row_write(leaf, idx, val, add: bool):
+def _row_write(leaf, idx, val, add: bool, nodes_axis: int = 0):
     def expr(leaf, val, *idx):
         ref = leaf.at[idx if len(idx) > 1 else idx[0]]
         return ref.add(val) if add else ref.set(val)
@@ -411,7 +421,7 @@ def _row_write(leaf, idx, val, add: bool):
     idx = idx if isinstance(idx, tuple) else (idx,)
     return _lane_batched(
         expr, expr, write=True,
-        dense=_dense_write(dense) if _short(leaf.shape[0]) else None,
+        dense=_dense_write(dense) if _short(leaf.shape[nodes_axis]) else None,
     )(leaf, val, *idx)
 
 
@@ -458,6 +468,34 @@ def read_row(leaf, idx, keepdims: bool = True):
         expr, lanes, write=False, per_lane=(1,),
         dense=(lambda _: dense) if _short(n) else None,
     )(leaf, idx)
+
+
+# ------------------------------------------- a small leaf, nodes last
+def add_entry(leaf, cls, node, delta):
+    """leaf.at[cls, node].add(delta) of a leaf [C, N] with the NODES on its
+    last axis (the affinity counts as the flat event loop holds them where
+    a kernel reads them: table_engine._run_chunk_impl). add_row's rule with
+    the node axis last: dense, one pass `leaf + where(class_iota == cls &
+    node_iota == node, delta, 0)` over full tiles."""
+    return _row_write(leaf, (cls, node), delta, add=True, nodes_axis=1)
+
+
+def read_column(leaf, node):
+    """Column `node` of such a leaf as the [1, C] row read_row gives of its
+    transpose: the `dynamic_slice` it always was; dense, the masked sum
+    over the LAST axis."""
+    n = leaf.shape[1]
+
+    def expr(leaf, node):
+        return lax.dynamic_slice_in_dim(leaf, node, 1, axis=1).T
+
+    def dense(leaf, node):
+        return _picked(leaf, _one_hot(n, node), axis=1)[None]
+
+    return _lane_batched(
+        expr, expr, write=False,
+        dense=(lambda _: dense) if _short(n) else None,
+    )(leaf, node)
 
 
 def read_pod(leaf, idx):

@@ -27,7 +27,7 @@ from tpusim.ops.resource import (
 )
 from tpusim.policies import ScoreContext, minmax_normalize_i32, pwr_normalize_i32
 from tpusim.policies.clustering import pod_affinity_class
-from tpusim.sim.lane_write import add_row, set_row
+from tpusim.sim.lane_write import add_entry, add_row, set_row
 from tpusim.types import NodeState, PodSpec
 
 _INT_MAX = np.int32(np.iinfo(np.int32).max)
@@ -395,6 +395,35 @@ def commit_affinity(node, cls, rs):
     return jnp.where((node >= 0) & (cls >= 0), -rs, 0)
 
 
+def _commit_row(p: "PendingCommit", offset, nloc: int):
+    """(owns, sel): whether the window of `nloc` rows from global node id
+    `offset` holds the commit's node, and its row there (clipped into the
+    window, so that a commit the window does not own adds 0 to a row that
+    exists)."""
+    li = p.node - offset
+    owns = (p.node >= 0) & (li >= 0) & (li < nloc)
+    return owns, jnp.clip(li, 0, nloc - 1)
+
+
+def _affinity_entry(p: "PendingCommit", owns):
+    """(class, delta) of the commit's one add into the affinity counts, at
+    its row of the window that `owns` speaks for."""
+    return jnp.maximum(p.cls, 0), jnp.where(
+        owns, commit_affinity(p.node, p.cls, p.rs), 0)
+
+
+def add_commit_affinity(aff_t, p: "PendingCommit"):
+    """apply_commit's add into aff_cnt for an event loop that carries the
+    counts NODES MINOR beside its state (`aff_t` i32[classes, N]: the flat
+    table body where a kernel reads them, table_engine._run_chunk_impl) and
+    commits the rest with affinity=False: the same entry, the same delta,
+    under the same scope."""
+    owns, sel = _commit_row(p, jnp.int32(0), aff_t.shape[1])
+    cls, delta = _affinity_entry(p, owns)
+    with jax.named_scope(COMMIT_AFFINITY_SCOPE):
+        return add_entry(aff_t, cls, sel, delta)
+
+
 def apply_commit_sharded(state: NodeState, placed, masks, failed,
                          p: "PendingCommit", offset, nloc: int,
                          affinity: bool = True, scoped: bool = False):
@@ -415,9 +444,7 @@ def apply_commit_sharded(state: NodeState, placed, masks, failed,
     COMMIT_AFFINITY_SCOPE: the table bodies' event loops ask for it
     (table_engine._scoped_commit), an epilogue's commit does not. A name
     is all it is: the operations and their order are the same either way."""
-    li = p.node - offset
-    owns = (p.node >= 0) & (li >= 0) & (li < nloc)
-    sel = jnp.clip(li, 0, nloc - 1)
+    owns, sel = _commit_row(p, offset, nloc)
     state = state._replace(
         cpu_left=add_row(
             state.cpu_left, sel, jnp.where(owns, p.rs * p.cpu, 0)
@@ -434,10 +461,9 @@ def apply_commit_sharded(state: NodeState, placed, masks, failed,
     if affinity:
         with (jax.named_scope(COMMIT_AFFINITY_SCOPE) if scoped
               else contextlib.nullcontext()):
-            state = state._replace(aff_cnt=add_row(
-                state.aff_cnt, (sel, jnp.maximum(p.cls, 0)),
-                jnp.where(owns, commit_affinity(p.node, p.cls, p.rs), 0),
-            ))
+            cls, delta = _affinity_entry(p, owns)
+            state = state._replace(
+                aff_cnt=add_row(state.aff_cnt, (sel, cls), delta))
     placed = set_row(placed, p.pod_write, p.placed_val)
     masks = set_row(masks, p.pod_write, p.mask_val)
     failed = set_row(failed, p.failed_write, p.failed_val)

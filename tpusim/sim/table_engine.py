@@ -57,6 +57,7 @@ from tpusim.sim.engine import EV_RETRY, ReplayResult
 from tpusim.sim.step import (
     SELF_SELECT_POLICIES,
     PendingCommit,
+    add_commit_affinity,
     apply_commit,
     block_reduce,
     build_decision,
@@ -336,9 +337,14 @@ def _num_types(types: PodTypes) -> int:
     return int(types.share.cpu.shape[0]) + int(types.whole.cpu.shape[0])
 
 
-def _row_state(state: NodeState, node) -> NodeState:
-    """1-node slice of the cluster state at a dynamic index."""
-    return jax.tree.map(lambda a: lane_write.read_row(a, node), state)
+def _row_state(state: NodeState, node, aff_t=None) -> NodeState:
+    """1-node slice of the cluster state at a dynamic index. With `aff_t`
+    (the affinity counts as the event loop carries them, [classes, N]) the
+    slice's aff_cnt is that leaf's column and state.aff_cnt is not read."""
+    if aff_t is None:
+        return jax.tree.map(lambda a: lane_write.read_row(a, node), state)
+    row = _row_state(state._replace(aff_cnt=None), node)
+    return row._replace(aff_cnt=lane_write.read_column(aff_t, node))
 
 
 def _pad_rank(rank: jnp.ndarray, n_pad: int) -> jnp.ndarray:
@@ -828,6 +834,11 @@ class _TableEngine(NamedTuple):
     # program in which no kernel reads that leaf and no fault step
     # rewrites it (SweepRecord.affinity_deferred)
     affinity_deferred: object
+    # (num_nodes, types) -> bool: whether that event loop keeps the add and
+    # holds the leaf nodes minor, [classes, N] a lane: the flat body of a
+    # program in which a kernel reads the leaf and no fault step rewrites
+    # it (SweepRecord.affinity_nodes_minor)
+    affinity_nodes_minor: object
 
 
 def _make_table_engine(
@@ -863,6 +874,17 @@ def _make_table_engine(
     # aff_cnt rows mid-scan, and overwrites the record of the touched
     # node). Never a caller's choice.
     defer_affinity = not faults and not policies_read_affinity(policies)
+    # Where a kernel DOES read it (GpuClustering) the add and the read stay
+    # in the flat event loop, which then holds the leaf NODES MINOR,
+    # i32[classes, N] a lane, beside its table carry (_run_chunk_impl
+    # transposes once on entry and once after the scan): at sweep width the
+    # dense add and the dense column read pass over full tiles, where
+    # i32[lanes, N, 9] uses nine of a tile's 128 minor entries (PERF.md
+    # section 6, PR 46). Fault steps rewrite [N, 9] rows: they keep that
+    # form, as the blocked body does. `beside`: the flat body's carry is a
+    # pair (table carry, what rides beside it).
+    nodes_minor_affinity = not faults and policies_read_affinity(policies)
+    beside = faults or nodes_minor_affinity
 
     def block_size_of(num_nodes: int, num_types: int) -> int:
         """The block size init_carry lays the carry out with; 0: flat."""
@@ -1258,7 +1280,10 @@ def _make_table_engine(
 
         Under defer_affinity its commit leaves the add into aff_cnt to
         _run_chunk_impl's epilogue: the leaf passes through the loop unread
-        and unwritten.
+        and unwritten. Under nodes_minor_affinity it passes through the
+        same way, and the carry is (table carry, aff_t): the commit's add
+        and the dirty node's counts for the column kernel go to aff_t, the
+        leaf with the nodes on its last axis.
 
         `grouped` (a wide sweep: flat_group_events) makes it one event of
         a group (_run_flat_group). Its carry is then (table carry,
@@ -1296,9 +1321,12 @@ def _make_table_engine(
             if grouped:
                 carry, late = carry
                 slot, *ev = ev
+            aff_t = None
             if faults:
                 carry, fc = carry
                 kind, idx, fpos, farg, faux = ev
+            elif nodes_minor_affinity:
+                carry, aff_t = carry
             (state, score_tbl, sdev_tbl, feas_tbl, pend, dirty,
              placed, masks, failed, arr_cpu, arr_gpu, key, ctr) = carry
             if not faults:
@@ -1328,8 +1356,10 @@ def _make_table_engine(
             # carried buffer is written before anything reads it this
             # iteration, so all updates alias in place (PendingCommit)
             state, placed, masks, failed = _scoped_commit(
-                state, placed, masks, failed, pend,
-                affinity=not defer_affinity)
+                state, placed, masks, failed, pend, affinity=faults)
+            if nodes_minor_affinity:
+                with jax.named_scope("tpusim.commit"):
+                    aff_t = add_commit_affinity(aff_t, pend)
 
             # refresh the one column whose node changed last event (from
             # the just-committed state). Grouped, the tables are not
@@ -1338,7 +1368,7 @@ def _make_table_engine(
             # read below applies the block, and the group's flush writes it
             with jax.named_scope("tpusim.refresh"):
                 col_scores, col_sdev, col_feas = _columns(
-                    _row_state(state, dirty), types, tp, k_rand
+                    _row_state(state, dirty, aff_t), types, tp, k_rand
                 )
                 if grouped:
                     late = LateColumns(*(
@@ -1524,6 +1554,8 @@ def _make_table_engine(
             )
             if faults:
                 new_carry, ys = (new_carry, fc), ys + (fy,)
+            elif nodes_minor_affinity:
+                new_carry = (new_carry, aff_t)
             return ((new_carry, late) if grouped else new_carry), ys
 
         return body
@@ -1534,7 +1566,7 @@ def _make_table_engine(
         fresh pending block, then the flush: each table takes the group's
         columns in one access. The carry that comes back has nothing
         pending."""
-        base = carry[0] if faults else carry
+        base = carry[0] if beside else carry
         n_pol, k_types = base.score_tbl.shape[:2]
         held = max(slots, 1)  # an empty segment still traces the body
         late = LateColumns(
@@ -1549,7 +1581,7 @@ def _make_table_engine(
             body, (carry, late),
             (jnp.arange(slots, dtype=jnp.int32),) + tuple(xs), unroll=4,
         )
-        base = carry[0] if faults else carry
+        base = carry[0] if beside else carry
         with jax.named_scope("tpusim.refresh"):
             base = base._replace(
                 score_tbl=lane_write.write_columns(
@@ -1559,7 +1591,7 @@ def _make_table_engine(
                 feas_tbl=lane_write.write_columns(
                     base.feas_tbl, late.feas, late.idx),
             )
-        return ((base, carry[1]) if faults else base), ys
+        return ((base, carry[1]) if beside else base), ys
 
     # FaultCarry pod-axis pad/trim to the carry's P+1 bookkeeping rows —
     # shared with the shard engine (fault_lane.pad/trim_fault_carry)
@@ -1675,7 +1707,10 @@ def _make_table_engine(
         left the per-event add out (defer_affinity): the segment's counts
         go in here, after its scan, so every carry a caller sees, between
         two chunks, in a checkpoint or into finish, holds the leaf the
-        per-event commit would have left at that event."""
+        per-event commit would have left at that event. Where the flat
+        loop keeps the add (nodes_minor_affinity) it holds the leaf
+        [classes, N], transposed here on the way in and on the way out:
+        the carry a caller sees keeps aff_cnt [N, classes]."""
         base = carry[0] if faults else carry
         n = base.state.num_nodes
         num_pods = pods.cpu.shape[0]
@@ -1702,6 +1737,8 @@ def _make_table_engine(
                 pods, type_id, types, tp, tiebreak_rank, n, num_pods, wts,
                 fault_ops, grouped=group > 1,
             )
+            if nodes_minor_affinity:
+                carry = (carry, base.state.aff_cnt.T)
         out, ys = _scan_events(body, carry, xs, group)
         if defer_affinity and not blocked:
             state = out.state
@@ -1709,6 +1746,9 @@ def _make_table_engine(
                 aff_cnt=state.aff_cnt + chunk_affinity(
                     base.pend, pods, ev_kind, ev_pod, ys[0], n,
                     state.aff_cnt.shape[1])))
+        elif nodes_minor_affinity and not blocked:
+            out, aff_t = out
+            out = out._replace(state=out.state._replace(aff_cnt=aff_t.T))
         return out, ys
 
     def _scan_events(body, carry, xs, group: int):
@@ -1806,6 +1846,9 @@ def _make_table_engine(
             decs, sers,
         )
 
+    def flat(num_nodes: int, types: PodTypes) -> bool:
+        return not block_size_of(num_nodes, _num_types(types))
+
     return _TableEngine(
         replay=_replay_impl,
         init_carry=init_carry,
@@ -1817,5 +1860,7 @@ def _make_table_engine(
         ),
         closes_over=(tuple(fn for fn, _ in policies), sel_idx),
         affinity_deferred=lambda num_nodes, types: (
-            defer_affinity and not block_size_of(num_nodes, _num_types(types))),
+            defer_affinity and flat(num_nodes, types)),
+        affinity_nodes_minor=lambda num_nodes, types: (
+            nodes_minor_affinity and flat(num_nodes, types)),
     )
