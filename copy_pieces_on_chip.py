@@ -2,15 +2,17 @@
 """One chip measurement, in no cell's path (ISSUE 43): what the readback's
 copy costs whole and in pieces, and where the bytes land on the host.
 
-A sweep's fetch is ONE `np.asarray` of one packed uint8 buffer
+Until PR 47 a sweep's fetch was ONE `np.asarray` of one packed uint8 buffer
 (tpusim/sim/fetch.py): 268,947,140 bytes in the openb cell, at 0.70-0.73
-GB/s in every cell (PERF.md section 7), where a copy of 8 MB runs at 3.4-4.8
-GB/s. ROADMAP S4 asks whether fetching "in pieces" would pay. This script
-decides it, inside a process that has just run the openb cell's own sweep
+GB/s in every cell, where a copy of 8 MB runs at 3.4-4.8 GB/s. ROADMAP S4
+asked whether fetching "in pieces" would pay. This script decided it (the
+fetch has gone in 8 MB pieces, all in flight, into a kept block since; run it
+again to choose the piece size on other hardware), inside a process that has
+just run the openb cell's own sweep
 (2,560 lanes x 512 events, twice: the second wave's device leaves are kept
 and packed by the fetch's own packer), on that very buffer:
 
-  one            np.asarray(packed), as device_fetch does
+  one            np.asarray(packed), as device_fetch did
   k x M MB       the same bytes as k device slices of 8 / 32 / 64 MB,
                  `np.asarray` one after another, or with
                  `copy_to_host_async` issued for all of them first
@@ -106,6 +108,7 @@ def main() -> int:
     repeats = args.repeats
 
     import jax
+    import jax.numpy as jnp
     import numpy as np
 
     from tpusim.compile_cache import enable_compile_cache
@@ -148,7 +151,7 @@ def main() -> int:
     packer = fetch._packer(sig)
 
     def packed():
-        p = packer(leaves)
+        p = jnp.concatenate(packer(leaves))  # the fetch's pieces, whole
         p.block_until_ready()
         return p
 

@@ -203,6 +203,7 @@ def test_the_derived_fields_account_for_the_call(one_sweep):
     start to the end of its last span, blocked or not; in to_dict() (so
     in the run record's timing.sweeps) beside the marks and the bytes."""
     from tpusim.obs.spans import DERIVED_FIELDS
+    from tpusim.sim.fetch import PIECE_BYTES
 
     profile, sim, rec, _, fetched = one_sweep
     values = [getattr(rec, name) for name in DERIVED_FIELDS]
@@ -235,10 +236,15 @@ def test_the_derived_fields_account_for_the_call(one_sweep):
     assert shared == [np.dtype(np.int32)] * 5
     a_lane = {shape[1:] for shape, _ in fetched if shape != (n,)}
     assert {(n,), (n, 8), (n, 9)} <= a_lane  # cpu_left, gpu_left, aff_cnt
+    # a buffer of at most one piece: one transfer, no landing block
+    assert want <= PIECE_BYTES
     assert fetch.meta == {"events": 3 * rec.events, "bytes": want,
+                          "fetch_pieces": 1, "landing_reused": 0,
                           "shared_bytes": 20 * n}
     d = rec.to_dict()
     assert d["fetch_bytes"] == want
+    assert (d["fetch_pieces"], d["landing_reused"]) == (
+        rec.fetch_pieces, rec.landing_reused) == (1, 0)
     for name, value in zip(DERIVED_FIELDS, values):
         assert d[name] == round(value, 6)
     by_name = {s["name"]: s for s in d["spans"]}
@@ -280,7 +286,8 @@ def test_device_fetch_with_and_without_marks_returns_the_same_bits():
             assert x is y is w
     span = rec.spans[0]
     assert list(span.marks) == ["ready", "copied"]
-    assert span.meta == {"bytes": 4 * 12 + 4 * 35 + 240 + 0 + 4}
+    assert span.meta == {"bytes": 4 * 12 + 4 * 35 + 240 + 0 + 4,
+                         "fetch_pieces": 1, "landing_reused": 0}
     # nothing to move: no mark, no bytes, the tree itself
     with rec.span("fetch") as h:
         assert device_fetch({"none": None, "n": 3}, marks=h) == {

@@ -225,8 +225,17 @@ class SweepRecord:
     # total every event), read off the policies; 0 for raw-score families
     normalized_policies: int = 0
     # bytes of the packed buffer the fetch span moved to the host
-    # (sim/fetch.device_fetch: every lane's result in one transfer)
+    # (sim/fetch.device_fetch: every lane's result in one packed buffer)
     fetch_bytes: int = 0
+    # transfers that buffer left the device in: 1 where it is no larger
+    # than one piece (fetch.PIECE_BYTES), else its size over the piece
+    # size, rounded up, every copy started before the first is taken
+    fetch_pieces: int = 0
+    # 1 where the pieces landed in a host block an earlier fetch had
+    # touched (no array cut from it was alive any more), 0 where the
+    # fetch had to allocate one or made one transfer; over a window its
+    # mean is the landing blocks' hit share
+    landing_reused: int = 0
     # Sub hypotheticals ONE column computation of the sweep's program
     # evaluates a lane (table_engine.sub_requests): the type set's distinct
     # (gpu_milli, gpu_num) requests where every scoring kernel takes its
@@ -349,6 +358,8 @@ class SweepRecord:
             "weight_rows": self.weight_rows,
             "normalized_policies": self.normalized_policies,
             "fetch_bytes": self.fetch_bytes,
+            "fetch_pieces": self.fetch_pieces,
+            "landing_reused": self.landing_reused,
             "sub_requests": self.sub_requests,
             "delete_events": self.delete_events,
             "rejected_creates": self.rejected_creates,

@@ -4330,6 +4330,8 @@ def schedule_pods_sweep(
         with obs.span("fetch", events=true_events) as h:
             out, amounts, watts = device_fetch((out, amounts, watts), marks=h)
             sweep.fetch_bytes = h.meta.get("bytes", 0)
+            sweep.fetch_pieces = h.meta.get("fetch_pieces", 0)
+            sweep.landing_reused = h.meta.get("landing_reused", 0)
             # of them, what the lanes share: the capacity leaves, once
             h.note(shared_bytes=sum(
                 getattr(out.state, f).nbytes for f in CAPACITY_LEAVES))
