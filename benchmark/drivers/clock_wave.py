@@ -209,11 +209,10 @@ def run(ctx) -> dict:
                 + [d for _, d in compare.counter_differences(lane, n_events)])
         return worst
 
-    one_wave(0)  # the warm wave: loads or compiles every program of the window
-    t_warm = time.perf_counter() - t_mark
+    warm = wave.warm_up(one_wave, traffic)
     setup_s = time.perf_counter() - ctx.t_start
     say(f"set-up {setup_s:.3f} s: inputs and the stream's window "
-        f"{t_inputs:.3f}, simulator {t_sim:.3f}, warm wave {t_warm:.3f}; "
+        f"{t_inputs:.3f}, simulator {t_sim:.3f}, warm waves {warm}; "
         f"{len(nodes)} nodes, {n_events} events = {n_events - n_deletes} "
         f"creations + {n_deletes} deletions over {len(window)} pods, {lanes} "
         f"lanes, engine {sim._last_engine}; cache {cache_dir}")
@@ -316,13 +315,9 @@ def run(ctx) -> dict:
             "wave_s": statistics.median(walls),
             "setup_s": setup_s,
         },
-        "waves": [{"wall_s": w["wall_s"],
-                   "scan_block_s": wave.span_seconds(
-                       w["spans"], "scan", "block_s"),
-                   "fetch_s": (wave.span_seconds(
-                       w["spans"], "fetch", "dispatch_s")
-                       + wave.span_seconds(w["spans"], "fetch", "block_s"))}
-                  for w in waves],
+        "waves": [wave.wave_account(w) for w in waves],
+        **wave.window_account(walls, warm, t_inputs, t_sim, setup_s),
+        "checks": checks,
         "spans_blocked": bool(ctx.trace),
         "shape": shape,
         "traced": traced,

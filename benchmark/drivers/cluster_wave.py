@@ -14,10 +14,11 @@ its lane to FGD's scoring rule, so this kind has a `correct` of its own.
 After the window, and in no metric: every lane of every wave is held to the
 in-scan counter identities with its own event count and carries series of
 its own length, the window may not compile, and every sweep record reads
-what the traffic file's `record_must_read` says (fields a program does not
-have, as the parent of the PR that brought them, are passed over); ONE lane
-of the last wave, drawn from `--seed`, (i) equals the sequential oracle's
-whole replay of its (shuffle, seed) with the report on: placements, masks,
+what the traffic file's `record_must_read` and `record_must_read_too` say
+(the second key holds what came later: an accepted tier-1 test pins the
+first one's entries; fields a program does not have, as the parent of the
+PR that brought them, are passed over); ONE lane of the last wave, drawn
+from `--seed`, (i) equals the sequential oracle's whole replay of its (shuffle, seed) with the report on: placements, masks,
 flags, every NodeState field and every integer series bit for bit, the
 float series within `load_wave.FLOAT_LIMITS`; (ii) equals the plain numpy
 reference's whole replay (`lib/reference_clustering.py`: its own Filter,
@@ -177,12 +178,11 @@ def run(ctx) -> dict:
                 d for _, d in compare.counter_differences(lane, events)])
         return worst
 
-    one_wave(0)  # the warm wave: loads or compiles every program of the window
-    t_warm = time.perf_counter() - t_mark
+    warm = wave.warm_up(one_wave, traffic)
     setup_s = time.perf_counter() - ctx.t_start
     say(f"set-up {setup_s:.3f} s: inputs and the reference's {t_inputs:.3f}, "
-        f"simulator and {len(traces)} traces {t_sim:.3f}, warm wave "
-        f"{t_warm:.3f}; {len(nodes)} nodes, events by shuffle {events_of} "
+        f"simulator and {len(traces)} traces {t_sim:.3f}, warm waves "
+        f"{warm}; {len(nodes)} nodes, events by shuffle {events_of} "
         f"({wave_events} real lane-events a wave), {lanes} lanes, policies "
         f"{list(cfg.policies)}, devices by {cfg.gpu_sel_method!r}, engine "
         f"{lead._last_engine}; cache {cache_dir}")
@@ -228,7 +228,8 @@ def run(ctx) -> dict:
                max(counter_gaps), 0),
               ("compiles inside the window", compiles.compiles, 0)]
     checks += record_gaps([w["record"] for w in waves],
-                          traffic.get("record_must_read", {}), say)
+                          {**traffic.get("record_must_read", {}),
+                           **traffic.get("record_must_read_too", {})}, say)
     last = waves[-1]
     rng = np.random.default_rng(ctx.seed)
     i = int(rng.integers(lanes))
@@ -296,13 +297,10 @@ def run(ctx) -> dict:
             "wave_s": statistics.median(walls),
             "setup_s": setup_s,
         },
-        "waves": [{"wall_s": w["wall_s"], "rejected": w["rejected"],
-                   "scan_block_s": wave.span_seconds(
-                       w["spans"], "scan", "block_s"),
-                   "fetch_s": (wave.span_seconds(
-                       w["spans"], "fetch", "dispatch_s")
-                       + wave.span_seconds(w["spans"], "fetch", "block_s"))}
+        "waves": [dict(wave.wave_account(w), rejected=w["rejected"])
                   for w in waves],
+        **wave.window_account(walls, warm, t_inputs, t_sim, setup_s),
+        "checks": checks,
         "spans_blocked": bool(ctx.trace),
         "shape": shape,
         "real_events": wave_events,

@@ -172,12 +172,11 @@ def run(ctx) -> dict:
                                    compare.counter_differences(lane, events)])
         return worst
 
-    one_wave(0)  # the warm wave: loads or compiles every program of the window
-    t_warm = time.perf_counter() - t_mark
+    warm = wave.warm_up(one_wave, traffic)
     setup_s = time.perf_counter() - ctx.t_start
     say(f"set-up {setup_s:.3f} s: inputs {t_inputs:.3f}, {len(sims)} "
         f"simulators and {len(sims) * len(tuning_seeds)} traces {t_sim:.3f}, "
-        f"warm wave {t_warm:.3f}; {len(nodes)} nodes, {events} events, "
+        f"warm waves {warm}; {len(nodes)} nodes, {events} events, "
         f"{lanes} lanes of {families}, typical pods "
         f"{[int(s.typical.cpu.shape[0]) for s in sims]}, engine "
         f"{lead._last_engine}; cache {cache_dir}")
@@ -282,13 +281,9 @@ def run(ctx) -> dict:
             "wave_s": statistics.median(walls),
             "setup_s": setup_s,
         },
-        "waves": [{"wall_s": w["wall_s"],
-                   "scan_block_s": wave.span_seconds(
-                       w["spans"], "scan", "block_s"),
-                   "fetch_s": (wave.span_seconds(
-                       w["spans"], "fetch", "dispatch_s")
-                       + wave.span_seconds(w["spans"], "fetch", "block_s"))}
-                  for w in waves],
+        "waves": [wave.wave_account(w) for w in waves],
+        **wave.window_account(walls, warm, t_inputs, t_sim, setup_s),
+        "checks": checks,
         "spans_blocked": bool(ctx.trace),
         "shape": shape,
         "traced": traced,

@@ -310,12 +310,11 @@ def run(ctx) -> dict:
     def rejected(w) -> int:
         return sum(int(lane.counters[2]) for lane in w["lanes"])
 
-    one_wave(0)  # the warm wave: loads or compiles every program of the window
-    t_warm = time.perf_counter() - t_mark
+    warm = wave.warm_up(one_wave, traffic)
     setup_s = time.perf_counter() - ctx.t_start
     say(f"set-up {setup_s:.3f} s: inputs and the reference's {t_inputs:.3f}, "
-        f"simulator and {len(traces)} traces {t_sim:.3f}, warm wave "
-        f"{t_warm:.3f}; {len(nodes)} nodes, events by shuffle {events_of} "
+        f"simulator and {len(traces)} traces {t_sim:.3f}, warm waves "
+        f"{warm}; {len(nodes)} nodes, events by shuffle {events_of} "
         f"({wave_events} real lane-events a wave), {lanes} lanes, typical "
         f"pods {int(lead.typical.cpu.shape[0])}, engine {lead._last_engine}; "
         f"cache {cache_dir}")
@@ -435,13 +434,10 @@ def run(ctx) -> dict:
             "wave_s": statistics.median(walls),
             "setup_s": setup_s,
         },
-        "waves": [{"wall_s": w["wall_s"], "rejected": w["rejected"],
-                   "scan_block_s": wave.span_seconds(
-                       w["spans"], "scan", "block_s"),
-                   "fetch_s": (wave.span_seconds(
-                       w["spans"], "fetch", "dispatch_s")
-                       + wave.span_seconds(w["spans"], "fetch", "block_s"))}
+        "waves": [dict(wave.wave_account(w), rejected=w["rejected"])
                   for w in waves],
+        **wave.window_account(walls, warm, t_inputs, t_sim, setup_s),
+        "checks": checks,
         "spans_blocked": bool(ctx.trace),
         "shape": shape,
         "real_events": wave_events,

@@ -183,11 +183,10 @@ def run(ctx) -> dict:
                                    compare.counter_differences(lane, events)])
         return worst
 
-    one_wave(0)  # the warm wave: loads or compiles every program of the window
-    t_warm = time.perf_counter() - t_mark
+    warm = wave.warm_up(one_wave, traffic)
     setup_s = time.perf_counter() - ctx.t_start
     say(f"set-up {setup_s:.3f} s: inputs {t_inputs:.3f}, simulator and "
-        f"{len(traces)} traces {t_sim:.3f}, warm wave {t_warm:.3f}; "
+        f"{len(traces)} traces {t_sim:.3f}, warm waves {warm}; "
         f"{len(nodes)} nodes, {events} events, {lanes} lanes = {len(rows)} "
         f"weight rows {rows} x {len(tuning_seeds)} shuffles x {per_shuffle} "
         f"seeds, typical pods {int(lead.typical.cpu.shape[0])}, engine "
@@ -309,13 +308,9 @@ def run(ctx) -> dict:
             "wave_s": statistics.median(walls),
             "setup_s": setup_s,
         },
-        "waves": [{"wall_s": w["wall_s"],
-                   "scan_block_s": wave.span_seconds(
-                       w["spans"], "scan", "block_s"),
-                   "fetch_s": (wave.span_seconds(
-                       w["spans"], "fetch", "dispatch_s")
-                       + wave.span_seconds(w["spans"], "fetch", "block_s"))}
-                  for w in waves],
+        "waves": [wave.wave_account(w) for w in waves],
+        **wave.window_account(walls, warm, t_inputs, t_sim, setup_s),
+        "checks": checks,
         "spans_blocked": bool(ctx.trace),
         "shape": shape,
         "traced": traced,

@@ -5,9 +5,11 @@ weights[B, n_pol], seeds[B])`, timed from the call to the returned
 [SweepLane]: host prep, table build, the vmapped scan, the one packed
 fetch and the per-lane slicing, which is what a caller of
 `Simulator.run_sweep` waits for. Set-up builds the cluster and the trace
-from the config and `--seed` and runs one warm wave of the exact shapes;
-the window then runs waves with fresh lane seeds until `--seconds` is up
-and lets the wave in flight finish. After the window, and in no metric,
+from the config and `--seed` and runs the traffic file's `warm_waves` (default
+1) of the exact shapes, each while the one before it is still held, as the
+window holds its last wave (`warm_up`); the window then runs waves with
+fresh lane seeds until `--seconds` is up and lets the wave in flight
+finish. After the window, and in no metric,
 `check_lanes` lanes of the last wave are replayed standalone on the
 sequential oracle and compared bit for bit; every lane of every wave is
 held to the in-scan counter identities, and the window may not compile.
@@ -28,9 +30,13 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
+STALL = 1.5  # a wave over this many median walls of its window is stalled
+
+
 def lane_seeds(seed: int, wave: int, lanes: int) -> list[int]:
     """`seed*10^6 + wave*lanes + i`, folded into 31 bits: distinct within a
-    wave and from wave to wave, and a pure function of `--seed`."""
+    wave and from wave to wave, and a pure function of `--seed`. The warm
+    waves are 0, -1, ...; the window's 1, 2, ..."""
     base = seed * 10**6 + wave * lanes
     return [(base + i) % LANE_SEED_MOD for i in range(lanes)]
 
@@ -131,6 +137,65 @@ def span_seconds(spans, name: str, part: str) -> float:
     return sum(getattr(sp, part) for sp in spans if sp.name == name)
 
 
+def warm_up(one_wave, traffic: dict) -> list[float]:
+    """The traffic file's `warm_waves` (default 1) before the window; their
+    walls. The first loads or compiles every program of the window. Each
+    later one runs while the wave before it is still held, as a window's
+    wave runs while the last one's lanes are kept: it finds the readback's
+    landing block taken and pays for the second block's fresh pages (0.4 s
+    at 2,560 lanes, PERF.md section 5), which a window behind ONE warm wave
+    pays in its second wave; and what a compile in the first leaves behind
+    in the process is out of the window. All of it is `setup_s`."""
+    walls, held = [], None
+    for k in range(int(traffic.get("warm_waves", 1))):
+        held = one_wave(-k)  # the wave before stays alive until this returns
+        walls.append(held["wall_s"])
+    del held
+    return walls
+
+
+def stalled_waves(walls) -> int:
+    """Waves of the window over STALL x its median wall. They are counted,
+    printed and left IN every end-to-end metric: their lanes are correct."""
+    mid = statistics.median(walls)
+    return sum(1 for x in walls if x > STALL * mid)
+
+
+POSTPASS_SPANS = ("frag_postpass", "event_metrics")
+
+
+def wave_account(w: dict) -> dict:
+    """What the layer metrics keep of one window wave: its wall and, from
+    the program's spans, the scan's block, the whole fetch, the table
+    build and the post-passes (frag amounts and watts; the per-event report
+    where it is on)."""
+    spans = w["spans"]
+
+    def whole(*names):
+        return sum(span_seconds(spans, n, "dispatch_s")
+                   + span_seconds(spans, n, "block_s") for n in names)
+
+    return {"wall_s": w["wall_s"],
+            "scan_block_s": span_seconds(spans, "scan", "block_s"),
+            "fetch_s": whole("fetch"),
+            "table_build_s": whole("init_tables"),
+            "postpass_s": whole(*POSTPASS_SPANS)}
+
+
+def window_account(walls, warm_walls, t_inputs: float, t_sim: float,
+                   setup_s: float) -> dict:
+    """What every wave driver returns beside its metrics for the line's
+    `window`: the stalled waves, the warm waves, and `setup_s` in the three
+    parts the driver times with the rest (imports, the device, the compile
+    cache's placing) before them."""
+    return {"stalled_waves": stalled_waves(walls),
+            "warm_waves": len(warm_walls),
+            "setup_parts": {
+                "inputs_s": t_inputs, "simulator_s": t_sim,
+                "warm_waves_s": list(warm_walls),
+                "before_s": setup_s - t_inputs - t_sim - sum(warm_walls)}}
+
+
 def run(ctx) -> dict:
     from tpusim.compile_cache import enable_compile_cache
     from tpusim.sim import driver
@@ -179,11 +244,10 @@ def run(ctx) -> dict:
                                    compare.counter_differences(lane, events)])
         return worst
 
-    wave(0)  # the warm wave: loads or compiles every program of the window
-    t_warm = time.perf_counter() - t_mark
+    warm = warm_up(wave, traffic)
     setup_s = time.perf_counter() - ctx.t_start
     say(f"set-up {setup_s:.3f} s: inputs {t_inputs:.3f}, simulator and trace "
-        f"{t_sim:.3f}, warm wave {t_warm:.3f}; {len(nodes)} nodes, {events} "
+        f"{t_sim:.3f}, warm waves {warm}; {len(nodes)} nodes, {events} "
         f"events, {lanes} lanes, engine {sim._last_engine}; cache {cache_dir}")
 
     # ---- the window
@@ -262,11 +326,9 @@ def run(ctx) -> dict:
             "wave_s": statistics.median(walls),
             "setup_s": setup_s,
         },
-        "waves": [{"wall_s": w["wall_s"],
-                   "scan_block_s": span_seconds(w["spans"], "scan", "block_s"),
-                   "fetch_s": (span_seconds(w["spans"], "fetch", "dispatch_s")
-                               + span_seconds(w["spans"], "fetch", "block_s"))}
-                  for w in waves],
+        "waves": [wave_account(w) for w in waves],
+        **window_account(walls, warm, t_inputs, t_sim, setup_s),
+        "checks": checks,
         "spans_blocked": bool(ctx.trace),
         "shape": shape,
         "traced": traced,

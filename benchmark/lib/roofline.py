@@ -17,6 +17,18 @@ def scan_bytes_per_lane_event(nodes: int, pod_types: int, policies: int) -> int:
     return nodes * (4 * policies + 1) + pod_types * (4 * policies + 5)
 
 
+def stream_bytes_per_lane_event(nodes: int, pod_types: int, policies: int,
+                                delete_share: float) -> float:
+    """The same, averaged over a stream of which `delete_share` of the
+    events are deletions. A deletion picks no node, so it reads no node row
+    of any table (the select's `nodes * (4 * policies + 1)` bytes); the
+    node it frees is dirty as a bound one is, so its column is rewritten
+    over all K pod types as a creation's is. With no deletions this is
+    `scan_bytes_per_lane_event`."""
+    return (scan_bytes_per_lane_event(nodes, pod_types, policies)
+            - delete_share * nodes * (4 * policies + 1))
+
+
 def carry_bytes_per_lane(nodes: int, pod_types: int, policies: int,
                          pods: int, events: int) -> int:
     """What one lane of a wave carries through its scan, from shapes alone

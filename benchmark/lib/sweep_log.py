@@ -2,15 +2,16 @@
 `schedule_pods_sweep` call, eight flat phase spans and the compile counts
 over the call), found again after the driver has dropped the Simulator.
 
-The `wave` driver runs one warm wave, N = run["attempted"] window waves
-and, in a traced run, one more wave under the profiler; the oracle replays
-through `run_events` and adds no record. So the log's last N + 1 records
-are the window's and the traced wave's, and the one before them is the
-warm wave's (not the first of the process: tests run several cells in
-one). That reading is checked against what the driver itself timed, and
-anything that does not line up reads as nothing: a program without the
-log (the parent of the PR that brought it), an untraced run whose spans
-did not block, a log too short, records that are not consecutive, or a
+The wave drivers run W = run["warm_waves"] warm waves (1 where the run
+does not say), N = run["attempted"] window waves and, in a traced run, one
+more wave under the profiler; the oracle replays through `run_events` and
+adds no record. So the log's last N + 1 records are the window's and the
+traced wave's, and the W before them are the warm waves', the FIRST of
+which loaded or compiled the window's programs (not the first of the
+process: tests run several cells in one). That reading is checked against
+what the driver itself timed, and anything that does not line up reads as
+nothing: a program without the log (the parent of the PR that brought it),
+an untraced run whose spans did not block, a log too short, records that are not consecutive, or a
 record whose wall is not just under the wall the driver measured around
 the same call.
 """
@@ -21,7 +22,7 @@ WALL_TOLERANCE = 0.01  # a record's wall is inside the driver's, within 1 %
 
 
 def records(run):
-    """(warm wave's record, [the window's records]) or None."""
+    """(the first warm wave's record, [the window's records]) or None."""
     try:
         from tpusim.obs.spans import sweep_log
     except ImportError:
@@ -29,15 +30,15 @@ def records(run):
     waves = run.get("waves")
     if not run.get("spans_blocked") or not waves:
         return None
-    n = len(waves)
-    tail = sweep_log()[-(n + 2):]
-    if len(tail) != n + 2:
+    n, warm_waves = len(waves), int(run.get("warm_waves", 1))
+    tail = sweep_log()[-(warm_waves + n + 1):]
+    if len(tail) != warm_waves + n + 1:
         return None
-    if [r.id for r in tail] != list(range(tail[0].id, tail[0].id + n + 2)):
+    if [r.id for r in tail] != list(range(tail[0].id, tail[0].id + len(tail))):
         return None
     if not all(r.blocked for r in tail):
         return None
-    warm, window = tail[0], tail[1:-1]
+    warm, window = tail[0], tail[warm_waves:-1]
     for rec, wave in zip(window, waves):
         outer = wave["wall_s"]
         if not (1.0 - WALL_TOLERANCE) * outer <= rec.wall_s <= outer:

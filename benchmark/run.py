@@ -1,6 +1,7 @@
 """One run of one cell: `python benchmark/run.py --workload <cell> --seed
 <n> --seconds <s> --trace <0|1>`. The last line of standard output is the
-result object.
+result object; its last key, and the last lines of standard error, are the
+numbers `correct` compared, each beside its limit.
 
 This file only resolves names. BENCHMARK.json names the cell's
 configuration file and traffic mix; `traffic/<mix>.json` names its driver
@@ -66,6 +67,11 @@ def by_name(entries: list, name: str, what: str) -> dict:
             return entry
     raise KeyError(f"BENCHMARK.json has no {what} {name!r}; it has: "
                    f"{[e['name'] for e in entries]}")
+
+
+def plain(number):
+    """A numpy scalar as the Python number json can print."""
+    return number.item() if hasattr(number, "item") else number
 
 
 def listed_for(metric: dict, cell: str) -> bool:
@@ -146,11 +152,27 @@ def execute(args) -> dict:
                                "idle_gaps": run["traced"]["idle_gaps"]}
     if args.rehearse:
         result["rehearsal"] = True
+    # what the driver ignores and a reader of the line wants: the window's
+    # walls, how many of them stalled, set-up by its parts
+    if run.get("waves"):
+        result["window"] = {
+            "wall_s": [w["wall_s"] for w in run["waves"]],
+            "stalled_waves": run.get("stalled_waves"),
+            "warm_waves": run.get("warm_waves", 1),
+            "setup_parts": run.get("setup_parts")}
+    # last: every number `correct` compared, beside its limit
+    result["checks"] = {what: {"value": plain(got), "limit": plain(limit)}
+                        for what, got, limit in run.get("checks", [])}
     return result
 
 
 def main(argv=None) -> int:
-    print(json.dumps(execute(parse(argv))), flush=True)
+    result = execute(parse(argv))
+    print(json.dumps(result), flush=True)
+    for what, c in result["checks"].items():
+        print(f"check: {what}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
+    print(f"correct: {result['correct']}", file=sys.stderr, flush=True)
     return 0
 
 

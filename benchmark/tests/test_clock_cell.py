@@ -49,9 +49,11 @@ def test_the_cell_is_the_one_the_issue_names():
     cell = bench_run.by_name(bench["workloads"], CELL, "workload")
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "openb-clock", "clock-seeds", 1)
-    assert bench["workloads"][-1] == cell and len(cell["why"]) <= 200
+    assert len(cell["why"]) <= 200
     entry = bench_run.by_name(bench["configs"], "openb-clock", "config")
-    assert bench["configs"][-1] == entry
+    # by name, not by position: later PRs append after them
+    assert [w["name"] for w in bench["workloads"]
+            if w["config"] == "openb-clock"] == [CELL]
     assert entry["reduced"] == ["depth_events"]
     config = bench_run.load_json(os.path.join(REPO, entry["file"]))
     assert config["source"] == entry["source"] and len(entry["source"]) <= 200
@@ -84,7 +86,9 @@ def test_the_cell_is_the_one_the_issue_names():
     listed = {m["name"]: m for m in bench["per_layer"]
               if m["name"] in NEW_METRICS}
     assert set(listed) == NEW_METRICS
-    assert [m["name"] for m in bench["per_layer"][-9:]] == [
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("clock_step_us_per_lane_event")
+    assert names[at:at + 9] == [
         "clock_step_us_per_lane_event", "delete_share"] + [
         f"clock_{name}" for name in SHARED]
     step = next(m for m in bench["per_layer"] if m["name"] == "scan_s")
@@ -99,12 +103,15 @@ def test_the_cell_is_the_one_the_issue_names():
     for name in SHARED:
         assert by_name[f"clock_{name}"] == dict(
             by_name[name], name=f"clock_{name}", workloads=[CELL])
-        assert by_name[name]["workloads"] == ["openb.fgd-seeds"]
+        # the control's own list holds the control; a `benchmark` PR may
+        # list further cells on it (PR 48: the family cell)
+        assert "openb.fgd-seeds" in by_name[name]["workloads"]
+        assert CELL not in by_name[name]["workloads"]
         reader = bench_run.load_module("layer_metrics", f"clock_{name}").read
         assert reader.__module__ == f"benchmark.layer_metrics.{name}"
     # nothing the benchmark had lists the new cell: it cannot move them
     assert all(CELL not in m.get("workloads", [])
-               for m in bench["per_layer"][:-9])
+               for m in bench["per_layer"][:at])
 
 
 def test_the_window_is_the_streams_first_events_or_the_driver_raises():
@@ -186,10 +193,10 @@ def test_traced_line_reads_the_list_less_metrics_and_the_two_new(capsys):
     assert got["correct"] is True
     # scan_roofline and device_idle_pct are a chip's: a rehearsal has no
     # device time to divide by
-    assert set(got["metrics"]) == (
-        NEW_METRICS | {"host_s", "scan_s", "fetch_s"}
-        | {f"clock_{name}" for name in SHARED})
-    for name in got["metrics"]:
+    expected = (NEW_METRICS | {"host_s", "scan_s", "fetch_s"}
+                | {f"clock_{name}" for name in SHARED})
+    assert set(got["metrics"]) >= expected
+    for name in expected:
         assert got["metrics"][name]["value"] > 0, name
     assert got["metrics"]["delete_share"] == {"value": 18 / 64, "unit": "share"}
     value = {k: v["value"] for k, v in got["metrics"].items()}

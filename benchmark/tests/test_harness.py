@@ -23,6 +23,9 @@ sys.path.insert(0, BENCH)
 import run as bench_run  # noqa: E402  (benchmark/run.py)
 
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# what the driver ignores: the window's walls, stalled waves and set-up by
+# parts; and, LAST in the line, every number compared beside its limit
+EXTRA_KEYS = {"window", "checks"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 CELL = "synth100k.fgd-seeds"
 
@@ -74,7 +77,20 @@ def test_a_backend_that_is_not_a_tpu_is_an_error_without_rehearse(capsys):
 
 def test_end_to_end_line_is_the_contracts_object(capsys):
     got = rehearse(capsys)
-    assert set(got) == CONTRACT_KEYS | {"rehearsal"}
+    assert set(got) == CONTRACT_KEYS | EXTRA_KEYS | {"rehearsal"}
+    assert list(got)[-1] == "checks" and got["checks"]
+    assert all(c["value"] <= c["limit"] for c in got["checks"].values())
+    assert "compiles inside the window" in got["checks"]
+    window = got["window"]
+    assert len(window["wall_s"]) == got["attempted"]
+    # (a rehearsal keeps ONE warm wave; the cell's own two are rehearsed in
+    # test_steady_window.py)
+    assert window["warm_waves"] == 1 and window["stalled_waves"] >= 0
+    parts = window["setup_parts"]
+    assert len(parts["warm_waves_s"]) == 1
+    assert (parts["before_s"] + parts["inputs_s"] + parts["simulator_s"]
+            + sum(parts["warm_waves_s"])) == pytest.approx(
+        got["metrics"]["setup_s"]["value"])
     assert set(got["device"]) == DEVICE_KEYS
     assert got["correct"] is True and got["failed"] == 0 and got["attempted"] >= 1
     bench = bench_run.load_json(os.path.join(REPO, "BENCHMARK.json"))
@@ -86,7 +102,8 @@ def test_end_to_end_line_is_the_contracts_object(capsys):
 
 def test_traced_line_has_span_metrics_busy_window_and_breakdown(capsys):
     got = rehearse(capsys, trace=1)
-    assert set(got) == CONTRACT_KEYS | {"rehearsal", "breakdown"}
+    assert set(got) == CONTRACT_KEYS | EXTRA_KEYS | {"rehearsal", "breakdown"}
+    assert list(got)[-1] == "checks"
     assert set(got["device"]) == DEVICE_KEYS | {"busy_s", "window_s"}
     assert 0 < got["device"]["busy_s"] <= got["device"]["window_s"]
     # span metrics are read; the device metrics find no device plane to

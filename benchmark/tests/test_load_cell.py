@@ -73,13 +73,15 @@ def test_the_cell_is_the_one_the_issue_names():
     assert traffic["seeds_per_shuffle"] in (16, 24, 32, 48, 64)
     assert traffic["lanes"] == 10 * traffic["seeds_per_shuffle"]
     assert traffic["scored_creates"] >= 1024
+    # by name, not by position: later PRs append after them
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(NEW):] == NEW
-    for m in bench["per_layer"][-len(NEW):]:
+    at = names.index(NEW[0])
+    assert names[at:at + len(NEW)] == NEW
+    for m in bench["per_layer"][at:at + len(NEW)]:
         assert m["workloads"] == [CELL]
     # nothing the benchmark had lists the new cell: it cannot move them
     assert all(CELL not in m.get("workloads", [])
-               for m in bench["per_layer"][:-len(NEW)])
+               for m in bench["per_layer"][:at])
 
 
 def test_the_references_shuffle_and_tuning_are_the_programs():

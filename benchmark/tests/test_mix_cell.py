@@ -118,8 +118,8 @@ def test_traced_line_reads_the_list_less_metrics_and_the_four_new(capsys):
     assert got["correct"] is True
     # scan_roofline and device_idle_pct are a chip's: a rehearsal has no
     # device time to divide by
-    assert set(got["metrics"]) == NEW_METRICS | {"host_s", "scan_s", "fetch_s"}
-    for name in got["metrics"]:
+    assert set(got["metrics"]) >= NEW_METRICS | {"host_s", "scan_s", "fetch_s"}
+    for name in NEW_METRICS | {"host_s", "scan_s", "fetch_s"}:
         assert got["metrics"][name]["value"] > 0, name
     assert got["metrics"]["weight_rows"] == {"value": 3, "unit": "rows"}
     assert got["metrics"]["normalized_policies"] == {

@@ -63,10 +63,11 @@ def test_aligned_walls_give_the_windows_records(monkeypatch):
         run, "lane_keys", "lane_ranks") == pytest.approx(0.004 * 2 * 5)
     assert sweep_log.window_median(
         run, lambda r: r.programs_requested) == 1
-    # the warm wave is the record before the window: 2 ms x (1 + .. + 8)
+    # the warm wave is the record before the window: 2 ms x (1 + .. + 6),
+    # its `fetch` and `slice_lanes` (7 and 8) are no dispatch (PR 48)
     warm_dispatch = bench_run.load_module(
         "layer_metrics", "warm_wave_dispatch_s").read(run)
-    assert warm_dispatch == pytest.approx(0.002 * 36)
+    assert warm_dispatch == pytest.approx(0.002 * 21)
 
 
 @pytest.mark.parametrize("damage", [
@@ -99,13 +100,13 @@ def test_what_does_not_line_up_reads_as_nothing(monkeypatch, damage):
 def test_a_rehearsal_prints_the_seven_beside_the_span_metrics_it_had(capsys):
     got = rehearse(capsys, trace=1)
     # the two device metrics find no device plane in a rehearsal
-    assert set(got["metrics"]) == NEW | {"host_s", "scan_s", "fetch_s"}
+    assert set(got["metrics"]) >= NEW | {"host_s", "scan_s", "fetch_s"}
     value = {k: v["value"] for k, v in got["metrics"].items()}
     assert value["programs_requested_per_wave"] >= 1
-    parts = sum(value[k] for k in ("spec_prep_s", "lane_inputs_s",
-                                   "slice_lanes_s", "table_build_s",
-                                   "frag_postpass_s"))
+    # `host_s` no longer holds the table build and the post-pass (PR 48);
     # medians of parts against a median of sums, less the scan's dispatch
+    parts = sum(value[k] for k in ("spec_prep_s", "lane_inputs_s",
+                                   "slice_lanes_s"))
     assert 0 < parts <= 1.1 * value["host_s"]
     assert rehearse(capsys, trace=0)["metrics"].keys() == {
         "lane_events_per_s", "wave_s", "setup_s"}
